@@ -1,0 +1,799 @@
+"""Smallest proof that today's tree starts and computes on the chip.
+
+    python chip_smoke.py             # one v5e chip: kernel_f32, served, density, df
+    python chip_smoke.py --chips 4   # four chips: the sharded phase only
+
+The parent process never imports JAX: every phase is a child process run in
+turn, so each owns the chip alone and ``QUEST_PRECISION`` can differ between
+them. A child refuses any platform but ``tpu``, raises on the first failed
+check (nothing is caught and carried on), and fails if a Pallas kernel it
+launched ran in the interpreter. Earlier lines carry one JSON object per
+phase (sizes, compile/run seconds, measured errors, telemetry counters); the
+LAST line of standard output is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+
+``--rehearse`` is the sandbox rehearsal (on-chip-measurement guide, section
+2): tiny sizes, CPU allowed, interpreted kernels allowed. It exercises the
+control flow only and never prints ``"ok": true``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+#: phase -> QUEST_PRECISION of its child (fixed at quest_tpu import)
+ONE_CHIP_PHASES = {"kernel_f32": "1", "served": "1", "density": "1",
+                   "df": "2"}
+FOUR_CHIP_PHASES = {"sharded": "1"}
+
+#: full sizes / rehearsal sizes per phase. Widths are the deployments' own;
+#: DEPTH is cut so a COLD run (no compile cache) fits the 1200 s the chip
+#: check allows: Mosaic compile time grows steeply with a run's op count
+#: and with the 2^k row-chunks of a folded frame swap (first v5e run, PR 24:
+#: a 24-op prefix of the depth-8 plan's first pass 127 s, a 1-op run with
+#: the plan's k=7 swap 172 s, the 20q depth-1 df plan 142 s), so the 26q
+#: depth-8 plan (9 runs of 37-53 ops, 4 of them swapped) cannot compile
+#: cold inside the limit. Depth 1 keeps both kernel forms -- a plain run
+#: and a run with the frame swap folded into its DMA. The served ansatz is
+#: cut from depth 4 to 2 for the same reason (first run of this script:
+#: 1121 s at depth 4, of which the adjoint-gradient program's XLA compile
+#: was 273 s and the engine's first request 117 s).
+SIZES = {
+    "kernel_f32": {"n": 26, "depth": 1, "n_oracle": 12},
+    "served": {"n": 20, "depth": 2, "requests": 16, "shots": 4096},
+    "density": {"n": 14, "n_oracle": 7},
+    "df": {"n": 20, "depth": 1},
+    "sharded": {"n": 28, "depth": 1},
+}
+REHEARSAL_SIZES = {
+    "kernel_f32": {"n": 14, "depth": 2, "n_oracle": 9},
+    "served": {"n": 10, "depth": 2, "requests": 4, "shots": 2048},
+    "density": {"n": 7, "n_oracle": 5},
+    "df": {"n": 12, "depth": 1},
+    "sharded": {"n": 16, "depth": 2},
+}
+
+SEED = 2026
+
+
+# ---------------------------------------------------------------------------
+# parent: spawn the phases, never touch JAX
+# ---------------------------------------------------------------------------
+
+def _parent(args) -> int:
+    phases = FOUR_CHIP_PHASES if args.chips == 4 else ONE_CHIP_PHASES
+    if args.only:
+        phases = {p: phases[p] for p in args.only.split(",")}
+    device = None
+    for phase, precision in phases.items():
+        env = dict(os.environ, QUEST_PRECISION=precision)
+        cmd = [sys.executable, os.path.abspath(__file__), "--phase", phase,
+               "--chips", str(args.chips)]
+        if args.rehearse:
+            cmd.append("--rehearse")
+        t0 = time.time()
+        proc = subprocess.Popen(cmd, env=env, cwd=HERE,
+                                stdout=subprocess.PIPE, text=True)
+        last = None
+        try:
+            for line in proc.stdout:
+                line = line.rstrip("\n")
+                if line:
+                    print(line, flush=True)
+                    last = line
+            rc = proc.wait()
+        finally:
+            if proc.poll() is None:
+                proc.kill()
+                proc.wait()
+        if rc != 0:
+            print(f"chip_smoke: phase {phase} failed (exit {rc}) after "
+                  f"{time.time() - t0:.0f}s", file=sys.stderr)
+            return rc if rc > 0 else 1
+        row = json.loads(last)
+        if row.get("phase") != phase or not row.get("passed"):
+            print(f"chip_smoke: phase {phase} printed no result row",
+                  file=sys.stderr)
+            return 1
+        if device is not None and row["device"] != device:
+            print(f"chip_smoke: phase {phase} ran on {row['device']}, "
+                  f"earlier phases on {device}", file=sys.stderr)
+            return 1
+        device = row["device"]
+    if args.rehearse:
+        print(json.dumps({"ok": False, "rehearsed": True, "device": device}))
+        return 0
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+# ---------------------------------------------------------------------------
+# child helpers (JAX is imported only below this line's callers)
+# ---------------------------------------------------------------------------
+
+def _log(msg: str) -> None:
+    print(f"# [{time.strftime('%H:%M:%S')}] {msg}", file=sys.stderr,
+          flush=True)
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise SystemExit(f"chip_smoke: FAILED: {what}")
+
+
+def _device(args) -> dict:
+    """The device row; refuses anything but the TPU (outside rehearsal)."""
+    import jax
+
+    devs = jax.devices()
+    d = devs[0]
+    row = {"platform": d.platform, "kind": d.device_kind, "count": len(devs)}
+    if not args.rehearse:
+        _require(d.platform == "tpu" and jax.default_backend() == "tpu",
+                 f"no TPU: jax.devices()[0].platform is {d.platform!r} "
+                 f"(backend {jax.default_backend()!r})")
+        _require(len(devs) == args.chips,
+                 f"expected {args.chips} chip(s), JAX reports {len(devs)}")
+    from quest_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    return row
+
+
+def _counters(*names) -> dict:
+    from quest_tpu import telemetry
+
+    out = {}
+    for name in names:
+        series = telemetry.counters(name)
+        out[name] = {k or "total": v for k, v in series.items()}
+    return out
+
+
+def _require_compiled_kernels(args, expect_kernels: bool = True) -> dict:
+    """Every Pallas kernel this process launched was compiled by Mosaic,
+    not interpreted; returns {compiled, interpreted} signature counts."""
+    from quest_tpu import telemetry
+
+    evs = [e for e in telemetry.events() if e.get("name") == "pallas.compile"]
+    interp = [e for e in evs if e.get("interpret")]
+    row = {"compiled": len(evs) - len(interp), "interpreted": len(interp)}
+    if not args.rehearse:
+        _require(not interp,
+                 f"{len(interp)} Pallas kernel(s) ran in the INTERPRETER "
+                 f"(first: {interp[:1]})")
+        if expect_kernels:
+            _require(evs, "no Pallas kernel was launched at all")
+    return row
+
+
+def _require_no_fallback() -> dict:
+    from quest_tpu import telemetry
+
+    fb = telemetry.counters("engine_fallback_total")
+    _require(not any(fb.values()),
+             f"kernel runs left for the engine: engine_fallback_total{fb}")
+    return fb
+
+
+def _sync(x) -> None:
+    import jax
+
+    jax.block_until_ready(x)
+
+
+def _amp_errors(got, want) -> tuple:
+    """(max|got-want| / max|want|, ||got-want|| / ||want||), on device."""
+    import jax.numpy as jnp
+
+    d = got - want
+    mx = float(jnp.max(jnp.abs(d)) / jnp.max(jnp.abs(want)))
+    l2 = float(jnp.sqrt(jnp.sum(d * d) / jnp.sum(want * want)))
+    return mx, l2
+
+
+class _NumpyTape:
+    """The gate set of ``__graft_entry__._random_layers`` and
+    ``bench.serving_ansatz`` on a dense numpy state, applied through
+    ``apply(state, targets, matrix, controls)`` -- the independent replay
+    the oracle checks compare against (textbook matrices, QuEST.h
+    conventions; nothing here calls quest_tpu). :func:`_numpy_replay`
+    drives it from a Circuit's tape."""
+
+    def __init__(self, state, apply):
+        self.state = state
+        self._apply = apply
+
+    def _u(self, q, m, controls=()):
+        self.state = self._apply(self.state, (q,), np.asarray(m),
+                                 tuple(controls))
+
+    def hadamard(self, q):
+        self._u(q, np.array([[1, 1], [1, -1]]) / np.sqrt(2))
+
+    def tGate(self, q):
+        self._u(q, np.diag([1, np.exp(0.25j * np.pi)]))
+
+    def rotateZ(self, q, th):
+        self._u(q, np.diag([np.exp(-0.5j * th), np.exp(0.5j * th)]))
+
+    def rotateX(self, q, th):
+        c, s = np.cos(th / 2), np.sin(th / 2)
+        self._u(q, np.array([[c, -1j * s], [-1j * s, c]]))
+
+    def controlledNot(self, c, t):
+        self._u(t, np.array([[0, 1], [1, 0]]), (c,))
+
+    def controlledPhaseFlip(self, q1, q2):
+        self._u(q2, np.diag([1, -1]), (q1,))
+
+
+def _np_apply(n: int):
+    """1-target gate (at most one control) on a 2^n complex128 vector by
+    exposing the target (and control) bit as array axes -- plain numpy on
+    the host, for sizes tests/oracle.py's dense 2^n x 2^n operators cannot
+    reach. Nothing here calls quest_tpu."""
+    def mix(a, b, m):
+        return m[0, 0] * a + m[0, 1] * b, m[1, 0] * a + m[1, 1] * b
+
+    def apply(psi, targets, m, controls):
+        (t,) = targets
+        if not controls:
+            v = psi.reshape(-1, 2, 1 << t)
+            out = np.empty_like(v)
+            out[:, 0], out[:, 1] = mix(v[:, 0], v[:, 1], m)
+            return out.reshape(-1)
+        (c,) = controls
+        lo, hi = sorted((c, t))
+        v = psi.reshape(-1, 2, 1 << (hi - lo - 1), 2, 1 << lo).copy()
+        if c == hi:     # control on axis 1, target on axis 3
+            sub = v[:, 1]
+            sub[:, :, 0], sub[:, :, 1] = mix(sub[:, :, 0].copy(),
+                                             sub[:, :, 1].copy(), m)
+        else:           # control on axis 3, target on axis 1
+            sub = v[:, :, :, 1]
+            sub[:, 0], sub[:, 1] = mix(sub[:, 0].copy(), sub[:, 1].copy(), m)
+        return v.reshape(-1)
+
+    return apply
+
+
+def _numpy_replay(circ, n: int, psi0=None, apply=None):
+    """The final state of a constant-angle Circuit tape replayed through
+    :class:`_NumpyTape` (the tape's entries name the API function they
+    recorded), from ``psi0`` (default |0...0>) with ``apply`` (default the
+    host's :func:`_np_apply`)."""
+    if psi0 is None:
+        psi0 = np.zeros(1 << n, np.complex128)
+        psi0[0] = 1.0
+    tape = _NumpyTape(psi0, apply or _np_apply(n))
+    for fn, fargs, fkwargs in circ._tape:
+        getattr(tape, fn.__name__)(*fargs, **fkwargs)
+    return tape.state
+
+
+def _on_host_meanwhile(fn):
+    """Start ``fn()`` on a host thread (the XLA/Mosaic compile it overlaps
+    holds no GIL); the returned ``result()`` joins and gives
+    ``(value, seconds)``, failing the phase if the thread died."""
+    box = {}
+
+    def work():
+        t0 = time.perf_counter()
+        box["value"] = fn()
+        box["seconds"] = time.perf_counter() - t0
+
+    thread = threading.Thread(target=work, name="host-replay")
+    thread.start()
+
+    def result():
+        thread.join()
+        _require("value" in box, "the host replay thread failed")
+        return box["value"], box["seconds"]
+
+    return result
+
+
+def _first_and_second_run(run, reinit, amps) -> tuple:
+    """(first_s, second_s) of ``run()`` with the register re-initialised
+    by ``reinit()`` in between: the first holds the compile."""
+    times = []
+    for again in (False, True):
+        if again:
+            reinit()
+        _sync(amps())
+        t0 = time.perf_counter()
+        run()
+        _sync(amps())
+        times.append(time.perf_counter() - t0)
+    return tuple(times)
+
+
+def _tests_oracle():
+    sys.path.insert(0, os.path.join(HERE, "tests"))
+    import oracle
+
+    return oracle
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+def phase_kernel_f32(args, size) -> dict:
+    """The headline deployment: 26q f32 register, random Clifford+T circuit,
+    fused two-frame Pallas plan, one HBM pass per run."""
+    import quest_tpu as qt
+    from quest_tpu import telemetry
+    from quest_tpu.circuits import Circuit
+    from __graft_entry__ import _random_layers
+
+    n, depth, n_or = size["n"], size["depth"], size["n_oracle"]
+    env = qt.createQuESTEnv()
+    circ = Circuit(n)
+    _random_layers(circ, n, depth, seed=SEED)
+    fused = circ.fused(max_qubits=5, pallas=True)
+    runs = telemetry.counter_total("fusion_pallas_runs_total")
+    _log(f"kernel_f32: {n}q depth {depth}: {len(circ)} gates -> "
+         f"{len(fused)} plan items, {int(runs)} Pallas runs")
+
+    # the same tape on the host meanwhile: numpy complex128, gate by gate,
+    # at the FULL width
+    host_replay = _on_host_meanwhile(lambda: _numpy_replay(circ, n))
+
+    q = qt.createQureg(n, env)
+    first_s, run_s = _first_and_second_run(
+        lambda: fused.run(q), lambda: qt.initZeroState(q), lambda: q.amps)
+    _log(f"kernel_f32: first run (compile + run) {first_s:.1f}s")
+    prob = float(qt.calcTotalProb(q))
+    _require(abs(prob - 1.0) < 1e-4, f"calcTotalProb {prob!r} off 1 by >1e-4")
+
+    # (The issue asked for the unfused circ.run replay on the chip as the
+    # reference; that program -- 40 engine ops at 2^26 -- took 444 s to
+    # compile for the v5e and emitted 797 MB of code in the sandbox's AOT
+    # compile, so it cannot share a cold 1200 s with the kernels it would
+    # check. The served phase compares the unfused lowering at 20q.)
+    want, oracle_s = host_replay()
+    got = np.asarray(q.amps)
+    scale = float(np.max(np.abs(want)))
+    err_max = max(float(np.max(np.abs(got[0] - want.real))),
+                  float(np.max(np.abs(got[1] - want.imag)))) / scale
+    err_l2 = float(np.sqrt(np.sum((got[0] - want.real) ** 2)
+                           + np.sum((got[1] - want.imag) ** 2)))
+    del got, want
+    _log(f"kernel_f32: numpy complex128 replay at {n}q took {oracle_s:.1f}s")
+    _require(err_max < 5e-4 and err_l2 < 5e-4,
+             f"fused vs numpy complex128 replay: max {err_max:.3e} of the "
+             f"largest amplitude, l2 {err_l2:.3e} of the norm (budget 5e-4)")
+
+    # the same fused plan shape at n_or qubits against the dense oracle
+    oracle = _tests_oracle()
+    small = Circuit(n_or)
+    _random_layers(small, n_or, depth, seed=SEED)
+    qs = qt.createQureg(n_or, env)
+    small.fused(max_qubits=5, pallas=True).run(qs)
+    dense = _numpy_replay(
+        small, n_or, apply=lambda s, t, m, c: oracle.apply_to_statevec(
+            s, n_or, list(t), m, list(c)))
+    err_or = float(np.max(np.abs(qt.get_np(qs) - dense)))
+    _require(err_or < 2e-5, f"{n_or}q fused plan vs tests/oracle.py: "
+             f"max amplitude error {err_or:.3e} (budget 2e-5)")
+
+    kernels = _require_compiled_kernels(args)
+    fallbacks = _require_no_fallback()
+    ctr = _counters("fusion_pallas_runs_total", "pallas_pass_total",
+                    "device_dispatch_total")
+    _require(sum(ctr["fusion_pallas_runs_total"].values()) > 0,
+             "fusion_pallas_runs_total is 0")
+    _require(sum(ctr["pallas_pass_total"].values()) > 0,
+             "pallas_pass_total is 0")
+    return {"qubits": n, "depth": depth, "gates": len(circ),
+            "state_bytes": 8 << n, "plan_items": len(fused),
+            "compile_s": round(first_s - run_s, 3),
+            "run_s": round(run_s, 4),
+            "host_replay_s": round(oracle_s, 1),
+            "total_prob": prob, "err_vs_numpy_max": err_max,
+            "err_vs_numpy_l2": err_l2, "oracle_qubits": n_or,
+            "err_vs_oracle": err_or, "kernels": kernels,
+            "engine_fallback_total": fallbacks, "counters": ctr}
+
+
+def phase_served(args, size) -> dict:
+    """The path clients use: Engine over the all-Param serving ansatz."""
+    import jax
+    import jax.numpy as jnp
+    import quest_tpu as qt
+    from quest_tpu.engine import Engine
+    from quest_tpu.sampling import request as rq
+    import bench
+
+    n, depth = size["n"], size["depth"]
+    nreq, shots = size["requests"], size["shots"]
+    env = qt.createQuESTEnv()
+    circ = bench.serving_ansatz(n, depth)
+    rng = np.random.RandomState(SEED)
+    codes = rng.randint(0, 4, size=(6, n)).astype(np.int32)
+    coeffs = rng.normal(size=6)
+    eng = Engine(circ, env, hamiltonian=(codes, coeffs), max_batch=8)
+    names = eng.param_names
+
+    def draw(i):
+        r = np.random.RandomState(SEED + 1 + i)
+        return {k: float(r.uniform(0, 2 * np.pi)) for k in names}
+
+    sets = [draw(i) for i in range(nreq)]
+
+    # every value-baked twin's tape replayed on the host meanwhile (numpy
+    # complex128, gate by gate)
+    host_replay = _on_host_meanwhile(lambda: [
+        _numpy_replay(bench.serving_ansatz(n, depth, values=p), n)
+        for p in sets])
+
+    t0 = time.perf_counter()
+    first = eng.submit(sets[0]).result(timeout=900)
+    _sync(first)
+    first_s = time.perf_counter() - t0
+    _log(f"served: first request (compile + run) {first_s:.1f}s")
+    t0 = time.perf_counter()
+    futs = [eng.submit(p) for p in sets]
+    results = [f.result(timeout=900) for f in futs]
+    _sync(results)
+    batch_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    warm = [f.result(timeout=900) for f in [eng.submit(p) for p in sets]]
+    _sync(warm)
+    warm_s = time.perf_counter() - t0
+    _log(f"served: {nreq} requests {batch_s:.2f}s, again warm {warm_s:.2f}s")
+
+    # each result against the replay of its value-baked twin: all of them
+    # against the host's numpy replay of the twin's tape, and the last one
+    # also against the twin run on the chip (twin.run: its angles are
+    # constants, so that is one XLA compile PER parameter set -- the eager
+    # API kernels cost more still, one compile per gate site: 141 s here)
+    twins, host_s = host_replay()
+    worst = 0.0
+    for want, got, again in zip(twins, results, warm):
+        for amps in (np.asarray(got), np.asarray(again)):
+            err = np.max(np.abs(amps[0] + 1j * amps[1] - want))
+            worst = max(worst, float(err / np.max(np.abs(want))))
+    _require(worst < 1e-4, f"served results vs value-baked twins (numpy): "
+             f"max {worst:.3e} of the largest amplitude (budget 1e-4)")
+    last = sets[-1]
+    twin = bench.serving_ansatz(n, depth, values=last)
+    q = qt.createQureg(n, env)
+    t0 = time.perf_counter()
+    twin.run(q)
+    _sync(q.amps)
+    twin_s = time.perf_counter() - t0
+    twin_err, _ = _amp_errors(jnp.asarray(warm[-1]), q.amps)
+    _require(twin_err < 1e-4, f"served result vs its twin run on the chip: "
+             f"{twin_err:.3e} of the largest amplitude (budget 1e-4)")
+    # q now holds the twin state of the LAST parameter set
+
+    # one sampling request: circuit + S shots as one dispatched program
+    targets = tuple(range(min(6, n)))
+    exe = rq.sample_request(twin, targets=targets, shots=shots, donate=False)
+    zero = qt.createQureg(n, env)
+    t0 = time.perf_counter()
+    table = rq.to_host(exe(zero.amps, 7))["shots"]
+    sample_s = time.perf_counter() - t0
+    _require(table.shape == (shots,), f"shot table shape {table.shape}")
+    probs = np.asarray(qt.calcProbOfAllOutcomes(q, list(targets)),
+                       dtype=np.float64)
+    freq = np.bincount(table, minlength=1 << len(targets)) / float(shots)
+    marg = float(np.max(np.abs(freq - probs)))
+    _require(marg <= 4.0 / np.sqrt(shots),
+             f"sampled marginals off the exact ones by {marg:.3e} "
+             f"(budget 4/sqrt(S) = {4.0 / np.sqrt(shots):.3e})")
+
+    # one gradient request: value against the twin state, two gradient
+    # components against the parameter-shift rule served by the engine
+    t0 = time.perf_counter()
+    value, grads = eng.submit_grad(last).result(timeout=900)
+    grad_s = time.perf_counter() - t0
+    value = float(value)
+    work = qt.createQureg(n, env)
+    want = float(qt.calcExpecPauliSum(q, codes.ravel().tolist(),
+                                      coeffs.tolist(), work))
+    _require(abs(value - want) < 1e-3,
+             f"submit_grad value {value!r} vs twin expectation {want!r}")
+    _require(set(grads) == set(names), "gradient keys differ from Params")
+    g = np.asarray([float(grads[k]) for k in names])
+    _require(bool(np.all(np.isfinite(g))), "non-finite gradient component")
+    grad_err = 0.0
+    for k in (names[0], names[len(names) // 2]):
+        es = []
+        for shift in (+np.pi / 2, -np.pi / 2):
+            amps = eng.submit({**last, k: last[k] + shift}).result(
+                timeout=900)
+            work.put(jnp.asarray(amps))
+            es.append(float(qt.calcExpecPauliSum(
+                work, codes.ravel().tolist(), coeffs.tolist(), zero)))
+        grad_err = max(grad_err, abs(float(grads[k]) - (es[0] - es[1]) / 2))
+    _require(grad_err < 1e-3, f"adjoint gradient vs parameter shift: "
+             f"{grad_err:.3e} (budget 1e-3)")
+
+    grad_eng = eng.grad_engine()
+    eng.close()
+    for e in (eng, grad_eng):
+        _require(not e.is_open() and not e._thread.is_alive(),
+                 "engine did not close cleanly")
+    ctr = _counters("engine_requests_total", "engine_request_timeouts_total",
+                    "engine_poisoned_requests_total", "fusion_param_barriers_total",
+                    "device_dispatch_total", "engine_trace_total")
+    _require(not any(ctr["engine_request_timeouts_total"].values()),
+             f"timeouts: {ctr['engine_request_timeouts_total']}")
+    _require(not any(ctr["engine_poisoned_requests_total"].values()),
+             f"poisoned requests: {ctr['engine_poisoned_requests_total']}")
+    return {"qubits": n, "depth": depth, "params": len(names),
+            "requests": nreq, "first_request_s": round(first_s, 3),
+            "batch_s": round(batch_s, 4), "warm_batch_s": round(warm_s, 4),
+            "host_replay_s": round(host_s, 1),
+            "err_vs_numpy_twins_max": worst,
+            "twin_on_chip_s": round(twin_s, 3), "err_vs_chip_twin": twin_err,
+            "sample_s": round(sample_s, 3),
+            "shots": shots, "marginal_maxdiff": marg,
+            "grad_s": round(grad_s, 3), "grad_value_err": abs(value - want),
+            "grad_shift_err": grad_err,
+            "kernels": _require_compiled_kernels(args, expect_kernels=False),
+            "backend": jax.default_backend(), "counters": ctr}
+
+
+def phase_density(args, size) -> dict:
+    """14q density register (2^28 amplitudes) under the 11-op channel
+    circuit, fused with pallas=True (kraus1 / kraus2 / krausn kernel ops)."""
+    import quest_tpu as qt
+    import bench
+
+    n, n_or = size["n"], size["n_oracle"]
+    env = qt.createQuESTEnv()
+    circ = bench._density_circuit(n, with_krausn=True)
+    fused = circ.fused(max_qubits=5, pallas=True)
+    rho = qt.createDensityQureg(n, env)
+    qt.initPlusState(rho)
+    first_s, run_s = _first_and_second_run(
+        lambda: fused.run(rho), lambda: qt.initPlusState(rho),
+        lambda: rho.amps)
+    _log(f"density: {n}q first run (compile + run) {first_s:.1f}s")
+    trace = float(qt.calcTotalProb(rho))
+    _require(abs(trace - 1.0) < 1e-4, f"trace {trace!r} off 1 by >1e-4")
+
+    # the n_or-qubit instance against tests/oracle.py
+    oracle = _tests_oracle()
+    small = bench._density_circuit(n_or, with_krausn=True)
+    rs = qt.createDensityQureg(n_or, env)
+    qt.initPlusState(rs)
+    small.fused(max_qubits=5, pallas=True).run(rs)
+    d = 1 << n_or
+    got = qt.get_np(rs).reshape(d, d).T     # flat index = col * 2^n + row
+    ref = np.full((d, d), 1.0 / d, dtype=complex)
+    x = np.array([[0, 1], [1, 0]], dtype=complex)
+    y = np.array([[0, -1j], [1j, 0]])
+    z = np.diag([1.0 + 0j, -1.0])
+    i2 = np.eye(2, dtype=complex)
+    h = (x + z) / np.sqrt(2)
+    k = 1 / np.sqrt(2)
+
+    def depol(p):
+        return [np.sqrt(1 - p) * i2] + [np.sqrt(p / 3) * m for m in (x, y, z)]
+
+    for t in range(4):
+        ref = oracle.apply_to_density(ref, n_or, [t], h)
+    ref = oracle.apply_to_density(ref, n_or, [1], x, controls=[0])
+    ref = oracle.apply_to_density(ref, n_or, [3], x, controls=[2])
+    ref = oracle.apply_kraus_to_density(ref, n_or, [0], depol(0.05))
+    ref = oracle.apply_kraus_to_density(ref, n_or, [n_or - 1], depol(0.05))
+    ref = oracle.apply_kraus_to_density(
+        ref, n_or, [1], [np.array([[k, 0], [0, k]]),
+                         np.array([[0, k], [k, 0]])])
+    p2 = 0.1
+    ref = oracle.apply_kraus_to_density(
+        ref, n_or, [0, 1],
+        [np.sqrt(1 - p2) * np.kron(i2, i2), np.sqrt(p2 / 3) * np.kron(i2, z),
+         np.sqrt(p2 / 3) * np.kron(z, i2), np.sqrt(p2 / 3) * np.kron(z, z)])
+    xxx = np.kron(np.kron(x, x), x)
+    ref = oracle.apply_kraus_to_density(
+        ref, n_or, [2, 3, 4], [0.8 * xxx, 0.6j * np.eye(8)])
+    err_or = float(np.max(np.abs(got - ref)))
+    _require(err_or < 2e-6, f"{n_or}q density plan vs tests/oracle.py: max "
+             f"element error {err_or:.3e} (budget 2e-6)")
+
+    kernels = _require_compiled_kernels(args)
+    fallbacks = _require_no_fallback()
+    ctr = _counters("fusion_pallas_runs_total", "pallas_pass_total")
+    _require(sum(ctr["pallas_pass_total"].values()) > 0,
+             "pallas_pass_total is 0")
+    return {"qubits": n, "channel_ops": len(circ), "state_bytes": 8 << (2 * n),
+            "plan_items": len(fused), "compile_s": round(first_s - run_s, 3),
+            "run_s": round(run_s, 4), "trace": trace, "oracle_qubits": n_or,
+            "err_vs_oracle": err_or, "kernels": kernels,
+            "engine_fallback_total": fallbacks, "counters": ctr}
+
+
+def phase_df(args, size) -> dict:
+    """QUEST_PRECISION=2 on the chip IS the double-float kernel route: one
+    fused plan at 20q against an independent numpy complex128 oracle, at
+    the budget tools/df_verify.py asserts (1e-12)."""
+    import jax
+    import quest_tpu as qt
+    from quest_tpu import telemetry
+    from quest_tpu.circuits import Circuit
+    from __graft_entry__ import _random_layers
+
+    n, depth = size["n"], size["depth"]
+    env = qt.createQuESTEnv()
+    circ = Circuit(n)
+    _random_layers(circ, n, depth, seed=SEED)
+    fused = circ.fused(max_qubits=5, pallas=True)
+    q = qt.createQureg(n, env)
+    _require(jax.config.jax_enable_x64
+             and np.dtype(q.dtype) == np.dtype("float64"),
+             f"QUEST_PRECISION=2 gave a {q.dtype} register "
+             f"(x64 {jax.config.jax_enable_x64})")
+    qt.initPlusState(q)
+    first_s, run_s = _first_and_second_run(
+        lambda: fused.run(q), lambda: qt.initPlusState(q), lambda: q.amps)
+    _log(f"df: {n}q first run (compile + run) {first_s:.1f}s")
+
+    plus = np.full(1 << n, 1.0 / np.sqrt(1 << n), dtype=np.complex128)
+    want = _numpy_replay(circ, n, psi0=plus)
+    got = qt.get_np(q)
+    err = float(np.max(np.abs(got - want)))
+    drift = abs(float(np.sum(np.abs(got) ** 2)) - 1.0)
+
+    kernels = _require_compiled_kernels(args)
+    fb = {k: v for k, v in
+          telemetry.counters("engine_fallback_total").items()}
+    # df_max_ops_split counts extra kernel passes (runs longer than
+    # DF_MAX_OPS chain several df kernels), not a departure to the engine
+    left = {k: v for k, v in fb.items() if "df_max_ops_split" not in k and v}
+    _require(not left, f"df runs left for the engine: {left}")
+    ctr = _counters("fusion_pallas_runs_total", "pallas_pass_total")
+    row = {"qubits": n, "depth": depth, "gates": len(circ),
+           "plan_items": len(fused), "compile_s": round(first_s - run_s, 3),
+           "run_s": round(run_s, 4), "max_amp_err": err, "norm_drift": drift,
+           "budget": 1e-12, "kernels": kernels,
+           "engine_fallback_total": fb, "counters": ctr}
+    if not args.rehearse:
+        # XLA:CPU cannot keep the error-free transforms exact (the caveat
+        # tools/df_verify.py states), so only the chip holds this budget
+        _require(sum(v for k, v in ctr["pallas_pass_total"].items()
+                     if "df" in k) > 0, "no df kernel pass was counted")
+        _require(err < 1e-12 and drift < 1e-12,
+                 f"df route vs numpy complex128 oracle: max amplitude error "
+                 f"{err:.3e}, norm drift {drift:.3e} (budget 1e-12) "
+                 f"row={json.dumps(row)}")
+    return row
+
+
+def phase_sharded(args, size) -> dict:
+    """Four chips: a 28q register sharded a quarter per chip, the fused plan
+    built for 4 shards, against the unsharded plan on one of the chips."""
+    import jax
+    import quest_tpu as qt
+    from quest_tpu.circuits import Circuit
+    from __graft_entry__ import _random_layers
+
+    n, depth = size["n"], size["depth"]
+    devs = jax.devices()
+    _require(len(devs) >= 4, f"need 4 devices, JAX reports {len(devs)}")
+    env = qt.createQuESTEnv(devs[:4])
+    circ = Circuit(n)
+    _random_layers(circ, n, depth, seed=SEED)
+    fused = circ.fused(max_qubits=5, pallas=True, shard_devices=4)
+    q = qt.createQureg(n, env)
+    _sync(q.amps)
+    t0 = time.perf_counter()
+    fused.run(q)
+    _sync(q.amps)
+    first_s = time.perf_counter() - t0
+    _log(f"sharded: {n}q over 4 devices, first run {first_s:.1f}s")
+    qt.initZeroState(q)
+    _sync(q.amps)
+    t0 = time.perf_counter()
+    fused.run(q)
+    _sync(q.amps)
+    run_s = time.perf_counter() - t0
+
+    dset = q.amps.sharding.device_set
+    _require(len(dset) == 4, f"state lives on {len(dset)} device(s), not 4")
+    shards = q.amps.addressable_shards
+    per_dev = {str(s.device): int(np.prod(s.data.shape)) for s in shards}
+    _require(len(per_dev) == 4 and
+             all(v == (2 << n) // 4 for v in per_dev.values()),
+             f"shards are not a quarter of the state each: {per_dev}")
+    prob = float(qt.calcTotalProb(q))
+    _require(abs(prob - 1.0) < 1e-4, f"calcTotalProb {prob!r} off 1")
+
+    # counted now, before the one-chip comparison adds its own passes
+    ctr = _counters("fusion_pallas_runs_total", "pallas_pass_total",
+                    "fusion_frame_transposes_total", "comm_chunk_units_total",
+                    "exchange_calls_total", "engine_fallback_total")
+    moved = (sum(v for k, v in ctr["pallas_pass_total"].items()
+                 if "frame_swap" in k)
+             + sum(ctr["comm_chunk_units_total"].values())
+             + sum(ctr["exchange_calls_total"].values()))
+    _require(moved > 0, "no collective transpose / exchange was counted: "
+             "the sharded qubits were never relocated")
+    _require(sum(v for k, v in ctr["pallas_pass_total"].items()
+                 if "fused_run" in k) > 0, "no per-shard kernel pass counted")
+    # the same tape UNSHARDED on one of those chips: the one-chip plan (the
+    # manual-DMA kernel over the whole 2 GiB state, frame swaps folded or
+    # shard-free transposes) is a different lowering from the per-shard
+    # grid kernels + collective transposes above. (The unfused replay is
+    # no option at this width: its XLA program took 444 s to compile for
+    # the v5e at 26q in the sandbox's AOT compile.)
+    env1 = qt.createQuESTEnv(devs[:1])
+    q1 = qt.createQureg(n, env1)
+    t0 = time.perf_counter()
+    circ.fused(max_qubits=5, pallas=True).run(q1)
+    _sync(q1.amps)
+    ref_s = time.perf_counter() - t0
+    _log(f"sharded: one-chip unsharded plan (compile + run) {ref_s:.1f}s")
+    _require(len(q1.amps.sharding.device_set) == 1,
+             "the comparison register is not on one chip")
+    ref = jax.device_put(q1.amps, q.amps.sharding)
+    err_max, err_l2 = _amp_errors(q.amps, ref)
+    _require(err_max < 5e-4 and err_l2 < 5e-4,
+             f"sharded plan vs one-chip unsharded plan: max {err_max:.3e}, "
+             f"l2 {err_l2:.3e} (budget 5e-4)")
+    kernels = _require_compiled_kernels(args)
+    _require_no_fallback()
+    return {"qubits": n, "depth": depth, "gates": len(circ),
+            "state_bytes": 8 << n, "devices": sorted(per_dev),
+            "shard_elements": sorted(per_dev.values()),
+            "compile_s": round(first_s - run_s, 3), "run_s": round(run_s, 4),
+            "one_chip_plan_s": round(ref_s, 3), "total_prob": prob,
+            "err_vs_one_chip_max": err_max, "err_vs_one_chip_l2": err_l2,
+            "kernels": kernels, "counters": ctr}
+
+
+PHASES = {"kernel_f32": phase_kernel_f32, "served": phase_served,
+          "density": phase_density, "df": phase_df, "sharded": phase_sharded}
+
+
+def _child(args) -> int:
+    sys.path.insert(0, HERE)
+    size = (REHEARSAL_SIZES if args.rehearse else SIZES)[args.phase]
+    t0 = time.time()
+    device = _device(args)
+    _log(f"{args.phase}: device {device}")
+    row = PHASES[args.phase](args, size)
+    out = {"phase": args.phase, "passed": True, "device": device,
+           "seconds": round(time.time() - t0, 1)}
+    out.update(row)
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+def main() -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                   help="4: run ONLY the sharded phase and its one-chip "
+                        "comparison (the builder's four-chip call)")
+    p.add_argument("--rehearse", action="store_true",
+                   help="sandbox rehearsal: tiny sizes, any platform; never "
+                        "prints an ok result")
+    p.add_argument("--only", help="with --rehearse: comma-separated subset "
+                                  "of the phases")
+    p.add_argument("--phase", choices=sorted(PHASES), help=argparse.SUPPRESS)
+    args = p.parse_args()
+    if args.only and not args.rehearse:
+        # an ok result always means every phase ran
+        p.error("--only needs --rehearse")
+    if args.phase:
+        return _child(args)
+    return _parent(args)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -117,7 +117,7 @@ def check_smoke_spec(spec: dict) -> list:
     one was built, matching what the bench config itself plans).
     Returns the concatenated findings -- the one implementation behind
     ``tools/lint.py --bench-plans`` and the tier-1 analysis gate."""
-    from .._compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     from ..environment import AMP_AXIS
 
     name = spec["name"]
@@ -139,7 +139,7 @@ def check_smoke_spec(spec: dict) -> list:
                                shard_qubits=shard_q,
                                location=f"{name}.plan")
     if spec.get("mesh_shape"):
-        mesh = abstract_mesh(tuple(spec["mesh_shape"]), (AMP_AXIS,))
+        mesh = AbstractMesh(tuple(spec["mesh_shape"]), (AMP_AXIS,))
         target = fz if fz is not None else circ
         sched_findings, _stats, _journal = check_circuit_comm(
             target, mesh, dtype=spec.get("dtype"),

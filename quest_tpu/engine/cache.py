@@ -208,13 +208,22 @@ def enable_persistent_cache(path: str | None = None,
     set). Compiled XLA/Mosaic binaries then survive process restarts: a
     cold Engine still traces, but re-loads its executables from disk
     instead of recompiling -- the cross-process leg of the plan/executable
-    cache (the in-memory LRU covers the in-process leg)."""
+    cache (the in-memory LRU covers the in-process leg).
+
+    ``JAX_COMPILATION_CACHE_DIR`` outranks both: where it is set the cache
+    is placed from outside and no directory is set here (the returned
+    path is then that variable's)."""
     import jax
+
+    from ..compile_cache import CACHE_ENV
 
     path = path or os.environ.get("QUEST_COMPILE_CACHE")
     if not path:
         return None
-    jax.config.update("jax_compilation_cache_dir", path)
+    if os.environ.get(CACHE_ENV):
+        path = os.environ[CACHE_ENV]
+    else:
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
     telemetry.event("engine.persistent_cache", path=path)

@@ -20,7 +20,9 @@ drives 1 chip or a pod slice.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
@@ -33,6 +35,28 @@ from . import validation
 
 #: name of the mesh axis amplitudes are sharded over
 AMP_AXIS = "amps"
+
+_PALLAS_MESH = threading.local()
+
+
+@contextlib.contextmanager
+def pallas_mesh(mesh):
+    """Ambient execution mesh for PallasRuns inside jit traces, where the
+    amps tracer hides its sharding. Circuit.run derives it from the actual
+    register and activates it around the traced replay, so a fused plan is
+    never bound to one device set; set it manually only when calling a
+    compiled replay directly on a sharded register (see
+    examples/distributed_34q.py)."""
+    prev = getattr(_PALLAS_MESH, "mesh", None)
+    _PALLAS_MESH.mesh = mesh
+    try:
+        yield
+    finally:
+        _PALLAS_MESH.mesh = prev
+
+
+def active_pallas_mesh():
+    return getattr(_PALLAS_MESH, "mesh", None)
 
 
 @dataclass
@@ -138,11 +162,14 @@ def syncQuESTSuccess(success_code: int) -> int:
 def reportQuESTEnv(env: QuESTEnv) -> None:
     """Print deployment info (reportQuESTEnv; format follows
     getEnvironmentString, QuEST_cpu_distributed.c:185-208)."""
-    print("EXECUTION ENVIRONMENT:")
-    print(f"Backend: TPU-native (JAX/XLA {jax.__version__})")
-    print(f"Number of devices: {env.num_ranks}")
     plats = {d.platform for d in (env.mesh.devices.flat if env.mesh is not None else [])}
-    print(f"Device platform(s): {', '.join(sorted(plats)) or 'none'}")
+    found = ', '.join(sorted(plats)) or 'none'
+    print("EXECUTION ENVIRONMENT:")
+    # the platform the devices are actually on, never a claim: a CPU run
+    # (a supported test platform) must not report itself as the TPU
+    print(f"Backend: {found} (JAX/XLA {jax.__version__})")
+    print(f"Number of devices: {env.num_ranks}")
+    print(f"Device platform(s): {found}")
     print(f"Precision default: {os.environ.get('QUEST_PRECISION', '1')}")
 
 

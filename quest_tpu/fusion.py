@@ -36,6 +36,9 @@ import numpy as np
 
 from . import precision
 from . import telemetry
+# the ambient replay mesh lives with the mesh (environment.py) so the
+# register layer can read it; fusion.pallas_mesh stays the documented name
+from .environment import active_pallas_mesh, pallas_mesh  # noqa: F401
 
 
 # ---------------------------------------------------------------------------
@@ -1297,31 +1300,6 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
                 for cls in (_FramePlanner, _FramePlannerTwoSlot)), key=score)
 
 
-import threading
-
-_PALLAS_MESH = threading.local()
-
-
-@contextlib.contextmanager
-def pallas_mesh(mesh):
-    """Ambient execution mesh for PallasRuns inside jit traces, where the
-    amps tracer hides its sharding. Circuit.run derives it from the actual
-    register and activates it around the traced replay, so a fused plan is
-    never bound to one device set; set it manually only when calling a
-    compiled replay directly on a sharded register (see
-    examples/distributed_34q.py)."""
-    prev = getattr(_PALLAS_MESH, "mesh", None)
-    _PALLAS_MESH.mesh = mesh
-    try:
-        yield
-    finally:
-        _PALLAS_MESH.mesh = prev
-
-
-def active_pallas_mesh():
-    return getattr(_PALLAS_MESH, "mesh", None)
-
-
 def _df_route(dtype) -> bool:
     """True when an f64 register's PallasRuns take the double-float
     (4-plane f32) kernel route: always on the TPU backend (Mosaic has no
@@ -1689,7 +1667,7 @@ def _exec_pallas_sharded(amps, mesh, ops: tuple, df: bool, n_local: int,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
     from .environment import AMP_AXIS
     from .ops import pallas_gates as PG
 
@@ -1799,7 +1777,7 @@ def _sched_df_pallas_run(qureg, ops: tuple, sched, tile_bits: int,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
     from .environment import AMP_AXIS
     from .ops.pallas_df import df_join, df_split
 

@@ -26,7 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .layout import grouped_axes, inverse_permutation
+from .layout import amps_jit, grouped_axes, inverse_permutation
 
 
 def _plan(n, targets, controls):
@@ -105,8 +105,8 @@ def _apply_matrix_window(amps, mr, mi, n, lo, hi):
     return out.reshape(2, -1)
 
 
-@partial(jax.jit, static_argnames=("n", "targets", "controls", "control_states", "conj"),
-         donate_argnums=(0,))
+@amps_jit(static_argnames=("n", "targets", "controls", "control_states", "conj"),
+          donate_argnums=(0,))
 def apply_matrix(amps, matrix, *, n: int, targets: tuple[int, ...],
                  controls: tuple[int, ...] = (), control_states: tuple[int, ...] = (),
                  conj: bool = False):
@@ -178,8 +178,8 @@ def _window_perm_matrix(span_lo, span_hi, flips, cbits, states, np_dtype):
     return mr
 
 
-@partial(jax.jit, static_argnames=("n", "targets", "controls", "control_states"),
-         donate_argnums=(0,))
+@amps_jit(static_argnames=("n", "targets", "controls", "control_states"),
+          donate_argnums=(0,))
 def apply_x_class(amps, *, n: int, targets: tuple[int, ...],
                   controls: tuple[int, ...] = (), control_states: tuple[int, ...] = ()):
     """Multi-controlled multi-qubit NOT: an amplitude permutation.
@@ -216,7 +216,7 @@ def apply_x_class(amps, *, n: int, targets: tuple[int, ...],
     return tensor.transpose(inv).reshape(2, -1)
 
 
-@partial(jax.jit, static_argnames=("n", "qb1", "qb2", "controls"), donate_argnums=(0,))
+@amps_jit(static_argnames=("n", "qb1", "qb2", "controls"), donate_argnums=(0,))
 def apply_swap(amps, *, n: int, qb1: int, qb2: int, controls: tuple[int, ...] = ()):
     """SWAP as an axis transposition (reference: statevec_swapQubitAmps,
     ``QuEST_cpu.c:3850-3931``; distributed odd-parity pair exchange

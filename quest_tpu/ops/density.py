@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import apply, cplx, diagonal
+from .layout import amps_jit
 
 
 def kraus_superoperator(kraus_ops) -> np.ndarray:
@@ -189,7 +190,7 @@ def _kraus_sum_pallas_run(amps, *, n, t, c, hi, terms, sublanes):
         store_swap_k=k, store_swap_hi=hi)
 
 
-@partial(jax.jit, static_argnames=("n", "targets", "signs"), donate_argnums=(0,))
+@amps_jit(static_argnames=("n", "targets", "signs"), donate_argnums=(0,))
 def _apply_kraus_sum(amps, ks, *, n: int, targets: tuple[int, ...],
                      signs: tuple[float, ...]):
     shifted = tuple(q + n for q in targets)

@@ -20,7 +20,11 @@ This is the moral equivalent of the reference's block/stride loops
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
+
+import jax
+from jax.sharding import NamedSharding
 
 
 def grouped_shape(n: int, qubits_desc: Sequence[int]) -> tuple[int, ...]:
@@ -49,3 +53,35 @@ def inverse_permutation(perm: Sequence[int]) -> tuple[int, ...]:
     for i, p in enumerate(perm):
         inv[p] = i
     return tuple(inv)
+
+
+def amps_jit(**jit_kwargs):
+    """``jax.jit`` for a dense applier (amps in as argument 0, amps out)
+    whose result is LOWERED with the sharding of its argument.
+
+    Left alone the partitioner chooses an output layout, and under jax 0.9
+    the window GEMM over a sharded qubit comes back fully replicated: every
+    device then holds, and from then on updates, the whole state (a register
+    sized to fill the mesh no longer fits), while ``len(sharding.device_set)``
+    still counts every device. ``out_shardings`` is fixed when a function is
+    jitted, so there is one jitted form per sharding met (a process sees one
+    or two meshes). A tracer hides its sharding: inside a jitted replay the
+    register boundary constrains the result instead (``Qureg.put``)."""
+    def deco(fn):
+        plain = jax.jit(fn, **jit_kwargs)
+
+        @functools.lru_cache(maxsize=None)
+        def pinned(sharding):
+            return jax.jit(fn, out_shardings=sharding, **jit_kwargs)
+
+        @functools.wraps(fn)
+        def call(amps, *args, **kwargs):
+            sharding = (None if isinstance(amps, jax.core.Tracer)
+                        else getattr(amps, "sharding", None))
+            if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1:
+                return pinned(sharding)(amps, *args, **kwargs)
+            return plain(amps, *args, **kwargs)
+
+        return call
+
+    return deco

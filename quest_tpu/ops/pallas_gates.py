@@ -69,7 +69,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import _compat
 from .. import telemetry
 
 LANE_BITS = 7          # minor dim fixed at 128 lanes
@@ -92,7 +91,7 @@ _DEF_SUBLANES = 1 << 12
 #: (2 * ring * 4 MiB at the S=4096 f32 tile) stay within _RING_VMEM_BUDGET
 #: alongside the op temporaries of the bench's longest fused runs -- the
 #: operating point committed from the tools/kernelprobe --ring sweep
-#: (re-sweep it on-chip when S or the op mix changes; BASELINE.md table).
+#: (re-sweep it on-chip when S or the op mix changes).
 _DEF_RING_DEPTH = 3
 
 #: env override for the ring depth: sweepable without code edits
@@ -148,7 +147,7 @@ def effective_ring_depth(ring_depth: int, nchunks: int, slot_bytes: int,
 #: lowers only DEFAULT and HIGHEST (Precision.HIGH raises
 #: NotImplementedError, probed round 3); HIGHEST keeps the 26q depth-8
 #: norm drift at ~1.4e-5 after 7 circuits vs DEFAULT's ~8e-5 per circuit
-#: (BASELINE.md precision table). f32 tiles take the manual bf16x3 route
+#: (round-3/4 chip measurements). f32 tiles take the manual bf16x3 route
 #: below instead; this setting remains for the f64-interpreter path.
 _DOT_PRECISION = jax.lax.Precision.HIGHEST
 
@@ -171,7 +170,7 @@ def _dot_bf16x3(x, w_pair, dtype):
     cross terms); the manual split keeps the three leading terms
     (hi*hi + hi*lo + lo*hi), whose dropped lo*lo term is O(2^-16) relative
     -- measured norm drift ~1e-6/circuit on the 26q depth-8 bench vs
-    HIGHEST's 1.4e-5/7-circuits budget (BASELINE.md precision table).
+    HIGHEST's 1.4e-5/7-circuits budget (round-4 chip measurement).
     Halves the MXU time of every zone dot: the lane dots are the
     serialized compute that bounds the 26q bench (round-3 floor
     analysis). ``w_pair`` = (2, ...) stacked bf16 hi/lo from _split_bf16."""
@@ -1091,7 +1090,7 @@ def fused_local_run(amps, *, n: int, ops: tuple, sublanes: int = _DEF_SUBLANES,
                                                1)),
                     load_swap_k=int(load_swap_k),
                     store_swap_k=int(store_swap_k), ring=ring,
-                    seconds=round(dt, 4))
+                    interpret=bool(interpret), seconds=round(dt, 4))
     return out
 
 
@@ -1125,9 +1124,13 @@ def _swap_spec(s: int, lo2_rel: int, k: int, planes: int = 2):
     gm_sz = 1 << (lo2_rel - s_bits)
 
     def imap(i):
-        gm = i % gm_sz
-        rest = i // gm_sz
-        return (0, rest // dk, 0, gm, rest % dk, 0, 0)
+        # np.int32 throughout: under jax x64 a bare python int is an i64
+        # constant, and Mosaic cannot legalize an index map that returns
+        # mixed (i64, i32) block indices (first v5e AOT compile, PR 24)
+        z = np.int32(0)
+        gm = i % np.int32(gm_sz)
+        rest = i // np.int32(gm_sz)
+        return (z, rest // np.int32(dk), z, gm, rest % np.int32(dk), z, z)
 
     return pl.BlockSpec((planes, 1, dk, 1, 1, s >> k, _LANES), imap,
                         memory_space=pltpu.VMEM)
@@ -1232,7 +1235,7 @@ def _fused_local_run(amps, shard_index, *, n: int, ops: tuple, sublanes: int,
                       pl.BlockSpec(memory_space=pltpu.SMEM)] +
                      [pl.BlockSpec(memory_space=pltpu.VMEM) for _ in ws],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            compiler_params=_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
             interpret=interpret,
         )(x_in, shard_index, *ws)
@@ -1257,13 +1260,15 @@ def _fused_local_run(amps, shard_index, *, n: int, ops: tuple, sublanes: int,
                       pl.BlockSpec(memory_space=pltpu.SMEM)] +
                      [pl.BlockSpec(memory_space=pltpu.VMEM) for _ in ws],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            compiler_params=_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
             interpret=interpret,
         )(x, shard_index, *ws)
         return out.reshape(P, -1)
 
-    plain = pl.BlockSpec((P, s, _LANES), lambda i: (0, i, 0),
+    # np.int32 zeros: see _swap_spec (x64 turns a bare 0 into an i64)
+    plain = pl.BlockSpec((P, s, _LANES),
+                         lambda i: (np.int32(0), i, np.int32(0)),
                          memory_space=pltpu.VMEM)
     if load_swap_k:
         x_in = _swap_view(x, rows, s, lo2_load - LANE_BITS, load_swap_k)
@@ -1285,13 +1290,15 @@ def _fused_local_run(amps, shard_index, *, n: int, ops: tuple, sublanes: int,
         out_shape=out_shape,
         grid=(grid,),
         in_specs=[in_spec0,
-                  pl.BlockSpec(memory_space=pltpu.SMEM)] +
-                 [pl.BlockSpec(w.shape, lambda i, _nd=w.ndim: (0,) * _nd,
+                  pl.BlockSpec((1,), lambda i: (np.int32(0),),
+                               memory_space=pltpu.SMEM)] +
+                 [pl.BlockSpec(w.shape,
+                               lambda i, _nd=w.ndim: (np.int32(0),) * _nd,
                                memory_space=pltpu.VMEM) for w in ws],
         out_specs=out_spec,
         # long fused runs accumulate per-gate temporaries past the default
         # 16 MiB scoped-VMEM budget; the physical VMEM is far larger
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(x_in, shard_index, *ws)
@@ -1363,15 +1370,16 @@ def _window_dot(amps, matrix, *, n: int, lo: int, hi: int, conj: bool,
         ac //= 2
     x = amps.reshape(2, a, d, b)
     grid = (a // ac, b // bc)
+    z = np.int32(0)  # not a bare 0: see _swap_spec (x64 makes it an i64)
     out = pl.pallas_call(
         _make_window_dot_kernel(ac, d),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         grid=grid,
-        in_specs=[pl.BlockSpec((2, ac, d, bc), lambda i, j: (0, i, 0, j),
+        in_specs=[pl.BlockSpec((2, ac, d, bc), lambda i, j: (z, i, z, j),
                                memory_space=pltpu.VMEM),
-                  pl.BlockSpec((2 * d, 2 * d), lambda i, j: (0, 0),
+                  pl.BlockSpec((2 * d, 2 * d), lambda i, j: (z, z),
                                memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((2, ac, d, bc), lambda i, j: (0, i, 0, j),
+        out_specs=pl.BlockSpec((2, ac, d, bc), lambda i, j: (z, i, z, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
     )(x, w4)

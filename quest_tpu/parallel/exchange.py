@@ -62,7 +62,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import telemetry
-from .._compat import shard_map
+from jax import shard_map
 
 from ..environment import AMP_AXIS
 from ..ops import apply as K
@@ -89,7 +89,7 @@ def _specs(mesh):
 _PIPE_ENV = "QUEST_COMM_PIPELINE"
 
 #: monolithic until the on-chip kernelprobe sweep picks a better default
-#: (BASELINE.md documents the sweep recipe); the emulated-CPU tier-1 mesh
+#: (tools/kernelprobe.py comm_sweep is the recipe); the emulated-CPU tier-1 mesh
 #: cannot measure overlap, so the committed default keeps the exchange
 #: lowering byte-identical to round 7.
 _DEF_COMM_PIPELINE = 1

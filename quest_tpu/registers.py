@@ -27,9 +27,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import precision, validation
-from .environment import QuESTEnv
+from .environment import AMP_AXIS, QuESTEnv, active_pallas_mesh
 from .ops import init as ops_init
 from .qasm import QASMLogger
 
@@ -77,7 +78,19 @@ class Qureg:
         return precision.eps_for_dtype(self.amps.dtype)
 
     def put(self, new_amps) -> None:
-        """Rebind the amplitude array, preserving the register's sharding."""
+        """Rebind the amplitude array, preserving the register's sharding.
+
+        Eagerly the appliers lower their result with the sharding of their
+        argument (``ops.layout.amps_jit``). Inside a jitted replay the
+        tracer hides it and the partitioner may leave an op's result fully
+        replicated (jax 0.9: a dense gate on a sharded qubit), so there the
+        new value is constrained to the ambient mesh Circuit.run derived
+        from the register -- every step of the replay stays partitioned."""
+        if isinstance(new_amps, jax.core.Tracer) and new_amps.ndim == 2:
+            mesh = active_pallas_mesh()
+            if mesh is not None and mesh.size > 1:
+                new_amps = jax.lax.with_sharding_constraint(
+                    new_amps, NamedSharding(mesh, PartitionSpec(None, AMP_AXIS)))
         self.amps = new_amps
 
     def __repr__(self):

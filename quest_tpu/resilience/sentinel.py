@@ -266,7 +266,7 @@ def _shard_partials(amps, mesh):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from .._compat import shard_map
+    from jax import shard_map
     from ..environment import AMP_AXIS
 
     def kernel(a):

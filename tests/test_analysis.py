@@ -30,7 +30,7 @@ import pytest
 
 from quest_tpu import analysis as A
 from quest_tpu import fusion, telemetry
-from quest_tpu._compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from quest_tpu.circuits import Circuit
 from quest_tpu.environment import AMP_AXIS
 from quest_tpu.ops import pallas_gates as PG
@@ -153,7 +153,7 @@ def test_plan_identity_frame_required_before_dense_item():
 # plancheck schedule: journal re-pricing and layout replay
 # ---------------------------------------------------------------------------
 
-MESH8 = abstract_mesh((8,), (AMP_AXIS,))
+MESH8 = AbstractMesh((8,), (AMP_AXIS,))
 
 
 def test_schedule_reprices_clean_batched_and_per_swap():

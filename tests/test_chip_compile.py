@@ -1,0 +1,267 @@
+"""Ask the v5e's own compiler, in the sandbox, before any chip time.
+
+Every other Pallas test in tier-1 runs the kernels in INTERPRET mode
+(tests/test_pallas.py), which cannot see what Mosaic refuses: a slice not
+aligned to the tiling, a kernel over its VMEM budget, an op with no TPU
+lowering. libtpu compiles for a chip that is only DESCRIBED
+(``jax.experimental.topologies``), so these tests lower the jitted kernel
+functions themselves with ``interpret=False`` at the sizes chip_smoke.py
+runs -- the fused f32 run of the 26q depth-8 plan (plain and with a folded
+frame swap), its per-shard form at the 4-chip local size, a double-float
+run at 20q, the 14q density run holding kraus1 + krausn ops, and the
+window-dot kernel -- and fail on whatever the chip's compiler would raise.
+A compile that passes is not a chip run: nothing executes here.
+
+Mosaic compile time grows steeply with the op count of a run (a 24-op
+prefix of the 26q plan's first pass took 127 s on the chip's host), so
+each case compiles one op of every KIND a real planned run holds after
+zone folding (``_one_of_each``): the geometry, DMA ring, swap folding and
+op kinds are the plan's own; only the run is shorter -- which also means
+the VMEM headroom of the LONGEST run is not what these tests see.
+
+The topology is described inside a module-scoped fixture (never at
+import, never in conftest.py, not autouse): only the worker that is handed
+this file loads libtpu. All compiles stay in this ONE file and in the
+test's own process.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from quest_tpu import fusion
+from quest_tpu.circuits import Circuit
+from quest_tpu.ops import pallas_gates as PG
+from quest_tpu.ops.pallas_df import DF_MAX_OPS, DF_SUBLANES
+
+#: double-float ops compiled (a real chunk's prefix): ~30 s per df op
+_DF_OPS = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on a described (not attached) v5e chip, with
+    the persistent compile cache off around the module: an AOT compile is
+    written to the cache but cannot be read back without a chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or its lock is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _planned_runs(circ, **fused_kw):
+    """The PallasRun argument tuples of ``circ.fused(pallas=True, ...)``:
+    (ops, tile_bits, load_swap_k, store_swap_k, load_swap_hi,
+    store_swap_hi, ring_depth, ...)."""
+    fz = circ.fused(max_qubits=5, pallas=True, dtype=np.float32, **fused_kw)
+    return [a for fn, a, _ in fz._tape if fn is fusion._apply_pallas_run]
+
+
+def _random_circuit(n, depth=8):
+    from __graft_entry__ import _random_layers
+
+    circ = Circuit(n)
+    _random_layers(circ, n, depth)
+    return circ
+
+
+def _one_of_each(ops_folded, lq, must=(), skip_zones=()):
+    """A short run that still holds every op KIND of the folded run (the
+    folded zone dots ``lane_u`` / ``window`` included), a ``matrix`` op of
+    each target zone it has (lane-roll butterfly, sublane-roll butterfly,
+    grid-bit diagonal; minus ``skip_zones``), and the ``must`` ops --
+    compile time is per op, coverage is per kind. One sublane-roll
+    butterfly alone costs Mosaic ~100 s at the 4 MiB tile, so only the
+    first test keeps it."""
+    picked, kinds, zones = list(must), set(), set()
+    for op in ops_folded:
+        if op in picked:
+            continue
+        if op[0] == "matrix":
+            zone = ("lane" if op[1] < PG.LANE_BITS
+                    else "sublane" if op[1] < lq else "grid")
+            if zone in zones or zone in skip_zones:
+                continue
+            zones.add(zone)
+        elif op[0] in kinds:
+            continue
+        kinds.add(op[0])
+        picked.append(op)
+    return tuple(picked)
+
+
+def _compile_fused(one_chip, n, ops, *, planes=2, sublanes=PG._DEF_SUBLANES,
+                   local_n=None, lk=0, sk=0, lh=None, sh=None,
+                   ring=None, must=(), skip_zones=()):
+    """Lower + compile ``_fused_local_run`` for the described chip exactly
+    as ``fused_local_run`` would call it on the TPU (interpret=False).
+
+    The state enters as (planes, rows, 128) and is flattened inside the
+    program: a (planes, 2^n) PARAMETER gets XLA's T(2,128) tiling, and
+    XLA's own relayout copy into the kernel's tile view -- not Mosaic --
+    then dominates the compile (170 s for the 26q plan's k=7 swap view
+    against 6.5 s this way; first v5e compiles, PR 24). Inside a replay the
+    kernels chain on each other's outputs, so this is also the closer
+    model of what Circuit.run compiles."""
+    df = planes == 4
+    lq = PG.local_qubits(n, sublanes)
+    ops_l = tuple(ops) if df else _one_of_each(
+        PG._fold_zone_ops(ops, lq), lq, must, skip_zones)
+    kw = dict(n=n, ops=ops_l, sublanes=sublanes, interpret=False,
+              local_n=local_n, load_swap_k=lk, store_swap_k=sk,
+              load_swap_hi=lh, store_swap_hi=sh,
+              ring_depth=PG.ring_depth_default() if ring is None else ring,
+              df_acc=False)
+
+    def run(x3, shard_index):
+        out = PG._fused_local_run(x3.reshape(planes, -1), shard_index, **kw)
+        return out.reshape(x3.shape)
+
+    x3 = jax.ShapeDtypeStruct((planes, (1 << n) // 128, 128), jnp.float32,
+                              sharding=one_chip)
+    si = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(run).lower(x3, si).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_f32_fused_run_26q_plan_pass(one_chip):
+    """First pass of the 26q depth-8 plan: manual-DMA kernel, default
+    3-slot ring, 100 MiB VMEM limit, 4 MiB tiles."""
+    n = 26
+    runs = _planned_runs(_random_circuit(n))
+    assert len(runs) >= 8
+    ops, tile_bits, lk, sk = runs[0][:4]
+    assert (tile_bits, lk, sk) == (PG.local_qubits(n), 0, 0)
+    assert PG.ring_depth_default() == 3
+    _compile_fused(one_chip, n, ops)
+
+
+def test_f32_fused_run_26q_folded_frame_swap(one_chip):
+    """A pass of the same plan whose frame swap is folded into the run's
+    load AND store DMA (load_swap_k/store_swap_k, 2^k strided row-chunks
+    per tile)."""
+    n = 26
+    swapped = [r for r in _planned_runs(_random_circuit(n))
+               if r[2] and r[3]]
+    assert swapped, "the 26q plan no longer folds a frame swap"
+    ops, tile_bits, lk, sk, lh, sh = swapped[0][:6]
+    # what fusion._apply_pallas_run requires before it folds the swap
+    assert tile_bits == PG.local_qubits(n)
+    assert tile_bits - PG.LANE_BITS - max(lk, sk) >= 3
+    _compile_fused(one_chip, n, ops, lk=lk, sk=sk, lh=lh, sh=sh,
+                   skip_zones=("sublane",))
+
+
+def test_f32_per_shard_run_28q_over_4(one_chip):
+    """The shard_index form (fused_local_run inside shard_map): one chip's
+    2^26-amplitude shard of the 28q plan built for 4 devices, sharded
+    qubits resolved from the SMEM shard index, shard-local swap folded."""
+    n, ndev = 28, 4
+    n_local = n - 2
+    runs = _planned_runs(_random_circuit(n), shard_devices=ndev)
+    assert runs
+    for ops, *_ in runs:   # every run is per-shard executable
+        assert all(q < PG.local_qubits(n_local)
+                   for op in ops for q in PG.op_dense_targets(op))
+    # a run that touches a SHARDED qubit (control / diagonal role)
+    plain = next(r for r in runs if any(
+        q >= n_local for op in r[0] for q in _op_qubits(op)))
+    sharded_role = [op for op in plain[0]
+                    if any(q >= n_local for q in _op_qubits(op))][:1]
+    _compile_fused(one_chip, n_local, plain[0], local_n=n_local,
+                   must=sharded_role, skip_zones=("sublane",))
+    # ... and a run whose frame swap is SHARD-LOCAL, so it folds into the
+    # per-shard kernel's BlockSpec index maps (the depth-3 plan has one;
+    # swaps reaching sharded bits are collective transposes, not kernels)
+    local_swaps = [r for r in _planned_runs(_random_circuit(n, depth=3),
+                                            shard_devices=ndev)
+                   if r[2] and r[3]
+                   and (r[1] if r[4] is None else r[4]) + r[2] <= n_local
+                   and (r[1] if r[5] is None else r[5]) + r[3] <= n_local]
+    assert local_swaps, "no shard-local folded swap in the 28q/4 plan"
+    ops, tile_bits, lk, sk, lh, sh = local_swaps[0][:6]
+    _compile_fused(one_chip, n_local, ops[:1], local_n=n_local, lk=lk,
+                   lh=lh, sk=sk, sh=sh)
+
+
+def _op_qubits(op):
+    kind = op[0]
+    if kind == "matrix":
+        return (op[1],) + tuple(op[2])
+    if kind == "parity":
+        return tuple(op[1]) + tuple(op[2])
+    if kind == "swap":
+        return (op[1], op[2]) + tuple(op[3])
+    if kind == "diagw":
+        return tuple(op[1]) + tuple(op[2])
+    return ()
+
+
+def test_df_run_20q(one_chip, monkeypatch):
+    """One double-float kernel (4 f32 planes, DF_SUBLANES tile, a prefix
+    of a DF_MAX_OPS chunk) of the 20q plan QUEST_PRECISION=2 runs on the
+    chip."""
+    n = 20
+    # plan at the double-float tile geometry, as the chip does on its own
+    # (pallas_df.df_wanted is True on the TPU backend)
+    monkeypatch.setenv("QUEST_PALLAS_DF", "1")
+    fz = _random_circuit(n, depth=1).fused(max_qubits=5, pallas=True,
+                                           dtype=np.float64)
+    runs = [a for fn, a, _ in fz._tape if fn is fusion._apply_pallas_run]
+    assert runs
+    lq = PG.local_qubits(n, DF_SUBLANES)
+    assert all(q < lq for op in runs[0][0]
+               for q in PG.op_dense_targets(op))
+    ops = runs[0][0][:DF_MAX_OPS][:_DF_OPS]
+    assert len(ops) == _DF_OPS
+    _compile_fused(one_chip, n, ops, planes=4, sublanes=DF_SUBLANES)
+
+
+def test_density_kraus_run_14q(one_chip):
+    """The 14q density circuit (2^28 amplitudes, 2 GiB): the run holding
+    the kraus1 (mixDepolarising / mixKrausMap) and krausn
+    (mixMultiQubitKrausMap) kernel ops."""
+    import bench
+
+    nsv = 28
+    runs = _planned_runs(bench._density_circuit(14, with_krausn=True))
+    kinds = {op[0] for r in runs for op in r[0]}
+    assert {"kraus1", "krausn"} <= kinds, kinds
+    run = next(r for r in runs if any(op[0] == "krausn" for op in r[0]))
+    _compile_fused(one_chip, nsv, run[0], skip_zones=("sublane",))
+
+
+def test_window_dot_26q(one_chip):
+    """The dense planner's window GEMM kernel: a 5-qubit window on the top
+    qubits of a 26q state (fusion._apply_dense_block's hi-window shape)."""
+    n, lo, hi = 26, 21, 25
+    assert PG.window_dot_supported(n, lo, hi)
+    d = 1 << (hi - lo + 1)
+
+    def run(x4, m):   # the kernel's own (2, A, D, B) view: see _compile_fused
+        out = PG._window_dot(x4.reshape(2, -1), m, n=n, lo=lo, hi=hi,
+                             conj=False, interpret=False)
+        return out.reshape(x4.shape)
+
+    x4 = jax.ShapeDtypeStruct((2, (1 << n) >> (hi + 1), d, 1 << lo),
+                              jnp.float32, sharding=one_chip)
+    m = jax.ShapeDtypeStruct((2, d, d), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(run).lower(x4, m).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -37,7 +37,7 @@ import pytest
 
 import quest_tpu as qt
 from quest_tpu import fusion, telemetry
-from quest_tpu._compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from quest_tpu.analysis.plancheck import check_circuit_comm, check_schedule
 from quest_tpu.circuits import Circuit
 from quest_tpu.environment import AMP_AXIS
@@ -52,7 +52,7 @@ ENV = qt.createQuESTEnv()  # 8-device mesh from conftest's virtual CPUs
 needs_mesh = pytest.mark.skipif(ENV.mesh is None or ENV.mesh.size < 8,
                                 reason="needs the 8-device host mesh")
 
-MESH8 = abstract_mesh((8,), (AMP_AXIS,))
+MESH8 = AbstractMesh((8,), (AMP_AXIS,))
 
 
 def _plan20(**kw):

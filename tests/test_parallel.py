@@ -390,8 +390,8 @@ def test_two_d_mesh_ici_dcn_plan_split_and_execution():
 def test_plan_comm_volume_model():
     """plan_circuit's per-device communication volume follows the cost
     model (2 chunks per pair exchange / rank permute, 1 per relocation,
-    0 for virtual swaps, measured reconcile_chunks for reconciliation --
-    BASELINE.md comm table), consistent with the reported op counts."""
+    0 for virtual swaps, measured reconcile_chunks for reconciliation),
+    consistent with the reported op counts."""
     n = 5
     circ = qt.Circuit(n)
     circ.hadamard(n - 1)
@@ -606,3 +606,48 @@ def test_local_ctrl_mask_jit_composition_regression():
     np.testing.assert_allclose(np.asarray(jax.jit(f)(amps0)), ref,
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(f(amps0)), ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("route", ["eager", "eager_density", "circuit",
+                                   "fused_pallas"])
+def test_register_stays_partitioned_not_replicated(route):
+    """A gate on a SHARDED qubit must leave the register partitioned: each
+    device keeps 1/ndev of the state. ``len(sharding.device_set)`` cannot
+    see the failure this guards -- under jax 0.9 the compiler returned such
+    a gate's result fully replicated (every device holding, and from then
+    on updating, the whole state) and the device set still counted 8.
+    ``Qureg.put`` moves nothing eagerly, so the eager routes see the layout
+    the appliers themselves were lowered with."""
+    from quest_tpu.circuits import Circuit
+
+    n = 12
+    ndev = ENV.mesh.size
+    if route == "eager_density":
+        q = qt.createDensityQureg(n // 2, ENV)
+        qt.initPlusState(q)
+        qt.hadamard(q, n // 2 - 1)
+        qt.mixDepolarising(q, n // 2 - 1, 0.1)
+        qt.mixDamping(q, n // 2 - 1, 0.2)
+        qt.mixTwoQubitDepolarising(q, 0, n // 2 - 1, 0.1)
+        qt.mixKrausMap(q, n // 2 - 1, [np.sqrt(0.5) * np.eye(2),
+                                       np.sqrt(0.5) * np.diag([1.0, -1.0])])
+    else:
+        q = qt.createQureg(n, ENV)
+    if route == "eager":
+        qt.hadamard(q, n - 1)
+        qt.controlledNot(q, n - 1, 0)
+        qt.swapGate(q, n - 2, n - 1)
+        qt.pauliX(q, n - 1)
+    elif route != "eager_density":
+        circ = Circuit(n)
+        circ.hadamard(0)
+        circ.hadamard(n - 1)
+        circ.controlledNot(n - 1, 0)
+        circ.rotateX(n - 2, 0.3)
+        if route == "fused_pallas":
+            circ = circ.fused(max_qubits=5, pallas=True, shard_devices=ndev)
+        circ.run(q)
+    shards = q.amps.addressable_shards
+    assert len({s.device for s in shards}) == ndev
+    assert {s.data.shape for s in shards} == {(2, (1 << n) // ndev)}
+    assert abs(qt.calcTotalProb(q) - 1.0) < 1e-10

@@ -456,10 +456,10 @@ def test_replay_slice_rejects_lifted_params():
 # ---------------------------------------------------------------------------
 
 def test_begin_defer_journals_segment_marker():
-    from quest_tpu._compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     from quest_tpu.environment import AMP_AXIS
     from quest_tpu.parallel import scheduler as S
-    sched = S.DistributedScheduler(mesh=abstract_mesh((8,), (AMP_AXIS,)))
+    sched = S.DistributedScheduler(mesh=AbstractMesh((8,), (AMP_AXIS,)))
     sched.journal = []
     assert sched.begin_defer(segment=5)
     segs = [rec for rec in sched.journal if rec[0] == "segment"]
@@ -472,9 +472,9 @@ def test_begin_defer_journals_segment_marker():
 
 def test_check_schedule_validates_segment_records():
     import bench
-    from quest_tpu._compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     from quest_tpu.environment import AMP_AXIS
-    mesh8 = abstract_mesh((8,), (AMP_AXIS,))
+    mesh8 = AbstractMesh((8,), (AMP_AXIS,))
     findings, stats, journal = A.check_circuit_comm(
         bench.build_circuit(20, 4), mesh8)
     assert findings == []

@@ -75,7 +75,7 @@ def _shard_run(mesh, planes, n_local, ops, **kw):
     """shard_map one per-shard df fused_local_run over the 4-plane state."""
     from jax.sharding import PartitionSpec as P
 
-    from quest_tpu._compat import shard_map
+    from jax import shard_map
     from quest_tpu.environment import AMP_AXIS
 
     def body(x):
@@ -180,7 +180,7 @@ def test_sharded_f32_folded_swap_matches_explicit():
 
     from jax.sharding import PartitionSpec as P
 
-    from quest_tpu._compat import shard_map
+    from jax import shard_map
     from quest_tpu.environment import AMP_AXIS
 
     def run(x, **kw):

@@ -22,6 +22,7 @@ MODULES = [
     "quest_tpu",
     "quest_tpu.registers",
     "quest_tpu.environment",
+    "quest_tpu.compile_cache",
     "quest_tpu.datatypes",
     "quest_tpu.state_init",
     "quest_tpu.gates",

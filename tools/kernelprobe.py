@@ -15,7 +15,7 @@ run, not from subtracting separately-measured floors):
 Round 8 adds the comm-pipeline sweep (multi-device hosts only): every
 pipelined collective kind x depth {1,2,4,8}, with each eager launch
 self-observing into the ``comm_collective_ms{kind,pipeline}`` histogram
-so the BASELINE.md table regenerates from telemetry alone.
+so the depth table regenerates from telemetry alone.
 """
 
 from __future__ import annotations
@@ -30,9 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from quest_tpu.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 
 def sync(a):
@@ -63,8 +63,8 @@ def comm_sweep(n):
 
     Times each pipelined launch site eagerly at depths {1,2,4,8}; the
     launch point (`exchange._launch`) self-observes every eager call into
-    the ``comm_collective_ms{kind,pipeline}`` histogram, so the committed
-    BASELINE.md table regenerates from telemetry alone. Skipped on
+    the ``comm_collective_ms{kind,pipeline}`` histogram, so the depth
+    table regenerates from telemetry alone. Skipped on
     single-device hosts (no collective to overlap).
     """
     ndev = 1 << (jax.device_count().bit_length() - 1)
@@ -119,8 +119,8 @@ def dispatch_sweep(n):
     program and the per-item interpreter rung, each timed end-to-end.
     The fixed host dispatch+sync tax amortizes by the mean
     items-per-segment, so the curve flattens once per-segment device
-    work dominates -- the committed BASELINE.md table regenerates from
-    this output alone (recipe there)."""
+    work dominates -- the per-cap table regenerates from
+    this output alone."""
     from bench import build_circuit
 
     import quest_tpu as qt
@@ -185,7 +185,7 @@ def main():
     # two signatures per point: the bare floor (DMA-bound) and a zone-dot
     # mix (compute overlapping the sweep -- where depth > 2 earns its
     # VMEM). Each observation lands in the pallas_per_pass_ms histogram so
-    # the committed BASELINE.md table regenerates from telemetry alone.
+    # the ring-depth table regenerates from telemetry alone.
     from quest_tpu import telemetry
 
     W3r = HashableMatrix(np.stack([ru(128).real.T, ru(128).real.T,

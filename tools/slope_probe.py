@@ -1,7 +1,7 @@
 """Two-point-slope microbench of fused-run passes at 2^26 (round 5).
 
 The round-4 probes divided (fixed dispatch+sync cost + work) by the rep
-count, so every per-pass figure was inflated by fixed/reps (BASELINE.md
+count, so every per-pass figure was inflated by fixed/reps (the
 round-5 correction). Here each config is timed at TWO rep counts inside
 one jit program and the SLOPE is reported -- the fixed cost cancels.
 
@@ -20,9 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from quest_tpu.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 
 def slope_time(fn, amps, r_small=4, r_big=16, trials=2):
