@@ -1,0 +1,4 @@
+"""Device: share of a library cell's traced slice in which no op ran on the
+chip."""
+
+from metric_util import idle_pct as read  # noqa: F401

@@ -1,0 +1,4 @@
+"""API / tape layer, small-register cells: programs the host dispatched per
+application (``device_dispatch_total``, every route), over the whole window."""
+
+from metric_util import dispatches_per_request as read  # noqa: F401
