@@ -58,6 +58,7 @@ from .checkpoint import (  # noqa: F401
 )
 from . import profiling  # noqa: F401
 from . import telemetry  # noqa: F401
+telemetry.watch_jax_compiles()
 from . import engine  # noqa: F401
 from .engine import Engine, EnginePool, P, Param  # noqa: F401
 from . import resilience  # noqa: F401

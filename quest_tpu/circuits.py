@@ -206,6 +206,22 @@ def _register_mesh(qureg):
     return _amps_mesh(qureg.amps)
 
 
+def named_program(fn, circuit, route: str, *extra) -> object:
+    """``fn`` under the stable name its jitted program carries into the
+    device trace: ``qt_<route>_<sv|dm>_n<qubits>_g<tape length>`` and
+    whatever ``extra`` adds (a batch width ``b8``, a slice ``i0_12``).
+    ``jax.jit`` names the module ``jit_<name>``, so the profiler's
+    ``XLA Modules`` line says which program of which circuit ran in place
+    of ``jit_fn``. Built from the circuit's static shape only -- the name
+    is part of the compile-cache key and must be the same in every
+    process."""
+    kind = "dm" if circuit.is_density_matrix else "sv"
+    name = "_".join([f"qt_{route}_{kind}_n{circuit.num_qubits}"
+                     f"_g{len(circuit._tape)}", *map(str, extra)])
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 class Circuit:
     """Deferred-execution circuit over ``num_qubits`` qubits.
 
@@ -327,7 +343,10 @@ class Circuit:
                         sched.advance(i)
                         if not _defer_safe(f):
                             shell.put(sched.reconcile(shell.amps, nsv))
-                    f(shell, *args, **kwargs)
+                    # the gate kind, into the op metadata of whatever
+                    # the entry lowers to (trace time only)
+                    with jax.named_scope(getattr(f, "__name__", "entry")):
+                        f(shell, *args, **kwargs)
                 if started:
                     shell.put(sched.end_defer(shell.amps, nsv))
                     sched.set_lookahead(None)
@@ -362,7 +381,8 @@ class Circuit:
         key = ("circuit", self._cache_token, donate, mesh, pmesh)
 
         def build():
-            inner = jax.jit(self.as_fn(), donate_argnums=(0,) if donate else ())
+            inner = jax.jit(named_program(self.as_fn(), self, "circuit"),
+                            donate_argnums=(0,) if donate else ())
 
             def fn(amps, _inner=inner, _mesh=mesh, _pmesh=pmesh):
                 # jit traces on first *call*, which may happen under a
@@ -454,7 +474,10 @@ class Circuit:
                 whole = lambda amps, values: reduce(body(amps, values))  # noqa: E731
             else:
                 whole = body
-            inner = jax.jit(whole, donate_argnums=(0,) if donate else ())
+            inner = jax.jit(
+                named_program(whole, self, "param",
+                              *(() if reduce is None else ("reduce",))),
+                donate_argnums=(0,) if donate else ())
 
             def fn(amps, values, _inner=inner, _mesh=mesh, _pmesh=pmesh):
                 pm = _pmesh if _pmesh is not None else _amps_mesh(amps)
@@ -695,7 +718,12 @@ class Circuit:
                 f"Circuit({self.num_qubits}q, density={self.is_density_matrix}) "
                 f"cannot run on {qureg!r}")
         from . import fusion
-        with fusion.pallas_mesh(_register_mesh(qureg)):
+        # the library path's host cost per application, measured where it
+        # is spent: cache lookup, mesh context, the jitted call, the put.
+        # It ends before any sync, and is on every application's path: a
+        # region (aggregate + profiler annotation), never a ring event
+        with telemetry.region("circuit.run"), \
+                fusion.pallas_mesh(_register_mesh(qureg)):
             telemetry.inc("device_dispatch_total", route="circuit")
             qureg.put(self.compiled()(qureg.amps))
         return qureg

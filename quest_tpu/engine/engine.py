@@ -166,6 +166,22 @@ class _Request:
         self.trace = trace
 
 
+class _Inflight:
+    """One completion-ring entry: the issued batch's in-flight result, its
+    requests, the issuing dispatch's ordinal (the sentinel tick), and
+    ``synced``: whether a device sync has proved the batch complete (the
+    serial-issue admission syncs an entry and leaves its resolution for
+    after the next issue)."""
+
+    __slots__ = ("out", "batch", "tick", "synced")
+
+    def __init__(self, out, batch: list, tick: int):
+        self.out = out
+        self.batch = batch
+        self.tick = tick
+        self.synced = False
+
+
 _ASYNC_ENV = "QUEST_ASYNC_DEPTH"
 _ASYNC_ENV_WARNED: set = set()
 
@@ -298,11 +314,11 @@ class Engine:
         # completion ring (round 18): in-flight issued batches awaiting
         # their device sync. BATCHER-THREAD-ONLY -- submit/close never
         # touch it, so it needs no lock; the loop drains it before exit.
-        # Entries are [out, batch, tick, dev_t0, t_ready]: t_ready flips
-        # from None when the serial-issue admission proved the device
-        # done (the entry is then "synced" and its resolution is
-        # deliberately deferred past the next issue).
+        # Entries are _Inflight records.
         self._ring: deque = deque()
+        # when the device was last seen done with a batch: the earliest a
+        # batch launched behind it can have started (_charge_device)
+        self._last_ready = 0.0
         self._serial: bool | None = None  # resolved lazily by _issue_serial
         self._cores: int | None = None  # resolved lazily by _spare_core
         self._open = True
@@ -588,7 +604,7 @@ class Engine:
             exc = QuESTCancelledError(
                 "request dropped by Engine.close before dispatch",
                 "Engine.close")
-            self._trace_error(req, exc)
+            self._trace_error(req, exc, "queue_wait")
             _sync.resolve_future(req.fut, exception=exc, site="engine.close")
         if self._thread.is_alive() and \
                 self._thread is not threading.current_thread():
@@ -661,8 +677,11 @@ class Engine:
                     lambda av: body(av[0], av[1]), (amps_b, values_b))
             else:
                 batched = jax.vmap(body, in_axes=(0, 0))
-            jitted = jax.jit(batched,
-                             donate_argnums=(0,) if donate else ())
+            from ..circuits import named_program
+            jitted = jax.jit(
+                named_program(batched, circuit, "engine_vmap",
+                              f"b{self.max_batch}"),
+                donate_argnums=(0,) if donate else ())
 
             def fn(amps_b, values_b, _inner=jitted):
                 with _dist.explicit_mesh(None), fusion.pallas_mesh(None):
@@ -730,7 +749,7 @@ class Engine:
                     f"{now - req.t0:.3f}s in queue "
                     f"(timeout={req.deadline - req.t0:.3f}s)",
                     "Engine.submit")
-                self._trace_error(req, exc)
+                self._trace_error(req, exc, "queue_wait")
                 _sync.resolve_future(req.fut, exception=exc,
                                      site="engine.expire")
             else:
@@ -759,17 +778,19 @@ class Engine:
         # pop) and coalesce (pop -> window close) per request, then bind
         # the batch's contexts to this thread so retry/guard/bisect hops
         # inside the dispatch can link to them. The binding MUST clear
-        # after the futures resolve (QT703) -- the finally below.
+        # after the futures resolve (QT703) -- the finally below. From
+        # here on every window is charged from the trace's own mark
+        # (_charge), so the phases tile the request whatever the host
+        # does between two of the batcher's regions.
         traced = [r.trace for r in batch if r.trace is not None]
         if traced:
             t_close = time.perf_counter()
             for req in batch:
-                tr = req.trace
-                if tr is None:
-                    continue
-                pivot = req.t0 if t_first is None else max(req.t0, t_first)
-                tr.phase("queue_wait", req.t0, max(0.0, pivot - req.t0))
-                tr.phase("coalesce", pivot, max(0.0, t_close - pivot))
+                if req.trace is not None:
+                    req.trace.charge("queue_wait", min(
+                        t_close, req.t0 if t_first is None
+                        else max(req.t0, t_first)))
+                    req.trace.charge("coalesce", t_close)
             telemetry.set_current_trace(traced)
         # the injectable hang/transient point: one visit per dispatch; with
         # QUEST_WATCHDOG_MS armed the WHOLE dispatch (tracing included --
@@ -798,14 +819,10 @@ class Engine:
                     # this batch's dispatch deadline would misattribute
                     # the wedge to the batch being issued. The wait for
                     # ring capacity is this batch's queue_wait.
-                    t_adm = time.perf_counter() if traced else 0.0
-                    self._ring_admit()
+                    with telemetry.region("engine.admit") as rg:
+                        self._ring_admit()
                     if traced:
-                        t_adm1 = time.perf_counter()
-                        for req in batch:
-                            if req.trace is not None and t_adm1 > t_adm:
-                                req.trace.phase("queue_wait", t_adm,
-                                                t_adm1 - t_adm)
+                        self._charge(batch, "queue_wait", rg.t1)
                 deferred = _watchdog.watched(
                     lambda: self._dispatch_one(batch, mode, defer=True),
                     site="engine.dispatch", hang=(kind == "hang"))
@@ -955,67 +972,102 @@ class Engine:
         import jax
         return jax.tree_util.tree_map(lambda a: a[i], out)
 
-    def _trace_done(self, req, rt0: float, rt1: float) -> None:
-        """Record the resolve phase; finish engine-owned traces (adopted
-        pool children only close their span -- the pool's settle owns
-        finishing the root)."""
+    @staticmethod
+    def _charge(batch, phase: str, t_end: float) -> None:
+        """Attribute to ``phase``, for every traced request of ``batch``,
+        the window from the trace's mark to ``t_end`` -- a stamp of the
+        batcher's region that just ended
+        (:meth:`~quest_tpu.telemetry.TraceContext.charge`). Every window
+        starts where the last one ended, so a request's phases tile its
+        root span by construction, whatever the host does between two
+        regions."""
+        for req in batch:
+            if req.trace is not None:
+                req.trace.charge(phase, t_end)
+
+    def _charge_device(self, batch, t_ready: float) -> None:
+        """The ``device`` phase of a batch the device has just been seen
+        done with, as the stream-ordered estimate: the chip runs one
+        program at a time, in launch order, so the batch ran from its
+        launch (the trace's mark) or from when the batch ahead was seen
+        done, whichever is later, to ``t_ready``. The time it spent
+        launched behind the batch ahead is ``queue_wait``."""
+        self._charge(batch, "queue_wait", min(self._last_ready, t_ready))
+        self._charge(batch, "device", t_ready)
+        self._last_ready = t_ready
+
+    def _trace_done(self, req) -> None:
+        """Close the resolve phase at this instant and finish an
+        engine-owned trace at the same stamp (adopted pool children only
+        close their span -- the pool's settle owns finishing the root)."""
         tr = req.trace
         if tr is None:
             return
-        tr.phase("resolve", rt0, rt1 - rt0)
+        now = time.perf_counter()
+        tr.charge("resolve", now)
         if tr.owns_root:
-            telemetry.finish_trace(tr)
+            telemetry.finish_trace(tr, now=now)
         else:
             tr.end()
 
-    def _trace_error(self, req, exc) -> None:
+    def _trace_error(self, req, exc, phase: str = "resolve") -> None:
         """Mark a request's trace failed: errored traces are ALWAYS
         retained (the QUEST_TRACE=errors contract), so every resolve-with-
-        exception site pairs with this."""
+        exception site pairs with this. What is left of the request's
+        time is charged too, to ``phase``: a dispatched request is being
+        settled; one that never left the queue only waited."""
         tr = req.trace
         if tr is None:
             return
+        now = time.perf_counter()
+        tr.charge(phase, now)
         if tr.owns_root:
-            telemetry.finish_trace(tr, error=type(exc).__name__)
+            telemetry.finish_trace(tr, error=type(exc).__name__, now=now)
         else:
             tr.event("error", type=type(exc).__name__)
             tr.end(status="error")
 
-    def _traced_replay(self, req, x, t_start):
-        """One per-request replay with compile/dispatch/device phase
-        attribution: the retrace-counter delta decides whether the call
-        paid a compile, and an explicit block_until_ready (the device
-        phase) separates dispatch from device drain. The launch phase
-        starts at the caller-supplied ``t_start`` and the device-sync
-        timestamp is returned so consecutive phase windows tile exactly
-        (bookkeeping such as the counter reads lands inside a phase, not
-        between two). Tracing-armed requests only -- the untraced path
-        never blocks."""
+    def _launch(self, batch, call):
+        """Run ``call`` (one program launch) as the ``engine.launch``
+        region and charge it to the traced requests of ``batch``:
+        ``compile`` when the launch retraced (the retrace-counter delta
+        decides), ``dispatch`` otherwise."""
+        traced = any(req.trace is not None for req in batch)
+        if traced:
+            before = telemetry.counter_value("engine_trace_total",
+                                             kind="param_replay")
+        with telemetry.region("engine.launch") as rg:
+            out = call()
+        if traced:
+            retraced = telemetry.counter_value(
+                "engine_trace_total", kind="param_replay") > before
+            self._charge(batch, "compile" if retraced else "dispatch",
+                         rg.t1)
+        return out
+
+    def _sync(self, batch, out) -> None:
+        """Block until ``out`` is ready, as the ``engine.sync`` region,
+        and charge the ``device`` phase. Synchronous routes only: a ring
+        entry syncs in :meth:`_retire_oldest`, bounded by its own
+        deadline."""
         import jax
 
-        before = telemetry.counter_value("engine_trace_total",
-                                         kind="param_replay")
-        res = self._maybe_corrupt(
-            x.with_values(self.initial_amps + 0, req.values))
-        t_d = time.perf_counter()
-        jax.block_until_ready(res)
-        t_e = time.perf_counter()
-        retraced = telemetry.counter_value(
-            "engine_trace_total", kind="param_replay") > before
-        req.trace.phase("compile" if retraced else "dispatch",
-                        t_start, t_d - t_start)
-        req.trace.phase("device", t_d, t_e - t_d)
-        return res, t_e
+        with telemetry.region("engine.sync") as rg:
+            jax.block_until_ready(out)
+        self._charge_device(batch, rg.t1)
+
+    def _lookup(self, batch, fetch):
+        """Fetch the executable as the ``engine.lookup`` region. The
+        dispatch preamble before it (context binding, the watchdog hop)
+        is ``dispatch``; the fetch itself ``cache_lookup``."""
+        with telemetry.region("engine.lookup") as rg:
+            x = fetch()
+        self._charge(batch, "dispatch", rg.t0)
+        self._charge(batch, "cache_lookup", rg.t1)
+        return x
 
     def _dispatch_sequential(self, batch: list) -> None:
-        tracing = any(req.trace is not None for req in batch)
-        t_a = time.perf_counter() if tracing else 0.0
-        x = self._exec1()
-        if tracing:
-            t_b = time.perf_counter()
-            for req in batch:
-                if req.trace is not None:
-                    req.trace.phase("cache_lookup", t_a, t_b - t_a)
+        x = self._lookup(batch, self._exec1)
         for req in batch:
             if req.poison is not None:
                 raise PoisonedRequestFault("engine.request", req.poison)
@@ -1023,25 +1075,24 @@ class Engine:
             # count: inside the program it would count traces)
             telemetry.inc("device_dispatch_total",
                           route=self._route or "engine_param")
-            if req.trace is None:
-                res = self._maybe_corrupt(
-                    x.with_values(self.initial_amps + 0, req.values))
-                self._sentinel_gate(res)
-                _sync.resolve_future(req.fut, result=res,
-                                     site="engine.dispatch")
-                continue
-            # sequential replays are serial: time spent on earlier batch
-            # mates is this request's in-batch queueing
-            t_i = time.perf_counter()
-            if t_i > t_b:
-                req.trace.phase("queue_wait", t_b, t_i - t_b)
-            res, t_e = self._traced_replay(req, x, t_i)
+            one = (req,)
+            if req.trace is not None:
+                # sequential replays are serial: time spent on earlier
+                # batch mates is this request's in-batch queueing
+                req.trace.charge("queue_wait", time.perf_counter())
+            res = self._launch(one, lambda: self._maybe_corrupt(
+                x.with_values(self.initial_amps + 0, req.values)))
+            if req.trace is not None:
+                # an explicit sync (the device phase) separates dispatch
+                # from device drain. Tracing-armed requests only -- the
+                # untraced path never blocks.
+                self._sync(one, res)
             self._sentinel_gate(res)
             # trace bookkeeping BEFORE the resolution: a woken waiter
             # must observe its trace already finished (the pool's settle
             # callback runs inside resolve_future and copies the phase
             # vector when it closes the root)
-            self._trace_done(req, t_e, time.perf_counter())
+            self._trace_done(req)
             _sync.resolve_future(req.fut, result=res,
                                  site="engine.dispatch")
 
@@ -1054,42 +1105,22 @@ class Engine:
             # device-rejected lane) -- _bisect isolates it
             if req.poison is not None:
                 raise PoisonedRequestFault("engine.request", req.poison)
-        traced = [req for req in batch if req.trace is not None]
+        traced = any(req.trace is not None for req in batch)
         if not self._lifted.slots:
             # value-free structure: every request computes the same state
             telemetry.inc("device_dispatch_total",
                           route=self._route or "engine_param")
-            t_a = time.perf_counter() if traced else 0.0
-            x = self._exec1()
+            x = self._lookup(batch, self._exec1)
+            out = self._launch(batch, lambda: self._maybe_corrupt(
+                x.with_values(self.initial_amps + 0, ())))
             if traced:
-                import jax
-
-                t_b = time.perf_counter()
-                before = telemetry.counter_value("engine_trace_total",
-                                                 kind="param_replay")
-                out = self._maybe_corrupt(
-                    x.with_values(self.initial_amps + 0, ()))
-                t_c = time.perf_counter()
-                jax.block_until_ready(out)
-                t_d = time.perf_counter()
-                retraced = telemetry.counter_value(
-                    "engine_trace_total", kind="param_replay") > before
-                for req in traced:
-                    tr = req.trace
-                    tr.phase("cache_lookup", t_a, t_b - t_a)
-                    tr.phase("compile" if retraced else "dispatch",
-                             t_b, t_c - t_b)
-                    tr.phase("device", t_c, t_d - t_c)
-            else:
-                out = self._maybe_corrupt(
-                    x.with_values(self.initial_amps + 0, ()))
+                self._sync(batch, out)
             self._sentinel_gate(out)
-            rt = time.perf_counter() if traced else 0.0
-            for req in batch:
-                if req.trace is not None:
-                    self._trace_done(req, rt, time.perf_counter())
-                _sync.resolve_future(req.fut, result=out,
-                                     site="engine.dispatch")
+            with telemetry.region("engine.resolve"):
+                for req in batch:
+                    self._trace_done(req)
+                    _sync.resolve_future(req.fut, result=out,
+                                         site="engine.dispatch")
             return False
         # async pipeline: ring admission (eager retires, the in-flight
         # bound, the serial-issue gate) already ran in _dispatch, outside
@@ -1104,82 +1135,49 @@ class Engine:
         # silently blocks for a full device execution. Host stacking
         # enters the program as plain transfers (bitwise the same lanes)
         # and keeps the whole batch at ~two enqueued computations.
-        t_asm = time.perf_counter() if traced else 0.0
-        pad = self.max_batch - len(batch)
-        vals = [req.values for req in batch] + [batch[-1].values] * pad
-        stacked = tuple(np.stack([np.asarray(v[k]) for v in vals])
-                        for k in range(len(self._lifted.slots)))
-        amps_b = jnp.repeat(self.initial_amps[None], self.max_batch, axis=0)
-        t_a = time.perf_counter() if traced else 0.0
-        fnB = self._execB()
-        if traced:
-            import jax
-
-            t_b = time.perf_counter()
-            before = telemetry.counter_value("engine_trace_total",
-                                             kind="param_replay")
+        with telemetry.region("engine.assemble") as rg:
+            pad = self.max_batch - len(batch)
+            vals = [req.values for req in batch] + [batch[-1].values] * pad
+            stacked = tuple(np.stack([np.asarray(v[k]) for v in vals])
+                            for k in range(len(self._lifted.slots)))
+            amps_b = jnp.repeat(self.initial_amps[None], self.max_batch,
+                                axis=0)
+        self._charge(batch, "dispatch", rg.t1)
+        fnB = self._lookup(batch, self._execB)
         # the whole coalesced batch is ONE vmap program launch
         telemetry.inc("device_dispatch_total",
                       route=self._route or "engine_vmap")
-        out = fnB(amps_b, stacked)
+        out = self._launch(batch, lambda: fnB(amps_b, stacked))
         if defer:
             # ASYNC ISSUE: park the in-flight result on the completion
             # ring and return to coalescing -- the device executes batch k
             # while the host assembles batch k+1. Futures resolve at
-            # retire; so do health credit and latency observation.
-            t_c = time.perf_counter() if traced else 0.0
-            dev_t0 = 0.0
-            if traced:
-                retraced = telemetry.counter_value(
-                    "engine_trace_total", kind="param_replay") > before
-                # jit COMPILE is synchronous at the call site, so a
-                # retraced launch begins device work only at t_c; a warm
-                # launch overlaps device execution with the launch-call
-                # window [t_b, t_c] -- the dispatch and device phases
-                # then legitimately overlap there, and the QT704 union
-                # rule counts the shared window once
-                dev_t0 = t_c if retraced else t_b
-                for req in traced:
-                    tr = req.trace
-                    tr.phase("cache_lookup", t_a, t_b - t_a)
-                    tr.phase("dispatch", t_asm, t_a - t_asm)
-                    tr.phase("compile" if retraced else "dispatch",
-                             t_b, t_c - t_b)
-            self._ring.append([out, batch, self._dispatches, dev_t0, None])
+            # retire; so do health credit, latency observation and the
+            # device phase, which begins where this launch returned (a
+            # jit COMPILE is synchronous at the call site, and a warm
+            # launch's few host microseconds on the device are not worth
+            # two phases that overlap)
+            self._ring.append(_Inflight(out, batch, self._dispatches))
             telemetry.set_gauge("engine_async_inflight", len(self._ring))
             return True
-        if traced:
-            t_c = time.perf_counter()
-            jax.block_until_ready(out)
-            t_d = time.perf_counter()
-            retraced = telemetry.counter_value(
-                "engine_trace_total", kind="param_replay") > before
-            for req in traced:
-                tr = req.trace
-                tr.phase("cache_lookup", t_a, t_b - t_a)
-                tr.phase("dispatch", t_asm, t_a - t_asm)
-                tr.phase("compile" if retraced else "dispatch",
-                         t_b, t_c - t_b)
-                tr.phase("device", t_c, t_d - t_c)
-        elif self.async_depth == 0:
-            # TRUE synchronous baseline: async_depth=0 drains each batch
-            # before resolving it -- the batcher never runs ahead of the
-            # device, the A/B floor the serve bench compares the
-            # completion ring against
-            import jax
-            jax.block_until_ready(out)
+        if traced or self.async_depth == 0:
+            # async_depth=0 is the TRUE synchronous baseline: it drains
+            # each batch before resolving it -- the batcher never runs
+            # ahead of the device, the A/B floor the serve bench compares
+            # the completion ring against
+            self._sync(batch, out)
         # each request's resolve phase runs from the device sync to ITS
         # resolution: lane extraction (a compiled slice on the first
         # run), the sentinel gate, and the wait behind earlier lanes.
         # The windows deliberately overlap -- phases tile each request's
         # own end-to-end latency, they are not a global partition.
-        for i, req in enumerate(batch):
-            lane = self._maybe_corrupt(self._lane(out, i))
-            self._sentinel_gate(lane)
-            if req.trace is not None:
-                self._trace_done(req, t_d, time.perf_counter())
-            _sync.resolve_future(req.fut, result=lane,
-                                 site="engine.dispatch")
+        with telemetry.region("engine.resolve"):
+            for i, req in enumerate(batch):
+                lane = self._maybe_corrupt(self._lane(out, i))
+                self._sentinel_gate(lane)
+                self._trace_done(req)
+                _sync.resolve_future(req.fut, result=lane,
+                                     site="engine.dispatch")
         return False
 
     def _fail_batch(self, batch: list, exc, *, site: str) -> None:
@@ -1199,7 +1197,7 @@ class Engine:
         backpressure bound forces a (then-instant) sync. A buffer without
         a readiness probe counts as ready: retiring it blocks no longer
         than the probe-less sync path always did."""
-        out = self._ring[0][0]
+        out = self._ring[0].out
         probe = getattr(out, "is_ready", None)
         if probe is None:
             return True
@@ -1264,7 +1262,7 @@ class Engine:
             # a 2.3s batch at 20q), so resolve-before-issue -- the
             # latency-optimal order when host and device share the core.
             defer_resolve = self._spare_core()
-            while self._ring and self._ring[0][4] is None:
+            while self._ring and not self._ring[0].synced:
                 self._retire_oldest(sync_only=defer_resolve)
 
     def _ring_settle(self) -> None:
@@ -1272,7 +1270,7 @@ class Engine:
         proved complete -- called right AFTER an issue, so lane
         extraction, the sentinel gate and future resolution run while
         the just-issued batch executes."""
-        while self._ring and self._ring[0][4] is not None:
+        while self._ring and self._ring[0].synced:
             self._retire_oldest()
 
     def _drop_entry(self, entry) -> None:
@@ -1304,7 +1302,7 @@ class Engine:
 
         from ..resilience import guard as _guard
         entry = self._ring[0]
-        out, batch, tick, dev_t0, t_ready = entry
+        out, batch, tick = entry.out, entry.batch, entry.tick
         traced = [r.trace for r in batch if r.trace is not None]
         if traced:
             telemetry.set_current_trace(traced)
@@ -1315,14 +1313,13 @@ class Engine:
         try:
             with telemetry.span("engine.retire", batch=len(batch),
                                 inflight=len(self._ring) - 1,
-                                stage="resolve" if t_ready else "sync"):
-                if t_ready is None:
-                    _guard.device_sync(lambda: jax.block_until_ready(out))
-                    t_ready = entry[4] = time.perf_counter()
-                    for req in batch:
-                        if req.trace is not None and dev_t0:
-                            req.trace.phase("device", dev_t0,
-                                            t_ready - dev_t0)
+                                stage="resolve" if entry.synced else "sync"):
+                if not entry.synced:
+                    with telemetry.region("engine.sync") as rg:
+                        _guard.device_sync(
+                            lambda: jax.block_until_ready(out))
+                    entry.synced = True
+                    self._charge_device(batch, rg.t1)
                 if sync_only:
                     # proven complete, left on the ring: the entry's
                     # resolution is deferred past the next issue
@@ -1330,13 +1327,13 @@ class Engine:
                     return True
                 self._ring.popleft()
                 telemetry.set_gauge("engine_async_inflight", len(self._ring))
-                for i, req in enumerate(batch):
-                    lane = self._maybe_corrupt(self._lane(out, i))
-                    self._sentinel_gate(lane, tick=tick)
-                    if req.trace is not None:
-                        self._trace_done(req, t_ready, time.perf_counter())
-                    _sync.resolve_future(req.fut, result=lane,
-                                         site="engine.retire")
+                with telemetry.region("engine.resolve"):
+                    for i, req in enumerate(batch):
+                        lane = self._maybe_corrupt(self._lane(out, i))
+                        self._sentinel_gate(lane, tick=tick)
+                        self._trace_done(req)
+                        _sync.resolve_future(req.fut, result=lane,
+                                             site="engine.retire")
         except QuESTHangError as e:
             # the device wedged AFTER issue: same quarantine as a
             # synchronous hang, charged to this entry's requests

@@ -150,7 +150,7 @@ class _PoolRequest:
     __slots__ = ("circuit", "fingerprint", "params", "tenant", "priority",
                  "fut", "deadline", "t0", "attempts", "failed", "inner",
                  "hedged", "dispatched_at", "last_exc", "settled",
-                 "trace", "last_span", "mark")
+                 "trace", "last_span")
 
     def __init__(self, circuit, fingerprint, params, tenant, priority,
                  deadline):
@@ -171,7 +171,6 @@ class _PoolRequest:
         self.settled = False
         self.trace = None                 # pool-minted TraceContext root
         self.last_span = None             # most recent attempt/hedge span
-        self.mark = 0.0  # perf_counter of the last phase-attributed point
 
     def remaining(self) -> float | None:
         if self.deadline is None:
@@ -357,7 +356,6 @@ class EnginePool:
                 req.trace = telemetry.start_trace(
                     "request", t0=t_adm, kind="pool", tenant=tenant,
                     priority=priority)
-                req.mark = t_adm
                 if req.trace is not None:
                     req.trace.record_span("pool.admission", t_adm,
                                           t_admitted - t_adm)
@@ -492,10 +490,7 @@ class EnginePool:
         """Open one attempt span (time since the last attributed point
         lands in ``queue_wait``) and link it to the previous attempt --
         the failover/hedge causality edge the waterfall renders."""
-        now = time.perf_counter()
-        if now > req.mark:
-            req.trace.phase("queue_wait", req.mark, now - req.mark)
-        req.mark = now
+        req.trace.charge("queue_wait", time.perf_counter())
         sp = req.trace.child(name, replica=rep.id, attempt=req.attempts)
         if req.last_span is not None:
             sp.link(req.last_span, kind=link_kind)
@@ -504,10 +499,9 @@ class EnginePool:
 
     def _attempt_failed(self, req: _PoolRequest, sp) -> None:
         """Close a failed attempt span; the re-route that follows charges
-        its latency to ``queue_wait`` from here."""
+        what is left of it, and its own latency, to ``queue_wait``."""
         if sp is not None:
             sp.end(status="error")
-            req.mark = time.perf_counter()
 
     def _dispatch_attempt(self, req: _PoolRequest, rep: _Replica) -> None:
         req.attempts += 1
@@ -538,18 +532,14 @@ class EnginePool:
             if req.trace is not None:
                 # engine resolution (a miss builds + compiles) is the
                 # pool-side cache_lookup phase
-                now = time.perf_counter()
-                req.trace.phase("cache_lookup", req.mark, now - req.mark)
-                req.mark = now
+                req.trace.charge("cache_lookup", time.perf_counter())
             f = self._adopted_submit(req, sp, eng)
             if req.trace is not None:
                 # the submit hop (param bind + engine-lock wait, which
-                # can block behind the batcher) is queueing too; the few
-                # microseconds of overlap with the engine-side
-                # queue_wait window are inside the 10% tiling tolerance
-                now = time.perf_counter()
-                req.trace.phase("queue_wait", req.mark, now - req.mark)
-                req.mark = now
+                # can block behind the batcher) is queueing too; pool and
+                # engine charge the trace's one mark, so what the batcher
+                # has charged by now is not charged again
+                req.trace.charge("queue_wait", time.perf_counter())
         except QuESTBackpressureError as e:
             req.failed.add(rep.id)
             req.last_exc = e
@@ -627,14 +617,11 @@ class EnginePool:
             # in _on_done) is the pool-side resolve; a request that never
             # reached an engine only ever waited
             now = time.perf_counter()
-            if now > req.mark:
-                req.trace.phase(
-                    "resolve" if req.dispatched_at is not None
-                    else "queue_wait", req.mark, now - req.mark)
-                req.mark = now
+            req.trace.charge("resolve" if req.dispatched_at is not None
+                             else "queue_wait", now)
             telemetry.finish_trace(
                 req.trace,
-                error=None if exc is None else type(exc).__name__)
+                error=None if exc is None else type(exc).__name__, now=now)
         # resolution happens OUTSIDE the pool lock (the settled flag above
         # is the once-guard); resolve_future re-verifies that under
         # QUEST_CONCHECK=1 (QT602 on any instrumented lock still held)
@@ -646,9 +633,6 @@ class EnginePool:
 
     def _on_done(self, req: _PoolRequest, rep: _Replica, fut,
                  *, hedge: bool) -> None:
-        if req.trace is not None:
-            # engine -> pool handoff: phase attribution resumes here
-            req.mark = time.perf_counter()
         with self._cv:
             mine = next((p[3] for p in req.inner if p[1] is fut), None)
             req.inner = [p for p in req.inner if p[1] is not fut]

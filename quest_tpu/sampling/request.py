@@ -217,7 +217,9 @@ def sample_request(circuit: Circuit, *, targets=None,
                            for i, v in enumerate(_base))
             return _reduce(_body(amps, values), seed)
 
-        inner = jax.jit(whole, donate_argnums=(0,) if donate else ())
+        from ..circuits import named_program
+        inner = jax.jit(named_program(whole, circuit, "sample"),
+                        donate_argnums=(0,) if donate else ())
         sched = _dist.active()
         mesh = sched.mesh if sched else None
         pmesh = fusion.active_pallas_mesh()

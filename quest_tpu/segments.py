@@ -271,8 +271,12 @@ def slice_executable(circuit, lo: int, hi: int, donate: bool = True):
     key = ("segment", circuit._cache_token, lo, hi, donate, mesh, pmesh)
 
     def build():
-        inner = jax.jit(circuit._replay_fn(None, lo=lo, hi=hi),
-                        donate_argnums=(0,) if donate else ())
+        from .circuits import named_program
+        stop = len(circuit._tape) if hi is None else hi
+        inner = jax.jit(
+            named_program(circuit._replay_fn(None, lo=lo, hi=hi), circuit,
+                          "segment", f"i{lo}_{stop}"),
+            donate_argnums=(0,) if donate else ())
 
         def fn(amps, _inner=inner, _mesh=mesh, _pmesh=pmesh):
             from .circuits import _amps_mesh
@@ -318,13 +322,10 @@ def run_slice(circuit, qureg, lo: int = 0, hi: int | None = None, *,
                 import time as _time
 
                 import jax as _jax
-                t0 = _time.perf_counter()
                 out = fn(qureg.amps)
-                t1 = _time.perf_counter()
+                ctx.charge("dispatch", _time.perf_counter())
                 _jax.block_until_ready(out)
-                t2 = _time.perf_counter()
-                ctx.phase("dispatch", t0, t1 - t0)
-                ctx.phase("device", t1, t2 - t1)
+                ctx.charge("device", _time.perf_counter())
                 qureg.put(out)
             else:
                 qureg.put(fn(qureg.amps))
@@ -429,7 +430,10 @@ def request_executable(circuit, donate: bool = True, reduce=None):
                 amps = f(amps)
             return amps if _reduce is None else _reduce(amps, *extra)
 
-        inner = jax.jit(whole, donate_argnums=(0,) if donate else ())
+        from .circuits import named_program
+        inner = jax.jit(
+            named_program(whole, circuit, "request", f"s{len(replays)}"),
+            donate_argnums=(0,) if donate else ())
 
         def fn(amps, *extra, _inner=inner, _mesh=mesh, _pmesh=pmesh):
             from .circuits import _amps_mesh

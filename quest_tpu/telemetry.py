@@ -19,9 +19,22 @@ of them report into and every artifact is derived from:
   aggregates into the registry (count / total_s / max_s) and, optionally,
   streams one JSONL event (``QUEST_TELEMETRY_JSONL=/path`` or
   :func:`export_jsonl`).
+  A span also opens a ``jax.profiler.TraceAnnotation`` of its name for
+  its lifetime: under a profiler session the program's spans sit in the
+  xplane's host lines, on the device trace's clock (with no session, one
+  activity check). Hot paths use :func:`region` instead (``circuit.run``,
+  the engine's per-batch ``engine.*`` regions): aggregate and annotate
+  only -- no ring event, no wall-clock read -- and the handle keeps the
+  window's ``perf_counter`` stamps for the engine's phase attribution.
+- **Compile events from inside JAX** (:func:`watch_jax_compiles`): two
+  ``jax.monitoring`` listeners feed ``jax_trace_seconds``,
+  ``jax_lower_seconds``, ``jax_backend_compile_seconds``,
+  ``jax_cache_retrieval_seconds`` and ``jax_cache_{hits,misses}_total``;
+  nested intervals on one thread are charged once.
 - **Snapshots**: :func:`snapshot` returns the whole registry as one nested
-  JSON-ready dict -- ``bench.py`` embeds it in ``BENCH_DETAIL.json`` so the
-  per-pass / comm-volume / fallback story ships with every headline number.
+  JSON-ready dict -- ``benchmark/run.py`` takes one where set-up ends and
+  one after the window, and every per-layer reader is a pure function of
+  the two (docs/observability.md).
 - **Request traces** (round 17): a :class:`TraceContext`
   (trace_id / span_id / parent_id) minted at ``Engine.submit`` /
   ``EnginePool.submit`` and propagated across every thread hop of the
@@ -64,6 +77,7 @@ Semantics notes:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
@@ -72,7 +86,8 @@ import threading
 import time
 
 __all__ = [
-    "enabled", "disabled", "inc", "set_gauge", "observe", "span", "event",
+    "enabled", "disabled", "inc", "set_gauge", "observe", "span", "region",
+    "event", "watch_jax_compiles",
     "counter_value", "counter_total", "counters", "snapshot", "reset",
     "export_jsonl", "events",
     "PHASES", "TraceContext", "trace_on", "trace_mode", "trace_policy",
@@ -126,12 +141,38 @@ def _series_key(name: str, labels: dict) -> str:
     return name + _label_key(labels)
 
 
+#: ``jax.profiler.TraceAnnotation``, resolved at the first span (telemetry
+#: imports without JAX); False where JAX has no profiler to annotate
+_ANNOTATION = None
+
+
+def _annotate(name: str, labels: dict):
+    """An entered profiler annotation of this name, or None. It puts the
+    program's span into the profiler's host lines, on the device trace's
+    clock; with no profiler session it is one activity check."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:  # pragma: no cover - JAX is a hard dependency
+            cls = False
+        _ANNOTATION = cls
+    if not cls:
+        return None
+    ann = cls(name, **labels)
+    ann.__enter__()
+    return ann
+
+
 class _SpanHandle:
     """One live span: context manager recording a monotonic duration into
-    the registry on exit (and one JSONL event). Nesting is tracked via the
-    registry's thread-local stack; ``path`` is the '/'-joined ancestry."""
+    the registry on exit (and one JSONL event), under a profiler
+    annotation of the same name. Nesting is tracked via the registry's
+    thread-local stack; ``path`` is the '/'-joined ancestry."""
 
-    __slots__ = ("_reg", "name", "labels", "_t0", "path", "duration_s")
+    __slots__ = ("_reg", "name", "labels", "_t0", "path", "duration_s",
+                 "_ann")
 
     def __init__(self, reg: "MetricsRegistry", name: str, labels: dict):
         self._reg = reg
@@ -140,17 +181,21 @@ class _SpanHandle:
         self._t0 = 0.0
         self.path = name
         self.duration_s = None
+        self._ann = None
 
     def __enter__(self):
         stack = self._reg._span_stack()
         if stack:
             self.path = stack[-1].path + "/" + self.name
         stack.append(self)
+        self._ann = _annotate(self.name, self.labels)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.duration_s = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._reg._span_stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -158,12 +203,46 @@ class _SpanHandle:
         return False
 
 
+class _Region:
+    """One live hot-path region (:meth:`MetricsRegistry.region`): the
+    ``perf_counter`` stamps ``t0``/``t1`` of a window on the calling
+    thread, under a profiler annotation of its name. On exit it
+    aggregates count / total / max under its name and writes nothing
+    else. With ``reg`` None (an in-process :func:`disabled` block) it
+    still stamps -- an armed request trace is charged from the stamps --
+    and records nothing."""
+
+    __slots__ = ("_reg", "name", "t0", "t1", "_ann")
+
+    def __init__(self, reg, name: str):
+        self._reg = reg
+        self.name = name
+        self.t0 = self.t1 = 0.0
+        self._ann = None
+
+    def __enter__(self):
+        if self._reg is not None:
+            self._ann = _annotate(self.name, {})
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._reg is not None:
+            self._reg._aggregate_span(self.name, self.t1 - self.t0)
+        return False
+
+
 class _NullSpan:
-    """Shared no-op span for the disabled path (no allocation per call)."""
+    """Shared no-op span and region for the disabled path (no allocation
+    per call)."""
 
     __slots__ = ()
     duration_s = None
     path = ""
+    t0 = t1 = 0.0
 
     def __enter__(self):
         return self
@@ -211,7 +290,9 @@ class MetricsRegistry:
         self._gauges: dict[str, float] = {}
         self._hists: dict[str, dict] = {}
         self._spans: dict[str, dict] = {}
-        self._events: list[dict] = []
+        #: the event ring; its bound is set at the first append, when
+        #: the cap is resolved (:meth:`_events_cap`)
+        self._events: collections.deque = collections.deque()
         self._events_dropped = 0
         #: bounded raw-sample reservoirs backing snapshot percentiles,
         #: series-keyed like _hists (only observe_sampled series get one)
@@ -304,6 +385,17 @@ class MetricsRegistry:
             return _NULL_SPAN
         return _SpanHandle(self, name, labels)
 
+    def region(self, name: str):
+        """Context manager for a HOT-PATH window (``circuit.run``, the
+        engine's per-batch regions): aggregate and annotate only. It
+        counts into the span aggregates (count / total_s / max_s under
+        ``name``) and opens the profiler annotation, and writes no ring
+        event, reads no wall clock and streams no JSONL line -- a span
+        per application would evict the ring's rare events
+        (``pallas.compile``) within minutes. The handle carries the
+        window's ``t0``/``t1`` stamps."""
+        return _Region(self if self.enabled else None, name)
+
     def event(self, name: str, **fields) -> None:
         """Append one raw flight-recorder event (JSONL-exportable)."""
         if not self.enabled:
@@ -311,17 +403,19 @@ class MetricsRegistry:
         self._append_event({"kind": "event", "name": name, "t": time.time(),
                             **fields})
 
-    def _finish_span(self, sp: _SpanHandle) -> None:
-        key = _series_key(sp.name, sp.labels)
+    def _aggregate_span(self, key: str, dur_s: float) -> None:
         with self._lock:
             agg = self._spans.get(key)
             if agg is None:
-                self._spans[key] = {"count": 1, "total_s": sp.duration_s,
-                                    "max_s": sp.duration_s}
+                self._spans[key] = {"count": 1, "total_s": dur_s,
+                                    "max_s": dur_s}
             else:
                 agg["count"] += 1
-                agg["total_s"] += sp.duration_s
-                agg["max_s"] = max(agg["max_s"], sp.duration_s)
+                agg["total_s"] += dur_s
+                agg["max_s"] = max(agg["max_s"], dur_s)
+
+    def _finish_span(self, sp: _SpanHandle) -> None:
+        self._aggregate_span(_series_key(sp.name, sp.labels), sp.duration_s)
         self._append_event({"kind": "span", "name": sp.name, "t": time.time(),
                             "path": sp.path, "dur_s": round(sp.duration_s, 9),
                             **({"labels": sp.labels} if sp.labels else {})})
@@ -348,13 +442,14 @@ class MetricsRegistry:
     def _append_event(self, ev: dict) -> None:
         cap = self._events_cap()
         with self._lock:
-            self._events.append(ev)
-            drop = len(self._events) - cap
-            if drop > 0:
-                del self._events[:drop]
-                self._events_dropped += drop
+            ring = self._events
+            if ring.maxlen != cap:
+                ring = self._events = collections.deque(ring, maxlen=cap)
+            if len(ring) == cap:   # the append below evicts the oldest
+                self._events_dropped += 1
                 key = "telemetry_events_dropped_total"
-                self._counters[key] = self._counters.get(key, 0.0) + drop
+                self._counters[key] = self._counters.get(key, 0.0) + 1
+            ring.append(ev)
         path = self._jsonl_path
         if path:
             self._stream_jsonl(ev, path)
@@ -448,7 +543,7 @@ class MetricsRegistry:
             evs = list(self._events)
             dropped = self._events_dropped
             if clear:
-                self._events = []
+                self._events.clear()
         if dropped:
             evs.insert(0, {"kind": "meta", "events_dropped": dropped,
                            "events_max": self._events_cap()})
@@ -499,6 +594,10 @@ def observe(name: str, value: float, **labels) -> None:
 
 def span(name: str, **labels):
     return REGISTRY.span(name, **labels)
+
+
+def region(name: str):
+    return REGISTRY.region(name)
 
 
 def event(name: str, **fields) -> None:
@@ -632,7 +731,7 @@ class _Trace:
 
     __slots__ = ("trace_id", "name", "labels", "wall0", "perf0", "spans",
                  "links", "events", "phases", "error", "sampled", "done",
-                 "nspans")
+                 "nspans", "mark")
 
     def __init__(self, trace_id, name, labels, wall0, perf0, sampled):
         self.trace_id = trace_id
@@ -648,6 +747,9 @@ class _Trace:
         self.sampled = sampled
         self.done = False
         self.nspans = 0
+        #: perf_counter stamp up to which the request's time has been
+        #: charged to a phase (:meth:`TraceContext.charge`)
+        self.mark = perf0
 
 
 class TraceContext:
@@ -722,13 +824,25 @@ class TraceContext:
         return self._add_span(name, self.span_id, t0,
                               round(dur_s * 1e3, 6), status, labels)
 
-    def phase(self, name: str, t0: float, dur_s: float) -> None:
-        """Attribute ``dur_s`` to the canonical phase ``name``: the trace's
-        phase vector accumulates it AND a closed ``cat="phase"`` span is
-        recorded so the waterfall shows where the time sat."""
+    def charge(self, name: str, t_end: float) -> None:
+        """Attribute to the canonical phase ``name`` the window from the
+        trace's mark (where its last charged window ended; the root's
+        start at first) to ``t_end``, a ``perf_counter`` stamp, and move
+        the mark there: the trace's phase vector accumulates it AND a
+        closed ``cat="phase"`` span is recorded so the waterfall shows
+        where the time sat. Every layer that works for the request
+        charges the one mark, so the phases tile the root span by
+        construction -- what happens between two charged windows falls to
+        the later one, nothing is dropped and nothing counted twice (a
+        ``t_end`` at or before the mark, as from the slower leg of a
+        hedge, charges nothing)."""
         tr = self._tr
-        ms = dur_s * 1e3
         with REGISTRY._lock:
+            t0 = tr.mark
+            if t_end <= t0:
+                return
+            tr.mark = t_end
+            ms = (t_end - t0) * 1e3
             tr.phases[name] = tr.phases.get(name, 0.0) + ms
             sid = f"s{tr.nspans}"
             tr.nspans += 1
@@ -798,15 +912,20 @@ def start_trace(name: str, t0: float | None = None,
     return ctx
 
 
-def finish_trace(ctx: TraceContext | None, error: str | None = None) -> None:
+def finish_trace(ctx: TraceContext | None, error: str | None = None,
+                 now: float | None = None) -> None:
     """Close a trace minted by :func:`start_trace` (idempotent): the root
     span closes, the phase vector is completed to all :data:`PHASES` keys
     and fed into the ``request_phase_ms{phase}`` rollups, and the trace is
-    retained (sampled, or ``error`` is set) or discarded."""
+    retained (sampled, or ``error`` is set) or discarded. ``now`` closes
+    the root at a ``perf_counter`` stamp the caller already holds -- the
+    end of the request's last phase window, so that the phases tile the
+    root exactly and no clock read falls between the two."""
     if ctx is None:
         return
     tr = ctx._tr
-    now = time.perf_counter()
+    if now is None:
+        now = time.perf_counter()
     with REGISTRY._lock:
         if tr.done:
             return
@@ -980,6 +1099,89 @@ def export_traces(path: str) -> int:
 
 
 # ---------------------------------------------------------------------------
+# compile events from inside JAX: which step traced, lowered, compiled
+# ---------------------------------------------------------------------------
+
+#: ``jax.monitoring`` duration event -> the histogram it feeds (seconds)
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_seconds",
+    "/jax/core/compile/backend_compile_duration":
+        "jax_backend_compile_seconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "jax_cache_retrieval_seconds",
+}
+#: ``jax.monitoring`` event -> the counter it feeds
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "jax_cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "jax_cache_misses_total",
+}
+#: intervals one thread keeps to find the nested ones (see below)
+_JAX_INTERVALS_MAX = 1024
+_JAX_WATCHING = False
+
+
+def _own_time(seen, start: float, end: float) -> float:
+    """What the interval [start, end] is charged: its length less the
+    intervals of ``seen`` (one thread's, in the order they ended) nested
+    in it, which it then replaces there. Intervals end in the order they
+    are reported and nest like a stack, so everything nested in this one
+    sits at the tail. ``start`` comes from a duration JAX measured on
+    another clock: a nested interval may seem to begin a moment early."""
+    inner = 0.0
+    while seen and seen[-1][0] >= start - 1e-4:
+        a, b = seen.pop()
+        inner += b - a
+    seen.append((start, end))
+    return max(0.0, end - start - inner)
+
+
+def _jax_duration(event: str, duration: float, **_kw) -> None:
+    """One finished interval of JAX's compile path, reported at its end.
+    JAX reports an inner ``jit``'s trace and again inside its caller's
+    (and a cache retrieval inside the backend compile that made it): an
+    interval is charged its own time LESS what the intervals nested in it
+    on this thread were already charged, whatever their series, so that
+    the four sums together never exceed the wall time of the thread."""
+    name = _JAX_DURATIONS.get(event)
+    if name is None or not REGISTRY.enabled:
+        return
+    end = time.perf_counter()
+    seen = getattr(REGISTRY._local, "jax_intervals", None)
+    if seen is None:
+        seen = REGISTRY._local.jax_intervals = collections.deque(
+            maxlen=_JAX_INTERVALS_MAX)
+    REGISTRY.observe(name, _own_time(seen, end - duration, end))
+
+
+def _jax_event(event: str, **_kw) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is not None:
+        REGISTRY.inc(name)
+
+
+def watch_jax_compiles() -> bool:
+    """Register the two ``jax.monitoring`` listeners that feed
+    ``jax_trace_seconds``, ``jax_lower_seconds``,
+    ``jax_backend_compile_seconds``, ``jax_cache_retrieval_seconds``
+    (histograms: count, sum, max) and ``jax_cache_hits_total`` /
+    ``jax_cache_misses_total``. Called when ``quest_tpu`` is imported;
+    idempotent. They fire only when JAX traces, lowers or compiles:
+    nothing runs on a warm call."""
+    global _JAX_WATCHING
+    if _JAX_WATCHING:
+        return True
+    try:
+        from jax import monitoring
+    except ImportError:  # pragma: no cover - JAX is a hard dependency
+        return False
+    monitoring.register_event_duration_secs_listener(_jax_duration)
+    monitoring.register_event_listener(_jax_event)
+    _JAX_WATCHING = True
+    return True
+
+
+# ---------------------------------------------------------------------------
 # QUEST_TELEMETRY=0: swap the whole surface for no-op stubs at import, so a
 # disabled process pays nothing beyond one module import (no allocation, no
 # lock, no dict lookups -- the "zero-overhead-when-disabled" guarantee)
@@ -1008,7 +1210,10 @@ if not _ENV_ENABLED:  # pragma: no cover - exercised via subprocess test
         return ()
 
     inc = set_gauge = observe = event = reset = _noop  # noqa: F811
-    span = _null_span                                  # noqa: F811
+    span = region = _null_span                         # noqa: F811
+
+    def watch_jax_compiles():                          # noqa: F811
+        return False
     counter_value = counter_total = _zero              # noqa: F811
     counters = _empty_dict                             # noqa: F811
 

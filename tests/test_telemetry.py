@@ -251,8 +251,8 @@ def test_df_tile_mismatch_increments_fallback_not_raises(monkeypatch):
 
 
 def test_pallas_pass_and_compile_telemetry():
-    """A fused Pallas run records pass counts, bytes moved and a compile-
-    seconds observation for its first kernel signature."""
+    """A fused Pallas run records its pass count (one per lowering) and a
+    compile-seconds observation for its first kernel signature."""
     from quest_tpu.ops import pallas_gates as PG
 
     n = 9
@@ -265,8 +265,6 @@ def test_pallas_pass_and_compile_telemetry():
     out = PG.fused_local_run(jax.numpy.asarray(amps), n=n, ops=ops)
     assert out.shape == (2, 1 << n)
     assert telemetry.counter_total("pallas_pass_total") == 1
-    assert telemetry.counter_total("pallas_bytes_moved_total") == \
-        2 * 2 * (1 << n) * np.dtype(dt).itemsize
     snap = telemetry.snapshot("mosaic_compile_seconds")
     assert len(snap["histograms"]) == 1
 

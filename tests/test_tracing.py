@@ -7,7 +7,7 @@ Contracts under test:
   contract);
 - ONE engine request under ``trace_policy("all")`` mints ONE trace whose
   canonical 7-phase vector (queue_wait, coalesce, cache_lookup, compile,
-  dispatch, device, resolve) sums within 10% of its end-to-end latency,
+  dispatch, device, resolve) sums within 1% of its end-to-end latency,
   with every span closed, and exports as Perfetto-loadable Chrome
   trace-event JSON;
 - hedged dispatch: the duplicate span links ``kind="hedge"`` to the
@@ -129,8 +129,11 @@ def test_single_request_full_phase_vector(tmp_path):
     assert t["labels"]["kind"] == "engine"
     assert t["error"] is None and t["dur_ms"] > 0
     assert sorted(t["phases_ms"]) == sorted(PHASES)
+    # the tiling contract (PR 26): consecutive phase windows share their
+    # stamps and the root closes on the last one's, so the vector sums to
+    # the request's latency by construction -- on a loaded host too
     frac = sum(t["phases_ms"].values()) / t["dur_ms"]
-    assert 0.9 <= frac <= 1.1, (frac, t["phases_ms"], t["dur_ms"])
+    assert abs(frac - 1.0) <= 0.01, (frac, t["phases_ms"], t["dur_ms"])
     # every span closed (QT702-clean), root present, one trace_id
     assert all(sp["dur_ms"] is not None for sp in t["spans"])
     assert A.check_traces(trs) == []
@@ -164,7 +167,7 @@ def test_batch_requests_each_get_own_trace():
     assert len({t["trace_id"] for t in trs}) == 4
     for t in trs:
         frac = sum(t["phases_ms"].values()) / t["dur_ms"]
-        assert 0.9 <= frac <= 1.1, (frac, t["phases_ms"])
+        assert abs(frac - 1.0) <= 0.01, (frac, t["phases_ms"])
     assert A.check_live_traces() == []
 
 
