@@ -106,7 +106,7 @@ def _defer_safe(f) -> bool:
 
     if getattr(f, "__module__", None) in _DEFER_SAFE_MODULES:
         return getattr(f, "__name__", "") not in _DEFER_BARRIER_NAMES
-    return f is fusion._apply_dense_block
+    return f in (fusion._apply_dense_block, fusion._apply_deferred_block)
 
 
 def _tape_accesses(tape, num_qubits, is_density, dtype):
@@ -148,20 +148,20 @@ def _tape_accesses(tape, num_qubits, is_density, dtype):
             out.append(None)
             dense_out.append(None)
             continue
+        # fused blocks expose their qubits directly: (qubits, dense?)
+        block = None
         if f is fusion._apply_dense_block:
-            qs = set(args[1])
+            block = (args[1], True)       # FusedBlock: (matrix, qubits)
+        elif getattr(f, "__name__", "") == "_apply_gate_diag":
+            block = (args[1], False)      # DiagBlock: (diag, qubits)
+        elif f is fusion._apply_deferred_block:
+            block = (args[0].qubits, args[0].kind == "dense")
+        if block is not None:
+            qs = set(block[0])
             if is_density:
                 qs |= {q + num_qubits for q in qs}
             out.append(frozenset(qs))
-            dense_out.append(frozenset(qs))
-            continue
-        if getattr(f, "__name__", "") == "_apply_gate_diag":
-            # DiagBlock tape entries: (diag, qubits)
-            qs = set(args[1])
-            if is_density:
-                qs |= {q + num_qubits for q in qs}
-            out.append(frozenset(qs))
-            dense_out.append(frozenset())
+            dense_out.append(frozenset(qs if block[1] else ()))
             continue
         events = fusion.capture(f, args, kwargs, num_qubits, dtype,
                                 is_density=is_density, aux=True)

@@ -167,6 +167,8 @@ def _canon(x):
         return ("hm",) + _canon(np.asarray(x.arr))[1:]
     if isinstance(x, (tuple, list)):
         return ("t", tuple(_canon(e) for e in x))
+    if isinstance(x, dict):  # the kwargs of a nested tape entry
+        return ("d", tuple(sorted((k, _canon(v)) for k, v in x.items())))
     if callable(x):
         return ("fn", getattr(x, "__module__", ""),
                 getattr(x, "__qualname__", repr(x)))

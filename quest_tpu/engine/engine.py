@@ -9,7 +9,10 @@ their own angles) arriving concurrently. Three mechanisms make that cheap:
 - **One executable, many parameter vectors**: the engine replays its
   circuit through the parameterized executable
   (:meth:`quest_tpu.circuits.Circuit.parameterized`), so a warm submit
-  triggers zero retraces -- values are runtime arguments.
+  triggers zero retraces -- values are runtime arguments. A raw tape is
+  fused first (:meth:`Engine._plan_program`): its Param gates join dense
+  window blocks whose matrices are composed inside the program, so a
+  request makes one pass over its state per block, not per gate.
 - **Micro-batching**: ``submit(params)`` returns a
   :class:`concurrent.futures.Future` immediately; a background batcher
   coalesces pending requests up to ``max_batch`` within a ``max_delay_ms``
@@ -182,6 +185,15 @@ class _Inflight:
         self.synced = False
 
 
+def _plan_items(circuit) -> int:
+    """How many entries of ``circuit``'s tape are items of a fusion plan
+    (blocks, kernel runs, frame swaps) rather than recorded gates."""
+    from .. import fusion
+
+    return sum(not isinstance(item, tuple)
+               for item in fusion.plan_from_tape(circuit._tape).items)
+
+
 _ASYNC_ENV = "QUEST_ASYNC_DEPTH"
 _ASYNC_ENV_WARNED: set = set()
 
@@ -219,11 +231,12 @@ class Engine:
     """Serving runtime for one circuit structure (see module docstring).
 
     ``circuit`` may be a raw or fused :class:`~quest_tpu.circuits.Circuit`
-    recorded with :class:`~quest_tpu.engine.params.Param` placeholders (and
-    any constant angles, which are lifted to runtime values too -- see
-    :func:`~quest_tpu.engine.params.lift_tape`). ``env`` supplies the
-    device mesh; with a multi-device env the initial state shards over it
-    and batches replay sequentially. ``initial`` is ``"zero"`` (|0...0>),
+    recorded with :class:`~quest_tpu.engine.params.Param` placeholders. A
+    fused one is replayed as given; a raw one is replayed through its
+    dense plan unless the engine is sharded (:meth:`_plan_program`), so
+    replies agree with the gate-by-gate replay to rounding, not bitwise.
+    ``env`` supplies the device mesh; with a multi-device env the initial
+    state shards over it and batches replay sequentially. ``initial`` is ``"zero"`` (|0...0>),
     ``"plus"``, or a concrete planar (2, 2^nsv) array.
     """
 
@@ -307,7 +320,10 @@ class Engine:
         #: planar initial-state template; each request donates a fresh copy
         self.initial_amps = amps
 
-        self._lifted = circuit.lifted()
+        #: what the two forward executables replay: the circuit's dense
+        #: plan, or the circuit itself (see _plan_program)
+        self._program = self._plan_program()
+        self._lifted = self._program.lifted()
         self.fingerprint = circuit.fingerprint()
         self._cv = _sync.Condition("engine.cv")
         self._q: deque = deque()
@@ -335,11 +351,15 @@ class Engine:
         # (quest_tpu/trajectories), surfaced here for the flight recorder
         self.seed_slots = sum(1 for s in self._lifted.slots
                               if s.kind == _SEED)
+        # the blocks of the replayed program are the full passes a request
+        # makes over its state; the barriers are its gate-by-gate entries
+        blocks = _plan_items(self._program)
         telemetry.event("engine.start", fingerprint=self.fingerprint[:12],
                         nsv=nsv, max_batch=self.max_batch,
                         sharded=self.sharded, async_depth=self.async_depth,
                         params=len(self._lifted.param_names),
-                        seed_slots=self.seed_slots)
+                        seed_slots=self.seed_slots, plan_blocks=blocks,
+                        plan_barriers=len(self._program._tape) - blocks)
 
     # -- submission ---------------------------------------------------------
 
@@ -515,10 +535,14 @@ class Engine:
                     "Engine.submit_grad needs the observable: construct "
                     "the Engine with hamiltonian=(pauli_codes, term_coeffs) "
                     "or a PauliHamil", "Engine.submit_grad")
+        from ..fusion import gatewise
         from ..gradients import grad_reduce
 
-        red = grad_reduce(self.circuit, self._hamiltonian, dtype=self.dtype)
-        eng = Engine(self.circuit, self.env,
+        # the adjoint sweep walks the tape gate by gate: a plan the caller
+        # fused is spelled out again, a raw tape is taken as it is
+        circuit = gatewise(self.circuit)
+        red = grad_reduce(circuit, self._hamiltonian, dtype=self.dtype)
+        eng = Engine(circuit, self.env,
                      precision_code=self._precision_code,
                      max_batch=self.max_batch,
                      max_delay_ms=self.max_delay_s * 1e3,
@@ -624,6 +648,42 @@ class Engine:
 
     # -- executables --------------------------------------------------------
 
+    def _plan_program(self):
+        """The circuit both forward executables replay. A raw tape is
+        fused with the dense planner first: its Param gates join window
+        blocks whose matrices are composed inside the program
+        (fusion._apply_deferred_block), so a request makes one pass over
+        its state per block instead of one per gate -- and ``_exec1`` and
+        ``_execB`` replay the SAME plan, so a lone and a coalesced request
+        run the same arithmetic. Kept as given: a circuit the caller
+        already fused, a sharded engine's circuit (its gates route through
+        the sharding-aware appliers one by one), a circuit under a
+        values-aware finalize (the adjoint sweep walks the raw tape
+        backward), and a tape with seed slots (a trajectory's Kraus draws
+        are thresholds on the state, so a sharded and an unsharded
+        ensemble must do the same arithmetic to walk the same path)."""
+        from .. import fusion
+        from ..circuits import Circuit
+        from ..ops.apply import DENSE_WINDOW_QUBITS
+
+        circuit = self.circuit
+        if self.sharded or getattr(self._finalize, "wants_values", False):
+            return circuit
+        if _plan_items(circuit) or any(s.kind == _SEED
+                                       for s in circuit.lifted().slots):
+            return circuit
+        plan = fusion.plan(tuple(circuit._tape), circuit.num_qubits,
+                           self.dtype, max_qubits=DENSE_WINDOW_QUBITS,
+                           is_density=circuit.is_density_matrix)
+        # the batch executable vmaps the replay, and a static dense block's
+        # own entry may take a kernel over ONE state: every dense block
+        # enters as its factor list, applied through the gate primitive
+        plan.items = [item.factored() if isinstance(item, fusion.FusedBlock)
+                      else item for item in plan.items]
+        program = Circuit(circuit.num_qubits, circuit.is_density_matrix)
+        program._tape = fusion.as_tape(plan)
+        return program
+
     def _exec1(self):
         """The single-request parameterized executable, re-fetched from the
         global LRU per dispatch (warm dispatches therefore count
@@ -632,8 +692,8 @@ class Engine:
         from .. import fusion
 
         with fusion.pallas_mesh(self._mesh):
-            return self.circuit.parameterized(donate=self._donate,
-                                              reduce=self._finalize)
+            return self._program.parameterized(donate=self._donate,
+                                               reduce=self._finalize)
 
     def _execB(self):
         """The vmap-over-params batch executable (unsharded registers):
@@ -647,9 +707,7 @@ class Engine:
         from .. import fusion
         from ..parallel import scheduler as _dist
 
-        key = ("param_vmap", self.fingerprint, self.max_batch, self.dtype.str,
-               self._donate, self._finalize)
-        circuit, donate = self.circuit, self._donate
+        circuit, donate = self._program, self._donate
         finalize = self._finalize
 
         def build():
@@ -689,7 +747,14 @@ class Engine:
 
             return fn
 
-        return _cache.executables().get_or_create(key, build)
+        return _cache.executables().get_or_create(self._batch_key(), build)
+
+    def _batch_key(self) -> tuple:
+        """The executable-cache key of :meth:`_execB`: the fingerprint is
+        the replayed PLAN's, so engines share a batch program exactly when
+        they replay the same structure."""
+        return ("param_vmap", self._program.fingerprint(), self.max_batch,
+                self.dtype.str, self._donate, self._finalize)
 
     # -- batcher ------------------------------------------------------------
 

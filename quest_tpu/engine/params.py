@@ -21,9 +21,11 @@ This module makes values *runtime arguments* of one compiled replay:
 - :func:`materialize_entry` substitutes the slot values back at replay time,
   inside the jit trace, so gate matrices are assembled from *traced* scalars
   (``matrices.py`` carries the traced assembly branches) and one executable
-  replays for arbitrary value vectors -- including through a fused Pallas
-  plan, where parameterized entries ride as apply-time-assembled barriers
-  between the static kernel runs (plan structure never depends on values).
+  replays for arbitrary value vectors -- including through a fused plan
+  (plan structure never depends on values): in a dense plan parameterized
+  entries join window blocks whose matrices are composed inside the program
+  (fusion._apply_deferred_block), in a Pallas plan they ride as
+  apply-time-assembled barriers between the static kernel runs.
 
 Two tapes that differ only in lifted values produce the SAME
 :func:`quest_tpu.engine.cache.structure_fingerprint`, which is what lets the
@@ -147,7 +149,7 @@ def _is_seed_value(x) -> bool:
 def has_params(args, kwargs=None) -> bool:
     """True when a tape entry's arguments carry a :class:`Param` anywhere
     (one level into tuples/lists) -- the fusion planner's pre-check: such
-    entries are apply-time-assembled barriers, never spy-captured."""
+    entries have no matrix at plan time (fusion._entry_has_params)."""
     items = list(args) + list((kwargs or {}).values())
     for x in items:
         if isinstance(x, Param):
@@ -227,6 +229,10 @@ def lift_tape(tape) -> LiftedTape:
 
     for fn, args, kwargs in tape:
         spec = _LIFTABLE.get(getattr(fn, "__name__", ""), {})
+        if hasattr(fn, "_lift_positions"):
+            # a fused block whose matrix is assembled in the program
+            # (fusion._apply_deferred_block) says where its values ride
+            spec = fn._lift_positions(args)
         new_args = []
         for i, v in enumerate(args):
             kind = spec.get(i)

@@ -909,11 +909,8 @@ class EnginePool:
                     eng = rep.engines.get(fp)
                 try:
                     if eng is not None and eng._open:
-                        key = ("param_vmap", eng.fingerprint,
-                               eng.max_batch, eng.dtype.str, eng._donate,
-                               eng._finalize)
-                        if eng._mode() != "vmap" or \
-                                _ec.executables().peek(key) is not None:
+                        if eng._mode() != "vmap" or _ec.executables().peek(
+                                eng._batch_key()) is not None:
                             telemetry.inc("engine_precompile_total",
                                           outcome="cached")
                             continue

@@ -15,8 +15,9 @@ This is the standard dense-fusion technique of state-vector simulators
 reference itself has no analogue -- it is pure TPU-side gain.
 
 Mechanics: each recorded tape entry is *replayed once against a spy
-register* with the gate-application primitives patched to record
-(kind, operands, qubits) instead of touching any device array. Entries
+register*: handed a spy, the gate-application primitives record
+(kind, operands, qubits) instead of touching any device array (the spy
+carries its recorders, ops.spy: nothing process-wide is patched). Entries
 that don't route through the four gate primitives (decoherence, phase
 functions, state inits, ...) simply fail capture and act as fusion
 barriers, passing through to the device path unchanged -- so ``fused()``
@@ -28,8 +29,9 @@ diagonal kernel (no matmul, one VPU pass) instead of a dense GEMM.
 
 from __future__ import annotations
 
-import contextlib
+import dataclasses
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Optional, Sequence
 
 import numpy as np
@@ -39,6 +41,7 @@ from . import telemetry
 # the ambient replay mesh lives with the mesh (environment.py) so the
 # register layer can read it; fusion.pallas_mesh stays the documented name
 from .environment import active_pallas_mesh, pallas_mesh  # noqa: F401
+from .ops.spy import Spy
 
 
 # ---------------------------------------------------------------------------
@@ -50,6 +53,16 @@ class GateEvent:
     """One primitive application captured from a tape entry.
 
     kind: 'matrix' | 'diag' | 'x' | 'parity' | 'swap' | 'channel'
+
+    Events captured from an entry recorded with engine.params.Param carry
+    ``source = (entry, index, count)``: the ``(fn, args, kwargs)`` tape
+    entry, the event's position among the ``count`` events that entry
+    captures. Those whose coefficients are runtime values are DEFERRED
+    (``theta`` None and no ``matrix`` / ``diag``): only the structure --
+    kind, targets, controls, control states -- is known at plan time, and
+    the operand is produced at trace time by running the same capture on
+    the materialised entry (:func:`_resolve_factors`), where it may be a
+    traced array.
 
     ``extended=True`` marks events that take no conj-shadow twin during
     density planning. For 'diag' events captured from the dephasing
@@ -64,30 +77,43 @@ class GateEvent:
     states: tuple = ()
     matrix: Optional[np.ndarray] = None   # 'matrix': (2^t, 2^t) complex
     diag: Optional[np.ndarray] = None     # 'diag':   (2^t,) complex
-    theta: float = 0.0                    # 'parity'
+    theta: Optional[float] = 0.0          # 'parity'; None: deferred
     superop: Optional[np.ndarray] = None  # 'channel': (4^t, 4^t) complex
     extended: bool = False                # targets already in 2n coords
+    source: Optional[tuple] = None        # (entry, event index, count)
 
     @property
     def support(self) -> frozenset:
         return frozenset(self.targets) | frozenset(self.controls)
 
+    @property
+    def deferred(self) -> bool:
+        return self.theta is None
 
-class _SpyAmps:
+    @property
+    def structure(self) -> tuple:
+        return (self.kind, tuple(self.targets), tuple(self.controls),
+                tuple(self.states))
+
+
+class _SpyAmps(Spy):
     """Stands in for ``qureg.amps`` during capture: carries a dtype for
     validation tolerances, raises on any real use."""
 
-    def __init__(self, dtype):
+    def __init__(self, dtype, recorders):
         self.dtype = dtype
+        self.recorders = recorders
 
 
-class _SpyQureg:
-    """Minimal stand-in satisfying validation + the patched primitives."""
+class _SpyQureg(Spy):
+    """Minimal stand-in satisfying validation + the capturable primitives
+    (ops.spy.records): they hand their arguments to ``recorders``."""
 
-    def __init__(self, num_qubits: int, is_density: bool, dtype):
+    def __init__(self, num_qubits: int, is_density: bool, dtype, recorders):
         self.num_qubits_represented = int(num_qubits)
         self.is_density_matrix = bool(is_density)
-        self.amps = _SpyAmps(dtype)
+        self.recorders = recorders
+        self.amps = _SpyAmps(dtype, recorders)
         self.qasm_log = None
         self.env = None
 
@@ -107,12 +133,10 @@ class _SpyQureg:
         self.amps = amps
 
 
-@contextlib.contextmanager
-def _channel_capture_ctx(events: list):
-    """Patch the density-channel appliers in :mod:`.ops.density` to record
-    events: Kraus channels (via apply_channel) and dephasing diagonals (via
+def _channel_recorders(events: list) -> dict:
+    """Recorders of the density-channel appliers in :mod:`.ops.density`:
+    Kraus channels (via apply_channel) and dephasing diagonals (via
     _diag_dispatch) -- both in flattened 2n coordinates."""
-    from .ops import density as DN
 
     def cap_channel(amps, superop, *, n, targets):
         events.append(GateEvent(
@@ -126,28 +150,17 @@ def _channel_capture_ctx(events: list):
                                 extended=True))
         return amps
 
-    saved = (DN.apply_channel, DN._diag_dispatch)
-    DN.apply_channel = cap_channel
-    DN._diag_dispatch = cap_dens_diag
-    try:
-        yield
-    finally:
-        DN.apply_channel, DN._diag_dispatch = saved
+    return {"apply_channel": cap_channel, "_diag_dispatch": cap_dens_diag}
 
 
-@contextlib.contextmanager
-def _aux_capture_ctx(events: list):
-    """Patch the operator-level kernel appliers (phase functions, direct
-    diagonals, projections, raw matrix applications) to record ACCESS-ONLY
+def _aux_recorders(events: list) -> dict:
+    """Recorders of the operator-level kernel appliers (phase functions,
+    direct diagonals, projections, raw matrix applications): ACCESS-ONLY
     events (kind 'aux': support coordinates, no operator data). Only the
     deferred scheduler's lookahead (circuits._tape_accesses) uses these --
     the fuser never captures with them, so operator entries keep acting as
     fusion barriers while still exposing their qubit sets to Belady
     eviction."""
-    from .ops import apply as KA
-    from .ops import diagonal as DG
-    from .ops import measure as MS
-    from .ops import phasefunc as PFK
 
     def cap_phase(amps, *a, **kw):
         events.append(GateEvent("aux", tuple(kw["qubits"])))
@@ -165,71 +178,144 @@ def _aux_capture_ctx(events: list):
         events.append(GateEvent("aux", tuple(targets), tuple(controls)))
         return amps
 
-    saved = (PFK.apply_poly_phase, PFK.apply_named_phase, DG.apply_diagonal,
-             MS.project_statevec, KA.apply_matrix)
-    PFK.apply_poly_phase = cap_phase
-    PFK.apply_named_phase = cap_phase
-    DG.apply_diagonal = cap_diag
-    MS.project_statevec = cap_project
-    KA.apply_matrix = cap_matrix
-    try:
-        yield
-    finally:
-        (PFK.apply_poly_phase, PFK.apply_named_phase, DG.apply_diagonal,
-         MS.project_statevec, KA.apply_matrix) = saved
+    return {"apply_poly_phase": cap_phase, "apply_named_phase": cap_phase,
+            "apply_diagonal": cap_diag, "project_statevec": cap_project,
+            "apply_matrix": cap_matrix}
 
 
-@contextlib.contextmanager
-def _capture_ctx(events: list):
-    """Patch the gate primitives in :mod:`.gates` to record events."""
-    from . import gates as G
-    from .ops import apply as K
+def _gate_recorders(events: list) -> dict:
+    """Recorders of the gate primitives in :mod:`.gates` (and the swap
+    kernel swapGate calls inline)."""
+    from .matrices import is_traced
 
+    # operands assembled from runtime values (matrices.py's traced
+    # branches) are kept as they come: a deferred block composes them
+    # inside the trace (_compose_dense / _compose_diag)
     def cap_matrix(qureg, matrix, targets, controls=(), states=()):
         events.append(GateEvent(
             "matrix", tuple(targets), tuple(controls), tuple(states),
-            matrix=np.asarray(matrix, dtype=complex)))
+            matrix=matrix if is_traced(matrix)
+            else np.asarray(matrix, dtype=complex)))
 
     def cap_diag(qureg, diag, targets, controls=()):
         events.append(GateEvent(
             "diag", tuple(targets), tuple(controls),
-            diag=np.asarray(diag, dtype=complex).reshape(-1)))
+            diag=diag.reshape(-1) if is_traced(diag)
+            else np.asarray(diag, dtype=complex).reshape(-1)))
 
     def cap_x(qureg, targets, controls=(), states=()):
         events.append(GateEvent("x", tuple(targets), tuple(controls), tuple(states)))
 
     def cap_parity(qureg, theta, qubits, controls=()):
         events.append(GateEvent(
-            "parity", tuple(qubits), tuple(controls), theta=float(theta)))
+            "parity", tuple(qubits), tuple(controls),
+            theta=theta if is_traced(theta) else float(theta)))
 
     def cap_swap(amps, *, n, qb1, qb2, controls=()):
         events.append(GateEvent("swap", (qb1, qb2), tuple(controls)))
         return amps
 
-    saved = (G._apply_gate_matrix, G._apply_gate_diag, G._apply_gate_x,
-             G._apply_gate_parity_phase, K.apply_swap)
-    G._apply_gate_matrix = cap_matrix
-    G._apply_gate_diag = cap_diag
-    G._apply_gate_x = cap_x
-    G._apply_gate_parity_phase = cap_parity
-    K.apply_swap = cap_swap
-    try:
-        yield
-    finally:
-        (G._apply_gate_matrix, G._apply_gate_diag, G._apply_gate_x,
-         G._apply_gate_parity_phase, K.apply_swap) = saved
+    return {"_apply_gate_matrix": cap_matrix, "_apply_gate_diag": cap_diag,
+            "_apply_gate_x": cap_x, "_apply_gate_parity_phase": cap_parity,
+            "apply_swap": cap_swap}
 
 
 def _entry_has_params(args, kwargs) -> bool:
-    """True when a tape entry carries engine.params.Param placeholders: the
-    planner never spy-captures it (there is no concrete matrix to fuse at
-    plan time) -- the entry passes through as a barrier whose matrix is
-    assembled from the traced runtime scalars at apply time, so the plan's
-    STRUCTURE stays value-independent and one compiled replay serves every
-    parameter vector."""
+    """True when a tape entry carries engine.params.Param placeholders:
+    there is no concrete matrix to fuse at plan time. The dense planner
+    captures such an entry's STRUCTURE (:func:`_capture_deferred`) and
+    lets it join a block whose matrix is assembled inside the program; the
+    Pallas planner passes it through as a barrier assembled at apply time.
+    Either way the plan's structure stays value-independent and one
+    compiled replay serves every parameter vector."""
     from .engine.params import has_params
 
     return has_params(args, kwargs)
+
+
+def _event_traced(ev: GateEvent) -> bool:
+    from .matrices import is_traced
+
+    return is_traced(ev.matrix, ev.diag, ev.theta)
+
+
+def _deferrable(ev: GateEvent) -> bool:
+    """The deferred factors the in-trace composition takes: what the
+    liftable family (engine.params._LIFTABLE) captures to -- one-target
+    matrices and diagonals under any controls, and parity phases."""
+    if ev.kind == "parity":
+        return True
+    return ev.kind in ("matrix", "diag") and len(ev.targets) == 1
+
+
+def _capture_deferred(entry, num_qubits: int, dtype) -> Optional[list]:
+    """Structure-only capture of a tape entry that carries Params: the
+    entry is replayed against the spy under ``jax.eval_shape`` with its
+    value slots abstract, so every gate builder takes its traced branch
+    and whatever needs a value to decide its structure raises a
+    concretization error (the entry then stays a barrier; any other error
+    is a defect and propagates). Every event names its ``source``; those
+    whose operands came out traced are returned DEFERRED (no data),
+    operands that never saw a value (multiRotatePauli's basis changes)
+    stay. None when the entry cannot be captured, holds a deferred event
+    the composition does not take, or defers nothing (its Params would
+    vanish from the plan)."""
+    import jax
+
+    from .engine.params import bind, lift_tape, materialize_entry
+    from .validation import QuESTError
+
+    if getattr(entry[0], "_fusion_barrier", False):
+        return None
+    try:
+        lifted = lift_tape((entry,))
+    except QuESTError:
+        # a Param where the lifter has no slot: the replay names it
+        return None
+    got = []
+
+    def run(values):
+        events = _spy_replay(*materialize_entry(lifted.entries[0], values),
+                             num_qubits, dtype)
+        for i, ev in enumerate(events):
+            source = (entry, i, len(events))
+            got.append(
+                GateEvent(ev.kind, ev.targets, ev.controls, ev.states,
+                          theta=None, source=source) if _event_traced(ev)
+                else dataclasses.replace(ev, source=source))
+
+    try:
+        jax.eval_shape(run, bind(lifted, dict.fromkeys(
+            lifted.param_names, 0.0)))
+    except (jax.errors.ConcretizationTypeError,
+            jax.errors.TracerArrayConversionError,
+            jax.errors.TracerIntegerConversionError):
+        return None
+    deferred = [ev for ev in got if ev.deferred]
+    if not deferred or not all(_deferrable(ev) for ev in deferred):
+        return None
+    return got
+
+
+def _spy_replay(fn, args, kwargs, num_qubits: int, dtype,
+                density_spy: bool = False, aux: bool = False) -> list:
+    """The GateEvents ``fn`` records on a spy register (:func:`capture`
+    says which); raises whatever ``fn`` raises on one."""
+    from .parallel import scheduler as _dist
+
+    events: list = []
+    recorders = _gate_recorders(events)
+    if density_spy:
+        recorders.update(_channel_recorders(events))
+    if aux:
+        recorders.update(_aux_recorders(events))
+    shell = _SpyQureg(num_qubits, density_spy, dtype, recorders)
+    # suspend any active distributed scheduler: the spy replay must not
+    # route through (or mutate) it -- swapGate's inline dispatch would
+    # otherwise record phantom virtual swaps in its layout/stats
+    with _dist.explicit_mesh(None):
+        fn(shell, *args, **kwargs)
+    return events
 
 
 def capture(fn, args, kwargs, num_qubits: int, dtype,
@@ -244,15 +330,16 @@ def capture(fn, args, kwargs, num_qubits: int, dtype,
     op too, and shadows are derived at planning/emission instead. Entries
     that fail that attempt on a density tape (decoherence channels, whose
     validation demands a density register) get a second attempt against a
-    density spy with the channel appliers patched -- their events carry
-    flattened-state coordinates and ``extended=True``.
+    density spy that also records the channel appliers -- their events
+    carry flattened-state coordinates and ``extended=True``.
 
-    ``aux=True`` additionally patches the operator-level appliers
-    (_aux_capture_ctx) so phase-function/projector/matrixN entries yield
+    The spy carries its recorders (ops.spy): no process-wide state is
+    touched, so captures run beside real traces in any number of threads.
+
+    ``aux=True`` additionally records the operator-level appliers
+    (_aux_recorders) so phase-function/projector/matrixN entries yield
     access-only 'aux' events -- used by the deferred scheduler's lookahead,
     never by the fuser (aux events carry no operator data)."""
-    from .parallel import scheduler as _dist
-
     # trajectory-noise sites (and anything else tagged _fusion_barrier)
     # assemble their operator at apply time from runtime PRNG draws: there
     # is no static event to capture, even with a constant seed. The
@@ -264,35 +351,18 @@ def capture(fn, args, kwargs, num_qubits: int, dtype,
     if getattr(fn, "_fusion_barrier", False):
         return None
 
-    aux_ctx = _aux_capture_ctx if aux else _null_ctx
-    events: list = []
-    shell = _SpyQureg(num_qubits, False, dtype)
     try:
-        # suspend any active distributed scheduler: the spy replay must not
-        # route through (or mutate) it -- swapGate's inline dispatch would
-        # otherwise record phantom virtual swaps in its layout/stats
-        with _dist.explicit_mesh(None), _capture_ctx(events), \
-                aux_ctx(events):
-            fn(shell, *args, **kwargs)
-        return events if events else None
+        return _spy_replay(fn, args, kwargs, num_qubits, dtype,
+                           aux=aux) or None
     except Exception:
         pass
     if not is_density:
         return None
-    events = []
-    shell = _SpyQureg(num_qubits, True, dtype)
     try:
-        with _dist.explicit_mesh(None), _capture_ctx(events), \
-                _channel_capture_ctx(events), aux_ctx(events):
-            fn(shell, *args, **kwargs)
+        return _spy_replay(fn, args, kwargs, num_qubits, dtype,
+                           density_spy=True, aux=aux) or None
     except Exception:
         return None
-    return events if events else None
-
-
-@contextlib.contextmanager
-def _null_ctx(events):
-    yield
 
 
 def event_dagger(ev: GateEvent) -> GateEvent:
@@ -431,18 +501,62 @@ class FusedBlock:
     Contiguity is load-bearing: a contiguous window applies with zero
     transposes as one MXU GEMM (ops.apply._apply_matrix_window), whereas
     scattered targets take the grouped-transpose path whose high-rank
-    intermediates tile-pad catastrophically at large n."""
+    intermediates tile-pad catastrophically at large n.
+
+    A block any of whose factors is deferred (GateEvent.deferred) has no
+    product at plan time: ``matrix`` is None and ``factors`` holds the
+    ordered GateEvents (first applied first), the block's static prefix as
+    one of them; :func:`_compose_dense` multiplies them out at apply time
+    and the product goes through the gate primitive."""
     qubits: tuple            # ascending contiguous run; qubits[j] is bit j
-    matrix: np.ndarray       # (2^k, 2^k) complex
+    matrix: Optional[np.ndarray]      # (2^k, 2^k) complex; None if deferred
+    factors: Optional[tuple] = None   # deferred: the ordered GateEvents
+
+    def factored(self) -> "FusedBlock":
+        """The block as its factor list (a static block: its product as
+        the one factor), the form that is applied through the gate
+        primitive -- the XLA window GEMM, which batches under ``vmap`` --
+        and never through :func:`_apply_dense_block`'s lane kernel over
+        ONE (2, 2^n) state."""
+        return FusedBlock(self.qubits, None, _block_factors(self))
 
 
 @dataclass
 class DiagBlock:
     """An accumulated diagonal over (possibly scattered) support qubits --
     diagonals broadcast against the grouped view without any transpose, so
-    they need no window constraint."""
+    they need no window constraint. Deferred like :class:`FusedBlock`:
+    ``diag`` None, ``factors`` the ordered diagonal-kind GateEvents."""
     qubits: tuple            # ascending; qubits[j] is bit j of the diag index
-    diag: np.ndarray         # (2^k,) complex
+    diag: Optional[np.ndarray]        # (2^k,) complex; None if deferred
+    factors: Optional[tuple] = None
+
+
+def _block_factors(block) -> tuple:
+    """A block as an ordered factor list: its own when deferred, else its
+    static product as the one factor."""
+    if block.factors is not None:
+        return block.factors
+    if isinstance(block, DiagBlock):
+        return (GateEvent("diag", block.qubits, diag=block.diag),)
+    return (GateEvent("matrix", block.qubits, matrix=block.matrix),)
+
+
+@dataclass
+class DeferredBlock:
+    """What a deferred block's tape entry carries
+    (``(_apply_deferred_block, (DeferredBlock, *values), {})``): the
+    block's factors with each ``source`` rewritten to ``(index into
+    entries, event index, count)``, and ``entries``, the source tape entries
+    as lifted templates (engine.params.lift_tape) whose slots are the
+    entry's trailing ``values``, kinds in ``slot_kinds``. Everything here
+    is structure: the values ride beside it, where ``lift_tape`` finds
+    them."""
+    kind: str                # 'dense' | 'diag'
+    qubits: tuple
+    factors: tuple
+    entries: tuple
+    slot_kinds: tuple
 
 
 @dataclass
@@ -936,6 +1050,8 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
     more high qubits for the frame machinery to relabel (the round-2 build
     excluded density tapes entirely; VERDICT r2 missing #1).
     """
+    from .ops.apply import _MIN_MINOR, MAX_LOW_WINDOW_TOP
+
     nsv = (2 if is_density else 1) * num_qubits
     if pallas_tile_bits is not None:
         with telemetry.span("fusion.plan", mode="pallas"):
@@ -956,60 +1072,92 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
         cur = None
 
     def window_ok(joint):
-        return len(joint) <= max_qubits
+        # a window that starts below the lane boundary is lowered as a
+        # GEMM over EVERY qubit below its top (ops.apply.
+        # _apply_matrix_window kron-expands it down to qubit 0), so its
+        # price is 2^(hi+1), not 2^len: past MAX_LOW_WINDOW_TOP that is a
+        # multi-GiB operand. No window of <= 5 qubits reaches it
+        return len(joint) <= max_qubits and (
+            joint[0] >= _MIN_MINOR or joint[-1] < MAX_LOW_WINDOW_TOP)
 
     def add_dense(ev):
         nonlocal cur
         win = _window(ev.support)
         if isinstance(cur, DiagBlock):
             joint = _window(set(cur.qubits) | ev.support)
-            if window_ok(joint):
+            if not window_ok(joint):
+                flush()
+            elif cur.factors is not None:
+                # diagonal-kind events are dense factors as they stand
+                cur = FusedBlock(joint, None, cur.factors)
+            else:
                 cur = FusedBlock(joint, np.diag(
                     _event_diag(GateEvent("diag", cur.qubits, diag=cur.diag),
                                 joint)))
-            else:
-                flush()
         if isinstance(cur, FusedBlock):
             joint = _window(set(cur.qubits) | ev.support)
             if window_ok(joint):
-                U = _embed_block(cur.matrix, cur.qubits, joint)
-                cur = FusedBlock(joint, event_matrix(ev, joint) @ U)
+                if not ev.deferred and cur.factors is None:
+                    U = _embed_block(cur.matrix, cur.qubits, joint)
+                    cur = FusedBlock(joint, event_matrix(ev, joint) @ U)
+                else:
+                    cur = FusedBlock(joint, None, _block_factors(cur) + (ev,))
                 return
             flush()
-        cur = FusedBlock(win, event_matrix(ev, win))
+        cur = (FusedBlock(win, event_matrix(ev, win)) if not ev.deferred
+               else FusedBlock(win, None, (ev,)))
 
     def add_diag(ev):
         nonlocal cur
+        static = not ev.deferred and (cur is None or cur.factors is None)
         if isinstance(cur, FusedBlock):
             joint = _window(set(cur.qubits) | ev.support)
             if window_ok(joint):
-                cur = FusedBlock(joint,
-                                 np.diag(_event_diag(ev, joint)) @
-                                 _embed_block(cur.matrix, cur.qubits, joint))
+                if static:
+                    cur = FusedBlock(
+                        joint, np.diag(_event_diag(ev, joint)) @
+                        _embed_block(cur.matrix, cur.qubits, joint))
+                else:
+                    cur = FusedBlock(joint, None, _block_factors(cur) + (ev,))
                 return
+            flush()
+        if isinstance(cur, DiagBlock) and ev.deferred \
+                and cur.factors is None:
+            # a deferred factor would turn the block's constant table into
+            # a traced one: on the chip a diagonal pass with a traced table
+            # over (0, 19) took 11.7 ms of a batch of 20q lanes where the
+            # constant one takes 0.2 (PR 27, PERF.md). It opens a block of
+            # its own, which the next dense factor turns into a window
             flush()
         if isinstance(cur, DiagBlock):
             joint = tuple(sorted(set(cur.qubits) | ev.support))
             if len(joint) <= max_diag_qubits:
-                d = _event_diag(GateEvent("diag", cur.qubits, diag=cur.diag), joint)
-                cur = DiagBlock(joint, d * _event_diag(ev, joint))
+                if static:
+                    d = _event_diag(
+                        GateEvent("diag", cur.qubits, diag=cur.diag), joint)
+                    cur = DiagBlock(joint, d * _event_diag(ev, joint))
+                else:
+                    cur = DiagBlock(joint, None, _block_factors(cur) + (ev,))
                 return
             flush()
         qs = tuple(sorted(ev.support))
-        cur = DiagBlock(qs, _event_diag(ev, qs))
+        cur = (DiagBlock(qs, _event_diag(ev, qs)) if not ev.deferred
+               else DiagBlock(qs, None, (ev,)))
 
-    for fn, args, kwargs in tape:
-        if _entry_has_params(args, kwargs):
-            flush()
-            out.items.append((fn, args, kwargs))
-            out.num_barriers += 1
-            telemetry.inc("fusion_param_barriers_total", mode="dense")
-            continue
-        events = capture(fn, args, kwargs, num_qubits, dtype)
+    for entry in tape:
+        fn, args, kwargs = entry
+        # an entry with Params joins blocks by its structure alone; its
+        # coefficients are assembled in the program (_capture_deferred)
+        has_params = _entry_has_params(args, kwargs)
+        events = (_capture_deferred(entry, num_qubits, dtype) if has_params
+                  else capture(fn, args, kwargs, num_qubits, dtype))
         fusible = events is not None and all(
             (len(ev.support) <= max_diag_qubits) if _event_is_diag(ev)
-            else (len(_window(ev.support)) <= max_qubits)
+            else window_ok(_window(ev.support))
             for ev in events)
+        if has_params:
+            telemetry.inc("fusion_param_fused_total" if fusible
+                          else "fusion_param_barriers_total", mode="dense")
         if not fusible:
             flush()
             out.items.append((fn, args, kwargs))
@@ -1117,7 +1265,8 @@ def transpose_stats(p: FusePlan, shard_qubits: int | None,
 def plan_from_tape(tape) -> FusePlan:
     """Decode an ``as_tape`` tape back into a :class:`FusePlan` -- the
     ONE decoder of the `_apply_pallas_run` / `_apply_frame_swap` /
-    `_apply_dense_block` / `_apply_gate_diag` tape-entry layouts
+    `_apply_dense_block` / `_apply_gate_diag` / `_apply_deferred_block`
+    tape-entry layouts
     (:func:`as_tape` is the encoder). Entries that aren't plan items pass
     through verbatim as ``(fn, args, kwargs)`` tuples, so
     ``plan_from_tape(as_tape(p))`` round-trips. Used by the bench
@@ -1150,6 +1299,17 @@ def plan_from_tape(tape) -> FusePlan:
             p.items.append(FusedBlock(tuple(a[1]), a[0]))
         elif name == "_apply_gate_diag":
             p.items.append(DiagBlock(tuple(a[1]), a[0]))
+        elif name == "_apply_deferred_block":
+            from .engine.params import materialize_entry
+
+            spec, values = a[0], a[1:]
+            entries = [materialize_entry(e, values) for e in spec.entries]
+            factors = tuple(
+                ev if ev.source is None else dataclasses.replace(
+                    ev, source=(entries[ev.source[0]],) + ev.source[1:])
+                for ev in spec.factors)
+            block = DiagBlock if spec.kind == "diag" else FusedBlock
+            p.items.append(block(tuple(spec.qubits), None, factors))
         else:
             p.items.append(entry)
     return p
@@ -1950,6 +2110,292 @@ def _apply_dense_block(qureg, U: np.ndarray, qubits: tuple) -> None:
     G._apply_gate_matrix(qureg, U, qubits)
 
 
+# ---------------------------------------------------------------------------
+# deferred blocks: factors composed inside the program
+# ---------------------------------------------------------------------------
+
+def _deferred_entry(block) -> tuple:
+    """The tape entry of a block with deferred factors (the encoder of
+    :class:`DeferredBlock`; :func:`plan_from_tape` decodes)."""
+    from .engine.params import Param, lift_tape
+
+    sources, index = [], {}     # the distinct source entries, by identity
+    for ev in block.factors:
+        if ev.source is not None and id(ev.source[0]) not in index:
+            index[id(ev.source[0])] = len(sources)
+            sources.append(ev.source[0])
+    lifted = lift_tape(tuple(sources))
+    factors = tuple(
+        ev if ev.source is None else dataclasses.replace(
+            ev, source=(index[id(ev.source[0])],) + ev.source[1:])
+        for ev in block.factors)
+    spec = DeferredBlock("diag" if isinstance(block, DiagBlock) else "dense",
+                         tuple(block.qubits), factors, lifted.entries,
+                         tuple(s.kind for s in lifted.slots))
+    values = tuple(Param(s.name) if s.name is not None else s.default
+                   for s in lifted.slots)
+    return (_apply_deferred_block, (spec,) + values, {})
+
+
+def _resolve_factors(spec: DeferredBlock, values, num_qubits: int,
+                     dtype) -> list:
+    """The block's factors with every deferred one given its operand: each
+    source entry is materialised with ``values`` (traced inside a
+    parameterized replay, host scalars in a constant one) and captured
+    again by the capture that planned it."""
+    from .engine.params import materialize_entry
+
+    captured = {}
+    out = []
+    for ev in spec.factors:
+        if not ev.deferred:
+            out.append(ev)
+            continue
+        i, j = ev.source[:2]
+        if i not in captured:
+            captured[i] = capture(*materialize_entry(spec.entries[i], values),
+                                  num_qubits, dtype)
+        events = captured[i]
+        if events is None or len(events) <= j \
+                or events[j].structure != ev.structure:
+            name = getattr(spec.entries[i][0], "__name__", "entry")
+            raise ValueError(
+                f"'{name}' no longer captures the structure it was planned "
+                f"with: {ev.structure}")
+        out.append(events[j])
+    return out
+
+
+def _controls_ok(ev: GateEvent, qubits: Sequence[int]) -> np.ndarray:
+    """Static mask over the 2^k indices of ``qubits``: True where every
+    control of ``ev`` reads its required state."""
+    rows = np.arange(1 << len(qubits))
+    ok = np.ones(rows.shape, dtype=bool)
+    states = ev.states if ev.states else (1,) * len(ev.controls)
+    for c, st in zip(ev.controls, states):
+        ok &= ((rows >> list(qubits).index(c)) & 1) == st
+    return ok
+
+
+def _event_phases(ev: GateEvent, qubits: Sequence[int], dtype) -> tuple:
+    """(re, im) planes, each (2^k,), of a diagonal-kind event whose operand
+    is traced, over ``qubits`` (qubits[j] is bit j), controls folded in:
+    the in-trace twin of :func:`_event_diag`. Index algebra is static
+    numpy; only selects and the two transcendentals of a parity phase are
+    traced. Planes travel apart through the whole composition: a stack is
+    a concatenate, which the TPU compiler keeps as an op of its own."""
+    import jax.numpy as jnp
+
+    from .ops import cplx
+
+    rows = np.arange(1 << len(qubits))
+    bits = [(rows >> list(qubits).index(q)) & 1 for q in ev.targets]
+    if ev.kind == "parity":
+        sign = np.prod([1 - 2 * b for b in bits], axis=0).astype(dtype)
+        half = jnp.asarray(ev.theta) / 2
+        re = jnp.cos(half).astype(dtype)
+        im = -jnp.sin(half).astype(dtype) * sign
+    else:
+        d = cplx.from_complex(ev.diag, dtype)
+        if len(bits) == 1:
+            re = jnp.where(bits[0] == 1, d[0, 1], d[0, 0])
+            im = jnp.where(bits[0] == 1, d[1, 1], d[1, 0])
+        else:
+            idx = sum(b << j for j, b in enumerate(bits))
+            re, im = jnp.take(d[0], idx), jnp.take(d[1], idx)
+    ok = _controls_ok(ev, qubits)
+    return jnp.where(ok, re, 1.0), jnp.where(ok, im, 0.0)
+
+
+def _cmul(ar, ai, br, bi):
+    return ar * br - ai * bi, ar * bi + ai * br
+
+
+def _rows_matrix(re, im, ev: GateEvent, qubits: Sequence[int], dtype):
+    """A traced one-target matrix event applied to the ROW index of the
+    accumulator (planes ``re``, ``im``, each (N, N)), as to an N-amplitude
+    state: every row takes its own entry times itself plus the other
+    entry times its partner row (the target bit flipped -- a reverse of
+    the size-2 axis the target splits off). Elementwise f32 arithmetic:
+    exact products, no MXU pass, so no precision choice to make."""
+    import jax.numpy as jnp
+
+    from .ops import cplx
+
+    n_rows = re.shape[0]
+    b = list(qubits).index(ev.targets[0])
+    m = cplx.from_complex(ev.matrix, dtype)
+    split = (n_rows >> (b + 1), 2, 1 << b, re.shape[1])
+    one = np.arange(2).reshape(1, 2, 1, 1) == 1
+    own = [jnp.where(one, m[p, 1, 1], m[p, 0, 0]) for p in (0, 1)]
+    other = [jnp.where(one, m[p, 1, 0], m[p, 0, 1]) for p in (0, 1)]
+    xr, xi = re.reshape(split), im.reshape(split)
+    ar, ai = _cmul(own[0], own[1], xr, xi)
+    br, bi = _cmul(other[0], other[1], xr[:, ::-1], xi[:, ::-1])
+    new = (ar + br).reshape(re.shape), (ai + bi).reshape(re.shape)
+    if ev.controls:
+        ok = _controls_ok(ev, qubits)[:, None]
+        new = jnp.where(ok, new[0], re), jnp.where(ok, new[1], im)
+    return new
+
+
+def _compose_dense(events: Sequence[GateEvent], qubits: Sequence[int],
+                   dtype):
+    """Product of ``events`` (first applied first) over the window
+    ``qubits``. Every operand on the host: the complex128 numpy product,
+    as the planner composes a static block. Otherwise the (re, im) planes,
+    each (N, N) traced in ``dtype``: each traced factor is applied to the
+    accumulator's row index as to an N-amplitude state (O(N^2) a factor,
+    elementwise), and each run of host factors is multiplied out on the
+    host first and enters as ONE small HIGHEST matmul."""
+    import jax
+    import jax.numpy as jnp
+
+    acc = None       # None (identity) | numpy complex | (re, im) traced
+    run = None       # host product of the static factors since ``acc``
+
+    def settle():
+        nonlocal acc, run
+        if run is None:
+            return
+        if acc is None:
+            acc = run
+        elif isinstance(acc, np.ndarray):
+            acc = run @ acc
+        else:
+            mm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+            r, i = jnp.asarray(run.real, dtype), jnp.asarray(run.imag, dtype)
+            acc = (mm(r, acc[0]) - mm(i, acc[1]),
+                   mm(r, acc[1]) + mm(i, acc[0]))
+        run = None
+
+    for ev in events:
+        if not _event_traced(ev):
+            m = event_matrix(ev, qubits)
+            run = m if run is None else m @ run
+            continue
+        settle()
+        if acc is None:
+            acc = np.eye(1 << len(qubits), dtype=complex)
+        if isinstance(acc, np.ndarray):
+            acc = (jnp.asarray(acc.real, dtype), jnp.asarray(acc.imag, dtype))
+        if ev.kind == "matrix":
+            acc = _rows_matrix(*acc, ev, qubits, dtype)
+        else:
+            pr, pi = _event_phases(ev, qubits, dtype)
+            acc = _cmul(pr[:, None], pi[:, None], *acc)
+    settle()
+    return acc
+
+
+def _compose_diag(events: Sequence[GateEvent], qubits: Sequence[int], dtype):
+    """Product of diagonal-kind ``events`` over ``qubits``: the complex128
+    numpy diagonal when every operand is on the host, else (re, im) planes
+    of 2^k, traced in ``dtype`` (the host factors multiplied out first)."""
+    import jax.numpy as jnp
+
+    host = np.ones(1 << len(qubits), dtype=complex)
+    acc = None
+    for ev in events:
+        if not _event_traced(ev):
+            host = host * _event_diag(ev, qubits)
+            continue
+        ph = _event_phases(ev, qubits, dtype)
+        acc = ph if acc is None else _cmul(*acc, *ph)
+    if acc is None:
+        return host
+    return _cmul(*acc, jnp.asarray(host.real, dtype),
+                 jnp.asarray(host.imag, dtype))
+
+
+def _apply_deferred_block(qureg, spec: DeferredBlock, *values) -> None:
+    """Tape-entry wrapper for a block with deferred factors: assemble its
+    operator from ``values`` and apply it through the gate primitives (the
+    contiguous-window GEMM of ops.apply, the flat diagonal pass, the
+    density shadow, the explicit scheduler's routing). Traced values give a
+    traced operator, assembled inside the program; host values (a constant
+    replay of the same plan) give the numpy product a static block has."""
+    import jax
+
+    from . import gates as G
+
+    events = _resolve_factors(spec, values, qureg.num_qubits_represented,
+                              qureg.dtype)
+    if spec.kind == "diag":
+        op = _compose_diag(events, spec.qubits, qureg.dtype)
+        apply = G._apply_gate_diag
+    else:
+        op = _compose_dense(events, spec.qubits, qureg.dtype)
+        apply = G._apply_gate_matrix
+    if not isinstance(op, np.ndarray):
+        op = jax.lax.complex(op[0], op[1])
+    apply(qureg, op, spec.qubits)
+
+
+def gatewise(circuit):
+    """``circuit`` with every deferred block spelled out again, for a
+    consumer that needs each Param gate on its own (the adjoint sweep of
+    quest_tpu.gradients harvests a derivative per gate): a block's Param
+    entries come back as recorded, its constant factors as the static
+    block entries they would be alone. The same operator, the same slots
+    in the same order; memoized per tape revision. ``circuit`` itself when
+    it holds no deferred block."""
+    from . import gates as G
+    from .circuits import Circuit
+    from .engine.params import materialize_entry
+    from .validation import QuESTError
+
+    if not any(f is _apply_deferred_block for f, _, _ in circuit._tape):
+        return circuit
+    memo = circuit.__dict__.get("_gatewise")
+    if memo is not None and memo[0] is circuit._cache_token:
+        return memo[1]
+    tape = []
+    for entry in circuit._tape:
+        if entry[0] is not _apply_deferred_block:
+            tape.append(entry)
+            continue
+        spec, values = entry[1][0], entry[1][1:]
+        k = 0
+        while k < len(spec.factors):
+            ev = spec.factors[k]
+            if ev.source is not None:
+                i, j, count = ev.source
+                run = spec.factors[k:k + count]
+                if j == 0 and [e.source for e in run] == [
+                        (i, m, count) for m in range(count)]:
+                    tape.append(materialize_entry(spec.entries[i], values))
+                    k += count
+                    continue
+            if ev.deferred:
+                name = getattr(spec.entries[ev.source[0]][0], "__name__", "")
+                raise QuESTError(
+                    f"'{name}' was split between two fused blocks and "
+                    "cannot be spelled out again; use the unfused circuit")
+            if _event_is_diag(ev):
+                qs = tuple(sorted(ev.support))
+                tape.append((G._apply_gate_diag, (_event_diag(ev, qs), qs),
+                             {}))
+            else:
+                win = _window(ev.support)
+                tape.append((_apply_dense_block, (event_matrix(ev, win), win),
+                             {}))
+            k += 1
+    out = Circuit(circuit.num_qubits, circuit.is_density_matrix)
+    out._tape = tape
+    circuit.__dict__["_gatewise"] = (circuit._cache_token, out)
+    return out
+
+
+def _lift_positions(args) -> dict:
+    """engine.params.lift_tape's view of a deferred block entry: the values
+    trail the DeferredBlock, kinds as it records them."""
+    return {1 + i: kind for i, kind in enumerate(args[0].slot_kinds)}
+
+
+_apply_deferred_block._lift_positions = _lift_positions
+
+
 def _apply_frame_swap(qureg, tile_bits: int, k: int,
                       hi: int | None = None,
                       comm_pipeline: int | None = None,
@@ -1984,7 +2430,9 @@ def as_tape(p: FusePlan) -> list:
 
     entries = []
     for item in p.items:
-        if isinstance(item, DiagBlock):
+        if getattr(item, "factors", None) is not None:
+            entries.append(_deferred_entry(item))
+        elif isinstance(item, DiagBlock):
             entries.append((G._apply_gate_diag, (item.diag, item.qubits), {}))
         elif isinstance(item, FusedBlock):
             entries.append((_apply_dense_block, (item.matrix, item.qubits), {}))

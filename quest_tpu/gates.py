@@ -19,6 +19,7 @@ import numpy as np
 from . import matrices, validation as V
 from .datatypes import SubDiagonalOp, Vector
 from .ops import apply as K, cplx, diagonal as D, measure as M
+from .ops.spy import records
 from .parallel import scheduler as _dist
 from .registers import Qureg
 
@@ -49,6 +50,7 @@ def _shift(qs, n):
     return tuple(q + n for q in qs)
 
 
+@records
 def _apply_gate_matrix(qureg: Qureg, matrix, targets, controls=(), states=()):
     """Gate semantics: U on a state-vector; U . U^dagger on a density matrix
     via the conj-shadow (QuEST.c:184-193). Routed through the explicit
@@ -68,6 +70,7 @@ def _apply_gate_matrix(qureg: Qureg, matrix, targets, controls=(), states=()):
     qureg.put(amps)
 
 
+@records
 def _apply_gate_diag(qureg: Qureg, diag, targets, controls=()):
     n = qureg.num_qubits_represented
     nsv = qureg.num_qubits_in_state_vec
@@ -82,6 +85,7 @@ def _apply_gate_diag(qureg: Qureg, diag, targets, controls=()):
     qureg.put(amps)
 
 
+@records
 def _apply_gate_x(qureg: Qureg, targets, controls=(), states=()):
     n = qureg.num_qubits_represented
     nsv = qureg.num_qubits_in_state_vec
@@ -96,6 +100,7 @@ def _apply_gate_x(qureg: Qureg, targets, controls=(), states=()):
     qureg.put(amps)
 
 
+@records
 def _apply_gate_parity_phase(qureg: Qureg, theta, qubits, controls=()):
     n = qureg.num_qubits_represented
     nsv = qureg.num_qubits_in_state_vec

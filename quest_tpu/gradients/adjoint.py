@@ -54,6 +54,7 @@ from .. import gates as G
 from .. import matrices as M
 from .. import telemetry
 from ..engine.params import _SlotRef
+from ..fusion import gatewise
 from ..ops import reduce as R
 from ..registers import Qureg
 from ..validation import QuESTError
@@ -408,7 +409,7 @@ def check_differentiable(circuit, dtype=None) -> int:
             "Circuit.gradient: density-matrix tapes are not supported by "
             "the adjoint sweep (⟨λ|∂G|φ⟩ needs pure states); use a "
             "statevector register", "gradient")
-    lifted = circuit.lifted()
+    lifted = gatewise(circuit).lifted()
     plan_backward(lifted, circuit.num_qubits, dtype)
     return len(lifted.slots)
 
@@ -486,7 +487,7 @@ def grad_reduce(circuit, hamiltonian, *, dtype=None):
     codes, coeffs = hamiltonian_terms(hamiltonian, circuit.num_qubits)
     check_differentiable(circuit, dtype)
     dt = np.dtype(dtype if dtype is not None else jnp.result_type(float))
-    return _cached_reduce(circuit.lifted(), circuit.num_qubits,
+    return _cached_reduce(gatewise(circuit).lifted(), circuit.num_qubits,
                           codes, coeffs, dt.str)
 
 
@@ -532,6 +533,9 @@ class GradExecutable:
 def gradient_executable(circuit, hamiltonian, *, donate=True, dtype=None):
     """Compile ``circuit``'s adjoint gradient against a Pauli-sum
     Hamiltonian -- the implementation behind :meth:`Circuit.gradient`."""
+    # the sweep harvests a derivative per Param gate: a dense plan's
+    # deferred blocks are spelled out again (the slots keep their order)
+    circuit = gatewise(circuit)
     reduce_fn = grad_reduce(circuit, hamiltonian, dtype=dtype)
     ex = circuit.parameterized(donate=donate, reduce=reduce_fn)
     return GradExecutable(ex, reduce_fn)

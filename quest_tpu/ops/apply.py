@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from .layout import amps_jit, grouped_axes, inverse_permutation
+from .spy import records
 
 
 def _plan(n, targets, controls):
@@ -44,6 +45,23 @@ def _plan(n, targets, controls):
 #: the GEMM's K dimension is at least 2^_MIN_MINOR (=the 128-lane width);
 #: keeps every buffer's trailing dim >= 128 and avoids TPU tile padding.
 _MIN_MINOR = 7
+
+#: widest window the serving Engine's dense plan fuses
+#: (Engine._plan_program). The chip's pick: ansatz20.serve-closed16 on one
+#: v5e, final tree, two seeds each (PR 27, call 5), read 159.3 / 160.6
+#: requests/s, a p50 of 100.4 / 99.5 ms and 32.2 ms of device time a batch
+#: at 5; 106.6 / 106.4, 149.9 / 150.3 and 57.3 at 6; 219.9 / 217.9, 72.8 /
+#: 73.3 and 20.4 at 7 (the first cut, call 1, one seed: 87.4, 68.0, 105.1
+#: requests/s). At 7 a layer of one-qubit gates falls into windows aligned
+#: to the lane boundary ([0-6], [7-13], ...); at 5 and 6 one window of
+#: every layer straddles it ([5-9], [6-10]) and pays a K = 1024 / 2048 GEMM.
+DENSE_WINDOW_QUBITS = 7
+
+#: a window that starts below _MIN_MINOR is expanded down to qubit 0, so
+#: its GEMM's K is 2^(top+1) however few qubits it spans: the planner
+#: (fusion.plan) opens no such window whose top reaches this qubit, which
+#: holds K to the [128, 2048] range _apply_matrix_window is written for
+MAX_LOW_WINDOW_TOP = 11
 
 
 def _mxu_precision(dtype):
@@ -105,6 +123,7 @@ def _apply_matrix_window(amps, mr, mi, n, lo, hi):
     return out.reshape(2, -1)
 
 
+@records
 @amps_jit(static_argnames=("n", "targets", "controls", "control_states", "conj"),
           donate_argnums=(0,))
 def apply_matrix(amps, matrix, *, n: int, targets: tuple[int, ...],
@@ -216,6 +235,7 @@ def apply_x_class(amps, *, n: int, targets: tuple[int, ...],
     return tensor.transpose(inv).reshape(2, -1)
 
 
+@records
 @amps_jit(static_argnames=("n", "qb1", "qb2", "controls"), donate_argnums=(0,))
 def apply_swap(amps, *, n: int, qb1: int, qb2: int, controls: tuple[int, ...] = ()):
     """SWAP as an axis transposition (reference: statevec_swapQubitAmps,

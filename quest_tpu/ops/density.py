@@ -25,6 +25,7 @@ import numpy as np
 
 from . import apply, cplx, diagonal
 from .layout import amps_jit
+from .spy import records
 
 
 def kraus_superoperator(kraus_ops) -> np.ndarray:
@@ -68,6 +69,7 @@ def choi_kraus(superop) -> list[tuple[float, np.ndarray]]:
     return out
 
 
+@records
 def apply_channel(amps, superop, *, n: int, targets: tuple[int, ...]):
     """Apply a (numpy complex) superoperator to density targets: qubits
     (T..., T+n...) of the flattened 2n-qubit state.
@@ -226,6 +228,7 @@ def dephase_factors_2q(prob: float) -> np.ndarray:
     return d
 
 
+@records
 def _diag_dispatch(amps, d, *, n, targets):
     """Dephasing diagonals via the explicit scheduler when one is active
     (comm-free by construction, counted in its plan stats)."""

@@ -16,6 +16,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .spy import records
+
 
 def _flat_bits(num_flat: int, qubit: int):
     """Elementwise bit-q of the flat amplitude index, shape (1, num_flat).
@@ -69,6 +71,7 @@ def _apply_diagonal_flat(amps, diag, targets, controls, conj):
     return jnp.stack([re, im])
 
 
+@records
 @partial(jax.jit, static_argnames=("n", "targets", "controls", "conj"), donate_argnums=(0,))
 def apply_diagonal(amps, diag, *, n: int, targets: tuple[int, ...],
                    controls: tuple[int, ...] = (), conj: bool = False):

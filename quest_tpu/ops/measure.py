@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from .layout import grouped_axes
 from .reduce import csum_rows
+from .spy import records
 
 
 def _acc_dtype():
@@ -85,6 +86,7 @@ def collapse_statevec(amps, prob, *, n: int, target: int, outcome: int):
     return (amps.reshape((2,) + shape) * mask[None] * scale).reshape(2, -1)
 
 
+@records
 @partial(jax.jit, static_argnames=("n", "target", "outcome"), donate_argnums=(0,))
 def project_statevec(amps, *, n: int, target: int, outcome: int):
     """Unnormalised projection (applyProjector, QuEST.h:7421)."""

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..datatypes import phaseFunc
+from .spy import records
 
 #: sentinel divergence parameters match the reference kernel defaults
 REAL_EPS_F32 = 1e-5
@@ -81,6 +82,7 @@ def _apply_overrides(phase, reg_inds, override_inds, override_phases, rdtype):
     return phase
 
 
+@records
 @partial(jax.jit, static_argnames=("n", "reg_sizes", "qubits", "encoding",
                                    "exponents", "num_terms_per_reg", "num_overrides", "conj"))
 def apply_poly_phase(amps, coeffs, override_inds, override_phases, *,
@@ -131,6 +133,7 @@ def apply_poly_phase(amps, coeffs, override_inds, override_phases, *,
     return _phase_to_factor(amps, phase, n)
 
 
+@records
 @partial(jax.jit, static_argnames=("n", "reg_sizes", "qubits", "encoding",
                                    "func_name", "num_params", "num_overrides", "conj"))
 def apply_named_phase(amps, params, override_inds, override_phases, *,

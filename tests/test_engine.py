@@ -526,7 +526,12 @@ def test_engine_value_free_circuit():
         outs = [np.asarray(f.result()) for f in futs]
         ref = qt.createQureg(3, ENV1)
         c.run(ref)
-        assert all(np.array_equal(o, np.asarray(ref.amps)) for o in outs)
+        # the engine replays the tape's dense plan (one block here), the
+        # reference the tape gate by gate: equal to f64 rounding, and the
+        # four replies of the one program equal to the bit
+        assert all(np.max(np.abs(o - np.asarray(ref.amps))) <= 1e-13
+                   for o in outs)
+        assert all(np.array_equal(o, outs[0]) for o in outs)
 
 
 def test_engine_bad_params_raise_at_submit():
@@ -549,3 +554,128 @@ def test_engine_telemetry_series():
     assert any(k.startswith("engine_request_latency_seconds")
                for k in snap["histograms"])
     assert snap["gauges"].get("engine_queue_depth") == 0
+
+
+# ---------------------------------------------------------------------------
+# the Engine replays a raw tape's dense plan
+# ---------------------------------------------------------------------------
+
+def _benchmark_ansatz():
+    """The served cell's builder (benchmark/circuits/serving_ansatz.py)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "circuits", "serving_ansatz.py")
+    spec = importlib.util.spec_from_file_location("serving_ansatz", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_engine_serves_raw_tape_through_its_dense_plan():
+    """The benchmark's ansatz at rehearsal size (10q, depth 2): the Engine
+    fuses the raw tape (40 Param entries in blocks, none a barrier) and
+    serves 16 requests from ONE program: no retrace, no fallback, every
+    coalesced lane bit-equal to ``eng.run`` of the same angles, and the
+    unfused parameterized replay within f32 rounding."""
+    sa = _benchmark_ansatz()
+    n, depth = 10, 2
+    circ = Circuit(n)
+    sa.build(circ, num_qubits=n, depth=depth, angle=P)
+    names = sa.param_names(num_qubits=n, depth=depth)
+    rng = np.random.RandomState(27)
+    sweep = [dict(zip(names, map(float, rng.uniform(0, 2 * np.pi,
+                                                    len(names)))))
+             for _ in range(16)]
+    fused0 = telemetry.counter_value("fusion_param_fused_total", mode="dense")
+    barriers0 = telemetry.counter_value("fusion_param_barriers_total",
+                                        mode="dense")
+    fallback0 = telemetry.counter_value("engine_fallback_total")
+    with Engine(circ, ENV1, max_batch=8, max_delay_ms=0.0,
+                precision_code=1) as eng:
+        assert telemetry.counter_value(
+            "fusion_param_fused_total", mode="dense") == fused0 + len(names)
+        assert telemetry.counter_value(
+            "fusion_param_barriers_total", mode="dense") == barriers0
+        start = [e for e in telemetry.events()
+                 if e.get("name") == "engine.start"][-1]
+        assert start["plan_blocks"] == len(eng._program._tape) == 10
+        assert start["plan_barriers"] == 0
+        assert set(eng.param_names) == set(names)
+        eng.warmup()
+        traces = telemetry.counter_value("engine_trace_total",
+                                         kind="param_replay")
+        lanes = [np.asarray(f.result()) for f in eng.submit_many(sweep)]
+        lone = [np.asarray(eng.run(p)) for p in sweep]
+        assert telemetry.counter_value(
+            "engine_trace_total", kind="param_replay") == traces
+        assert all(np.array_equal(a, b) for a, b in zip(lanes, lone))
+    assert telemetry.counter_value("engine_fallback_total") == fallback0
+    exe = circ.parameterized(donate=False)
+    amps0 = np.zeros((2, 1 << n), np.float32)
+    amps0[0, 0] = 1.0
+    for p, lane in zip(sweep[:4], lanes):
+        want = np.asarray(exe(jax.numpy.asarray(amps0), p))
+        assert np.max(np.abs(lane - want)) <= 1e-6
+
+
+def test_engine_keeps_given_plan_sharded_and_gradient_routes():
+    """What the code can see decides what an Engine replays: a circuit the
+    caller fused, a sharded register and a values-aware finalize keep the
+    circuit as given."""
+    _, cp = _pair()
+    fused = cp.fused(max_qubits=3)
+    with Engine(fused, ENV1, max_batch=2) as eng:
+        assert eng._program is fused
+    with Engine(cp, ENV8, max_batch=2) as eng:
+        assert eng.sharded and eng._program is cp
+    codes = [[3, 0, 0, 0, 0]]
+    with Engine(cp, ENV1, max_batch=2, hamiltonian=(codes, [1.0])) as eng:
+        assert eng._program is not cp
+        assert eng.grad_engine()._program is cp
+
+
+def test_engines_plan_and_trace_side_by_side():
+    """Planning and tracing touch no process-wide state: eight Engines
+    over distinct structures, built and served from eight threads at
+    once, each reply with the raw tape's parameterized replay -- every
+    Param entry in a block, none fallen back to a barrier."""
+    import threading
+
+    sa = _benchmark_ansatz()
+    barriers0 = telemetry.counter_value("fusion_param_barriers_total",
+                                        mode="dense")
+    start = threading.Barrier(8)
+    errs, failures = {}, []
+
+    def serve(i):
+        try:
+            n, depth = 6 + i % 4, 1 + i // 4
+            circ = Circuit(n)
+            sa.build(circ, num_qubits=n, depth=depth, angle=P)
+            names = sa.param_names(num_qubits=n, depth=depth)
+            rng = np.random.RandomState(i)
+            params = dict(zip(names, map(float, rng.uniform(
+                0, 2 * np.pi, len(names)))))
+            start.wait(60)
+            with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0) as eng:
+                got = np.asarray(eng.submit(params).result(120))
+            amps0 = np.zeros((2, 1 << n))
+            amps0[0, 0] = 1.0
+            want = np.asarray(circ.parameterized(donate=False)(
+                jax.numpy.asarray(amps0), params))
+            errs[i] = float(np.max(np.abs(got - want)))
+        except Exception as e:  # surfaced below, on the test's thread
+            failures.append((i, repr(e)))
+
+    threads = [threading.Thread(target=serve, args=(i,)) for i in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+    assert not failures, failures
+    assert sorted(errs) == list(range(8))
+    assert max(errs.values()) <= 1e-12, errs
+    assert telemetry.counter_value("fusion_param_barriers_total",
+                                   mode="dense") == barriers0

@@ -240,3 +240,179 @@ def test_synth_frame_boundary_anchors():
     op3 = _POp("kraus1", (10, 32), (), (), (), False)
     f3 = pl._synth_frame(op3)
     assert f3 == (32, 1) and f3[0] >= 30
+
+
+# ---------------------------------------------------------------------------
+# Param entries in dense plans: blocks whose matrices are assembled in-trace
+# ---------------------------------------------------------------------------
+
+def _family(name, circ, th):
+    """One member group of the liftable family (engine.params._LIFTABLE)
+    on 4 qubits, its angles from ``th`` (Params, or that request's floats)."""
+    circ.hadamard(0)
+    circ.controlledNot(0, 3)
+    if name == "rotations":
+        circ.rotateZ(0, th("a"))
+        circ.rotateX(0, th("b"))
+        circ.rotateY(1, th("c"))
+        circ.phaseShift(2, th("d"))
+        circ.rotateAroundAxis(3, th("e"), qt.Vector(1.0, 2.0, -0.5))
+        circ.rotateX(1, 0.7)                    # a constant among Params
+    elif name == "compactUnitary":
+        circ.compactUnitary(1, th("alpha"), th("beta"))
+        circ.controlledCompactUnitary(0, 2, th("alpha"), th("beta"))
+    elif name == "controlled":
+        circ.controlledRotateX(0, 1, th("a"))
+        circ.controlledRotateY(2, 1, th("b"))
+        circ.controlledRotateZ(1, 3, th("c"))
+        circ.controlledPhaseShift(0, 2, th("d"))
+        circ.multiControlledPhaseShift([0, 1, 3], th("e"))
+        circ.controlledRotateAroundAxis(3, 0, th("a"),
+                                        qt.Vector(0.3, -1.0, 0.8))
+    elif name == "multiRotateZ":
+        circ.multiRotateZ([0, 2, 3], th("a"))
+        circ.multiControlledMultiRotateZ([1], [0, 3], th("b"))
+    else:
+        assert name == "multiRotatePauli"
+        circ.multiRotatePauli([0, 1, 3], [1, 2, 3], th("a"))
+        circ.multiRotatePauli([2], [0], th("b"))          # all-identity
+        circ.multiControlledMultiRotatePauli([2], [0, 1], [2, 1], th("c"))
+    circ.tGate(1)
+    circ.controlledPhaseFlip(1, 2)
+
+
+_FAMILY = ("rotations", "compactUnitary", "controlled", "multiRotateZ",
+           "multiRotatePauli")
+_VALUES = {"a": 0.37, "b": 1.234, "c": -0.8, "d": 2.2, "e": 0.61,
+           "alpha": complex(np.cos(0.4), 0.0),
+           "beta": complex(0.0, np.sin(0.4))}
+
+
+@pytest.mark.parametrize("precision,tol", [(1, 1e-6), (2, 1e-12)])
+@pytest.mark.parametrize("density", [False, True])
+@pytest.mark.parametrize("family", _FAMILY)
+def test_param_dense_plan_matches_constant_raw_tape(family, density,
+                                                    precision, tol):
+    """The parameterised replay of a Param tape's dense plan agrees with
+    the constant replay of the RAW tape at the same values: no Param is a
+    barrier, and the block matrices composed inside the trace are the
+    host's."""
+    from quest_tpu import telemetry
+    from quest_tpu.engine import P
+
+    n = 4
+    raw, par = Circuit(n, density), Circuit(n, density)
+    _family(family, raw, _VALUES.__getitem__)
+    _family(family, par, P)
+    dtype = real_dtype(precision)
+    b0 = telemetry.counter_value("fusion_param_barriers_total", mode="dense")
+    f0 = telemetry.counter_value("fusion_param_fused_total", mode="dense")
+    plan = par.fused(max_qubits=4, dtype=dtype)
+    assert telemetry.counter_value("fusion_param_barriers_total",
+                                   mode="dense") == b0
+    with_params = sum(fusion._entry_has_params(a, k) for _, a, k in par._tape)
+    assert telemetry.counter_value("fusion_param_fused_total",
+                                   mode="dense") == f0 + with_params
+    assert all(f.__name__ in ("_apply_dense_block", "_apply_gate_diag",
+                              "_apply_deferred_block")
+               for f, _, _ in plan._tape)
+    mk = ((lambda: ops_init.density_init_plus(1 << (2 * n), dtype))
+          if density else (lambda: ops_init.init_debug(1 << n, dtype)))
+    want = np.asarray(raw.as_fn()(mk()))
+    got = np.asarray(plan.parameterized()(
+        mk(), {k: _VALUES[k] for k in plan.param_names}))
+    assert np.max(np.abs(got - want)) <= tol * max(1.0, np.abs(want).max())
+
+
+def test_param_dense_plan_structure_is_value_free():
+    """One plan serves every parameter vector: its fingerprint is that of
+    its host-materialised constant tape at ANY values, and planning twice
+    gives the same structure."""
+    from quest_tpu.engine import P
+    from quest_tpu.engine.params import bind, materialize_tape
+
+    par = Circuit(4)
+    _family("rotations", par, P)
+    plan = par.fused(max_qubits=3)
+    fp = plan.fingerprint()
+    twin = Circuit(4)
+    _family("rotations", twin, P)
+    assert twin.fused(max_qubits=3).fingerprint() == fp
+    lifted = plan.lifted()
+    for scale in (1.0, -2.5):
+        const = Circuit(4)
+        const._tape = materialize_tape(lifted, bind(
+            lifted, {k: scale * _VALUES[k] for k in plan.param_names},
+            device=False))
+        assert const.fingerprint() == fp
+    # and a plan round-trips through its tape
+    again = fusion.as_tape(fusion.plan_from_tape(plan._tape))
+    twice = Circuit(4)
+    twice._tape = again
+    assert twice.fingerprint() == fp
+
+
+def test_fusion_barrier_and_channel_still_split_param_plan():
+    """What has no structure without its values stays a barrier: a
+    mid-circuit measurement (tagged _fusion_barrier) and a channel split
+    the plan between the Param blocks."""
+    from quest_tpu.engine import P
+
+    circ = Circuit(3, is_density_matrix=True)
+    circ.rotateX(0, P("a"))
+    circ.rotateZ(1, P("b"))
+    circ.applyMidMeasurement(1, seed=5)
+    circ.rotateY(1, P("c"))
+    circ.mixDephasing(0, 0.1)
+    circ.rotateX(2, P("d"))
+    p = fusion.plan(tuple(circ._tape), 3, real_dtype(), max_qubits=3,
+                    is_density=True)
+    kinds = [it[0].__name__ if isinstance(it, tuple) else type(it).__name__
+             for it in p.items]
+    assert kinds == ["FusedBlock", "applyMidMeasurement", "FusedBlock",
+                     "mixDephasing", "FusedBlock"]
+    assert all(it.factors is not None for it in p.items
+               if not isinstance(it, tuple))
+    assert p.num_barriers == 2
+
+
+def test_low_window_guard_keeps_gemm_bounded():
+    """A window starting below the lane boundary is a GEMM over every qubit
+    below its top: the planner opens none that reaches
+    ops.apply.MAX_LOW_WINDOW_TOP, whatever ``max_qubits`` allows."""
+    from quest_tpu.ops.apply import _MIN_MINOR, MAX_LOW_WINDOW_TOP
+
+    n = 14
+    circ = Circuit(n)
+    for q in range(n):
+        circ.hadamard(q)
+    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=7)
+    for it in p.items:
+        assert it.qubits[0] >= _MIN_MINOR or \
+            it.qubits[-1] < MAX_LOW_WINDOW_TOP
+    assert [it.qubits[0] for it in p.items] == [0, 7]
+
+
+def test_deferred_diagonal_leaves_a_static_diagonal_block_static():
+    """A diagonal block with a constant table is cheap and one with a
+    traced table is not (PERF.md, PR 27): a Param's diagonal factor does
+    not join a static diagonal block; it opens its own, which the next
+    dense factor turns into a window block."""
+    from quest_tpu.engine import P
+
+    circ = Circuit(6)
+    circ.controlledPhaseFlip(0, 5)
+    circ.rotateZ(0, P("a"))
+    circ.rotateX(0, P("b"))
+    circ.rotateZ(1, P("c"))
+    p = fusion.plan(tuple(circ._tape), 6, real_dtype(), max_qubits=3)
+    assert [type(it).__name__ for it in p.items] == ["DiagBlock",
+                                                     "FusedBlock"]
+    assert p.items[0].factors is None and p.items[0].qubits == (0, 5)
+    assert p.items[1].qubits == (0, 1) and len(p.items[1].factors) == 3
+    # Param diagonals alone still share one (deferred) diagonal block
+    only = Circuit(6)
+    only.rotateZ(0, P("a"))
+    only.rotateZ(5, P("b"))
+    q = fusion.plan(tuple(only._tape), 6, real_dtype(), max_qubits=3)
+    assert len(q.items) == 1 and len(q.items[0].factors) == 2

@@ -403,3 +403,38 @@ def test_backpressure_error_reason_attribute():
     e = QuESTBackpressureError("m", "f", reason="quota")
     assert e.reason == "quota"
     assert QuESTBackpressureError("m", "f").reason is None
+
+
+def test_pool_mixed_structures_in_parallel_match_raw_replay():
+    """Six structures over three replicas, submitted from six threads at
+    once: each replica's Engine plans and traces while the others do, and
+    every reply agrees with the raw tape's parameterized replay."""
+    circuits = [_ansatz(3), _other(3), _ansatz(4), _other(4), _ansatz(5),
+                _other(5)]
+    start = threading.Barrier(len(circuits))
+    errs, failures = {}, []
+
+    with EnginePool(ENV1, replicas=3, max_batch=2, max_delay_ms=0.0) as pool:
+        def serve(i):
+            try:
+                c = circuits[i]
+                p = _params(c, i)
+                start.wait(60)
+                got = np.asarray(pool.submit(c, p).result(120))
+                amps0 = np.zeros((2, 1 << c.num_qubits))
+                amps0[0, 0] = 1.0
+                want = np.asarray(c.parameterized(donate=False)(
+                    jax.numpy.asarray(amps0), p))
+                errs[i] = float(np.max(np.abs(got - want)))
+            except Exception as e:  # surfaced on the test's thread
+                failures.append((i, repr(e)))
+
+        threads = [threading.Thread(target=serve, args=(i,))
+                   for i in range(len(circuits))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(300)
+    assert not failures, failures
+    assert sorted(errs) == list(range(len(circuits)))
+    assert max(errs.values()) <= 1e-12, errs
