@@ -18,7 +18,8 @@ import states
 
 
 class Driver:
-    #: the engine lowers the unfused tape: no Pallas kernel is expected
+    #: the engine replays its dense plan of the tape (window GEMMs and
+    #: diagonal passes, XLA only): no Pallas kernel is expected
     expects_kernels = False
 
     def __init__(self, run):

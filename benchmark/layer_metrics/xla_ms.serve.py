@@ -1,4 +1,5 @@
-"""XLA ops: device time per served request of the unfused program: busy
+"""XLA ops: device time per served request of the Engine's batch program
+(its dense plan of the tape: one pass a block over the whole batch): busy
 device time per batch in the traced slice, over the mean width of the batches
 the engine dispatched (``engine_batch_size``)."""
 

@@ -5,13 +5,31 @@ from __future__ import annotations
 import math
 
 
+def counter_total(snap: dict, name: str) -> float:
+    """Every series of counter ``name`` in one snapshot, summed."""
+    return sum(v for k, v in snap["counters"].items()
+               if k == name or k.startswith(name + "{"))
+
+
 def counter_delta(m: dict, name: str) -> float:
     """Growth over the window of every series of counter ``name``."""
-    def total(snap):
-        return sum(v for k, v in snap["counters"].items()
-                   if k == name or k.startswith(name + "{"))
+    return counter_total(m["after"], name) - counter_total(m["before"], name)
 
-    return total(m["after"]) - total(m["before"])
+
+#: what the planner counts of a tape's Param gates, once a plan
+PARAM_PLAN_COUNTERS = ("fusion_param_fused_total",
+                       "fusion_param_barriers_total")
+
+
+def param_plan_count(m: dict, name: str):
+    """Counter ``name`` of ``PARAM_PLAN_COUNTERS`` over the whole process (the
+    plan is made in set-up). A series only appears with its first count, so
+    an absent one reads 0 beside its sibling, and None where the program's
+    planner counts neither."""
+    snap = m["after"]
+    if not any(counter_total(snap, n) for n in PARAM_PLAN_COUNTERS):
+        return None
+    return counter_total(snap, name)
 
 
 def histogram_delta(m: dict, name: str) -> tuple:

@@ -10,8 +10,10 @@ One process that owns the chip. It resolves the cell by name from
 ``tpu``, keeps JAX's compile cache at one fixed path inside the checkout,
 builds the cell, warms the cell's own shapes (all of that is ``setup_s``),
 measures for ``--seconds``, checks what the timed path produced against the
-plain reference once the window has closed, and prints the contract's result
-as the last line of standard output.
+plain reference once the window has closed, and prints every number compared
+beside its limit as the last lines of standard error and the contract's
+result, with those numbers under ``checks``, as the last line of standard
+output.
 
 ``--trace 0`` reports the cell's end-to-end metrics. ``--trace 1`` profiles a
 short slice of the window, reduces it with ``trace_reduce.py`` and reports
@@ -37,6 +39,7 @@ import json
 import os
 import re
 import shutil
+import subprocess
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -131,12 +134,37 @@ class Run:
         return np.stack([low.real, low.imag])
 
 
+def cache_dir() -> str:
+    """Where JAX's compile cache is kept: where the environment says, or at
+    one fixed path inside the checkout (the path is part of the key)."""
+    return os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                                 os.path.join(ROOT, ".jax_cache"))
+
+
+def set_up_apart(run: Run, trace: int) -> int:
+    """A cell's first run on a cache sets the cell up twice: once in a child
+    that compiles, fills the cache, leaves a marker there and exits, then in
+    this process, which so loads its programs from the cache like every
+    later run. A process that has compiled its own program serves 4-10%
+    slower through its whole window (``PERF.md`` section 6, PR 29), and a
+    check's every set holds one such run. Both set-ups are in ``setup_s``.
+    This process has not touched JAX yet: the child owns the chip alone.
+    Returns the child's exit code (0 where there was nothing to do)."""
+    marker = os.path.join(cache_dir(), run.cell["name"] + ".set-up")
+    if run.rehearse or os.path.exists(marker):
+        return 0
+    log("no set-up of this cell in the cache yet: one in a process of its own")
+    with run.span("apart_s"):
+        child = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--workload",
+             run.cell["name"], "--seed", str(run.seed), "--trace", str(trace),
+             "--set-up-only", marker], stdout=subprocess.DEVNULL)
+    return child.returncode
+
+
 def start_jax(run: Run):
     """Import JAX with the cache placed; the device row, or exit."""
-    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-        # one fixed path inside the checkout: the path is part of the key
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT,
-                                                               ".jax_cache")
+    cache_dir()     # in the environment before JAX reads it
     if run.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         # nothing to save at rehearsal sizes, and XLA:CPU logs every load
@@ -232,14 +260,12 @@ def reduce_slice(trace_dir: str) -> dict | None:
 def program_checks(run: Run, driver, telemetry, after: dict) -> list:
     """Counts that fail a run: a kernel left for the engine, an interpreted
     kernel, a timeout, a poisoned request."""
-    def total(snap, name):
-        return sum(v for k, v in snap["counters"].items()
-                   if k == name or k.startswith(name + "{"))
+    from metric_util import counter_total
 
     out = []
     for name in ("engine_fallback_total", "engine_request_timeouts_total",
                  "engine_poisoned_requests_total"):
-        out.append((name, float(total(after, name)), 0.0))
+        out.append((name, float(counter_total(after, name)), 0.0))
     compiles = [e for e in telemetry.events()
                 if e.get("name") == "pallas.compile"]
     interpreted = sum(1 for e in compiles if e.get("interpret"))
@@ -250,8 +276,32 @@ def program_checks(run: Run, driver, telemetry, after: dict) -> list:
     return out
 
 
+def dump_window(path: str, win, m: dict) -> None:
+    """``--dump``: every request of the window on the window's clock, what
+    the program counted over it, and the engine's phase vectors of a traced
+    run, for ``spread.py`` to read."""
+    def grown(kind, fields):
+        return {k: {f: v[f] - m["before"][kind].get(k, {}).get(f, 0)
+                    for f in fields}
+                for k, v in m["after"][kind].items()}
+
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump({
+            "seconds": win.deadline - win.t0, "end": win.end - win.t0,
+            "requests": [[c, k, a - win.t0, b - win.t0, bool(ok)]
+                         for c, k, a, b, ok in win.requests],
+            "spans": m["spans"],
+            "histograms": grown("histograms", ("count", "sum")),
+            "program_spans": grown("spans", ("count", "total_s")),
+            "engine_traces": [
+                {"t0": t["t0"] - win.wall0, "dur_ms": t["dur_ms"],
+                 "error": t["error"], "phases_ms": t["phases_ms"]}
+                for t in m["engine_traces"] if t["t0"] >= win.wall0]}, f)
+
+
 def measure(run: Run, driver, seconds: float, trace: bool, device: dict,
-            peaks, setup_s: float) -> dict:
+            peaks, setup_s: float, dump: str | None = None) -> dict:
     """The window, the check and the result line of one seed."""
     from quest_tpu import telemetry
 
@@ -274,14 +324,10 @@ def measure(run: Run, driver, seconds: float, trace: bool, device: dict,
 
     reduced = reduce_slice(trace_dir) if trace else None
     checks = driver.check(win) + program_checks(run, driver, telemetry, after)
-    correct = not win.errors
-    for name, value, limit in checks:
-        ok = value <= limit
-        correct = correct and ok
-        print(f"check {name}: value {value!r} limit {limit!r} "
-              f"{'ok' if ok else 'FAILED'}", flush=True)
+    correct = not win.errors and all(value <= limit
+                                     for _, value, limit in checks)
     for err in win.errors[:5]:
-        print(f"request failed: {err}", flush=True)
+        log(f"request failed: {err}")
     log(f"check: reference {run.spans.get('reference_s', 0.0):.2f}s")
 
     measured = {
@@ -290,6 +336,8 @@ def measure(run: Run, driver, seconds: float, trace: bool, device: dict,
         "trace": reduced, "shapes": driver.shapes(), "peaks": peaks,
         "engine_traces": telemetry.traces() if trace else [],
     }
+    if dump:
+        dump_window(dump, win, measured)
     metrics = {}
     for spec in run.metrics_of("per_layer" if trace else "end_to_end"):
         if run.rehearse and spec["source"] != "program_counter":
@@ -311,6 +359,9 @@ def measure(run: Run, driver, seconds: float, trace: bool, device: dict,
                                "idle_gaps": reduced["idle_gaps"]}
     if run.rehearse:
         result["rehearsed"] = True
+    # every number compared, beside its limit: the result's last key
+    result["checks"] = {name: {"value": value, "limit": limit}
+                        for name, value, limit in checks}
     return result
 
 
@@ -321,6 +372,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--dump", metavar="FILE",
+                    help="also write the window's requests there (spread.py)")
+    ap.add_argument("--set-up-only", metavar="MARKER",
+                    help="set the cell up, write MARKER, print no result "
+                         "(what a first run on a cold cache starts)")
     args = ap.parse_args(argv)
 
     run = Run(args.workload, args.seed, args.rehearse)
@@ -328,6 +384,10 @@ def main(argv=None) -> int:
                else float(run.bench["run_seconds"]))
     if args.trace:
         os.environ.setdefault("QUEST_TRACE", "all")   # the engine's phases
+    if not args.set_up_only:
+        rc = set_up_apart(run, args.trace)
+        if rc:
+            return rc
     device = start_jax(run)
     peaks = peaks_for(device["kind"], run.rehearse)
     try:
@@ -335,7 +395,8 @@ def main(argv=None) -> int:
     except ImportError as exc:
         log(f"the program is not in this checkout: {exc}")
         return EXIT_NO_PROGRAM
-    run.spans["import_s"] = time.perf_counter() - T_START
+    run.spans["import_s"] = (time.perf_counter() - T_START
+                             - run.spans.get("apart_s", 0.0))
     log(f"{run.cell['name']} seed {run.seed} on {device}")
 
     driver = load_module("drivers", run.config["driver"]).Driver(run)
@@ -343,10 +404,20 @@ def main(argv=None) -> int:
         driver.setup()
         setup_s = time.perf_counter() - T_START
         log("set-up " + " ".join(f"{k}={v:.2f}" for k, v in run.spans.items()))
+        if args.set_up_only:
+            os.makedirs(os.path.dirname(args.set_up_only), exist_ok=True)
+            with open(args.set_up_only, "w") as f:
+                f.write(f"{run.cell['name']} set up in {setup_s:.1f}s\n")
+            return 0
         result = measure(run, driver, seconds, bool(args.trace), device, peaks,
-                         setup_s)
+                         setup_s, args.dump)
     finally:
         driver.close()
+    # the last lines of standard error, and the last line of standard output
+    for name, c in result["checks"].items():
+        print(f"check {name}: value {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['value'] <= c['limit'] else 'FAILED'}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

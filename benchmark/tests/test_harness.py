@@ -13,6 +13,10 @@ from conftest import ROOT, child_env, run_child
 
 CELLS = [c["name"] for c in
          json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]]
+#: counts a rehearsal has to read, by (cell, trace): the served plan at the
+#: rehearsal's 10 qubits and depth 2 fuses its 40 Params and leaves none
+COUNTS = {("ansatz20.serve-closed16", 1): {"param_fused.serve": 40,
+                                           "param_barriers.serve": 0}}
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -30,6 +34,8 @@ def test_cell_rehearses_to_its_last_line(cell, trace, bench):
                for m in bench["end_to_end"] + bench["per_layer"]}
     assert all(sources[name] == "program_counter" for name in last["metrics"])
     assert "busy_s" not in last["device"]
+    for name, count in COUNTS.get((cell, trace), {}).items():
+        assert last["metrics"][name]["value"] == count
 
 
 def test_no_chip_no_result():
@@ -134,3 +140,34 @@ def test_traffic_generator_is_found_by_the_mix_s_loop_key():
         assert {r[0] for r in win.requests} == {0, 1} and not win.errors
     with pytest.raises(SystemExit):
         harness.load_module("loops", "no-such-loop")
+
+
+def test_a_cold_cache_is_filled_by_a_child_before_this_process_touches_jax(
+        tmp_path, monkeypatch):
+    """No marker of the cell in the cache: the set-up runs once in a child
+    (same cell, seed and trace, no result line), whose exit code is handed
+    on; with the marker there, and in a rehearsal, nothing is started."""
+    import run as harness
+
+    calls = []
+
+    def fake(cmd, **kw):
+        calls.append((cmd, kw))
+        return subprocess.CompletedProcess(cmd, 3 if len(calls) == 1 else 0)
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(harness.subprocess, "run", fake)
+    run = harness.Run("sv20.block", 2**31 + 5)
+    assert harness.set_up_apart(run, 1) == 3            # no chip: handed on
+    cmd, kw = calls[0]
+    marker = str(tmp_path / "sv20.block.set-up")
+    assert cmd[1:] == [os.path.join(ROOT, "benchmark", "run.py"),
+                       "--workload", "sv20.block", "--seed", str(2**31 + 5),
+                       "--trace", "1", "--set-up-only", marker]
+    assert kw["stdout"] == subprocess.DEVNULL and "apart_s" in run.spans
+    assert harness.set_up_apart(run, 1) == 0 and len(calls) == 2
+    open(marker, "w").close()
+    assert harness.set_up_apart(run, 1) == 0 and len(calls) == 2
+    os.remove(marker)
+    assert harness.set_up_apart(harness.Run("sv20.block", 1, True), 0) == 0
+    assert len(calls) == 2

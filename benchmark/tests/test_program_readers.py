@@ -13,9 +13,9 @@ def reader(name):
     return harness.load_module("layer_metrics", name).read
 
 
-def snap(spans=None, histograms=None):
-    return {"counters": {}, "gauges": {}, "histograms": histograms or {},
-            "spans": spans or {}}
+def snap(spans=None, histograms=None, counters=None):
+    return {"counters": counters or {}, "gauges": {},
+            "histograms": histograms or {}, "spans": spans or {}}
 
 
 def trace(t0, error=None, **phases):
@@ -102,6 +102,35 @@ def test_compile_readers_read_the_set_up_snapshot():
     assert reader("backend_compile_s")(measured()) is None
 
 
+@pytest.mark.parametrize("counters, fused, barriers", [
+    # the plan of today: every Param in a block, no barrier series at all
+    ({"fusion_param_fused_total{mode=dense}": 160}, 160, 0),
+    # a plan that fell back: the barriers have a name, and fused reads 0
+    ({"fusion_param_barriers_total{mode=dense}": 160}, 0, 160),
+    ({"fusion_param_fused_total{mode=dense}": 120,
+      "fusion_param_barriers_total{mode=dense}": 36,
+      "fusion_param_barriers_total{mode=pallas}": 4}, 120, 40),
+    # a program whose planner counts neither: nothing to read
+    ({"device_dispatch_total{route=engine_vmap}": 9}, None, None),
+])
+def test_planner_counters_are_the_totals_of_the_after_snapshot(
+        counters, fused, barriers):
+    m = measured(after=snap(counters=counters))
+    assert reader("param_fused.serve")(m) == fused
+    assert reader("param_barriers.serve")(m) == barriers
+
+
+def test_planner_counters_have_their_entries(bench):
+    entries = {e["name"]: e for e in bench["per_layer"]}
+    for name, better in (("param_fused.serve", "higher"),
+                         ("param_barriers.serve", "lower")):
+        assert entries[name] == {
+            "name": name, "unit": "count", "better": better,
+            "source": "program_counter", "layer": "planner",
+            "moves": "request_p50_ms",
+            "workloads": ["ansatz20.serve-closed16"]}
+
+
 def test_every_new_metric_has_its_file_and_its_entry(bench):
     new = ["host_launch_ms.lib", "host_launch_ms.small", *sorted(ENGINE),
            "trace_lower_s", "backend_compile_s"]
@@ -112,7 +141,9 @@ def test_every_new_metric_has_its_file_and_its_entry(bench):
     # the compile pair is every cell's; the rest list their cells
     assert "workloads" not in entries["trace_lower_s"]
     assert "workloads" not in entries["backend_compile_s"]
-    assert [e["name"] for e in bench["per_layer"]][-8:] == [
+    names = [e["name"] for e in bench["per_layer"]]
+    at = names.index("host_launch_ms.lib")
+    assert names[at:at + 8] == [
         "host_launch_ms.lib", "host_launch_ms.small",
         "engine_queue_ms.serve", "engine_issue_ms.serve",
         "engine_device_ms.serve", "engine_resolve_ms.serve",

@@ -51,7 +51,46 @@ def test_synthetic_trace_reduces_to_known_numbers():
     assert r["kernel_s"] == pytest.approx(200e-9) and r["kernel_launches"] == 4
     assert r["xla_s"] == pytest.approx(120e-9) and r["xla_ops"] == 4
     assert r["device_ops"][0][0] == "_k.1 (custom-call)"
-    assert r["idle_gaps"] == [["sync", pytest.approx(60e-9)]]
+    # three gaps of 20 ns, each 15 ns under the sync and 5 under the next apply
+    assert r["idle_gaps"] == [["sync", pytest.approx(45e-9)],
+                              ["apply", pytest.approx(15e-9)]]
+
+
+SPANS = [("wait", 0, 100), ("wait", 10, 110),            # two client threads
+         ("engine.dispatch", 20, 60), ("engine.launch", 30, 50),
+         ("submit", 40, 45)]                             # a third client
+
+
+def test_idle_goes_to_the_innermost_open_span():
+    """The program's span beats the benchmark's, also one that opened later
+    on another thread; among the program's the innermost; instants under no
+    span are ``unannotated``."""
+    assert tr.timeline(SPANS) == [
+        (0, 20, "wait"), (20, 30, "engine.dispatch"),
+        (30, 50, "engine.launch"), (50, 60, "engine.dispatch"),
+        (60, 110, "wait")]
+    assert tr.label_gaps([(5, 35), (55, 120)], SPANS) == {
+        "wait": 15 + 50, "engine.dispatch": 10 + 5, "engine.launch": 5,
+        "unannotated": 10}
+    assert tr.label_gaps([(5, 35)], []) == {"unannotated": 30}
+
+
+def test_program_spans_are_read_by_name_without_their_labels():
+    from jax.profiler import ProfileData
+
+    profile = ProfileData.from_text_proto('''
+planes { name: "/host:CPU"
+  lines { name: "python3" %s %s %s }
+  event_metadata { key: 1 value { id: 1 name: "engine.dispatch#batch=8,mode=vmap#" } }
+  event_metadata { key: 2 value { id: 2 name: "engine.launch" } }
+  event_metadata { key: 3 value { id: 3 name: "fusion.plan" } }
+}''' % (_ev(1, 20, 40), _ev(2, 30, 20), _ev(3, 0, 5)))
+    assert tr.annotations(profile) == [("engine.dispatch", 20, 60),
+                                       ("engine.launch", 30, 50)]
+    assert set(tr.PROGRAM_SPANS) >= {
+        "circuit.run", "engine.admit", "engine.assemble", "engine.lookup",
+        "engine.launch", "engine.sync", "engine.resolve", "engine.dispatch",
+        "engine.retire"}
 
 
 def test_union_is_not_a_sum():
@@ -91,3 +130,11 @@ def test_recorded_v5e_trace():
     assert 0.01 < 1 - r["busy_s"] / r["window_s"] < 0.04
     assert r["device_ops"][0][0].startswith("_fused_local_run")
     assert {g[0] for g in r["idle_gaps"]} <= {"apply", "sync", "unannotated"}
+    # as PR 25's reduction read this file, before idle went span by span
+    assert (r["runs"], r["kernel_launches"], r["xla_ops"]) == (17, 34.0, 102.0)
+    assert r["window_s"] == pytest.approx(0.720194557, rel=1e-9)
+    assert r["busy_s"] == pytest.approx(0.706840511, rel=1e-9)
+    assert r["kernel_s"] == pytest.approx(0.48512625, rel=1e-9)
+    assert r["xla_s"] == pytest.approx(0.221714261, rel=1e-9)
+    assert sum(g[1] for g in r["idle_gaps"]) == pytest.approx(
+        r["window_s"] - r["busy_s"], rel=1e-9)

@@ -12,12 +12,14 @@ What is read (seen on a TPU v5e trace, jax 0.9):
   HLO op, named by its whole HLO text (``%name = type opcode(...)``); line
   ``XLA Modules`` one event per program run (``jit_fn(<id>)``). A Pallas
   (Mosaic) kernel is the op whose text holds
-  ``custom_call_target="tpu_custom_call"`` -- the kernels carry no ``name=``
-  of their own yet, so they are told from XLA's ops by kind;
+  ``custom_call_target="tpu_custom_call"``: kernels are told from XLA's ops by
+  kind, whatever they are named (``qt_fused_...`` since PR 26);
 - plane ``/host:CPU``, lines ``python3`` (one per thread): the benchmark's own
   ``TraceAnnotation`` spans (``apply``/``sync``/``submit``/``wait``, and
-  ``bench.slice`` around the traced slice), on the same clock. A span that
-  began before the capture is not recorded.
+  ``bench.slice`` around the traced slice) and, since PR 26, the program's
+  spans and regions under their own names (``circuit.run``, ``engine.launch``,
+  ...), on the same clock. A span that began before the capture is not
+  recorded.
 
 The traced window runs from the start of the first WHOLE run of the dominant
 program (the module with most device time) to the end of its last whole run:
@@ -25,6 +27,11 @@ the capture cuts the first and the last run it sees, so those two are left
 out, and so is anything outside ``bench.slice``. Device ops are clipped to the
 window. Busy time is the UNION of device-op intervals (per device, then
 averaged over devices), never a sum; per-run numbers divide by the whole runs.
+
+Idle time is charged, instant by instant, to the innermost host span open at
+that instant: a span of the program beats one of the benchmark's (the program
+runs inside the benchmark's call, also where that call is another thread's),
+and among spans of one kind the one that opened last wins.
 """
 
 from __future__ import annotations
@@ -36,7 +43,13 @@ import sys
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+#: the benchmark's own spans, around its calls into the program
 ANNOTATIONS = ("apply", "sync", "submit", "wait")
+#: the program's spans (``quest_tpu.telemetry.span`` / ``region``, PR 26): a
+#: library call, and the batcher thread's work on a batch
+PROGRAM_SPANS = ("circuit.run", "engine.admit", "engine.assemble",
+                 "engine.lookup", "engine.launch", "engine.sync",
+                 "engine.resolve", "engine.dispatch", "engine.retire")
 SLICE = "bench.slice"
 #: what marks a Mosaic kernel launch in an op's HLO text
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
@@ -104,16 +117,18 @@ def module_runs(profile) -> dict:
     return out
 
 
-def annotations(profile, names=ANNOTATIONS) -> list:
-    """[(name, start_ns, end_ns)] of the benchmark's host spans."""
+def annotations(profile, names=ANNOTATIONS + PROGRAM_SPANS) -> list:
+    """[(name, start_ns, end_ns)] of the host spans called ``names``. A
+    span's labels (``engine.dispatch#batch=8#``) are not part of its name."""
     out = []
     for plane in profile.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name in names:
-                    out.append((ev.name, ev.start_ns,
+                name = ev.name.partition("#")[0]
+                if name in names:
+                    out.append((name, ev.start_ns,
                                 ev.start_ns + ev.duration_ns))
     return sorted(out, key=lambda a: a[1])
 
@@ -134,18 +149,50 @@ def _clip(intervals, lo, hi):
             if min(b, hi) > max(a, lo)]
 
 
-def _label_gap(lo, hi, spans) -> str:
-    """The annotation covering most of the gap [lo, hi]."""
-    cover = {}
-    for name, a, b in spans:
-        if b <= lo:
+def timeline(spans) -> list:
+    """Sorted, disjoint [(start, end, name)]: at every instant the innermost
+    span open then -- the program's before the benchmark's, then the one
+    that opened last. Instants under no span are left out."""
+    def rank(span):
+        return (span[0] in PROGRAM_SPANS, span[1])
+
+    edges = sorted({t for _, a, b in spans for t in (a, b)})
+    todo = sorted(spans, key=lambda sp: sp[1])
+    out, open_, nxt = [], [], 0
+    for lo, hi in zip(edges, edges[1:]):
+        while nxt < len(todo) and todo[nxt][1] <= lo:
+            open_.append(todo[nxt])
+            nxt += 1
+        open_ = [sp for sp in open_ if sp[2] > lo]
+        if not open_:
             continue
-        if a >= hi:
-            break
-        cover[name] = cover.get(name, 0) + min(b, hi) - max(a, lo)
-    if not cover:
-        return "unannotated"
-    return max(cover, key=cover.get)
+        name = max(open_, key=rank)[0]
+        if out and out[-1][2] == name and out[-1][1] == lo:
+            out[-1][1] = hi
+        else:
+            out.append([lo, hi, name])
+    return [tuple(seg) for seg in out]
+
+
+def label_gaps(gaps, spans) -> dict:
+    """{span name: idle ns charged to it} over the sorted, disjoint ``gaps``;
+    what no span covers is ``unannotated``."""
+    line = timeline(spans)
+    totals, i = {}, 0
+    for lo, hi in gaps:
+        while i < len(line) and line[i][1] <= lo:
+            i += 1
+        covered, j = 0, i
+        while j < len(line) and line[j][0] < hi:
+            a, b, name = line[j]
+            part = min(b, hi) - max(a, lo)
+            totals[name] = totals.get(name, 0) + part
+            covered += part
+            j += 1
+        if hi - lo > covered:
+            totals["unannotated"] = (totals.get("unannotated", 0)
+                                     + hi - lo - covered)
+    return totals
 
 
 def _top(totals: dict, n=10) -> list:
@@ -218,10 +265,8 @@ def reduce(profile) -> dict | None:
                 xla_count += 1
         if dev == first:    # one host drives every device: label gaps once
             edges = [lo] + [t for ab in merged for t in ab] + [hi]
-            for a, b in zip(edges[0::2], edges[1::2]):
-                if b > a:
-                    label = _label_gap(a, b, spans)
-                    gaps[label] = gaps.get(label, 0) + b - a
+            gaps = label_gaps([(a, b) for a, b in
+                               zip(edges[0::2], edges[1::2]) if b > a], spans)
     devices = len(ops_by_dev)
     return {
         "devices": devices,
