@@ -1015,6 +1015,14 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
     telemetry.inc("fusion_pallas_runs_total", len(runs), mode=mode)
     telemetry.inc("fusion_frame_transposes_total", folded + explicit,
                   mode=mode)
+    sharded = {}
+    if shard_qubits is not None:
+        # what the plan prices, under the names of the counters that say
+        # what the replay then decided (fusion_collective_swaps_total,
+        # fusion_sharded_runs_total)
+        sharded = transpose_stats(p, shard_qubits)
+        sharded.update(collective_swaps=sharded["collective_transposes"],
+                       sharded_runs=len(runs))
     telemetry.event(
         "fusion.plan", mode=mode, nsv=nsv, tile_bits=tile_bits,
         items=len(p.items), pallas_runs=len(runs),
@@ -1023,8 +1031,7 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         frame_transposes=folded + explicit,
         ops_per_run=[len(r.ops) for r in runs],
         fused_gates=p.num_fused_gates, barriers=p.num_barriers,
-        **(transpose_stats(p, shard_qubits)
-           if shard_qubits is not None else {}))
+        **sharded)
 
 
 def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
@@ -1475,6 +1482,37 @@ def _df_route(dtype) -> bool:
     return np.dtype(dtype) == np.dtype("float64") and df_wanted()
 
 
+def _amp_shards(qureg) -> int:
+    """Devices the register's amplitudes are split over, as a replay sees
+    them: the explicit scheduler's mesh, inside a trace the ambient mesh
+    (the tracer hides its sharding; Circuit.run derived the mesh from the
+    register), else the concrete array's own sharding."""
+    import jax
+
+    from .parallel import scheduler as _dist
+
+    sched = _dist.active()
+    if sched is not None and sched.mesh is not None:
+        return sched.mesh.size
+    if isinstance(qureg.amps, jax.core.Tracer):
+        mesh = active_pallas_mesh()
+        return 1 if mesh is None else mesh.size
+    sharding = getattr(qureg.amps, "sharding", None)
+    return 1 if sharding is None else len(sharding.device_set)
+
+
+def _count_frame_swap(qureg, lo2: int, k: int) -> None:
+    """Count one explicit relabeling pass (a LOWERING, like every counter
+    inside a replay). Where the moved block [lo2, lo2 + k) reaches a
+    sharded qubit the pass is a transpose over the mesh -- GSPMD's
+    all-to-all, or the scheduler's grouped permute -- and is counted as
+    ``fusion_collective_swaps_total`` too; a shard-local one is not."""
+    telemetry.inc("pallas_pass_total", kind="frame_swap")
+    shard_bits = _amp_shards(qureg).bit_length() - 1
+    if lo2 + k > qureg.num_qubits_in_state_vec - shard_bits:
+        telemetry.inc("fusion_collective_swaps_total")
+
+
 def _apply_pallas_run(qureg, ops: tuple, tile_bits: int,
                       load_swap_k: int = 0, store_swap_k: int = 0,
                       load_swap_hi: int | None = None,
@@ -1517,21 +1555,19 @@ def _apply_pallas_run(qureg, ops: tuple, tile_bits: int,
 
     nsv = qureg.num_qubits_in_state_vec
 
+    def explicit_swap(k, hi):
+        lo2 = tile_bits if hi is None else hi
+        _count_frame_swap(qureg, lo2, k)
+        qureg.put(swap_bit_blocks(qureg.amps, n=nsv, lo1=tile_bits - k,
+                                  lo2=lo2, k=k))
+
     def pre_swap():
         if load_swap_k:
-            telemetry.inc("pallas_pass_total", kind="frame_swap")
-            qureg.put(swap_bit_blocks(
-                qureg.amps, n=nsv, lo1=tile_bits - load_swap_k,
-                lo2=tile_bits if load_swap_hi is None else load_swap_hi,
-                k=load_swap_k))
+            explicit_swap(load_swap_k, load_swap_hi)
 
     def post_swap():
         if store_swap_k:
-            telemetry.inc("pallas_pass_total", kind="frame_swap")
-            qureg.put(swap_bit_blocks(
-                qureg.amps, n=nsv, lo1=tile_bits - store_swap_k,
-                lo2=tile_bits if store_swap_hi is None else store_swap_hi,
-                k=store_swap_k))
+            explicit_swap(store_swap_k, store_swap_hi)
 
     amps = qureg.amps
     sched = _dist.active()
@@ -1831,6 +1867,7 @@ def _exec_pallas_sharded(amps, mesh, ops: tuple, df: bool, n_local: int,
     from .environment import AMP_AXIS
     from .ops import pallas_gates as PG
 
+    telemetry.inc("fusion_sharded_runs_total")
     if df:
         from .ops.pallas_df import df_join, df_split
 
@@ -1949,7 +1986,7 @@ def _sched_df_pallas_run(qureg, ops: tuple, sched, tile_bits: int,
     nsv = qureg.num_qubits_in_state_vec
     planes = df_split(qureg.amps)
     if lk:
-        telemetry.inc("pallas_pass_total", kind="frame_swap")
+        _count_frame_swap(qureg, tile_bits if lh is None else lh, lk)
         planes = sched.apply_frame_permute(
             planes, n=nsv, lo1=tile_bits - lk,
             lo2=tile_bits if lh is None else lh, k=lk,
@@ -1962,7 +1999,7 @@ def _sched_df_pallas_run(qureg, ops: tuple, sched, tile_bits: int,
     planes = shard_map(body, mesh=sched.mesh, in_specs=P(None, AMP_AXIS),
                        out_specs=P(None, AMP_AXIS), check_vma=False)(planes)
     if sk:
-        telemetry.inc("pallas_pass_total", kind="frame_swap")
+        _count_frame_swap(qureg, tile_bits if sh is None else sh, sk)
         planes = sched.apply_frame_permute(
             planes, n=nsv, lo1=tile_bits - sk,
             lo2=tile_bits if sh is None else sh, k=sk,
@@ -2411,17 +2448,17 @@ def _apply_frame_swap(qureg, tile_bits: int, k: int,
     from .ops.pallas_gates import swap_bit_blocks
     from .parallel import scheduler as _dist
 
-    telemetry.inc("pallas_pass_total", kind="frame_swap")
+    lo2 = tile_bits if hi is None else hi
+    _count_frame_swap(qureg, lo2, k)
     nsv = qureg.num_qubits_in_state_vec
     sched = _dist.active()
     if sched is not None and sched.mesh is not None and sched.mesh.size > 1:
         qureg.put(sched.apply_frame_permute(
-            qureg.amps, n=nsv, lo1=tile_bits - k,
-            lo2=tile_bits if hi is None else hi, k=k,
+            qureg.amps, n=nsv, lo1=tile_bits - k, lo2=lo2, k=k,
             pipeline=comm_pipeline, pipeline_dcn=comm_pipeline_dcn))
         return
     qureg.put(swap_bit_blocks(qureg.amps, n=nsv, lo1=tile_bits - k,
-                              lo2=tile_bits if hi is None else hi, k=k))
+                              lo2=lo2, k=k))
 
 
 def as_tape(p: FusePlan) -> list:
