@@ -22,9 +22,12 @@ their own angles) arriving concurrently. Three mechanisms make that cheap:
   cuQuantum's batched ``custatevecApplyMatrix``), short batches padded to
   ``max_batch``: one executable ever compiles, and a request computes the
   same bits whether or not it was coalesced (batch lanes are independent
-  and identical). Sharded registers replay sequentially with donated
-  buffers inside the one dispatch instead (a (B, 2, N) batch axis would
-  fight the amplitude sharding for the mesh).
+  and identical). A batch crosses to the device in one piece: the
+  initial state and one packed array per slot kind in, the lanes as the
+  program's own outputs (:meth:`Engine._execB`). Sharded registers
+  replay sequentially with donated buffers inside the one dispatch
+  instead (a (B, 2, N) batch axis would fight the amplitude sharding
+  for the mesh).
 - **Executable reuse across structures**: executables are fetched from the
   process-global LRU (:mod:`quest_tpu.engine.cache`) per dispatch, keyed by
   the circuit's structure fingerprint -- a second Engine over a
@@ -100,7 +103,7 @@ prologue/steady-state/epilogue collective pipeline):
   hides. On CPU, ring admission therefore device-syncs the in-flight
   head before the next issue and -- when a spare host core exists --
   defers its RESOLUTION until just after it: assembly and coalescing
-  overlap device execution on the way in, lane extraction and future
+  overlap device execution on the way in, the sentinel gate and future
   resolution on the way out, and the device never timeshares two
   batches. On a single-core host there is nothing to overlap (the
   "overlapped" host thread is starved by the execution thread), so
@@ -109,9 +112,11 @@ prologue/steady-state/epilogue collective pipeline):
   own ``engine.retire`` deadline and charged to the entry it retires.
 - **Continuous batching** (Orca, PAPERS.md): while a batch is in
   flight, the device -- not the ``max_delay_ms`` timer -- paces the
-  window: a late submit joins the NEXT vmap window instead of waiting
-  out a full coalescing tick (the padded fixed-shape program makes the
-  join point well-defined).
+  window: every arrival restarts the timer, and the window closes when
+  it is full, when ``max_delay_ms`` passed without an arrival, or at
+  once when the batch ahead is done; a late submit joins the NEXT vmap
+  window instead of waiting out a full coalescing tick (the padded
+  fixed-shape program makes the join point well-defined).
 
 Lifecycle: construct, optionally :meth:`warmup`, ``submit``/``run``, then
 :meth:`close` -- which drains the queue AND the completion ring (every
@@ -139,7 +144,8 @@ from ..resilience.errors import (PoisonedRequestFault, QuESTBackpressureError,
                                  QuESTCancelledError, QuESTHangError,
                                  QuESTIntegrityError, QuESTTimeoutError)
 from . import cache as _cache
-from .params import _SEED, bind
+from .params import (_SEED, _pack_layout, _pack_rows, _unpack_columns,
+                     bind)
 
 __all__ = ["Engine", "HEALTH_STATES"]
 
@@ -149,10 +155,17 @@ HEALTH_STATES = ("healthy", "degraded", "quarantined")
 #: consecutive clean dispatches that heal ``degraded`` -> ``healthy``
 _HEAL_STREAK = 3
 
+#: how often an open coalescing window looks whether the device has
+#: finished the batch ahead (seconds); arrivals wake it at once
+_WINDOW_POLL_S = 0.0005
+
 
 class _Request:
-    """One queued parameter set: bound values, the caller's future, the
-    enqueue timestamp, an optional wall-clock deadline, the injected
+    """One queued parameter set: bound values in the form the engine's
+    mode dispatches (the per-slot tuple where batches replay
+    sequentially; one row per slot kind, the request's lane of the batch
+    program's arguments, where they are vmapped), the caller's future,
+    the enqueue timestamp, an optional wall-clock deadline, the injected
     poison kind pinned at submit time (None on healthy requests), and the
     request's trace context (None whenever tracing is off)."""
 
@@ -324,6 +337,9 @@ class Engine:
         #: plan, or the circuit itself (see _plan_program)
         self._program = self._plan_program()
         self._lifted = self._program.lifted()
+        #: which slots ride in which argument of the batch program: one
+        #: packed array per slot kind the tape has (params._pack_layout)
+        self._packs = _pack_layout(self._lifted)
         self.fingerprint = circuit.fingerprint()
         self._cv = _sync.Condition("engine.cv")
         self._q: deque = deque()
@@ -371,7 +387,8 @@ class Engine:
     def submit(self, params: dict | None = None,
                timeout: float | None = None) -> Future:
         """Queue one parameter set; returns a Future resolving to the final
-        planar (2, 2^nsv) amplitude array (a batch slice when coalesced).
+        planar (2, 2^nsv) amplitude array (one lane of the batch program's
+        outputs, an array of its own).
         ``timeout`` (seconds) sets a deadline: a request still queued when
         it expires resolves with QuESTTimeoutError instead of running."""
         return self.submit_many([params], timeout=timeout)[0]
@@ -389,6 +406,10 @@ class Engine:
         if not self._open:
             raise RuntimeError("Engine is closed")
         values_list = [bind(self._lifted, p) for p in params_list]
+        if self._mode() == "vmap":
+            # a request's lane of the batch program's arguments is packed
+            # here, on the submitter's thread: the batcher only stacks rows
+            values_list = [_pack_rows(self._packs, v) for v in values_list]
         futs = []
         with self._cv:
             if not self._open:
@@ -699,15 +720,22 @@ class Engine:
         """The vmap-over-params batch executable (unsharded registers):
         ONE fused program evolving ``max_batch`` states, batches padded to
         that size so the shape -- and hence the compiled program -- is
-        constant. An armed ``finalize`` composes inside the vmapped body,
-        so the program returns ``max_batch`` finalized results (e.g. shot
-        tables) and the 2^N lanes never leave the device."""
+        constant. Its calling convention is what crosses the host-device
+        boundary once a batch: IN, the initial state (unbatched, not
+        donated: the program makes its own batch of it) and one
+        ``(max_batch, n)`` array per slot kind the tape has
+        (params._pack_layout), from which the per-slot values tuple is
+        rebuilt by static column index before the vmap; OUT, a tuple of
+        ``max_batch`` lanes, each a result of its own (a state, or
+        whatever an armed ``finalize`` -- composed inside the vmapped
+        body -- makes of one), so retiring a batch dispatches nothing."""
         import jax
+        import jax.numpy as jnp
 
         from .. import fusion
         from ..parallel import scheduler as _dist
 
-        circuit, donate = self._program, self._donate
+        circuit, width, packs = self._program, self.max_batch, self._packs
         finalize = self._finalize
 
         def build():
@@ -735,15 +763,20 @@ class Engine:
                     lambda av: body(av[0], av[1]), (amps_b, values_b))
             else:
                 batched = jax.vmap(body, in_axes=(0, 0))
-            from ..circuits import named_program
-            jitted = jax.jit(
-                named_program(batched, circuit, "engine_vmap",
-                              f"b{self.max_batch}"),
-                donate_argnums=(0,) if donate else ())
 
-            def fn(amps_b, values_b, _inner=jitted):
+            def program(amps, *packed):
+                amps_b = jnp.broadcast_to(amps[None], (width,) + amps.shape)
+                out = batched(amps_b, _unpack_columns(packs, packed))
+                return tuple(jax.tree_util.tree_map(lambda a: a[i], out)
+                             for i in range(width))
+
+            from ..circuits import named_program
+            jitted = jax.jit(named_program(program, circuit, "engine_vmap",
+                                           f"b{width}"))
+
+            def fn(amps, *packed, _inner=jitted):
                 with _dist.explicit_mesh(None), fusion.pallas_mesh(None):
-                    return _inner(amps_b, values_b)
+                    return _inner(amps, *packed)
 
             return fn
 
@@ -754,7 +787,7 @@ class Engine:
         the replayed PLAN's, so engines share a batch program exactly when
         they replay the same structure."""
         return ("param_vmap", self._program.fingerprint(), self.max_batch,
-                self.dtype.str, self._donate, self._finalize)
+                self.dtype.str, self._finalize)
 
     # -- batcher ------------------------------------------------------------
 
@@ -769,21 +802,39 @@ class Engine:
                     batch = None  # idle (or closing) with work in flight
                 else:
                     batch = [self._q.popleft()]
-                    deadline = time.perf_counter() + self.max_delay_s
+                    t_open = time.perf_counter()
+                    deadline = t_open + self.max_delay_s
+                    held = 0
                     while len(batch) < self.max_batch:
                         if self._q:
                             batch.append(self._q.popleft())
                             continue
                         if not self._open:
                             break
-                        remaining = deadline - time.perf_counter()
-                        # continuous batching (round 18): with a batch in
-                        # flight the device, not the timer, paces the
-                        # window -- issue what we have and let a late
-                        # submit join the NEXT vmap window
-                        if remaining <= 0 or self._ring:
+                        now = time.perf_counter()
+                        wait = deadline - now
+                        if self._ring:
+                            # continuous batching (round 18): with a batch
+                            # in flight the device, not the first request's
+                            # timer, paces the window. What is here would
+                            # only queue behind the batch executing, so
+                            # waiting for the next request costs nothing
+                            # until that batch is done: every arrival
+                            # restarts the timer, and the window closes
+                            # when it is full, when ``max_delay_ms`` has
+                            # passed with no arrival, or -- at once --
+                            # when the batch ahead is done (its retire is
+                            # the admission's first step). A submit wakes
+                            # the wait; the device's progress is polled.
+                            if self._ring_head_ready():
+                                break
+                            if len(batch) > held:
+                                held = len(batch)
+                                deadline = now + self.max_delay_s
+                            wait = min(_WINDOW_POLL_S, deadline - now)
+                        if wait <= 0:
                             break
-                        self._cv.wait(remaining)
+                        self._cv.wait(wait)
                     telemetry.set_gauge("engine_queue_depth", len(self._q))
             if batch is None:
                 # queue idle but batches in flight: retire the oldest ring
@@ -793,12 +844,12 @@ class Engine:
                 continue
             live = self._expire(batch)
             if live:
-                # t_first (the pop instant) is recovered from the already
-                # taken deadline reading: queue_wait/coalesce attribution
-                # costs the untraced path zero extra clock reads. Handed
-                # over on the instance so _dispatch keeps its one-argument
-                # seam (tests wrap it with lambda b: ...).
-                self._t_first = deadline - self.max_delay_s
+                # t_first (the pop instant) is the window's own reading:
+                # queue_wait/coalesce attribution costs the untraced path
+                # zero extra clock reads. Handed over on the instance so
+                # _dispatch keeps its one-argument seam (tests wrap it
+                # with lambda b: ...).
+                self._t_first = t_open
                 self._dispatch(live)
 
     def _expire(self, batch: list) -> list:
@@ -928,9 +979,9 @@ class Engine:
                 telemetry.clear_current_trace()
         if deferred:
             # entries the admission proved complete resolve only NOW,
-            # after the issue: their lane extraction, sentinel gate and
-            # future resolution overlap the batch just put on the device
-            # instead of holding it idle
+            # after the issue: their sentinel gate and future resolution
+            # overlap the batch just put on the device instead of
+            # holding it idle
             self._ring_settle()
             return
         now = time.perf_counter()
@@ -1029,13 +1080,10 @@ class Engine:
         return _guard.corrupt_amps(amps)
 
     def _lane(self, out, i: int):
-        """Lane ``i`` of a vmap batch result: a plain slice for the
-        amps-returning path, a tree_map'd slice when ``finalize`` made the
-        result an arbitrary pytree (e.g. ``{"shots": ..., "expec": ...}``)."""
-        if self._finalize is None:
-            return out[i]
-        import jax
-        return jax.tree_util.tree_map(lambda a: a[i], out)
+        """Lane ``i`` of a batch result: the batch program returns its
+        lanes as outputs of their own (:meth:`_execB`), so this is an
+        index, not a device computation."""
+        return out[i]
 
     @staticmethod
     def _charge(batch, phase: str, t_end: float) -> None:
@@ -1162,8 +1210,6 @@ class Engine:
                                  site="engine.dispatch")
 
     def _dispatch_vmap(self, batch: list, defer: bool = False) -> bool:
-        import jax.numpy as jnp
-
         for req in batch:
             # an injected poisoned request fails the whole batched program
             # (the real-world analogue: one NaN-producing parameter set or
@@ -1192,27 +1238,22 @@ class Engine:
         # the dispatch watchdog -- this method only assembles and issues
         defer = defer and self.async_depth > 0
         # host-side batch assembly (pad to the fixed vmap shape): on the
-        # traced path this lands in the dispatch phase. The per-slot
-        # stacks are NUMPY, not jnp -- each jnp.stack is its own device
-        # computation, the PJRT CPU client bounds in-flight computations
-        # (32), and a slot-rich ansatz issuing one stack per slot behind
-        # an in-flight batch blows that bound: the "async" issue then
-        # silently blocks for a full device execution. Host stacking
-        # enters the program as plain transfers (bitwise the same lanes)
-        # and keeps the whole batch at ~two enqueued computations.
+        # traced path this lands in the dispatch phase. Each request
+        # brought its lane packed (submit_many); one numpy stack a slot
+        # kind makes the program's value arguments, which enter it as
+        # plain transfers: nothing is dispatched to the device here
         with telemetry.region("engine.assemble") as rg:
             pad = self.max_batch - len(batch)
-            vals = [req.values for req in batch] + [batch[-1].values] * pad
-            stacked = tuple(np.stack([np.asarray(v[k]) for v in vals])
-                            for k in range(len(self._lifted.slots)))
-            amps_b = jnp.repeat(self.initial_amps[None], self.max_batch,
-                                axis=0)
+            rows = [req.values for req in batch] + [batch[-1].values] * pad
+            packed = [np.stack(kind) for kind in zip(*rows)]
         self._charge(batch, "dispatch", rg.t1)
         fnB = self._lookup(batch, self._execB)
-        # the whole coalesced batch is ONE vmap program launch
+        # the whole coalesced batch is ONE vmap program launch, handed the
+        # initial state and one array a slot kind
         telemetry.inc("device_dispatch_total",
                       route=self._route or "engine_vmap")
-        out = self._launch(batch, lambda: fnB(amps_b, stacked))
+        telemetry.inc("engine_launch_args_total", 1 + len(packed))
+        out = self._launch(batch, lambda: fnB(self.initial_amps, *packed))
         if defer:
             # ASYNC ISSUE: park the in-flight result on the completion
             # ring and return to coalescing -- the device executes batch k
@@ -1232,8 +1273,7 @@ class Engine:
             # the completion ring against
             self._sync(batch, out)
         # each request's resolve phase runs from the device sync to ITS
-        # resolution: lane extraction (a compiled slice on the first
-        # run), the sentinel gate, and the wait behind earlier lanes.
+        # resolution: the sentinel gate and the wait behind earlier lanes.
         # The windows deliberately overlap -- phases tile each request's
         # own end-to-end latency, they are not a global partition.
         with telemetry.region("engine.resolve"):
@@ -1262,8 +1302,11 @@ class Engine:
         backpressure bound forces a (then-instant) sync. A buffer without
         a readiness probe counts as ready: retiring it blocks no longer
         than the probe-less sync path always did."""
-        out = self._ring[0].out
-        probe = getattr(out, "is_ready", None)
+        import jax
+
+        # one program made every lane: its first array speaks for all
+        leaves = jax.tree_util.tree_leaves(self._ring[0].out)
+        probe = getattr(leaves[0], "is_ready", None) if leaves else None
         if probe is None:
             return True
         try:
@@ -1332,9 +1375,9 @@ class Engine:
 
     def _ring_settle(self) -> None:
         """Resolve ring entries whose device work admission already
-        proved complete -- called right AFTER an issue, so lane
-        extraction, the sentinel gate and future resolution run while
-        the just-issued batch executes."""
+        proved complete -- called right AFTER an issue, so the sentinel
+        gate and future resolution run while the just-issued batch
+        executes."""
         while self._ring and self._ring[0].synced:
             self._retire_oldest()
 

@@ -282,19 +282,18 @@ def bind(lifted: LiftedTape, params=None, device: bool = True) -> tuple:
     ``params`` maps Param names to numbers (missing names raise); anonymous
     slots replay their recorded defaults. With ``device=True`` (the
     executable hot path) scalars are coerced to NUMPY 0-d arrays at the
-    process float/complex width (f64/c128 under jax x64, else f32/c64) so
-    the jit signature is stable across calls. Numpy, not jnp, on purpose:
-    ``jnp.asarray(v, dtype=...)`` enqueues a convert_element_type
-    COMPUTATION per scalar, and the PJRT CPU client bounds in-flight
-    computations -- a slot-rich circuit binding behind an in-flight batch
-    would block the SUBMITTER for a full device execution (the async
-    dispatch pipeline then starves at one arrival per batch). A numpy
-    scalar enters the program as a plain transfer at call time, has the
-    identical abstract value (no retrace), and binds in microseconds no
-    matter what the device is running. ``device=False`` returns plain
-    Python scalars (a tape materialized with them replays through the
-    constant/numpy assembly path -- the bit-identity baseline the tests
-    compare against)."""
+    process float/complex width (f64/c128 under jax x64, else f32/c64),
+    seeds to uint32, so the jit signature is stable across calls. Numpy,
+    not jnp, on purpose: ``jnp.asarray(v, dtype=...)`` enqueues a
+    convert_element_type COMPUTATION per scalar on the submitter's
+    thread, behind whatever the device is running; a numpy scalar costs
+    microseconds and has the identical abstract value (no retrace). The
+    single-request executable takes the tuple as it is, one transfer a
+    slot at call time; the Engine's batch program takes the same scalars
+    packed one array per slot kind (:func:`_pack_rows`).
+    ``device=False`` returns plain Python scalars (a tape materialized
+    with them replays through the constant/numpy assembly path -- the
+    bit-identity baseline the tests compare against)."""
     import jax.numpy as jnp
 
     from ..validation import QuESTError
@@ -327,6 +326,39 @@ def bind(lifted: LiftedTape, params=None, device: bool = True) -> tuple:
         else:
             out.append(complex(v) if s.kind == _CPLX else float(v))
     return tuple(out)
+
+
+def _pack_layout(lifted: LiftedTape) -> tuple:
+    """How a lifted tape's values cross into a batched program: one array
+    per slot kind the tape HAS, in the order real, complex, seed, each
+    ``(kind, slot indices in slot order)``. A kind without a slot has no
+    entry (and the program no argument for it)."""
+    packs = []
+    for kind in (_REAL, _CPLX, _SEED):
+        cols = tuple(s.index for s in lifted.slots if s.kind == kind)
+        if cols:
+            packs.append((kind, cols))
+    return tuple(packs)
+
+
+def _pack_rows(packs: tuple, values: tuple) -> tuple:
+    """One bound values tuple (``bind(..., device=True)``) as one row per
+    pack of ``packs``: the row of kind k holds the tuple's k-kind scalars
+    in slot order, at the width ``bind`` gave them. Rows of several
+    requests stack into the batched program's arguments."""
+    return tuple(np.array([values[i] for i in cols]) for _, cols in packs)
+
+
+def _unpack_columns(packs: tuple, packed: tuple) -> tuple:
+    """The inverse of :func:`_pack_rows` inside a traced program: from the
+    stacked packs (one ``(batch, n_kind)`` array a kind) the per-slot
+    tuple of ``(batch,)`` columns, by static index, in slot order -- what
+    a vmapped replay takes as its values."""
+    out = {}
+    for (_, cols), arr in zip(packs, packed):
+        for j, i in enumerate(cols):
+            out[i] = arr[:, j]
+    return tuple(out[i] for i in range(len(out)))
 
 
 class ParamExecutable:

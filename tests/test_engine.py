@@ -16,6 +16,8 @@ Contracts under test:
   executable cache (``plan_cache_hit_total``).
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -26,7 +28,8 @@ from quest_tpu import telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.engine import Engine, LRUCache, P, Param
 from quest_tpu.engine import cache as ecache
-from quest_tpu.engine.params import bind, lift_tape, materialize_tape
+from quest_tpu.engine.params import (_pack_rows, bind, lift_tape,
+                                     materialize_tape)
 from quest_tpu.validation import QuESTError
 
 ENV1 = qt.createQuESTEnv(jax.devices()[:1])
@@ -679,3 +682,406 @@ def test_engines_plan_and_trace_side_by_side():
     assert max(errs.values()) <= 1e-12, errs
     assert telemetry.counter_value("fusion_param_barriers_total",
                                    mode="dense") == barriers0
+
+
+# ---------------------------------------------------------------------------
+# the batch program's calling convention: one packed array a slot kind in,
+# the lanes as outputs of their own (PR 31)
+# ---------------------------------------------------------------------------
+
+def _kraus():
+    return tuple(qt.channels.kraus_ops("depolarising", 0.3))
+
+
+def _served(n, depth):
+    """The served cell's ansatz at a test's size: ``2 * n * depth`` named
+    real slots."""
+    sa = _benchmark_ansatz()
+    circ = Circuit(n)
+    sa.build(circ, num_qubits=n, depth=depth, angle=P)
+    return circ, sa.param_names(num_qubits=n, depth=depth)
+
+
+def _three_real():
+    circ, names = Circuit(3), ["u", "v", "w"]
+    for q, name in enumerate(names):
+        circ.rotateY(q, P(name))
+    return circ, names
+
+
+def _angle_sets(names, rng, count):
+    return [dict(zip(names, map(float, rng.uniform(-3, 3, len(names)))))
+            for _ in range(count)]
+
+
+def _three_kinds():
+    """Real, complex and seed slots interleaved on one tape, named and
+    anonymous: slot order real, complex, complex, seed, real, seed,
+    complex, complex, real."""
+    c = Circuit(3)
+    c.hadamard(0)
+    c.rotateY(0, P("a0"))
+    c.compactUnitary(1, P("al"), P("be"))
+    c.applyTrajectoryKraus((2,), _kraus(), P("s0"), site=0)
+    c.rotateZ(2, P("a1"))
+    c.applyTrajectoryKraus((0,), _kraus(), P("s1"), site=1)
+    c.compactUnitary(0, complex(np.cos(0.3), 0.0), complex(0.0, np.sin(0.3)))
+    c.rotateX(1, 0.25)
+    return c
+
+
+def _three_kinds_params(rng):
+    th = float(rng.uniform(0, np.pi))
+    return {"a0": float(rng.uniform(-3, 3)), "a1": float(rng.uniform(-3, 3)),
+            "al": complex(np.cos(th), 0.0), "be": complex(0.0, np.sin(th)),
+            "s0": int(rng.randint(1, 2**31)), "s1": int(rng.randint(1, 2**31))}
+
+
+def _launch_counts():
+    return (telemetry.counter_value("engine_launch_args_total"),
+            telemetry.counter_value("device_dispatch_total",
+                                    route="engine_vmap"))
+
+
+@pytest.mark.parametrize("shape,slots,kinds", [
+    ("served", 160, 1), ("three real", 3, 1), ("three kinds", 9, 3)])
+def test_batch_program_takes_one_array_per_slot_kind(shape, slots, kinds):
+    """The state and one packed array a slot kind the tape has, however
+    many slots: ``engine_launch_args_total`` over the launches."""
+    rng = np.random.RandomState(31)
+    if shape == "three kinds":
+        circ = _three_kinds()
+        sweep = [_three_kinds_params(rng) for _ in range(4)]
+    else:
+        circ, names = _served(4, 20) if shape == "served" else _three_real()
+        sweep = _angle_sets(names, rng, 4)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0) as eng:
+        assert len(eng._lifted.slots) == slots
+        assert len(eng._packs) == kinds
+        assert sorted(i for _, cols in eng._packs for i in cols) == list(
+            range(len(eng._lifted.slots)))
+        eng.warmup()
+        args0, launches0 = _launch_counts()
+        for f in eng.submit_many(sweep):
+            f.result()
+        for p in sweep[:2]:
+            eng.run(p)
+        args1, launches1 = _launch_counts()
+    assert launches1 - launches0 == 3
+    assert (args1 - args0) / (launches1 - launches0) == 1 + kinds
+
+
+def test_interleaved_slot_kinds_bind_each_slot_to_its_own_column():
+    """Every lane of a batch over a tape with the three kinds interleaved
+    is the single-request executable's state on the same values tuple: no
+    slot reads another slot's column, no lane another's row."""
+    circ = _three_kinds()
+    rng = np.random.RandomState(7)
+    sweep = [_three_kinds_params(rng) for _ in range(8)]
+    with Engine(circ, ENV1, max_batch=8, max_delay_ms=0.0) as eng:
+        assert [s.kind for s in eng._lifted.slots] == [
+            "real", "complex", "complex", "seed", "real", "seed",
+            "complex", "complex", "real"]
+        assert eng._packs == (("real", (0, 4, 8)),
+                              ("complex", (1, 2, 6, 7)), ("seed", (3, 5)))
+        eng.warmup()
+        lanes = [np.asarray(f.result()) for f in eng.submit_many(sweep)]
+        one = eng._exec1()
+        lone = [np.asarray(eng.run(p)) for p in sweep]
+        for p, lane, alone in zip(sweep, lanes, lone):
+            values = bind(eng._lifted, p)
+            rows = _pack_rows(eng._packs, values)
+            assert [r.dtype for r in rows] == [
+                values[0].dtype, values[1].dtype, np.uint32]
+            assert [r.tolist() for r in rows] == [
+                [values[i].item() for i in cols] for _, cols in eng._packs]
+            # the same program, alone in its batch: the same bits
+            assert np.array_equal(lane, alone)
+            # the single-request executable is another XLA program (an
+            # unbatched contraction may round its last bit otherwise): a
+            # slot bound to a neighbour's column would miss by O(1)
+            want = np.asarray(one.with_values(eng.initial_amps + 0, values))
+            assert np.max(np.abs(lane - want)) <= 1e-14
+    assert len({lane.tobytes() for lane in lanes}) == 8
+
+
+def test_short_batch_padded_to_width_resolves_its_own_lanes():
+    """Five requests through the program of eight: five futures, five
+    distinct states, each the state of its own angles."""
+    circ, names = _served(4, 2)
+    rng = np.random.RandomState(5)
+    sweep = _angle_sets(names, rng, 5)
+    with Engine(circ, ENV1, max_batch=8, max_delay_ms=0.0) as eng:
+        eng.warmup()
+        _, launches0 = _launch_counts()
+        futs = eng.submit_many(sweep)
+        lanes = [np.asarray(f.result()) for f in futs]
+        assert _launch_counts()[1] == launches0 + 1
+        lone = [np.asarray(eng.run(p)) for p in sweep]
+    assert len(lanes) == 5 and len({lane.tobytes() for lane in lanes}) == 5
+    assert all(np.array_equal(a, b) for a, b in zip(lanes, lone))
+    exe = circ.parameterized(donate=False)
+    for p, lane in zip(sweep, lanes):
+        q = qt.createQureg(4, ENV1)
+        assert np.max(np.abs(lane - np.asarray(exe(q.amps, p)))) <= 1e-12
+
+
+def test_finalize_returning_a_dict_resolves_per_lane_dicts():
+    circ, names = _served(3, 1)
+    rng = np.random.RandomState(9)
+    sweep = _angle_sets(names, rng, 3)
+
+    def finalize(amps):
+        return {"p0": amps[0, 0] ** 2 + amps[1, 0] ** 2,
+                "head": amps[:, :2], "nested": (amps[0, 1],)}
+
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0) as plain:
+        states = [np.asarray(f.result()) for f in plain.submit_many(sweep)]
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0,
+                finalize=finalize) as eng:
+        outs = [f.result() for f in eng.submit_many(sweep)]
+    for out, state in zip(outs, states):
+        assert set(out) == {"p0", "head", "nested"}
+        assert np.asarray(out["head"]).shape == (2, 2)
+        assert np.array_equal(np.asarray(out["head"]), state[:, :2])
+        assert float(out["p0"]) == pytest.approx(
+            state[0, 0] ** 2 + state[1, 0] ** 2, abs=1e-15)
+        assert float(out["nested"][0]) == state[0, 1]
+
+
+def test_grad_engine_lanes_match_the_single_request_gradient():
+    circ, names = _served(3, 2)
+    codes, coeffs = [[3, 0, 0], [1, 1, 0]], [0.7, -0.4]
+    rng = np.random.RandomState(13)
+    sweep = _angle_sets(names, rng, 4)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0,
+                hamiltonian=(codes, coeffs)) as eng:
+        eng.warmup_grad()
+        grad = eng.grad_engine()
+        assert grad._packs == (("real", tuple(range(len(names)))),)
+        d0 = telemetry.counter_value("device_dispatch_total",
+                                     route="grad_request")
+        batch = [f.result() for f in grad.submit_many(sweep)]
+        assert telemetry.counter_value(
+            "device_dispatch_total", route="grad_request") == d0 + 1
+        gx = circ.gradient((codes, coeffs), donate=False)
+        for p, out in zip(sweep, batch):
+            value, grads = eng.submit_grad(p).result()
+            assert float(out["value"]) == float(value)
+            assert {k: float(v) for k, v in out["grads"].items()} == {
+                k: float(v) for k, v in grads.items()}
+            ref = gx(qt.createQureg(3, ENV1).amps, p)
+            np.testing.assert_allclose(float(value), float(ref["value"]),
+                                       atol=1e-12, rtol=0)
+            for k in names:
+                np.testing.assert_allclose(float(grads[k]),
+                                           float(ref["grads"][k]),
+                                           atol=1e-12, rtol=0)
+
+
+def test_poisoned_request_bisects_to_its_neighbours_exact_results():
+    from quest_tpu.resilience import fault_plan
+    from quest_tpu.resilience.errors import PoisonedRequestFault
+
+    circ, names = _served(3, 2)
+    rng = np.random.RandomState(17)
+    sweep = _angle_sets(names, rng, 6)
+    b0 = telemetry.counter_value("engine_bisections_total")
+    with Engine(circ, ENV1, max_batch=8, max_delay_ms=0.0) as eng:
+        eng.warmup()
+        with fault_plan("engine.request:poison:4"):
+            futs = eng.submit_many(sweep)
+            got = []
+            for f in futs:
+                try:
+                    got.append(np.asarray(f.result(timeout=120)))
+                except PoisonedRequestFault as e:
+                    got.append(e)
+        assert isinstance(got[3], PoisonedRequestFault)
+        for i in (0, 1, 2, 4, 5):
+            assert np.array_equal(got[i], np.asarray(eng.run(sweep[i])))
+    assert telemetry.counter_value("engine_bisections_total") > b0
+
+
+def test_warm_batch_is_one_executable_and_one_transfer(tmp_path):
+    """After warm-up a batch is exactly one device program and one transfer
+    a slot kind: the profiler's host line counts every executable the
+    process runs and every array it puts, whoever dispatched it, so an
+    eager slice, repeat or stack in assemble, launch or resolve would show."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    circ, names = _served(4, 20)
+    rng = np.random.RandomState(23)
+    sweeps = [_angle_sets(names, rng, 8) for _ in range(20)]
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    with Engine(circ, ENV1, max_batch=8, max_delay_ms=0.0) as eng:
+        eng.warmup()
+        for f in eng.submit_many(sweeps[0]):
+            jax.block_until_ready(f.result())
+        traces = telemetry.counter_value("engine_trace_total",
+                                         kind="param_replay")
+        _, launches0 = _launch_counts()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            for sweep in sweeps:
+                for f in eng.submit_many(sweep):
+                    jax.block_until_ready(f.result())
+        finally:
+            jax.profiler.stop_trace()
+        assert _launch_counts()[1] == launches0 + 20
+        assert telemetry.counter_value(
+            "engine_trace_total", kind="param_replay") == traces
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                seen[ev.name] = seen.get(ev.name, 0) + 1
+    executed = {k: v for k, v in seen.items()
+                if k.startswith("PjRt") and k.endswith("Executable::Execute")}
+    assert sum(executed.values()) == 20, executed
+    assert seen.get("DevicePut", 0) == 20
+    assert not [k for k in seen if k.startswith("PjitFunction(")
+                and "engine_vmap" not in k], seen
+
+
+@pytest.mark.parametrize("closes", ["full", "device done", "quiet"])
+def test_window_stays_open_while_the_batch_ahead_executes(closes):
+    """With a batch in flight a request that finds the window open is not
+    issued alone behind it as a whole padded program of its own: every
+    arrival restarts the timer, and the window closes when it is full,
+    at once when the device finishes the batch ahead, or when
+    ``max_delay_ms`` passes with no arrival."""
+    import threading
+
+    circ, names = _served(3, 1)
+    sweep = _angle_sets(names, np.random.RandomState(29), 8)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0) as oracle:
+        want = [np.asarray(oracle.run(p)) for p in sweep]
+    # the executable is the oracle's (one structure, one cache entry)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=400.0,
+                async_depth=2) as eng:
+        done_ahead = threading.Event()
+        probe = eng._ring_head_ready
+        eng._ring_head_ready = lambda: done_ahead.is_set() and probe()
+        n0, sum0 = (telemetry.snapshot("engine_batch_size")["histograms"]
+                    ["engine_batch_size"][k] for k in ("count", "sum"))
+
+        def batches():
+            h = telemetry.snapshot("engine_batch_size")["histograms"][
+                "engine_batch_size"]
+            return h["count"] - n0, h["sum"] - sum0
+
+        futs = eng.submit_many(sweep[:5])      # a full batch and one more
+        deadline = time.perf_counter() + 30
+        while batches()[0] < 1 and time.perf_counter() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.1)                        # 200 polls of the window
+        assert batches() == (1, 4) and not futs[4].done()
+        if closes == "full":
+            # one at a time, each inside the timer the one before restarted:
+            # 3 x 0.25 s is past the 0.4 s the first request's timer had
+            for p in sweep[5:]:
+                time.sleep(0.25)
+                assert batches() == (1, 4)
+                futs.append(eng.submit(p))
+            want_batches = (2, 8)
+        elif closes == "device done":
+            done_ahead.set()
+            want_batches = (2, 5)
+        else:
+            want_batches = (2, 5)              # the timer alone closes it
+        lanes = [np.asarray(f.result(timeout=60)) for f in futs]
+        assert batches() == want_batches
+        done_ahead.set()
+        assert all(np.array_equal(lane, w) for lane, w in zip(lanes, want))
+
+
+def _held_window(eng, sweep):
+    """A full batch issued and one more request held in the open window
+    behind it: the readiness probe is made to say the batch ahead is
+    still executing. Returns the five futures."""
+    eng._ring_head_ready = lambda: False
+    n0 = telemetry.snapshot("engine_batch_size")["histograms"][
+        "engine_batch_size"]["count"]
+    futs = eng.submit_many(sweep[:4]) + [eng.submit(sweep[4])]
+    deadline = time.perf_counter() + 30
+    while (telemetry.snapshot("engine_batch_size")["histograms"][
+            "engine_batch_size"]["count"] == n0
+           and time.perf_counter() < deadline):
+        time.sleep(0.005)
+    return futs
+
+
+def test_partial_window_behind_a_hung_head_is_bounded_by_the_watchdog():
+    """A wedged device must stay a DETECTABLE hang with a partial window
+    behind it: the window closes by its timer, the head's bounded retire
+    names the hang, and the request that was held is served behind it."""
+    from quest_tpu.resilience import fault_plan, watchdog_deadline
+    from quest_tpu.resilience.errors import QuESTHangError
+
+    circ, names = _served(3, 1)
+    sweep = _angle_sets(names, np.random.RandomState(31), 5)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=20.0,
+                async_depth=2) as eng:
+        eng.warmup()
+        want = np.asarray(eng.run(sweep[4]))
+        with watchdog_deadline(200), fault_plan("engine.retire:hang:1"):
+            t0 = time.perf_counter()
+            futs = _held_window(eng, sweep)
+            for f in futs[:4]:
+                with pytest.raises(QuESTHangError):
+                    f.result(timeout=30)
+            held = np.asarray(futs[4].result(timeout=30))
+            took = time.perf_counter() - t0
+        assert np.array_equal(held, want)
+        # the window's timer, one deadline of bounded sync, and slack
+        assert 0.2 <= took < 10, took
+
+
+def test_request_deadline_expires_inside_a_held_window():
+    """``timeout=`` is honoured behind a batch the device does not let go
+    of: the window closes by its timer and the request, its deadline
+    passed, expires instead of running."""
+    from quest_tpu.resilience.errors import QuESTTimeoutError
+
+    circ, names = _served(3, 1)
+    sweep = _angle_sets(names, np.random.RandomState(37), 5)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=100.0,
+                async_depth=2) as eng:
+        eng.warmup()
+        eng._ring_head_ready = lambda: False
+        futs = eng.submit_many(sweep[:4])
+        late = eng.submit(sweep[4], timeout=0.02)
+        t0 = time.perf_counter()
+        with pytest.raises(QuESTTimeoutError):
+            late.result(timeout=30)
+        assert time.perf_counter() - t0 < 10
+        lanes = [np.asarray(f.result(timeout=30)) for f in futs]
+        assert all(np.array_equal(lane, np.asarray(eng.run(p)))
+                   for lane, p in zip(lanes, sweep))
+
+
+def test_finished_batch_ahead_closes_the_window_without_the_timer():
+    """Once the batch ahead is done the window closes at once: neither
+    its futures nor the held request wait out ``max_delay_ms``."""
+    circ, names = _served(3, 1)
+    sweep = _angle_sets(names, np.random.RandomState(41), 5)
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=0.0) as oracle:
+        want = [np.asarray(oracle.run(p)) for p in sweep]
+    # the executable is the oracle's (one structure, one cache entry), so
+    # nothing compiles inside the timed part
+    with Engine(circ, ENV1, max_batch=4, max_delay_ms=5_000.0,
+                async_depth=2) as eng:
+        t0 = time.perf_counter()
+        futs = eng.submit_many(sweep)          # a full batch and one more
+        lanes = [np.asarray(f.result(timeout=15)) for f in futs]
+        assert time.perf_counter() - t0 < 2.5
+        assert all(np.array_equal(lane, w) for lane, w in zip(lanes, want))
