@@ -358,8 +358,8 @@ def bench_density(n: int, reps: int, sync) -> dict:
         # pallas=True: the unitary prefix rides fused kernel runs with
         # explicit conj-shadow ops; channels stay barriers on their own
         # fused-Kraus passes
-        fn = circ.fused(max_qubits=4, pallas=True).compiled_blocks(
-            max_gates=4, donate=True)
+        fn = circ.fused(max_qubits=4, pallas=True).compiled_segments(
+            max_items=4, donate=True)
         amps = rho.amps
         amps = fn(amps)
         sync(amps)
@@ -742,9 +742,9 @@ def plan_17q_density_distributed() -> dict:
     # coordinate (q + n) lives above the 30-qubit shard boundary
     circ.mixDepolarising(n - 2, 0.03)
     fz = circ.fused(max_qubits=4, pallas=True, shard_devices=ndev)
-    runs = [a for f, a, _ in fz._tape
-            if f.__name__ == "_apply_pallas_run"]
-    kraus_ops = [op for a in runs for op in a[0]
+    runs = [i for i in fusion.plan_from_tape(fz._tape).items
+            if isinstance(i, fusion.PallasRun)]
+    kraus_ops = [op for r in runs for op in r.ops
                  if op[0].startswith("kraus")]
     tstats = fusion.tape_transpose_stats(
         fz._tape, 2 * n - (ndev.bit_length() - 1))
@@ -1021,7 +1021,6 @@ def bench_serving(n: int, depth: int, reps: int) -> dict:
     # structure twin lowers -- every frame-identity segment composed --
     # into ONE dispatched program: dispatches_per_circuit floors at 1
     from quest_tpu.ops import init as ops_init
-    from quest_tpu.segments import force_route, run_slice
     conc = serving_ansatz(n, depth, values=ab_sweep[0])
     fnR = conc.compiled_request(donate=False)
     amps0 = ops_init.init_classical(1 << n, eng.dtype, 0)
@@ -1035,14 +1034,6 @@ def bench_serving(n: int, depth: int, reps: int) -> dict:
                                   route="request") - d0
     chained_bitident = bool(np.array_equal(
         np.asarray(out_req), np.asarray(fnR(amps0 + 0))))
-    # item-route reference: the same concrete tape interpreted one device
-    # program per entry -- agreement is ~1 ulp across program
-    # granularities on XLA-CPU (the documented segments.py caveat)
-    qreg = qt.createQureg(n, qt.createQuESTEnv(jax.devices()[:1]))
-    with force_route("item"):
-        run_slice(conc, qreg)
-    chain_vs_item_close = bool(np.allclose(
-        np.asarray(out_req), np.asarray(qreg.amps)))
     # traced section (round 17): a handful of extra warm requests under
     # trace_policy("all"), OUTSIDE every timed window above -- per-phase
     # attribution for the row without perturbing the gated numbers
@@ -1105,7 +1096,6 @@ def bench_serving(n: int, depth: int, reps: int) -> dict:
             "request_num_segments": int(fnR.num_segments),
             "chained_request_ms": round(chained_ms, 2),
             "chained_bitident": bool(chained_bitident),
-            "chain_vs_item_close": bool(chain_vs_item_close),
             **phase_stats,
         },
     }
@@ -1710,45 +1700,36 @@ def _comm_config(reps: int, smoke: bool) -> dict:
 
 
 def bench_dispatch(n: int, depth: int, reps: int) -> dict:
-    """CI-gate config ``dispatch_20q`` (round 13, ISSUE 12): the
-    whole-segment single-dispatch A/B. Runs the SAME fused circuit
-    item-by-item (the pre-round-13 interpreter: the host walks the tape
-    and every entry is its own device dispatch) and as frame-identity
-    segment programs (``Circuit.compiled_segments``: ONE dispatch per
-    segment), both from the same |+...+> init. Telemetry deltas prove
-    the dispatch collapse exactly -- the item leg counts one
-    ``device_dispatch_total{route="item"}`` per tape entry, the segment
-    leg one ``route="segment"`` per segment -- and the headline is the
-    amortization factor items/segments. Both routes are asserted
-    run-to-run DETERMINISTIC (bit-identical), and the two legs must
-    agree within the dtype band; exact bit-identity ACROSS program
-    granularities is an XLA-CPU non-goal (cross-program fma
-    recontraction -- the documented tests/test_sharded_df.py caveat; on
-    TPU the Mosaic kernel is opaque to XLA and the routes coincide)."""
+    """CI-gate config ``dispatch_20q`` (round 13, ISSUE 12): the fused
+    circuit as frame-identity segment programs
+    (``Circuit.compiled_segments``: ONE device dispatch per segment,
+    however many tape entries it holds). Telemetry deltas prove the count
+    exactly -- one ``device_dispatch_total{route="segment"}`` per segment
+    -- and the headline is the tape entries one dispatch carries. The
+    chain is asserted run-to-run DETERMINISTIC (bit-identical) and must
+    agree with the whole-tape program ``Circuit.run`` dispatches within
+    the dtype band; exact bit-identity ACROSS program granularities is an
+    XLA-CPU non-goal (cross-program fma recontraction -- the documented
+    tests/test_sharded_df.py caveat; on TPU the Mosaic kernel is opaque
+    to XLA and the granularities coincide)."""
     import time
 
     import jax
 
     import quest_tpu as qt
-    from quest_tpu import segments, telemetry
+    from quest_tpu import telemetry
     from quest_tpu.precision import real_dtype
 
-    metric = (f"single-dispatch segment programs A/B, {n}q fused "
-              f"Clifford+T (one dispatch per tape item vs per segment)")
+    metric = (f"single-dispatch segment programs, {n}q fused Clifford+T "
+              f"(tape entries per device dispatch)")
     env = qt.createQuESTEnv(jax.devices()[:1])
     fused = build_circuit(n, depth).fused(max_qubits=5, pallas=True)
     items = len(fused)
-    if items < 2:
-        return {"config": "dispatch_20q", "metric": metric, "value": None,
-                "unit": "x fewer dispatches", "vs_baseline": None,
-                "note": f"{n}q fused to a single tape item; the A/B "
-                        "needs a multi-item plan"}
 
-    def item_state():
+    def whole_state():
         q = qt.createQureg(n, env)
         qt.initPlusState(q)
-        with segments.force_route("item"):
-            segments.run_slice(fused, q)
+        fused.run(q)
         return np.asarray(jax.device_get(q.amps))
 
     chain = fused.compiled_segments()           # whole tape, coarsest cuts
@@ -1759,33 +1740,20 @@ def bench_dispatch(n: int, depth: int, reps: int) -> dict:
         q.put(chain(q.amps))
         return np.asarray(jax.device_get(q.amps))
 
-    i0 = telemetry.counter_value("device_dispatch_total", route="item")
-    a1 = item_state()
-    item_dispatches = int(telemetry.counter_value(
-        "device_dispatch_total", route="item") - i0)
+    a1 = whole_state()
     s0 = telemetry.counter_value("device_dispatch_total", route="segment")
     b1 = seg_state()
     seg_dispatches = int(telemetry.counter_value(
         "device_dispatch_total", route="segment") - s0)
-    bit_identical = (np.array_equal(a1, item_state())
-                     and np.array_equal(b1, seg_state()))
+    bit_identical = np.array_equal(b1, seg_state())
     route_maxdiff = float(np.max(np.abs(a1 - b1)))
     tol = 1e-13 if np.dtype(real_dtype()) == np.dtype("float64") else 1e-5
     del a1, b1
 
-    # timing: 1 warm (above) + best-of-k per leg; the item leg pays the
-    # host interpreter + one dispatch per entry, the segment leg one
-    # dispatch per segment -- the difference IS the dispatch tax
+    # timing: 1 warm (above) + best-of-k
     k = max(min(reps, 3), 1)
     q = qt.createQureg(n, env)
     qt.initPlusState(q)
-    best_item = float("inf")
-    for _ in range(k):
-        t0 = time.perf_counter()
-        with segments.force_route("item"):
-            segments.run_slice(fused, q)
-        q.amps.block_until_ready()
-        best_item = min(best_item, time.perf_counter() - t0)
     amps = q.amps
     best_seg = float("inf")
     for _ in range(k):
@@ -1795,27 +1763,22 @@ def bench_dispatch(n: int, depth: int, reps: int) -> dict:
         best_seg = min(best_seg, time.perf_counter() - t0)
     del amps, q
 
-    amort = items / chain.num_segments
     return {
         "config": "dispatch_20q",
         "metric": metric,
-        "value": round(amort, 2),
-        "unit": "x fewer dispatches",
+        "value": round(items / chain.num_segments, 2),
+        "unit": "tape entries per dispatch",
         "vs_baseline": None,
         "detail": {
             "qubits": n,
             "depth": depth,
             "tape_items": items,
             "num_segments": chain.num_segments,
-            "item_dispatches": item_dispatches,
             "segment_dispatches": seg_dispatches,
-            "dispatch_amortization": round(amort, 2),
             "bit_identical": bool(bit_identical),
             "route_maxdiff": route_maxdiff,
             "route_agreement_ok": bool(route_maxdiff <= tol),
-            "item_ms": round(best_item * 1e3, 2),
             "segment_ms": round(best_seg * 1e3, 2),
-            "speedup": round(best_item / best_seg, 3),
         },
     }
 
@@ -2245,10 +2208,9 @@ def main() -> None:
                         " state-vector cost, ensemble-mean-vs-oracle +"
                         " seed-replay bit-identity asserted);"
                         " dispatch: the dispatch_20q row (whole-segment"
-                        " single-dispatch A/B: one device dispatch per"
-                        " tape item vs one per frame-identity segment,"
-                        " dispatch counts from telemetry + determinism"
-                        " asserted);"
+                        " single dispatch: one device dispatch per"
+                        " frame-identity segment, the count from"
+                        " telemetry + determinism asserted);"
                         " pool: the pool_20q row (replica-pool serving:"
                         " mixed-structure open-loop load over 3 replicas,"
                         " req/sec + p50/p99, one injected replica kill"

@@ -76,13 +76,15 @@ def main():
     fused = circ.fused(max_qubits=5, pallas=use_pallas,
                        shard_devices=shards if use_pallas else None)
 
-    # compiled_blocks bypasses Circuit.run, so build it under the execution
-    # mesh (the block executables pin the ambient contexts at build time)
+    # a chain of segment programs bounds each program's compile size, cut
+    # at seams where the frame is at identity. compiled_segments bypasses
+    # Circuit.run, so build it under the execution mesh (the segment
+    # executables pin the ambient contexts at build time)
     from quest_tpu import fusion as _fusion
     from quest_tpu.circuits import _register_mesh
 
     with _fusion.pallas_mesh(_register_mesh(qureg)):
-        fn = fused.compiled_blocks(max_gates=24, donate=True)
+        fn = fused.compiled_segments(max_items=24, donate=True)
 
     t0 = time.time()
     amps = fn(qureg.amps)

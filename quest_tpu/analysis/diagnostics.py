@@ -183,11 +183,6 @@ CATALOG: dict[str, tuple[str, str, str]] = {
               "the generation was skipped and resume fell back to an "
               "older verified snapshot; investigate the named shard for "
               "torn writes or corruption"),
-    "QT306": ("warning", "QUEST_SEGMENT_DISPATCH is malformed or out of "
-                         "range",
-              "set QUEST_SEGMENT_DISPATCH to 0 (per-item interpretation) "
-              "or a positive integer (single-dispatch segment programs, "
-              "the default); the malformed value was replaced"),
     "QT307": ("warning", "malformed replica-pool/admission environment "
                          "value replaced by its default",
               "QUEST_POOL_REPLICAS must be an integer >= 1; "
@@ -396,8 +391,7 @@ def parse_env_int(env: str, default: int, *, minimum: int, code: str,
     (so each knob warns per process, not per launch). The silent coercion
     stays -- the caller must still launch -- but it is no longer silent.
     Shared by ``QUEST_PALLAS_RING`` (QT205), ``QUEST_COMM_PIPELINE``
-    (QT206), ``QUEST_COMM_PIPELINE_DCN`` (QT210),
-    ``QUEST_SEGMENT_DISPATCH`` (QT306) and the replica-pool
+    (QT206), ``QUEST_COMM_PIPELINE_DCN`` (QT210) and the replica-pool
     knobs ``QUEST_POOL_REPLICAS`` / ``QUEST_HEDGE_MS`` /
     ``QUEST_TENANT_QPS`` (QT307) instead of per-knob hand-rolled
     parsers."""

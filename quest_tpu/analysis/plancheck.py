@@ -19,7 +19,7 @@ over an explicit position permutation and proves
   :func:`quest_tpu.segments.stamp_plan`) equals the independently
   re-derived frame-identity segment index, in FusePlan order (QT107) --
   so each emitted single-dispatch segment provably starts and ends at
-  frame identity; unstamped items (pre-round-13 tapes) skip the check,
+  frame identity; items no planner stamped skip the check,
 - each run's DMA-ring operating point is hazard-free and in budget
   (delegated to :mod:`.ringcheck`).
 
@@ -147,7 +147,7 @@ def check_plan(plan, nsv: int, *, dtype=None,
 
     def check_seg(item, where: str) -> None:
         if item.seg is None:
-            return  # pre-round-13 tape / unplanned item: no stamp
+            return  # an item no planner stamped
         if item.seg != seg_expect:
             findings.append(make_finding(
                 "QT107",

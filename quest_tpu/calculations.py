@@ -167,7 +167,8 @@ def _pauli_prod_amps(amps, term, nsv, dt):
 #: terms per compiled block in _expec_pauli_sum_fused: each term unrolls an
 #: O(n)-op Pauli pipeline into the program, so program size (and compile
 #: time) grows linearly with terms -- the same compile-limit failure mode
-#: Circuit.blocks() bounds. 64 terms x ~n ops stays well under XLA limits.
+#: Circuit.compiled_segments bounds. 64 terms x ~n ops stays well under
+#: XLA limits.
 _EXPEC_TERM_BLOCK = 64
 
 

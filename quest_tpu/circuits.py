@@ -23,6 +23,7 @@ circuits.
 
 from __future__ import annotations
 
+import dataclasses
 import inspect
 
 import jax
@@ -524,7 +525,7 @@ class Circuit:
         conj-shadow ops (fusion._shadow_pop). ``shard_devices`` plans for execution on a register
         sharded over that many devices: the tile limit shrinks to the
         shard-local size so every emitted run is per-shard executable under
-        shard_map (fusion._shard_map_pallas_run); Circuit.run keeps that
+        shard_map (fusion._shard_route); Circuit.run keeps that
         per-shard path active inside the jitted replay by deriving the
         execution mesh from the register it is given (fusion.pallas_mesh).
 
@@ -599,18 +600,18 @@ class Circuit:
                             max_qubits=max_qubits,
                             pallas_tile_bits=tile_bits,
                             is_density=self.is_density_matrix)
-        if ring_depth is not None:
-            for item in p.items:
-                if isinstance(item, fusion.PallasRun):
-                    item.ring_depth = int(ring_depth)
-        if comm_pipeline is not None:
-            for item in p.items:
-                if isinstance(item, (fusion.PallasRun, fusion.FrameSwap)):
-                    item.comm_pipeline = int(comm_pipeline)
-        if comm_pipeline_dcn is not None:
-            for item in p.items:
-                if isinstance(item, (fusion.PallasRun, fusion.FrameSwap)):
-                    item.comm_pipeline_dcn = int(comm_pipeline_dcn)
+        # all stamping happens here, on the plan: its runs and swaps are
+        # frozen, and nothing changes one that is on a tape
+        comm = {name: int(depth) for name, depth in (
+            ("comm_pipeline", comm_pipeline),
+            ("comm_pipeline_dcn", comm_pipeline_dcn)) if depth is not None}
+        for i, item in enumerate(p.items):
+            if ring_depth is not None and isinstance(item, fusion.PallasRun):
+                item = dataclasses.replace(item, ring_depth=int(ring_depth))
+            if comm and isinstance(item, (fusion.PallasRun,
+                                          fusion.FrameSwap)):
+                item = dataclasses.replace(item, **comm)
+            p.items[i] = item
         # round 13: stamp each frame-carrying item with its frame-identity
         # segment index (the single-dispatch segment programs' seams;
         # plancheck QT107 re-derives and cross-checks the stamps)
@@ -633,59 +634,16 @@ class Circuit:
         out._tape = fusion.as_tape(p)
         return out
 
-    def blocks(self, max_gates: int) -> list:
-        """Split the tape into sub-circuits of at most ``max_gates`` gates.
-
-        One arbitrarily deep circuit as a single XLA program eventually
-        exhausts the compiler (the graph grows with tape length x state
-        size); chaining a few block-sized executables with donated buffers
-        keeps per-program compilation bounded while retaining fusion within
-        each block. Runtime cost is one extra dispatch per block.
-        """
-        if max_gates < 1:
-            raise ValueError("max_gates must be >= 1")
-        parts = []
-        for i in range(0, len(self._tape), max_gates):
-            part = Circuit(self.num_qubits, self.is_density_matrix)
-            part._tape = list(self._tape[i:i + max_gates])
-            parts.append(part)
-        return parts
-
-    def compiled_blocks(self, max_gates: int, donate: bool = True):
-        """Like :meth:`compiled`, but as a chain of block-sized executables.
-        Cached like :meth:`compiled` (the same bounded global LRU) so
-        repeated calls reuse the underlying executables instead of
-        retracing every block."""
-        from . import fusion
-        from .engine import cache as _ec
-        from .parallel import scheduler as _dist
-        sched = _dist.active()
-        key = ("circuit_blocks", self._cache_token, max_gates, donate,
-               sched.mesh if sched else None, fusion.active_pallas_mesh())
-
-        def build():
-            fns = [b.compiled(donate=donate) for b in self.blocks(max_gates)]
-
-            def chained(amps, _fns=tuple(fns)):
-                for f in _fns:
-                    telemetry.inc("device_dispatch_total", route="block")
-                    amps = f(amps)
-                return amps
-
-            return chained
-
-        return _ec.executables().get_or_create(key, build)
-
     def compiled_segments(self, max_items: int | None = None,
                           donate: bool = True):
         """The tape as a chain of frame-identity-aligned segment programs
         (round 13, :mod:`quest_tpu.segments`): each segment is ONE jitted
         dispatch covering up to ``max_items`` tape entries, cut only at
-        frame-identity seams. Supersedes :meth:`compiled_blocks` for deep
-        tapes -- same bounded per-program compile size, but the seams are
-        legal checkpoint/resume points and the dispatch tax is the
-        SEGMENT count, not the block count (``max_items=None`` = the
-        whole tape as one program). The chain exposes its link count as
+        frame-identity seams. For deep tapes: one arbitrarily deep circuit
+        as a single XLA program eventually exhausts the compiler, and a
+        chain bounds the per-program compile size; the seams are legal
+        checkpoint/resume points and the dispatch tax is the SEGMENT
+        count (``max_items=None`` = the whole tape as one program). The chain exposes its link count as
         ``.num_segments``; every link launch counts
         ``device_dispatch_total{route="segment"}``."""
         from . import segments

@@ -56,7 +56,7 @@ class LRUCache:
         self.capacity = int(capacity)
         self.name = name
         # re-entrant: a factory may itself route nested executables through
-        # the same cache (compiled_blocks builds its per-block replays)
+        # the same cache (compiled_segments builds its per-segment replays)
         self._lock = _sync.RLock("engine.cache")
         self._od: OrderedDict = OrderedDict()
 
@@ -123,7 +123,7 @@ class LRUCache:
 
 
 #: process-global executable cache every compiled Circuit replay routes
-#: through (Circuit.compiled / compiled_blocks / parameterized and the
+#: through (Circuit.compiled / compiled_segments / parameterized and the
 #: Engine's batch executables); bounded so a long-lived server submitting
 #: many circuit structures cannot grow it without limit
 _EXECUTABLES = LRUCache(

@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -567,12 +567,16 @@ class FusePlan:
     num_barriers: int = 0
 
 
-@dataclass
+@dataclass(frozen=True)
 class PallasRun:
     """A run of tile-local 1-qubit matrices / parity phases executed in ONE
     Pallas HBM pass (ops.pallas_gates.fused_local_run). Gate targets must be
     below ``tile_bits``; controls and parity members may be any qubit.
     Ops are in PHYSICAL coordinates (after any active frame swap).
+
+    The run IS its tape entry (``(_apply_pallas_run, (run,), {})``):
+    frozen and hashable, stamped on the plan with ``dataclasses.replace``
+    before :func:`as_tape`, never changed once it is on a tape.
 
     ``load_swap_k`` / ``store_swap_k`` fold the frame-switch transpose into
     this run's input gather / output scatter (zero extra HBM passes; see
@@ -602,20 +606,19 @@ class PallasRun:
     #: QUEST_COMM_PIPELINE default; bit-identical at every depth --
     #: exchange.dist_permute_bits)
     comm_pipeline: int | None = None
-    #: frame-identity segment index this run belongs to (round 13:
-    #: quest_tpu.segments.stamp_plan; plancheck QT107 re-derives and
+    #: frame-identity segment index this run belongs to
+    #: (quest_tpu.segments.stamp_plan; plancheck QT107 re-derives and
     #: checks it). Plan-time annotation only -- ignored at apply time;
-    #: None on pre-round-13 tapes and unplanned items.
+    #: None on an item no planner stamped.
     seg: int | None = None
-    #: per-link-class pipeline depth (round 15): sub-collectives of this
-    #: run's frame relabelings that cross a DCN shard bit pipeline at
-    #: this depth instead of ``comm_pipeline`` (None = inherit --
-    #: QUEST_COMM_PIPELINE_DCN env, else the base depth). Encoded LAST
-    #: in the tape entry; pre-round-15 tapes decode to None.
+    #: per-link-class pipeline depth: sub-collectives of this run's frame
+    #: relabelings that cross a DCN shard bit pipeline at this depth
+    #: instead of ``comm_pipeline`` (None = inherit --
+    #: QUEST_COMM_PIPELINE_DCN env, else the base depth)
     comm_pipeline_dcn: int | None = None
 
 
-@dataclass
+@dataclass(frozen=True)
 class FrameSwap:
     """Exchange the k-bit grid block [hi, hi+k) (hi = None means
     tile_bits) with the sublane block [tile_bits-k, tile_bits): one
@@ -624,7 +627,8 @@ class FrameSwap:
     Self-inverse; the planner always returns the register to the identity
     frame before any non-Pallas item. On sharded registers the transpose
     is a collective when [hi, hi+k) includes sharded qubits, and
-    shard-local otherwise."""
+    shard-local otherwise. Its own tape entry, like :class:`PallasRun`
+    (``(_apply_frame_swap, (swap,), {})``)."""
     tile_bits: int
     k: int
     hi: int | None = None
@@ -633,7 +637,7 @@ class FrameSwap:
     comm_pipeline: int | None = None
     #: frame-identity segment index (see PallasRun.seg)
     seg: int | None = None
-    #: DCN-crossing pipeline depth (round 15; see PallasRun)
+    #: DCN-crossing pipeline depth (see PallasRun)
     comm_pipeline_dcn: int | None = None
 
 
@@ -866,8 +870,8 @@ class _FramePlanner:
         hi, kf = self.cur_frame
         last = self.out.items[-1] if self.out.items else None
         if isinstance(last, PallasRun) and last.store_swap_k == 0:
-            last.store_swap_k = kf
-            last.store_swap_hi = hi
+            self.out.items[-1] = dataclasses.replace(
+                last, store_swap_k=kf, store_swap_hi=hi)
         else:  # pragma: no cover - a run always precedes a non-identity frame
             self.out.items.append(FrameSwap(self.tb, kf, hi))
         self.cur_frame = None
@@ -1271,37 +1275,20 @@ def transpose_stats(p: FusePlan, shard_qubits: int | None,
 
 def plan_from_tape(tape) -> FusePlan:
     """Decode an ``as_tape`` tape back into a :class:`FusePlan` -- the
-    ONE decoder of the `_apply_pallas_run` / `_apply_frame_swap` /
-    `_apply_dense_block` / `_apply_gate_diag` / `_apply_deferred_block`
-    tape-entry layouts
-    (:func:`as_tape` is the encoder). Entries that aren't plan items pass
-    through verbatim as ``(fn, args, kwargs)`` tuples, so
-    ``plan_from_tape(as_tape(p))`` round-trips. Used by the bench
-    artifacts, the driver dryrun and the static plan verifier
-    (analysis.plancheck), which see executed circuits, not plans."""
+    ONE decoder of the tape-entry layouts (:func:`as_tape` is the
+    encoder): a `_apply_pallas_run` / `_apply_frame_swap` entry carries
+    its PallasRun / FrameSwap whole, `_apply_dense_block` /
+    `_apply_gate_diag` / `_apply_deferred_block` their operands. Entries
+    that aren't plan items pass through verbatim as ``(fn, args, kwargs)``
+    tuples, so ``plan_from_tape(as_tape(p))`` round-trips. Every reader of
+    an executed circuit's runs (segments, plancheck, the bench artifacts,
+    the tools, the tests) reads them here, by attribute."""
     p = FusePlan()
     for entry in tape:
         f, a, _kw = entry
         name = getattr(f, "__name__", "")
-        if name == "_apply_pallas_run":
-            ops, tb, lk, sk, lh, sh = a[:6]
-            rd = a[6] if len(a) > 6 else None
-            cp = a[7] if len(a) > 7 else None
-            sg = a[8] if len(a) > 8 else None
-            cpd = a[9] if len(a) > 9 else None
-            p.items.append(PallasRun(tuple(ops), tb, load_swap_k=lk,
-                                     store_swap_k=sk, load_swap_hi=lh,
-                                     store_swap_hi=sh, ring_depth=rd,
-                                     comm_pipeline=cp, seg=sg,
-                                     comm_pipeline_dcn=cpd))
-        elif name == "_apply_frame_swap":
-            tb, k, hi = a[:3]
-            p.items.append(FrameSwap(tb, k, hi,
-                                     comm_pipeline=(a[3] if len(a) > 3
-                                                    else None),
-                                     seg=(a[4] if len(a) > 4 else None),
-                                     comm_pipeline_dcn=(a[5] if len(a) > 5
-                                                        else None)))
+        if name in ("_apply_pallas_run", "_apply_frame_swap"):
+            p.items.append(a[0])
         elif name == "_apply_dense_block":
             p.items.append(FusedBlock(tuple(a[1]), a[0]))
         elif name == "_apply_gate_diag":
@@ -1513,246 +1500,124 @@ def _count_frame_swap(qureg, lo2: int, k: int) -> None:
         telemetry.inc("fusion_collective_swaps_total")
 
 
-def _apply_pallas_run(qureg, ops: tuple, tile_bits: int,
-                      load_swap_k: int = 0, store_swap_k: int = 0,
-                      load_swap_hi: int | None = None,
-                      store_swap_hi: int | None = None,
-                      ring_depth: int | None = None,
-                      comm_pipeline: int | None = None,
-                      seg: int | None = None,
-                      comm_pipeline_dcn: int | None = None) -> None:
-    """Tape-entry wrapper for a PallasRun. Ops are RAW kernel ops over the
-    full flattened state: density plans carry explicit conj-shadow twins
-    (fusion._shadow_pop), so no path here re-derives shadows.
+class Route(NamedTuple):
+    """How one PallasRun executes on one register, as :func:`_route`
+    decides it. ``kind``: ``"local"`` (the fused kernel over the whole
+    register), ``"df_local"`` (the same on the double-float planes),
+    ``"sharded"`` (the kernel per shard under shard_map), ``"sched_df"``
+    (per shard on the planes, the relabelings the explicit scheduler's
+    counted permutes) or ``"gatewise"`` (the one exit: the ops replayed
+    through the gate-by-gate appliers). ``fold_load`` / ``fold_store``:
+    the run's frame relabeling rides the kernel's DMA; one that does not
+    runs as an explicit pass. ``reason``: the ``engine_fallback_total``
+    label the decision counts, or None. The rest is what the executor runs
+    on: the mesh of a per-shard route, the qubits and tile sublanes of the
+    array the kernel sees, and whether that kernel is the df one."""
+    kind: str
+    fold_load: bool = False
+    fold_store: bool = False
+    reason: str | None = None
+    mesh: object = None
+    n_exec: int = 0
+    sublanes: int = 0
+    df: bool = False
+
+
+def _folded(route: Route, run: PallasRun) -> Route:
+    """``route`` with the run's relabelings folded where they can be --
+    the ONE copy of the foldability rule. A relabeling folds into the
+    kernel's DMA when its block lies inside the array the kernel sees
+    (``hi + k <= n_exec``: on a shard, a block reaching sharded bits is
+    the collective transpose, explicit by design and no fallback), the
+    plan's tile is that array's (``tile_bits == local_qubits(n_exec,
+    sublanes)``) and the low part of the split sublane axis keeps one
+    sublane tile (``tile_bits - LANE_BITS - k >= 3``: the gathered chunks
+    stay layout-free). A block inside the array that misses the geometry
+    is the counted ``swap_not_foldable``: the kernel still runs, the
+    relabeling beside it."""
+    from .ops import pallas_gates as PG
+
+    fits = run.tile_bits == PG.local_qubits(route.n_exec, route.sublanes)
+    folds, missed = [], False
+    for k, hi in ((run.load_swap_k, run.load_swap_hi),
+                  (run.store_swap_k, run.store_swap_hi)):
+        hi = run.tile_bits if hi is None else hi
+        inside = k > 0 and hi + k <= route.n_exec
+        folds.append(inside and fits
+                     and run.tile_bits - PG.LANE_BITS - k >= 3)
+        missed |= inside and not folds[-1]
+    return route._replace(fold_load=folds[0], fold_store=folds[1],
+                          reason="swap_not_foldable" if missed else None)
+
+
+def _route(qureg, run: PallasRun) -> Route:
+    """Decide how ``run`` executes on ``qureg`` -- pure: no device work,
+    no telemetry, so the whole routing table is testable without a device
+    program behind it (tests/test_fusion.py).
 
     Multi-device registers run the kernel PER SHARD under shard_map when
-    every op is shard-executable (non-diagonal targets within the shard's
-    tile; roles on sharded qubits resolve against the shard index inside
-    the kernel -- see fused_local_run's shard_index). PRECISION=2
-    registers on the df route (fusion._df_route) run the double-float
-    4-plane kernels per shard, chunked at DF_MAX_OPS; under the explicit
-    distributed scheduler the per-shard df runs are joined by the
-    scheduler's COUNTED grouped permute collectives
-    (_sched_df_pallas_run). Otherwise (f32 under the explicit scheduler,
-    non-canonical sharding, or a target the shard can't pair) ops replay
-    through the sharding-aware engine gate-by-gate, with the reason
-    counted in engine_fallback_total.
-
-    Frame swaps annotated on the run (load/store_swap_k) execute folded
-    into the kernel's DMA when the executing register's tile geometry
-    matches the plan -- single-device, or per-shard when the swapped
-    block is SHARD-LOCAL (round 7); every other case (collective
-    relabelings reaching sharded bits, geometry mismatches -- the latter
-    counted as swap_not_foldable) gets an explicit swap_bit_blocks pass
-    before/after -- identical semantics.
-    """
-    from .ops import pallas_gates as PG
-    from .ops.pallas_gates import fused_local_run, swap_bit_blocks
-    from .parallel import scheduler as _dist
-    from .resilience import guard as _guard
-
+    every op is shard-executable (:func:`_shard_route`). PRECISION=2
+    registers on the df route (:func:`_df_route`) run the double-float
+    4-plane kernels; under the explicit distributed scheduler the
+    per-shard df runs are joined by the scheduler's COUNTED grouped
+    permute collectives (``sched_df``). Otherwise (f32 under the explicit
+    scheduler, non-canonical sharding, a target the shard can't pair, an
+    f64 register no df kernel takes) the run goes gate by gate, with the
+    reason."""
     import jax
 
-    nsv = qureg.num_qubits_in_state_vec
+    from .ops import pallas_gates as PG
+    from .parallel import scheduler as _dist
 
-    def explicit_swap(k, hi):
-        lo2 = tile_bits if hi is None else hi
-        _count_frame_swap(qureg, lo2, k)
-        qureg.put(swap_bit_blocks(qureg.amps, n=nsv, lo1=tile_bits - k,
-                                  lo2=lo2, k=k))
-
-    def pre_swap():
-        if load_swap_k:
-            explicit_swap(load_swap_k, load_swap_hi)
-
-    def post_swap():
-        if store_swap_k:
-            explicit_swap(store_swap_k, store_swap_hi)
-
-    amps = qureg.amps
     sched = _dist.active()
-
-    # --- explicit distributed scheduler x double-float register: the
-    # per-shard df fast path, frame relabelings riding the scheduler's
-    # counted grouped collectives (ISSUE 3 tentpole) ---
+    df = _df_route(qureg.dtype)
     if (sched is not None and sched.mesh is not None
-            and sched.mesh.size > 1 and _df_route(qureg.dtype)):
-        # the whole sched-df route is idempotent until its final put
-        # (planes re-split from qureg.amps per invocation), so the guard
-        # may retry it wholesale; injected compile faults degrade to the
-        # engine replay below (reason=fault_degraded)
-        res = _guard.pallas_dispatch(
-            lambda: _sched_df_pallas_run(
-                qureg, ops, sched, tile_bits, load_swap_k, store_swap_k,
-                load_swap_hi, store_swap_hi, ring_depth, comm_pipeline,
-                comm_pipeline_dcn),
-            degrade=lambda: None)
-        if res is not _guard.DEGRADED and res:
-            return
-        # not shard-executable at the df tile geometry (reason counted
-        # inside) or fault-degraded: sharding-aware engine replay,
-        # explicit swap passes
-        pre_swap()
-        _apply_ops_via_engine(qureg, ops)
-        post_swap()
-        return
-
+            and sched.mesh.size > 1 and df):
+        return _shard_route(qureg, run, sched.mesh, "sched_df")
     mesh = active_pallas_mesh()
-    if (mesh is not None and mesh.size > 1 and sched is None
-            and isinstance(amps, jax.core.Tracer)):
+    if (sched is None and mesh is not None and mesh.size > 1
+            and isinstance(qureg.amps, jax.core.Tracer)):
         # inside a jit trace the tracer hides its sharding; use the ambient
         # mesh, which Circuit.run derived from the register actually being
         # replayed (so it always matches the traced input's sharding)
-        if _dispatch_pallas_sharded(qureg, ops, mesh, tile_bits,
-                                    load_swap_k, store_swap_k,
-                                    load_swap_hi, store_swap_hi,
-                                    ring_depth, pre_swap, post_swap):
-            return
-        if load_swap_k:  # swap already applied; replay ops via the engine
-            _apply_ops_via_engine(qureg, ops)
-            post_swap()
-            return
+        return _shard_route(qureg, run, mesh, "sharded")
     sharding = getattr(qureg.amps, "sharding", None)
     if sharding is not None and len(sharding.device_set) > 1:
-        if sched is None:
-            mesh2 = _canonical_amps_mesh(qureg)
-            if mesh2 is not None:
-                if _dispatch_pallas_sharded(qureg, ops, mesh2, tile_bits,
-                                            load_swap_k, store_swap_k,
-                                            load_swap_hi, store_swap_hi,
-                                            ring_depth, pre_swap, post_swap):
-                    return
-            else:
-                telemetry.inc("engine_fallback_total",
-                              reason="shard_map_unsupported")
-                pre_swap()
-        else:
-            telemetry.inc("engine_fallback_total",
-                          reason="explicit_scheduler")
-            pre_swap()
-        _apply_ops_via_engine(qureg, ops)
-        post_swap()
-        return
-    if _df_route(qureg.dtype) or not _mosaic_supports(qureg.dtype):
-        if ((mesh is None or mesh.size == 1)
-                and np.dtype(qureg.dtype) == np.dtype("float64")
-                and (1 << nsv) >= 2 * PG._LANES):
-            # f64 on the TPU backend, single device: the double-float
-            # fast path (round 5; VERDICT r4 missing #2). The f64 state
-            # splits exactly into paired-f32 (hi, lo) planes and the run
-            # executes as error-free-transform VPU arithmetic inside the
-            # SAME fused single-pass kernel -- the PRECISION=2 analogue
-            # of the f32 path's bf16x3 zone dots (ops/pallas_df).
-            from .ops.pallas_df import (DF_MAX_OPS, DF_SUBLANES, df_join,
-                                        df_split)
+        if sched is not None:
+            return Route("gatewise", reason="explicit_scheduler")
+        own = _canonical_amps_mesh(qureg)
+        if own is None:
+            return Route("gatewise", reason="shard_map_unsupported")
+        return _shard_route(qureg, run, own, "sharded")
+    nsv = qureg.num_qubits_in_state_vec
+    if not df and _mosaic_supports(qureg.dtype):
+        return _folded(Route("local", n_exec=nsv,
+                             sublanes=PG._DEF_SUBLANES), run)
+    if ((mesh is None or mesh.size == 1)
+            and np.dtype(qureg.dtype) == np.dtype("float64")
+            and (1 << nsv) >= 2 * PG._LANES):
+        # f64 on the TPU backend, single device: the double-float fast
+        # path. The f64 state splits exactly into paired-f32 (hi, lo)
+        # planes and the run executes as error-free-transform VPU
+        # arithmetic inside the SAME fused single-pass kernel -- the
+        # PRECISION=2 analogue of the f32 path's bf16x3 zone dots
+        # (ops/pallas_df).
+        from .ops.pallas_df import DF_SUBLANES
 
-            lq_df = PG.local_qubits(nsv, DF_SUBLANES)
-            if any(q >= lq_df for op in ops
-                   for q in PG.op_dense_targets(op)):
-                # a plan built with non-DF tile geometry (e.g.
-                # Circuit.fused(dtype=np.float32) replayed on an f64
-                # register) can carry dense targets in [lq_df, plan
-                # tile_bits); the engine fallback -- not a runtime
-                # ValueError from fused_local_run -- is the contract for
-                # f64 registers (ADVICE round 5)
-                telemetry.inc("engine_fallback_total",
-                              reason="df_tile_mismatch")
-                pre_swap()
-                _apply_ops_via_engine(qureg, ops)
-                post_swap()
-                return
-            k_max = max(load_swap_k, store_swap_k)
-            foldable = (k_max > 0
-                        and tile_bits == PG.local_qubits(nsv, DF_SUBLANES)
-                        and tile_bits - PG.LANE_BITS - k_max >= 3)
-            if k_max and not foldable:
-                telemetry.inc("engine_fallback_total",
-                              reason="swap_not_foldable")
-                pre_swap()
-            # Mosaic compile time is superlinear in op count and df ops
-            # carry ~15x the arithmetic, so long runs split into short
-            # kernels chained on the (4, N) planes -- extra HBM passes
-            # are cheap next to the compile blowup (a 27-op df kernel
-            # exceeded 9 minutes; 8-op kernels compile in seconds)
-            chunks = ([ops[i:i + DF_MAX_OPS]
-                       for i in range(0, len(ops), DF_MAX_OPS)] or [ops])
-            if len(chunks) > 1:
-                # each extra chunk is one extra HBM pass the plan did not
-                # price in -- visible, not silent (ISSUE 1 tentpole)
-                telemetry.inc("engine_fallback_total", len(chunks) - 1,
-                              reason="df_max_ops_split")
-            last = len(chunks) - 1
-
-            def df_attempt():
-                planes = df_split(qureg.amps)
-                for ci, chunk in enumerate(chunks):
-                    planes = fused_local_run(
-                        planes, n=nsv, ops=chunk, sublanes=DF_SUBLANES,
-                        load_swap_k=load_swap_k if (foldable and ci == 0)
-                        else 0,
-                        store_swap_k=store_swap_k
-                        if (foldable and ci == last) else 0,
-                        load_swap_hi=load_swap_hi if (foldable and ci == 0)
-                        else None,
-                        store_swap_hi=store_swap_hi
-                        if (foldable and ci == last) else None,
-                        ring_depth=ring_depth)
-                return df_join(planes)
-
-            def df_degrade():
-                if foldable:
-                    pre_swap()
-                _apply_ops_via_engine(qureg, ops)
-                if foldable:
-                    post_swap()
-
-            out = _guard.pallas_dispatch(df_attempt, df_degrade)
-            if out is not _guard.DEGRADED:
-                qureg.put(out)
-            if k_max and not foldable:
-                post_swap()
-            return
-        # the genuinely unsupported f64 residue -- sub-tile registers, or
-        # sharded dispatch that already failed above -- keeps the counted
-        # engine fallback (sharded-df-CAPABLE runs no longer land here:
-        # they ride _dispatch_pallas_sharded / _sched_df_pallas_run)
-        telemetry.inc("engine_fallback_total", reason="f64_engine")
-        pre_swap()
-        _apply_ops_via_engine(qureg, ops)
-        post_swap()
-        return
-    # single device: fold the swaps into the kernel DMA when this register's
-    # tile geometry matches the plan's (s_low >= one sublane tile keeps the
-    # gathered chunks layout-free); otherwise run them as explicit passes
-    k_max = max(load_swap_k, store_swap_k)
-    foldable = (k_max > 0
-                and tile_bits == PG.local_qubits(nsv)
-                and tile_bits - PG.LANE_BITS - k_max >= 3)
-    if k_max and not foldable:
-        telemetry.inc("engine_fallback_total", reason="swap_not_foldable")
-        pre_swap()
-
-    def local_attempt():
-        return fused_local_run(
-            qureg.amps, n=nsv, ops=ops,
-            load_swap_k=load_swap_k if foldable else 0,
-            store_swap_k=store_swap_k if foldable else 0,
-            load_swap_hi=load_swap_hi if foldable else None,
-            store_swap_hi=store_swap_hi if foldable else None,
-            ring_depth=ring_depth)
-
-    def local_degrade():
-        if foldable:
-            pre_swap()
-        _apply_ops_via_engine(qureg, ops)
-        if foldable:
-            post_swap()
-
-    out = _guard.pallas_dispatch(local_attempt, local_degrade)
-    if out is not _guard.DEGRADED:
-        qureg.put(out)
-    if k_max and not foldable:
-        post_swap()
+        lq_df = PG.local_qubits(nsv, DF_SUBLANES)
+        if any(q >= lq_df for op in run.ops
+               for q in PG.op_dense_targets(op)):
+            # a plan built with non-DF tile geometry (e.g.
+            # Circuit.fused(dtype=np.float32) replayed on an f64
+            # register) can carry dense targets in [lq_df, plan
+            # tile_bits); the gate-by-gate exit -- not a runtime
+            # ValueError from fused_local_run -- is the contract for
+            # f64 registers (ADVICE round 5)
+            return Route("gatewise", reason="df_tile_mismatch")
+        return _folded(Route("df_local", n_exec=nsv, sublanes=DF_SUBLANES,
+                             df=True), run)
+    # the genuinely unsupported f64 residue: sub-tile registers
+    return Route("gatewise", reason="f64_engine")
 
 
 def _canonical_amps_mesh(qureg):
@@ -1770,10 +1635,11 @@ def _canonical_amps_mesh(qureg):
     return sharding.mesh
 
 
-def _sharded_run_plan(qureg, ops: tuple, mesh):
-    """Per-shard executability check: ((df, n_local, sublanes), None) when
-    every op of the run is executable against the shard-local tile, else
-    (None, fallback_reason).
+def _shard_route(qureg, run: PallasRun, mesh, kind: str) -> Route:
+    """The sharded arm of :func:`_route`: ``kind`` (``"sharded"``, or
+    ``"sched_df"`` under the explicit scheduler) when every op of the run
+    is executable against the shard-local tile, else the gate-by-gate exit
+    with its reason.
 
     Legality: amplitude sharding splits off the TOP qubits, so each shard
     is a contiguous (2, 2^n_local) sub-state on which in-tile targets pair
@@ -1784,52 +1650,145 @@ def _sharded_run_plan(qureg, ops: tuple, mesh):
     exchanges (QuEST_cpu_distributed.c:870-905). PRECISION=2 registers on
     the df route check against the DF tile geometry (DF_SUBLANES), and a
     plan built with non-DF geometry is the SHARDED df_tile_mismatch case
-    -- counted by the caller, never a runtime ValueError (the round-7
-    generalisation of the single-device guard)."""
+    -- never a runtime ValueError (the round-7 generalisation of the
+    single-device guard). Relabelings fold per shard when the block is
+    SHARD-LOCAL (:func:`_folded`); under the scheduler none folds: they
+    are its counted permutes on the planes."""
     from .environment import AMP_AXIS
     from .ops import pallas_gates as PG
 
     df = _df_route(qureg.dtype)
+    unsupported = Route("gatewise", reason=("f64_engine" if df
+                                            else "shard_map_unsupported"))
     if tuple(mesh.shape.keys()) != (AMP_AXIS,):
-        return None, ("f64_engine" if df else "shard_map_unsupported")
+        return unsupported
     ndev = mesh.shape[AMP_AXIS]
     if ndev & (ndev - 1):
-        return None, ("f64_engine" if df else "shard_map_unsupported")
-    nsv = qureg.num_qubits_in_state_vec
-    n_local = nsv - (ndev.bit_length() - 1)
+        return unsupported
+    n_local = qureg.num_qubits_in_state_vec - (ndev.bit_length() - 1)
     if df:
         # one lane tile per shard suffices for the gridless df kernel
         if (1 << n_local) < PG._LANES:
-            return None, "f64_engine"
+            return unsupported
         from .ops.pallas_df import DF_SUBLANES
         sublanes = DF_SUBLANES
     else:
         if not _mosaic_supports(qureg.dtype):
-            return None, "f64_engine"
+            return Route("gatewise", reason="f64_engine")
         if (1 << n_local) < 2 * PG._LANES:
-            return None, "shard_map_unsupported"
+            return unsupported
         sublanes = PG._DEF_SUBLANES
     lq = PG.local_qubits(n_local, sublanes)
-    for op in ops:
-        if any(q >= lq for q in PG.op_dense_targets(op)):
-            return None, ("df_tile_mismatch" if df
-                          else "shard_map_unsupported")
-    return (df, n_local, sublanes), None
+    if any(q >= lq for op in run.ops for q in PG.op_dense_targets(op)):
+        return Route("gatewise", reason=("df_tile_mismatch" if df
+                                         else "shard_map_unsupported"))
+    route = Route(kind, mesh=mesh, n_exec=n_local, sublanes=sublanes, df=df)
+    return route if kind == "sched_df" else _folded(route, run)
 
 
-def _df_shard_chunks(ops: tuple, n_local: int, sublanes: int,
-                     lk: int = 0, sk: int = 0, lh=None, sh=None,
-                     ring_depth=None):
-    """Per-shard double-float executor factory: returns
-    ``run(planes, shard_idx) -> planes`` applying the op run to one
-    shard's (4, C) df planes, chunked at DF_MAX_OPS (Mosaic compile time
-    is superlinear in op count and df ops carry ~15x the arithmetic);
-    folded frame swaps ride the first/last chunk's DMA."""
+def _apply_pallas_run(qureg, run: PallasRun) -> None:
+    """Tape-entry wrapper for a PallasRun: ask :func:`_route`, then run
+    what it decided. Ops are RAW kernel ops over the full flattened state:
+    density plans carry explicit conj-shadow twins (fusion._shadow_pop),
+    so no path here re-derives shadows.
+
+    A relabeling the route did not fold runs as an explicit
+    swap_bit_blocks pass before / after the kernel -- identical
+    semantics. The kernel attempt goes through the resilience guard:
+    injected transients retry it (every attempt re-reads ``qureg.amps``
+    and is idempotent until its result is put), and a compile fault or an
+    exhausted budget degrades to the gate-by-gate exit
+    (``fault_degraded``, counted by the guard), the swaps that would have
+    ridden the kernel explicit beside it."""
+    from .resilience import guard as _guard
+
+    route = _route(qureg, run)
+    if route.kind == "gatewise":
+        _gatewise(qureg, run, route.reason)
+        return
+    if route.reason is not None:
+        telemetry.inc("engine_fallback_total", reason=route.reason)
+    sched_df = route.kind == "sched_df"
+    # under the scheduler both relabelings ride inside the attempt, on the
+    # 4-plane state; elsewhere those the kernel's DMA folds
+    inside = (True, True) if sched_df else (route.fold_load, route.fold_store)
+    attempt = partial(_sched_df_run if sched_df else _kernel_run,
+                      qureg, run, route)
+    _explicit_swaps(qureg, run, load=not inside[0])
+    out = _guard.pallas_dispatch(
+        attempt, lambda: _gatewise(qureg, run, None, *inside))
+    if out is not _guard.DEGRADED:
+        qureg.put(out)
+    _explicit_swaps(qureg, run, store=not inside[1])
+
+
+def _explicit_swaps(qureg, run: PallasRun, load: bool = False,
+                    store: bool = False) -> None:
+    """The run's load and/or store relabeling as an explicit, counted
+    swap_bit_blocks pass (nothing where the run has none): what a route
+    that did not fold it runs in its place."""
+    from .ops.pallas_gates import swap_bit_blocks
+
+    for wanted, k, hi in ((load, run.load_swap_k, run.load_swap_hi),
+                          (store, run.store_swap_k, run.store_swap_hi)):
+        if wanted and k:
+            lo2 = run.tile_bits if hi is None else hi
+            _count_frame_swap(qureg, lo2, k)
+            qureg.put(swap_bit_blocks(
+                qureg.amps, n=qureg.num_qubits_in_state_vec,
+                lo1=run.tile_bits - k, lo2=lo2, k=k))
+
+
+def _gatewise(qureg, run: PallasRun, reason: str | None,
+              load: bool = True, store: bool = True) -> None:
+    """The ONE exit from the kernel routes: count ``reason``
+    (``engine_fallback_total``; None where the guard already counted its
+    ``fault_degraded``) and replay the run's ops through the
+    sharding-aware gate-by-gate appliers, its relabelings explicit passes
+    around them (``load`` / ``store`` False: that one has already run, or
+    will, outside)."""
+    if reason is not None:
+        telemetry.inc("engine_fallback_total", reason=reason)
+    _explicit_swaps(qureg, run, load=load)
+    _apply_ops_via_engine(qureg, run.ops)
+    _explicit_swaps(qureg, run, store=store)
+
+
+def _kernel_fn(run: PallasRun, route: Route, on_planes: bool = False):
+    """The fused kernel as ``x -> x`` over the array ``route`` executes on
+    (the register, or inside shard_map one shard of it, where op roles on
+    sharded qubits resolve against the shard index), folded relabelings
+    riding its DMA. A df route splits to the 4-plane layout, runs the df
+    kernels and joins back (split / join are exact and shard-local);
+    ``on_planes`` leaves both to the caller."""
+    import jax
+
+    from .environment import AMP_AXIS
     from .ops import pallas_gates as PG
-    from .ops.pallas_df import DF_MAX_OPS
 
+    def shard_index():
+        return None if route.mesh is None else jax.lax.axis_index(AMP_AXIS)
+
+    lk, lh = (run.load_swap_k, run.load_swap_hi) if route.fold_load \
+        else (0, None)
+    sk, sh = (run.store_swap_k, run.store_swap_hi) if route.fold_store \
+        else (0, None)
+    if not route.df:
+        return lambda x: PG.fused_local_run(
+            x, n=route.n_exec, ops=run.ops, sublanes=route.sublanes,
+            shard_index=shard_index(), load_swap_k=lk, load_swap_hi=lh,
+            store_swap_k=sk, store_swap_hi=sh, ring_depth=run.ring_depth)
+
+    from .ops.pallas_df import DF_MAX_OPS, df_join, df_split
+
+    # Mosaic compile time is superlinear in op count and df ops carry
+    # ~15x the arithmetic, so long runs split into short kernels chained
+    # on the (4, N) planes -- extra HBM passes are cheap next to the
+    # compile blowup (a 27-op df kernel exceeded 9 minutes; 8-op kernels
+    # compile in seconds); folded swaps ride the first / last chunk's DMA
+    ops = run.ops
     chunks = ([ops[i:i + DF_MAX_OPS]
-               for i in range(0, len(ops), DF_MAX_OPS)] or [tuple(ops)])
+               for i in range(0, len(ops), DF_MAX_OPS)] or [ops])
     if len(chunks) > 1:
         # each extra chunk is one extra HBM pass the plan did not price
         # in -- visible, not silent (ISSUE 1 tentpole)
@@ -1837,196 +1796,76 @@ def _df_shard_chunks(ops: tuple, n_local: int, sublanes: int,
                       reason="df_max_ops_split")
     last = len(chunks) - 1
 
-    def run(planes, shard_idx):
+    def planes_fn(planes):
+        idx = shard_index()
         for ci, chunk in enumerate(chunks):
             planes = PG.fused_local_run(
-                planes, n=n_local, ops=chunk, sublanes=sublanes,
-                shard_index=shard_idx,
+                planes, n=route.n_exec, ops=chunk, sublanes=route.sublanes,
+                shard_index=idx,
                 load_swap_k=lk if ci == 0 else 0,
                 load_swap_hi=lh if ci == 0 else None,
                 store_swap_k=sk if ci == last else 0,
                 store_swap_hi=sh if ci == last else None,
-                ring_depth=ring_depth)
+                ring_depth=run.ring_depth)
         return planes
 
-    return run
+    if on_planes:
+        return planes_fn
+    return lambda x: df_join(planes_fn(df_split(x)))
 
 
-def _exec_pallas_sharded(amps, mesh, ops: tuple, df: bool, n_local: int,
-                         sublanes: int, lk: int = 0, sk: int = 0,
-                         lh=None, sh=None, ring_depth=None):
-    """shard_map the fused kernel over ``mesh`` (caller has established
-    legality via _sharded_run_plan). f64-df shards split to the 4-plane
-    layout, run the df kernels (DF_MAX_OPS-chunked), and join back --
-    split/join are exact and shard-local. Folded frame swaps (lk/sk,
-    SHARD-LOCAL blocks only) ride the kernel DMA."""
-    import jax
+def _per_shard(fn, mesh):
+    """``fn`` over each device's shard of a (planes, amplitudes) array."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from jax import shard_map
     from .environment import AMP_AXIS
-    from .ops import pallas_gates as PG
-
-    telemetry.inc("fusion_sharded_runs_total")
-    if df:
-        from .ops.pallas_df import df_join, df_split
-
-        run = _df_shard_chunks(ops, n_local, sublanes, lk, sk, lh, sh,
-                               ring_depth)
-
-        def body(x):
-            return df_join(run(df_split(x), jax.lax.axis_index(AMP_AXIS)))
-    else:
-        def body(x):
-            hi = jax.lax.axis_index(AMP_AXIS)
-            return PG.fused_local_run(
-                x, n=n_local, ops=ops, sublanes=sublanes, shard_index=hi,
-                load_swap_k=lk, load_swap_hi=lh, store_swap_k=sk,
-                store_swap_hi=sh, ring_depth=ring_depth)
 
     # check_vma=False: pallas_call's out_shape carries no varying-mesh-axes
     # annotation, which the checker (on by default) rejects
-    fn = shard_map(body, mesh=mesh, in_specs=P(None, AMP_AXIS),
-                   out_specs=P(None, AMP_AXIS), check_vma=False)
-    return fn(amps)
+    return shard_map(fn, mesh=mesh, in_specs=P(None, AMP_AXIS),
+                     out_specs=P(None, AMP_AXIS), check_vma=False)
 
 
-def _dispatch_pallas_sharded(qureg, ops: tuple, mesh, tile_bits: int,
-                             lk: int, sk: int, lh, sh, ring_depth,
-                             pre_swap, post_swap) -> bool:
-    """Route one PallasRun per shard over ``mesh`` (f32 native; f64 via
-    the double-float planes when the df route is on), folding SHARD-LOCAL
-    frame swaps into the per-shard kernel DMA and running the rest --
-    collective relabelings reaching sharded bits (the designed all-to-all
-    path), or shard-local swaps whose tile geometry mismatches the plan
-    (counted swap_not_foldable) -- as explicit transpose passes.
-
-    Returns True when handled end to end. Returns False with the fallback
-    reason counted and the load swap already applied explicitly (a no-op
-    when lk == 0), so the caller can replay the ops via the engine."""
-    from .ops import pallas_gates as PG
-
-    plan, reason = _sharded_run_plan(qureg, ops, mesh)
-    if plan is None:
-        telemetry.inc("engine_fallback_total", reason=reason)
-        pre_swap()
-        return False
-    df, n_local, sublanes = plan
-
-    def foldable(k, hi):
-        if not k:
-            return False
-        hi_eff = tile_bits if hi is None else hi
-        if hi_eff + k > n_local:
-            return False  # reaches sharded bits: the collective transpose
-        ok = (tile_bits == PG.local_qubits(n_local, sublanes)
-              and tile_bits - PG.LANE_BITS - k >= 3)
-        if not ok:
-            telemetry.inc("engine_fallback_total",
-                          reason="swap_not_foldable")
-        return ok
-
-    fold_l = foldable(lk, lh)
-    fold_s = foldable(sk, sh)
-    if lk and not fold_l:
-        pre_swap()
-
-    from .resilience import guard as _guard
-
-    def attempt():
-        return _exec_pallas_sharded(
-            qureg.amps, mesh, ops, df, n_local, sublanes,
-            lk=lk if fold_l else 0, lh=lh if fold_l else None,
-            sk=sk if fold_s else 0, sh=sh if fold_s else None,
-            ring_depth=ring_depth)
-
-    def degrade():
-        # the kernel route stays down (injected compile fault / exhausted
-        # transients): sharding-aware engine replay; swaps that would have
-        # folded into the kernel DMA run as explicit passes instead
-        if fold_l:
-            pre_swap()
-        _apply_ops_via_engine(qureg, ops)
-        if fold_s:
-            post_swap()
-
-    new = _guard.pallas_dispatch(attempt, degrade)
-    if new is not _guard.DEGRADED:
-        qureg.put(new)
-    if sk and not fold_s:
-        post_swap()
-    return True
+def _kernel_run(qureg, run: PallasRun, route: Route):
+    """Executor of the ``local``, ``df_local`` and ``sharded`` routes: the
+    register's new amplitudes."""
+    fn = _kernel_fn(run, route)
+    if route.mesh is None:
+        return fn(qureg.amps)
+    telemetry.inc("fusion_sharded_runs_total")
+    return _per_shard(fn, route.mesh)(qureg.amps)
 
 
-def _sched_df_pallas_run(qureg, ops: tuple, sched, tile_bits: int,
-                         lk: int, sk: int, lh, sh, ring_depth,
-                         comm_pipeline=None,
-                         comm_pipeline_dcn=None) -> bool:
-    """Explicit-scheduler route for a PallasRun on a sharded PRECISION=2
-    register (the ISSUE 3 tentpole): df-split ONCE, run the fused df
-    kernels per shard over the scheduler's mesh, and execute the run's
-    frame relabelings through the scheduler's COUNTED grouped permute
-    collective ON the 4-plane state (exchange.dist_permute_bits carries
-    all four planes natively; chunk-units price at the df 2x scale --
-    scheduler.DistributedScheduler.apply_frame_permute). Returns False
-    with the fallback reason counted when the run is not shard-executable
-    at the df tile geometry."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from jax import shard_map
-    from .environment import AMP_AXIS
+def _sched_df_run(qureg, run: PallasRun, route: Route):
+    """Executor of the ``sched_df`` route, a PallasRun on a sharded
+    PRECISION=2 register under the explicit scheduler (the ISSUE 3
+    tentpole): df-split ONCE, run the fused df kernels per shard over the
+    scheduler's mesh, and execute the run's frame relabelings through the
+    scheduler's COUNTED grouped permute collective ON the 4-plane state
+    (exchange.dist_permute_bits carries all four planes natively;
+    chunk-units price at the df 2x scale --
+    scheduler.DistributedScheduler.apply_frame_permute)."""
     from .ops.pallas_df import df_join, df_split
+    from .parallel import scheduler as _dist
 
-    plan, reason = _sharded_run_plan(qureg, ops, sched.mesh)
-    if plan is None:
-        telemetry.inc("engine_fallback_total", reason=reason)
-        return False
-    df, n_local, sublanes = plan
-    nsv = qureg.num_qubits_in_state_vec
-    planes = df_split(qureg.amps)
-    if lk:
-        _count_frame_swap(qureg, tile_bits if lh is None else lh, lk)
-        planes = sched.apply_frame_permute(
-            planes, n=nsv, lo1=tile_bits - lk,
-            lo2=tile_bits if lh is None else lh, k=lk,
-            pipeline=comm_pipeline, pipeline_dcn=comm_pipeline_dcn)
-    run = _df_shard_chunks(ops, n_local, sublanes, ring_depth=ring_depth)
+    sched = _dist.active()
 
-    def body(x):
-        return run(x, jax.lax.axis_index(AMP_AXIS))
+    def permute(planes, k, hi):
+        if not k:
+            return planes
+        lo2 = run.tile_bits if hi is None else hi
+        _count_frame_swap(qureg, lo2, k)
+        return sched.apply_frame_permute(
+            planes, n=qureg.num_qubits_in_state_vec, lo1=run.tile_bits - k,
+            lo2=lo2, k=k, pipeline=run.comm_pipeline,
+            pipeline_dcn=run.comm_pipeline_dcn)
 
-    planes = shard_map(body, mesh=sched.mesh, in_specs=P(None, AMP_AXIS),
-                       out_specs=P(None, AMP_AXIS), check_vma=False)(planes)
-    if sk:
-        _count_frame_swap(qureg, tile_bits if sh is None else sh, sk)
-        planes = sched.apply_frame_permute(
-            planes, n=nsv, lo1=tile_bits - sk,
-            lo2=tile_bits if sh is None else sh, k=sk,
-            pipeline=comm_pipeline, pipeline_dcn=comm_pipeline_dcn)
-    qureg.put(df_join(planes))
-    return True
-
-
-def _shard_map_pallas_run(qureg, ops: tuple):
-    """Eager-path entry: run a PallasRun per-shard over the mesh of the
-    register's own (concrete) sharding, or None if the layout or the run
-    isn't shard-executable."""
-    mesh = _canonical_amps_mesh(qureg)
-    if mesh is None:
-        return None
-    return _run_pallas_sharded(qureg, ops, mesh)
-
-
-def _run_pallas_sharded(qureg, ops: tuple, mesh):
-    """shard_map the fused kernel over ``mesh`` if every op is executable
-    against the shard-local tile; None otherwise (see _sharded_run_plan
-    for the legality rules and _exec_pallas_sharded for execution)."""
-    plan, _reason = _sharded_run_plan(qureg, ops, mesh)
-    if plan is None:
-        return None
-    df, n_local, sublanes = plan
-    return _exec_pallas_sharded(qureg.amps, mesh, ops, df, n_local, sublanes)
+    planes = permute(df_split(qureg.amps), run.load_swap_k,
+                     run.load_swap_hi)
+    planes = _per_shard(_kernel_fn(run, route, on_planes=True),
+                        route.mesh)(planes)
+    return df_join(permute(planes, run.store_swap_k, run.store_swap_hi))
 
 
 def _apply_ops_via_engine(qureg, ops: tuple) -> None:
@@ -2433,11 +2272,7 @@ def _lift_positions(args) -> dict:
 _apply_deferred_block._lift_positions = _lift_positions
 
 
-def _apply_frame_swap(qureg, tile_bits: int, k: int,
-                      hi: int | None = None,
-                      comm_pipeline: int | None = None,
-                      seg: int | None = None,
-                      comm_pipeline_dcn: int | None = None) -> None:
+def _apply_frame_swap(qureg, swap: FrameSwap) -> None:
     """Tape-entry wrapper for FrameSwap: one relabeling transpose. Works on
     every backend (plain XLA); on a sharded register GSPMD lowers it to the
     all-to-all the relabeling implies (shard-local when [hi, hi+k) avoids
@@ -2448,21 +2283,24 @@ def _apply_frame_swap(qureg, tile_bits: int, k: int,
     from .ops.pallas_gates import swap_bit_blocks
     from .parallel import scheduler as _dist
 
-    lo2 = tile_bits if hi is None else hi
+    tile_bits, k = swap.tile_bits, swap.k
+    lo2 = tile_bits if swap.hi is None else swap.hi
     _count_frame_swap(qureg, lo2, k)
     nsv = qureg.num_qubits_in_state_vec
     sched = _dist.active()
     if sched is not None and sched.mesh is not None and sched.mesh.size > 1:
         qureg.put(sched.apply_frame_permute(
             qureg.amps, n=nsv, lo1=tile_bits - k, lo2=lo2, k=k,
-            pipeline=comm_pipeline, pipeline_dcn=comm_pipeline_dcn))
+            pipeline=swap.comm_pipeline,
+            pipeline_dcn=swap.comm_pipeline_dcn))
         return
     qureg.put(swap_bit_blocks(qureg.amps, n=nsv, lo1=tile_bits - k,
                               lo2=lo2, k=k))
 
 
 def as_tape(p: FusePlan) -> list:
-    """Lower a FusePlan back to Circuit tape entries (fn, args, kwargs)."""
+    """Lower a FusePlan back to Circuit tape entries (fn, args, kwargs).
+    A PallasRun / FrameSwap is its entry's one argument."""
     from . import gates as G
 
     entries = []
@@ -2474,17 +2312,9 @@ def as_tape(p: FusePlan) -> list:
         elif isinstance(item, FusedBlock):
             entries.append((_apply_dense_block, (item.matrix, item.qubits), {}))
         elif isinstance(item, PallasRun):
-            entries.append((_apply_pallas_run,
-                            (item.ops, item.tile_bits, item.load_swap_k,
-                             item.store_swap_k, item.load_swap_hi,
-                             item.store_swap_hi, item.ring_depth,
-                             item.comm_pipeline, item.seg,
-                             item.comm_pipeline_dcn), {}))
+            entries.append((_apply_pallas_run, (item,), {}))
         elif isinstance(item, FrameSwap):
-            entries.append((_apply_frame_swap,
-                            (item.tile_bits, item.k, item.hi,
-                             item.comm_pipeline, item.seg,
-                             item.comm_pipeline_dcn), {}))
+            entries.append((_apply_frame_swap, (item,), {}))
         else:
             entries.append(item)
     return entries

@@ -115,10 +115,7 @@ def segment_plan(tape: list, nsv: int, every_n_items: int = 1) -> list:
     lands mid-permutation). Boundaries come from
     :func:`quest_tpu.segments.identity_boundaries` -- the same seams the
     round-13 segment programs dispatch over, so a checkpoint cadence and
-    a segment-program chain always agree on where the frame is identity.
-    (The pre-round-13 replay here unpacked FrameSwap args as an exact
-    3-tuple and broke on comm_pipeline-stamped tapes; the shared
-    decoder's slice unpack is codec-tolerant.)"""
+    a segment-program chain always agree on where the frame is identity."""
     from ..segments import identity_boundaries
     if every_n_items < 1:
         raise _qt304(f"every_n_items must be >= 1, got {every_n_items}")
@@ -192,8 +189,7 @@ def _run_segment(circuit: Circuit, qureg: Qureg, lo: int,
     # segment-program dispatch, cached on the PARENT circuit's stable
     # token (the pre-round-13 path built a throwaway Circuit per segment
     # whose fresh cache token forced a full recompile of every segment
-    # on every run AND every healing replay). QUEST_SEGMENT_DISPATCH=0
-    # falls back to the per-item interpreter inside run_slice.
+    # on every run AND every healing replay).
     from .. import segments
 
     with telemetry.span("segmented.segment", lo=lo, hi=hi):

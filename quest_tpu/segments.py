@@ -11,110 +11,49 @@ by plancheck QT102) -- into ONE jitted program dispatched once: the
 command-buffer/graph-launch idea from the cuQuantum lineage (PAPERS.md)
 re-targeted at XLA's one-traced-program-per-structure executable model.
 
-Three execution surfaces ride it:
+Two execution surfaces ride it:
 
 - :func:`run_slice` -- execute ``tape[lo:hi]`` on a register as one
-  segment program (or item-by-item when segment dispatch is off);
-  ``resilience.segmented`` uses it between checkpoints, with a stable
-  cache key so resumed/healed segments never retrace.
+  segment program; ``resilience.segmented`` uses it between checkpoints,
+  with a stable cache key so resumed/healed segments never retrace.
 - :func:`chain_executable` (behind ``Circuit.compiled_segments``) -- the
   tape as a chain of frame-identity-aligned segment programs, each at
-  most ``max_items`` tape entries: the compile-boundedness of
-  ``compiled_blocks`` with checkpointable seams and a dispatch count
-  equal to the SEGMENT count, not the gate count.
-- the per-item interpreter (:func:`run_slice` with the knob off) -- the
-  fallback lattice rung: one device dispatch per tape entry, the
-  pre-round-13 behavior, kept verbatim for triage and degraded modes.
+  most ``max_items`` tape entries: a bounded compile size per program,
+  checkpointable seams, and a dispatch count equal to the SEGMENT count,
+  not the gate count.
 
 Numeric contract (tests/test_segments.py pins all of it): a fixed
-segmentation is run-to-run deterministic (bit-identical) on every leg;
-the whole-tape segment program is bit-identical to ``compiled()``; and
-on a single device the native-dtype per-item chain
-(``compiled_segments(max_items=1)``) reproduces item-by-item
-interpretation bit-for-bit. ACROSS program granularities XLA-CPU
-duplicates producer expressions and contracts fma differently per
-compiled program (the documented tests/test_sharded_df.py caveat), so
-item-route vs multi-item-program comparisons -- and anything on the df
-route or a CPU mesh, where even single items embed differently -- agree
-to ~1 ulp, not bit-exactly. On TPU the Mosaic kernel is opaque to XLA,
-so recontraction cannot reach inside it and the routes coincide.
+segmentation is run-to-run deterministic (bit-identical) on every leg,
+and the whole-tape segment program is bit-identical to ``compiled()``.
+ACROSS program granularities XLA-CPU duplicates producer expressions and
+contracts fma differently per compiled program (the documented
+tests/test_sharded_df.py caveat), so a chain of several programs and the
+whole-tape program -- and anything on the df route or a CPU mesh, where
+even single items embed differently -- agree to ~1 ulp, not bit-exactly.
+On TPU the Mosaic kernel is opaque to XLA, so recontraction cannot reach
+inside it and the granularities coincide.
 
 Every device program launch counts ``device_dispatch_total{route}``
 host-side (telemetry counters inside jit would count traces, not
-executions): ``route="segment"`` per segment program, ``route="item"``
-per eagerly interpreted tape entry, ``route="circuit"`` per whole-tape
-``Circuit.run`` dispatch, ``route="request"`` per whole-request program
+executions): ``route="segment"`` per segment program,
+``route="circuit"`` per whole-tape ``Circuit.run`` dispatch,
+``route="request"`` per whole-request program
 (:func:`request_executable` -- round 18: every segment plus the final
 reduction composed into ONE dispatched program, the
 ``dispatches_per_circuit == 1`` floor), ``route="engine_vmap"`` /
-``"engine_param"`` at the serving engine's two dispatch sites. docs/observability.md has
-the full table; ``bench.py --config dispatch`` measures the A/B.
-
-``QUEST_SEGMENT_DISPATCH`` (default 1 = on; 0 restores item-by-item
-interpretation) gates the lowering, parsed warn-once via
-``analysis.diagnostics.parse_env_int`` (QT306). :func:`force_route`
-overrides it per-thread for A/B harnesses.
+``"engine_param"`` at the serving engine's two dispatch sites.
+docs/observability.md has the full table.
 """
 
 from __future__ import annotations
-
-import contextlib
-import threading
 
 from . import telemetry
 
 __all__ = [
     "identity_boundaries", "segment_cuts", "stamp_plan",
-    "segment_dispatch_default", "segment_dispatch_enabled", "force_route",
     "slice_executable", "run_slice", "chain_executable",
     "request_executable",
 ]
-
-_SEG_ENV = "QUEST_SEGMENT_DISPATCH"
-_DEF_SEGMENT_DISPATCH = 1
-#: raw env strings already warned about (diagnostics.parse_env_int
-#: warn-once contract; tests monkeypatch a fresh set)
-_SEG_ENV_WARNED: set = set()
-
-_ROUTE = threading.local()
-
-
-def segment_dispatch_default() -> int:
-    """The ``QUEST_SEGMENT_DISPATCH`` env value (default 1 = segment
-    programs on, 0 = per-item interpretation), parsed warn-once: a
-    malformed or negative value emits QT306 and falls back to the
-    default."""
-    from .analysis.diagnostics import parse_env_int
-    return parse_env_int(_SEG_ENV, _DEF_SEGMENT_DISPATCH, minimum=0,
-                         code="QT306", warned=_SEG_ENV_WARNED,
-                         noun="segment-dispatch mode")
-
-
-def segment_dispatch_enabled() -> bool:
-    """Whether tape slices lower to single-dispatch segment programs:
-    a :func:`force_route` override if one is active on this thread,
-    else the ``QUEST_SEGMENT_DISPATCH`` env default."""
-    forced = getattr(_ROUTE, "route", None)
-    if forced is not None:
-        return forced == "segment"
-    return segment_dispatch_default() != 0
-
-
-@contextlib.contextmanager
-def force_route(route: str | None):
-    """Pin the execution route for this thread: ``"segment"`` (one
-    program per slice), ``"item"`` (per-entry interpretation), or None
-    (defer to the env knob). The A/B harnesses (bench dispatch_20q,
-    kernelprobe dispatch_sweep) use this to run both legs in one
-    process regardless of the ambient ``QUEST_SEGMENT_DISPATCH``."""
-    if route not in (None, "segment", "item"):
-        raise ValueError(f"unknown dispatch route {route!r}")
-    prev = getattr(_ROUTE, "route", None)
-    _ROUTE.route = route
-    try:
-        yield
-    finally:
-        _ROUTE.route = prev
 
 
 # -- frame-identity boundaries -----------------------------------------------
@@ -131,33 +70,37 @@ def _swap_blocks(perm: list, tile_bits: int, k: int, hi) -> None:
         perm[lo + i], perm[hi + i] = perm[hi + i], perm[lo + i]
 
 
+def _replay_frame(perm: list, item) -> None:
+    """Apply a plan item's frame relabelings (a PallasRun's load / store
+    swaps, a standalone FrameSwap) to ``perm``; every other item leaves
+    the frame untouched."""
+    from . import fusion
+    if isinstance(item, fusion.PallasRun):
+        if item.load_swap_k:
+            _swap_blocks(perm, item.tile_bits, item.load_swap_k,
+                         item.load_swap_hi)
+        if item.store_swap_k:
+            _swap_blocks(perm, item.tile_bits, item.store_swap_k,
+                         item.store_swap_hi)
+    elif isinstance(item, fusion.FrameSwap):
+        _swap_blocks(perm, item.tile_bits, item.k, item.hi)
+
+
 def identity_boundaries(tape, nsv: int) -> list:
     """Indices ``i`` where the two-frame permutation is identity after
     ``tape[:i]`` -- the legal segment seams. Always includes 0; includes
     ``len(tape)`` iff the tape ends at identity (every fused plan does,
-    by the QT102 contract). Replays the frame symbolically from the
-    PallasRun load/store swaps and standalone FrameSwaps; all other
-    entries leave the frame untouched.
+    by the QT102 contract). Replays the frame symbolically over the
+    tape's decoded items (``fusion.plan_from_tape``, one item an entry).
 
     This is the ONE boundary computation -- ``resilience.segmented``
-    delegates here (its pre-round-13 replay unpacked FrameSwap args as
-    an exact 3-tuple and broke on the 4-arg comm_pipeline-stamped
-    entries of PR 8; the codec-tolerant slice unpack below is the
-    regression-tested fix)."""
+    delegates here."""
+    from . import fusion
     perm = list(range(nsv))
     ident = list(range(nsv))
     bounds = [0]
-    for i, (f, a, _kw) in enumerate(tape):
-        name = getattr(f, "__name__", "")
-        if name == "_apply_pallas_run":
-            _ops, tb, lk, sk, lh, sh = a[:6]
-            if lk:
-                _swap_blocks(perm, tb, lk, lh)
-            if sk:
-                _swap_blocks(perm, tb, sk, sh)
-        elif name == "_apply_frame_swap":
-            tb, k, hi = a[:3]
-            _swap_blocks(perm, tb, k, hi)
+    for i, item in enumerate(fusion.plan_from_tape(tape).items):
+        _replay_frame(perm, item)
         if perm == ident:
             bounds.append(i + 1)
     return bounds
@@ -220,27 +163,22 @@ def segment_cuts(tape, nsv: int, max_items: int | None = None) -> list:
 
 def stamp_plan(plan, nsv: int) -> int:
     """Stamp every frame-carrying plan item (PallasRun / FrameSwap) with
-    the index of the frame-identity segment it belongs to (``item.seg``,
-    round-13 tape codec slot) and return the segment count. Segment
-    indices advance exactly at identity returns, so plancheck's QT107
-    check can re-derive them independently and prove each emitted
-    segment starts and ends at frame identity in FusePlan order."""
+    the index of the frame-identity segment it belongs to (``item.seg``;
+    the items are frozen, so each is replaced on the plan) and return the
+    segment count. Segment indices advance exactly at identity returns,
+    so plancheck's QT107 check can re-derive them independently and prove
+    each emitted segment starts and ends at frame identity in FusePlan
+    order."""
+    import dataclasses
+
     from . import fusion
     perm = list(range(nsv))
     ident = list(range(nsv))
     seg = 0
-    for item in plan.items:
-        if isinstance(item, fusion.PallasRun):
-            item.seg = seg
-            if item.load_swap_k:
-                _swap_blocks(perm, item.tile_bits, item.load_swap_k,
-                             item.load_swap_hi)
-            if item.store_swap_k:
-                _swap_blocks(perm, item.tile_bits, item.store_swap_k,
-                             item.store_swap_hi)
-        elif isinstance(item, fusion.FrameSwap):
-            item.seg = seg
-            _swap_blocks(perm, item.tile_bits, item.k, item.hi)
+    for i, item in enumerate(plan.items):
+        if isinstance(item, (fusion.PallasRun, fusion.FrameSwap)):
+            plan.items[i] = dataclasses.replace(item, seg=seg)
+            _replay_frame(perm, item)
         if perm == ident:
             seg += 1
     return seg
@@ -291,19 +229,13 @@ def slice_executable(circuit, lo: int, hi: int, donate: bool = True):
 
 def run_slice(circuit, qureg, lo: int = 0, hi: int | None = None, *,
               donate: bool = True):
-    """Execute ``tape[lo:hi]`` on ``qureg`` (mutates its amps).
-
-    With segment dispatch on (:func:`segment_dispatch_enabled`), the
-    slice runs as ONE segment program --
-    ``device_dispatch_total{route="segment"}`` counts exactly one
-    launch. Otherwise the host interprets item-by-item, the fallback
-    lattice rung: each entry is applied eagerly (its own device
-    program(s), the pre-round-13 behavior) and counts
-    ``route="item"``. Both routes satisfy the numeric contract in the
-    module docstring: deterministic per route, bit-identical where the
-    compiled programs match, ~1 ulp across program granularities on
-    XLA-CPU (granularity-invariant on TPU, where Mosaic kernels are
-    opaque to fma recontraction)."""
+    """Execute ``tape[lo:hi]`` on ``qureg`` (mutates its amps) as ONE
+    segment program: ``device_dispatch_total{route="segment"}`` counts
+    exactly one launch. The numeric contract is the module docstring's:
+    deterministic run to run, bit-identical where the compiled programs
+    match, ~1 ulp across program granularities on XLA-CPU
+    (granularity-invariant on TPU, where Mosaic kernels are opaque to fma
+    recontraction)."""
     from . import fusion
     from .circuits import _register_mesh
     hi = len(circuit._tape) if hi is None else hi
@@ -311,28 +243,23 @@ def run_slice(circuit, qureg, lo: int = 0, hi: int | None = None, *,
         return qureg
     ctx = telemetry.current_trace() if telemetry.trace_on() else None
     with fusion.pallas_mesh(_register_mesh(qureg)):
-        if segment_dispatch_enabled():
-            fn = slice_executable(circuit, lo, hi, donate=donate)
-            telemetry.inc("device_dispatch_total", route="segment")
-            if ctx is not None:
-                # the segment launch splits into its dispatch/device
-                # phases: an explicit sync separates the host-side
-                # launch from the device drain (armed path only -- the
-                # untraced path never blocks)
-                import time as _time
+        fn = slice_executable(circuit, lo, hi, donate=donate)
+        telemetry.inc("device_dispatch_total", route="segment")
+        if ctx is not None:
+            # the segment launch splits into its dispatch/device
+            # phases: an explicit sync separates the host-side
+            # launch from the device drain (armed path only -- the
+            # untraced path never blocks)
+            import time as _time
 
-                import jax as _jax
-                out = fn(qureg.amps)
-                ctx.charge("dispatch", _time.perf_counter())
-                _jax.block_until_ready(out)
-                ctx.charge("device", _time.perf_counter())
-                qureg.put(out)
-            else:
-                qureg.put(fn(qureg.amps))
+            import jax as _jax
+            out = fn(qureg.amps)
+            ctx.charge("dispatch", _time.perf_counter())
+            _jax.block_until_ready(out)
+            ctx.charge("device", _time.perf_counter())
+            qureg.put(out)
         else:
-            for f, a, kw in circuit._tape[lo:hi]:
-                telemetry.inc("device_dispatch_total", route="item")
-                f(qureg, *a, **kw)
+            qureg.put(fn(qureg.amps))
     return qureg
 
 
@@ -343,9 +270,7 @@ def chain_executable(circuit, max_items: int | None = None,
     Each link is a cached :func:`slice_executable`; the chain itself is
     cached too. Calling the chain counts one
     ``device_dispatch_total{route="segment"}`` per link -- the dispatch
-    tax is the segment count, amortizing the per-item tax by the mean
-    items-per-segment (the dispatch_20q bench row asserts the
-    collapse)."""
+    tax is the segment count, not the gate count."""
     from . import fusion
     from .engine import cache as _ec
     from .parallel import scheduler as _dist
@@ -392,9 +317,9 @@ def request_executable(circuit, donate: bool = True, reduce=None):
     is the composition of the SAME per-segment replays the chained and
     checkpointed routes run -- slice replays compose into the identical
     primitive sequence as the whole-tape replay, making the request
-    program bit-identical to ``compiled()`` run-to-run (the chained-vs-
-    item cross-granularity caveat in the module docstring still applies
-    on XLA-CPU). Cached in the process-global LRU under
+    program bit-identical to ``compiled()`` run-to-run (the
+    cross-granularity caveat in the module docstring still applies on
+    XLA-CPU). Cached in the process-global LRU under
     ``("request_chain", ...)``; ``fn.num_segments`` reports how many
     segments were composed, ``fn.num_dispatches = 1`` the launch
     count."""

@@ -20,6 +20,22 @@ from quest_tpu.precision import default_precision
 TOL = 1e-10 if default_precision() == 2 else 2e-4
 
 
+def pallas_runs(circuit) -> list:
+    """The PallasRuns a fused circuit's tape carries, in order, read through
+    the one decoder (``fusion.plan_from_tape``)."""
+    from quest_tpu import fusion
+    return [i for i in fusion.plan_from_tape(circuit._tape).items
+            if isinstance(i, fusion.PallasRun)]
+
+
+def shape_register(n: int, dtype, sharding=None):
+    """A state-vector register of shapes only (no amplitudes anywhere):
+    all that ``fusion._route`` reads of one."""
+    import jax
+    return qt.Qureg(n, False, jax.ShapeDtypeStruct(
+        (2, 1 << n), np.dtype(dtype), sharding=sharding), env=None)
+
+
 def get_statevec(qureg) -> np.ndarray:
     return qt.get_np(qureg)
 

@@ -47,6 +47,33 @@ def apply_to_statevec(state: np.ndarray, n, targets, matrix, controls=(),
     return full_operator(n, targets, matrix, controls, control_states) @ state
 
 
+def apply_to_statevec_indexed(state: np.ndarray, n, targets, matrix,
+                              controls=(), control_states=None) -> np.ndarray:
+    """``apply_to_statevec`` without the 4^n operator, for registers too
+    wide to build it: the same explicit bit arithmetic, carried out on the
+    vector of all 2^n indices at once (each output amplitude gathers the
+    2^t inputs that differ from it on ``targets`` only)."""
+    m = np.asarray(matrix, dtype=np.complex128)
+    idx = np.arange(1 << n)
+    states = control_states if control_states is not None else [1] * len(controls)
+    active = np.ones(1 << n, dtype=bool)
+    for c, s in zip(controls, states):
+        active &= ((idx >> c) & 1) == s
+    r_out = np.zeros(1 << n, dtype=np.int64)
+    base = idx.copy()
+    for k, q in enumerate(targets):
+        r_out |= ((idx >> q) & 1) << k
+        base &= ~(1 << q)
+    out = np.zeros(1 << n, dtype=np.complex128)
+    for r_in in range(1 << len(targets)):
+        j = base.copy()
+        for k, q in enumerate(targets):
+            if (r_in >> k) & 1:
+                j |= 1 << q
+        out += m[r_out, r_in] * state[j]
+    return np.where(active, out, state)
+
+
 def apply_to_density(rho: np.ndarray, n, targets, matrix, controls=(),
                      control_states=None) -> np.ndarray:
     F = full_operator(n, targets, matrix, controls, control_states)

@@ -23,6 +23,7 @@ and (b) catch a seeded fault:
 All checks are zero-device: nothing here executes a state vector.
 """
 
+import dataclasses
 import warnings
 
 import numpy as np
@@ -105,9 +106,9 @@ def test_fused_plan_replays_clean():
 
 def test_plan_mutation_dropped_store_swap():
     plan = _plan_20q()
-    for it in plan.items:
+    for i, it in enumerate(plan.items):
         if isinstance(it, fusion.PallasRun) and it.store_swap_k:
-            it.store_swap_k = 0
+            plan.items[i] = dataclasses.replace(it, store_swap_k=0)
             break
     else:
         pytest.fail("20q plan no longer folds a store swap")
@@ -116,10 +117,10 @@ def test_plan_mutation_dropped_store_swap():
 
 def test_plan_mutation_grid_block_out_of_range():
     plan = _plan_20q()
-    for it in plan.items:
+    for i, it in enumerate(plan.items):
         if isinstance(it, fusion.PallasRun) and it.load_swap_k:
             hi = it.tile_bits if it.load_swap_hi is None else it.load_swap_hi
-            it.load_swap_hi = hi + 9
+            plan.items[i] = dataclasses.replace(it, load_swap_hi=hi + 9)
             break
     else:
         pytest.fail("20q plan no longer folds a load swap")

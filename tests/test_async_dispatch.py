@@ -241,7 +241,8 @@ def test_retire_hang_fails_only_the_retired_batch():
 
 def test_compiled_request_single_dispatch_bit_identical():
     from quest_tpu.ops import init as ops_init
-    from quest_tpu.segments import force_route, run_slice
+
+    from . import oracle
 
     n = 3
     conc = Circuit(n)
@@ -260,11 +261,25 @@ def test_compiled_request_single_dispatch_bit_identical():
     assert fnR.num_segments >= 1
     # run-to-run bit-identity of the one chained program
     assert np.array_equal(np.asarray(out), np.asarray(fnR(amps0 + 0)))
-    # ~1 ulp agreement across program granularities (segments.py caveat)
+    # ~1 ulp agreement across program granularities (segments.py caveat):
+    # against the whole-tape program Circuit.run dispatches ...
     qreg = qt.createQureg(n, ENV1)
-    with force_route("item"):
-        run_slice(conc, qreg)
+    conc.run(qreg)
     assert np.allclose(np.asarray(out), np.asarray(qreg.amps),
+                       rtol=1e-5, atol=1e-6)
+    # ... and, both being lowerings of one tape, against the dense oracle
+    psi = np.zeros(1 << n, dtype=np.complex128)
+    psi[0] = 1.0
+    half = lambda t: (np.cos(t / 2), np.sin(t / 2))  # noqa: E731
+    cz, sz = half(0.37)
+    cx, sx = half(-0.8)
+    for targets, m, controls in (
+            ((0,), np.array([[1, 1], [1, -1]]) / np.sqrt(2), ()),
+            ((1,), np.diag([cz - 1j * sz, cz + 1j * sz]), ()),
+            ((2,), np.array([[0, 1], [1, 0]]), (0,)),
+            ((2,), np.array([[cx, -1j * sx], [-1j * sx, cx]]), ())):
+        psi = oracle.apply_to_statevec(psi, n, targets, m, controls)
+    assert np.allclose(np.asarray(out), np.stack([psi.real, psi.imag]),
                        rtol=1e-5, atol=1e-6)
 
 

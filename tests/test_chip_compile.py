@@ -32,11 +32,15 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from quest_tpu import fusion
 from quest_tpu.circuits import Circuit
+from quest_tpu.environment import AMP_AXIS
 from quest_tpu.ops import pallas_gates as PG
 from quest_tpu.ops.pallas_df import DF_MAX_OPS, DF_SUBLANES
+
+from .helpers import pallas_runs, shape_register
 
 #: double-float ops compiled (a real chunk's prefix): ~30 s per df op
 _DF_OPS = 4
@@ -66,11 +70,9 @@ def one_chip():
 
 
 def _planned_runs(circ, **fused_kw):
-    """The PallasRun argument tuples of ``circ.fused(pallas=True, ...)``:
-    (ops, tile_bits, load_swap_k, store_swap_k, load_swap_hi,
-    store_swap_hi, ring_depth, ...)."""
-    fz = circ.fused(max_qubits=5, pallas=True, dtype=np.float32, **fused_kw)
-    return [a for fn, a, _ in fz._tape if fn is fusion._apply_pallas_run]
+    """The PallasRuns of ``circ.fused(pallas=True, ...)``."""
+    return pallas_runs(circ.fused(max_qubits=5, pallas=True,
+                                  dtype=np.float32, **fused_kw))
 
 
 def _random_circuit(n, depth=8):
@@ -147,10 +149,11 @@ def test_f32_fused_run_26q_plan_pass(one_chip):
     n = 26
     runs = _planned_runs(_random_circuit(n))
     assert len(runs) >= 8
-    ops, tile_bits, lk, sk = runs[0][:4]
-    assert (tile_bits, lk, sk) == (PG.local_qubits(n), 0, 0)
+    first = runs[0]
+    assert (first.tile_bits, first.load_swap_k, first.store_swap_k) \
+        == (PG.local_qubits(n), 0, 0)
     assert PG.ring_depth_default() == 3
-    _compile_fused(one_chip, n, ops)
+    _compile_fused(one_chip, n, first.ops)
 
 
 def test_f32_fused_run_26q_folded_frame_swap(one_chip):
@@ -159,14 +162,15 @@ def test_f32_fused_run_26q_folded_frame_swap(one_chip):
     per tile)."""
     n = 26
     swapped = [r for r in _planned_runs(_random_circuit(n))
-               if r[2] and r[3]]
+               if r.load_swap_k and r.store_swap_k]
     assert swapped, "the 26q plan no longer folds a frame swap"
-    ops, tile_bits, lk, sk, lh, sh = swapped[0][:6]
-    # what fusion._apply_pallas_run requires before it folds the swap
-    assert tile_bits == PG.local_qubits(n)
-    assert tile_bits - PG.LANE_BITS - max(lk, sk) >= 3
-    _compile_fused(one_chip, n, ops, lk=lk, sk=sk, lh=lh, sh=sh,
-                   skip_zones=("sublane",))
+    run = swapped[0]
+    # the program folds both into this kernel's DMA on a 26q register
+    route = fusion._route(shape_register(n, np.float32), run)
+    assert route[:4] == ("local", True, True, None), route
+    _compile_fused(one_chip, n, run.ops, lk=run.load_swap_k,
+                   sk=run.store_swap_k, lh=run.load_swap_hi,
+                   sh=run.store_swap_hi, skip_zones=("sublane",))
 
 
 def test_f32_per_shard_run_28q_over_4(one_chip):
@@ -177,28 +181,33 @@ def test_f32_per_shard_run_28q_over_4(one_chip):
     n_local = n - 2
     runs = _planned_runs(_random_circuit(n), shard_devices=ndev)
     assert runs
-    for ops, *_ in runs:   # every run is per-shard executable
+    for run in runs:   # every run is per-shard executable
         assert all(q < PG.local_qubits(n_local)
-                   for op in ops for q in PG.op_dense_targets(op))
+                   for op in run.ops for q in PG.op_dense_targets(op))
     # a run that touches a SHARDED qubit (control / diagonal role)
     plain = next(r for r in runs if any(
-        q >= n_local for op in r[0] for q in _op_qubits(op)))
-    sharded_role = [op for op in plain[0]
+        q >= n_local for op in r.ops for q in _op_qubits(op)))
+    sharded_role = [op for op in plain.ops
                     if any(q >= n_local for q in _op_qubits(op))][:1]
-    _compile_fused(one_chip, n_local, plain[0], local_n=n_local,
+    _compile_fused(one_chip, n_local, plain.ops, local_n=n_local,
                    must=sharded_role, skip_zones=("sublane",))
     # ... and a run whose frame swap is SHARD-LOCAL, so it folds into the
     # per-shard kernel's BlockSpec index maps (the depth-3 plan has one;
     # swaps reaching sharded bits are collective transposes, not kernels)
+    if len(jax.devices()) < ndev:
+        pytest.skip("needs the multi-device CPU mesh to ask the router")
+    mesh = Mesh(np.array(jax.devices()[:ndev]), (AMP_AXIS,))
+    sharded = shape_register(n, np.float32,
+                             NamedSharding(mesh, P(None, AMP_AXIS)))
     local_swaps = [r for r in _planned_runs(_random_circuit(n, depth=3),
                                             shard_devices=ndev)
-                   if r[2] and r[3]
-                   and (r[1] if r[4] is None else r[4]) + r[2] <= n_local
-                   and (r[1] if r[5] is None else r[5]) + r[3] <= n_local]
+                   if fusion._route(sharded, r)[:4]
+                   == ("sharded", True, True, None)]
     assert local_swaps, "no shard-local folded swap in the 28q/4 plan"
-    ops, tile_bits, lk, sk, lh, sh = local_swaps[0][:6]
-    _compile_fused(one_chip, n_local, ops[:1], local_n=n_local, lk=lk,
-                   lh=lh, sk=sk, sh=sh)
+    run = local_swaps[0]
+    _compile_fused(one_chip, n_local, run.ops[:1], local_n=n_local,
+                   lk=run.load_swap_k, lh=run.load_swap_hi,
+                   sk=run.store_swap_k, sh=run.store_swap_hi)
 
 
 def _op_qubits(op):
@@ -224,12 +233,12 @@ def test_df_run_20q(one_chip, monkeypatch):
     monkeypatch.setenv("QUEST_PALLAS_DF", "1")
     fz = _random_circuit(n, depth=1).fused(max_qubits=5, pallas=True,
                                            dtype=np.float64)
-    runs = [a for fn, a, _ in fz._tape if fn is fusion._apply_pallas_run]
+    runs = pallas_runs(fz)
     assert runs
     lq = PG.local_qubits(n, DF_SUBLANES)
-    assert all(q < lq for op in runs[0][0]
+    assert all(q < lq for op in runs[0].ops
                for q in PG.op_dense_targets(op))
-    ops = runs[0][0][:DF_MAX_OPS][:_DF_OPS]
+    ops = runs[0].ops[:DF_MAX_OPS][:_DF_OPS]
     assert len(ops) == _DF_OPS
     _compile_fused(one_chip, n, ops, planes=4, sublanes=DF_SUBLANES)
 
@@ -242,10 +251,10 @@ def test_density_kraus_run_14q(one_chip):
 
     nsv = 28
     runs = _planned_runs(bench._density_circuit(14, with_krausn=True))
-    kinds = {op[0] for r in runs for op in r[0]}
+    kinds = {op[0] for r in runs for op in r.ops}
     assert {"kraus1", "krausn"} <= kinds, kinds
-    run = next(r for r in runs if any(op[0] == "krausn" for op in r[0]))
-    _compile_fused(one_chip, nsv, run[0], skip_zones=("sublane",))
+    run = next(r for r in runs if any(op[0] == "krausn" for op in r.ops))
+    _compile_fused(one_chip, nsv, run.ops, skip_zones=("sublane",))
 
 
 def test_window_dot_26q(one_chip):

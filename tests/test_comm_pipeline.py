@@ -32,8 +32,8 @@ contract on the 8-virtual-device CPU mesh:
   replays the WHOLE launch bit-identically (guard wraps the full
   shard_map closure, never a mid-slice resume);
 - tape codec: fused(comm_pipeline=) stamps every PallasRun/FrameSwap and
-  round-trips through as_tape/plan_from_tape; pre-round-8 tapes (7-arg
-  PallasRun / 3-arg FrameSwap entries) decode to comm_pipeline=None.
+  round-trips through as_tape/plan_from_tape; an unstamped plan leaves
+  comm_pipeline=None.
 """
 
 import warnings
@@ -430,7 +430,7 @@ def test_pipelined_collective_transient_retries_bit_identical():
 
 
 # ---------------------------------------------------------------------------
-# tape codec: fused(comm_pipeline=) stamps + backward-compat decode
+# tape codec: fused(comm_pipeline=) stamps ride the run
 # ---------------------------------------------------------------------------
 
 def test_fused_comm_pipeline_stamps_and_roundtrips():
@@ -447,15 +447,11 @@ def test_fused_comm_pipeline_stamps_and_roundtrips():
     assert runs, "sharded pallas plan should carry PallasRun items"
     assert all(i.comm_pipeline == 2 for i in runs)
 
-    # pre-round-8 tapes carry 7-arg PallasRun / 3-arg FrameSwap entries:
-    # they must decode to comm_pipeline=None (the env default at run time)
-    old = []
-    for fn, a, kw in fusion.as_tape(p):
-        if getattr(fn, "__name__", "") == "_apply_pallas_run":
-            a = a[:7]
-        elif getattr(fn, "__name__", "") == "_apply_frame_swap":
-            a = a[:3]
-        old.append((fn, a, kw))
-    p2 = fusion.plan_from_tape(old)
-    assert all(i.comm_pipeline is None for i in p2.items
+    # the stamp rides the run through the tape codec, and an unstamped
+    # plan leaves None for the env default at run time
+    again = fusion.plan_from_tape(fusion.as_tape(p))
+    assert again.items == p.items
+    bare = fusion.plan_from_tape(tuple(
+        c.fused(max_qubits=5, pallas=True, shard_devices=8)._tape))
+    assert all(i.comm_pipeline is None for i in bare.items
                if isinstance(i, (fusion.PallasRun, fusion.FrameSwap)))

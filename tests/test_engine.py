@@ -368,8 +368,8 @@ def test_lru_scripted_hit_miss_evict_counters():
 
 
 def test_circuit_compiled_routes_through_global_lru(monkeypatch):
-    """The per-circuit executable dicts are gone: compiled()/compiled_blocks
-    hit the bounded global LRU, a tape append invalidates, and capacity
+    """The per-circuit executable dicts are gone: compiled() hits the
+    bounded global LRU, a tape append invalidates, and capacity
     pressure evicts with counters."""
     small = LRUCache(capacity=2, name="executable")
     monkeypatch.setattr(ecache, "_EXECUTABLES", small)

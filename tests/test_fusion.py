@@ -13,10 +13,14 @@ import quest_tpu as qt
 from quest_tpu import fusion
 from quest_tpu.circuits import Circuit
 from quest_tpu.ops import init as ops_init
+# the df route's switch off the TPU, by its own name: the surface audit
+# reads a test FILE that spells the variable out as df coverage of every
+# API function the file calls, which the routing tests below are not
+from quest_tpu.ops.pallas_df import _DF_ENV
 
 from quest_tpu.precision import real_dtype
 
-from .helpers import TOL
+from .helpers import TOL, shape_register
 
 ENV = qt.createQuESTEnv()
 
@@ -416,3 +420,294 @@ def test_deferred_diagonal_leaves_a_static_diagonal_block_static():
     only.rotateZ(5, P("b"))
     q = fusion.plan(tuple(only._tape), 6, real_dtype(), max_qubits=3)
     assert len(q.items) == 1 and len(q.items[0].factors) == 2
+
+
+# ---------------------------------------------------------------------------
+# a fused run from planner to kernel: the run on the tape, the route, the exit
+# ---------------------------------------------------------------------------
+
+def test_fused_run_fingerprints_by_content():
+    """A run is its tape entry, and the structure fingerprint hashes it
+    field by field: two fused tapes that differ in one gate's angle differ
+    in fingerprint, two equal ones do not."""
+    def fused(theta):
+        c = Circuit(10)
+        for q in range(10):
+            c.hadamard(q)
+        c.rotateZ(3, theta)
+        c.controlledNot(0, 9)
+        fz = c.fused(max_qubits=4, pallas=True)
+        assert all(isinstance(a[0], fusion.PallasRun) for _, a, _ in fz._tape)
+        return fz
+
+    a, b, other = fused(0.25), fused(0.25), fused(0.26)
+    assert a._tape[0][1][0] == b._tape[0][1][0]
+    assert hash(a._tape[0][1][0]) == hash(b._tape[0][1][0])
+    assert a.fingerprint() == b.fingerprint()
+    assert a.fingerprint() != other.fingerprint()
+    # no Param hides inside a run (the Pallas planner keeps such entries
+    # apart, as barriers), so lifting finds no slot in it
+    assert a.param_names == () and not a.lifted().slots
+
+
+def _mesh(ndev, axis=None):
+    import jax
+    from jax.sharding import Mesh
+    from quest_tpu.environment import AMP_AXIS
+    if len(jax.devices()) < ndev:
+        pytest.skip(f"needs the {ndev}-device CPU mesh")
+    return Mesh(np.array(jax.devices()[:ndev]), (axis or AMP_AXIS,))
+
+
+def _canonical(mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return NamedSharding(mesh, P(None, mesh.axis_names[0]))
+
+
+def _x_run(target, tile_bits, **swaps):
+    from quest_tpu.ops.pallas_gates import HashableMatrix
+    x = HashableMatrix(np.array([[0, 1], [1, 0]], dtype=complex))
+    return fusion.PallasRun((("matrix", target, (), (), x),), tile_bits,
+                            **swaps)
+
+
+# (case, dtype, df route on, qubits, devices the register is split over,
+#  how the replay sees that split, the run as (target, tile_bits, swaps),
+#  the route's (kind, fold_load, fold_store, reason))
+_F32, _F64 = "float32", "float64"
+_LQ22 = 19          # local_qubits(22): 7 lane bits + the 4096-sublane tile
+_LQ_DF = 17         # local_qubits(n >= 17, DF_SUBLANES)
+_ROUTE_TABLE = [
+    # -- one device, plain -------------------------------------------------
+    ("local/no-swap", _F32, False, 22, 1, "concrete",
+     (0, _LQ22, {}), ("local", False, False, None)),
+    ("local/folded", _F32, False, 22, 1, "concrete",
+     (0, _LQ22, dict(load_swap_k=2, store_swap_k=2)),
+     ("local", True, True, None)),
+    ("local/load-only-folded", _F32, False, 22, 1, "concrete",
+     (0, _LQ22, dict(load_swap_k=3)), ("local", True, False, None)),
+    ("local/geometry-miss", _F32, False, 22, 1, "concrete",
+     (0, _LQ22 - 1, dict(load_swap_k=2, store_swap_k=2)),
+     ("local", False, False, "swap_not_foldable")),
+    ("local/k-too-wide", _F32, False, 29, 1, "concrete",
+     (0, _LQ22, dict(load_swap_k=10, store_swap_k=10)),
+     ("local", False, False, "swap_not_foldable")),
+    ("local/native-f64-interpreter", _F64, False, 22, 1, "concrete",
+     (0, _LQ22, dict(load_swap_k=2)), ("local", True, False, None)),
+    # -- one device, double-float -------------------------------------------
+    ("df_local/folded", _F64, True, 20, 1, "concrete",
+     (0, _LQ_DF, dict(load_swap_k=2, store_swap_k=2)),
+     ("df_local", True, True, None)),
+    ("df_local/geometry-miss", _F64, True, 20, 1, "concrete",
+     (0, _LQ_DF - 1, dict(store_swap_k=2)),
+     ("df_local", False, False, "swap_not_foldable")),
+    ("df_local/tile-mismatch", _F64, True, 20, 1, "concrete",
+     (_LQ_DF, _LQ22, {}), ("gatewise", False, False, "df_tile_mismatch")),
+    ("df_local/sub-tile", _F64, True, 7, 1, "concrete",
+     (0, 7, {}), ("gatewise", False, False, "f64_engine")),
+    # -- per shard (GSPMD) ---------------------------------------------------
+    ("sharded/shard-local-swap-folded", _F32, False, 24, 4, "concrete",
+     (0, _LQ22, dict(load_swap_k=2, store_swap_k=2)),
+     ("sharded", True, True, None)),
+    ("sharded/traced-ambient-mesh", _F32, False, 24, 4, "traced",
+     (0, _LQ22, dict(load_swap_k=2, store_swap_k=2)),
+     ("sharded", True, True, None)),
+    ("sharded/swap-reaching-sharded-bits-explicit", _F32, False, 24, 4,
+     "concrete",
+     (0, _LQ22, dict(load_swap_k=2, store_swap_k=2, store_swap_hi=21)),
+     ("sharded", True, False, None)),
+    ("sharded/shard-local-geometry-miss", _F32, False, 24, 4, "concrete",
+     (0, _LQ22 - 1, dict(load_swap_k=2)),
+     ("sharded", False, False, "swap_not_foldable")),
+    ("sharded/df-folded", _F64, True, 21, 8, "concrete",
+     (0, _LQ_DF, dict(load_swap_k=1, store_swap_k=1)),
+     ("sharded", True, True, None)),
+    ("sharded/df-tile-mismatch", _F64, True, 21, 8, "traced",
+     (_LQ_DF, 18, {}), ("gatewise", False, False, "df_tile_mismatch")),
+    ("sharded/non-canonical-mesh", _F32, False, 24, 4, "other-axis",
+     (0, _LQ22, {}), ("gatewise", False, False, "shard_map_unsupported")),
+    ("sharded/non-power-of-two", _F32, False, 24, 3, "traced",
+     (0, _LQ22, {}), ("gatewise", False, False, "shard_map_unsupported")),
+    ("sharded/dense-target-above-shard-tile", _F32, False, 24, 4, "traced",
+     (20, 21, {}), ("gatewise", False, False, "shard_map_unsupported")),
+    ("sharded/sub-tile-shard", _F32, False, 10, 8, "concrete",
+     (0, 7, {}), ("gatewise", False, False, "shard_map_unsupported")),
+    ("sharded/df-sub-tile-shard", _F64, True, 9, 8, "concrete",
+     (0, 6, {}), ("gatewise", False, False, "f64_engine")),
+    # -- explicit scheduler ---------------------------------------------------
+    ("scheduler/f32", _F32, False, 24, 4, "scheduler",
+     (0, _LQ22, dict(load_swap_k=2)),
+     ("gatewise", False, False, "explicit_scheduler")),
+    ("scheduler/df", _F64, True, 21, 8, "scheduler",
+     (0, _LQ_DF, dict(load_swap_k=1, store_swap_k=1)),
+     ("sched_df", False, False, None)),
+    ("scheduler/df-tile-mismatch", _F64, True, 21, 8, "scheduler",
+     (_LQ_DF, 18, {}), ("gatewise", False, False, "df_tile_mismatch")),
+]
+
+
+@pytest.mark.parametrize(
+    "dtype,df,n,ndev,seen,run,want",
+    [c[1:] for c in _ROUTE_TABLE], ids=[c[0] for c in _ROUTE_TABLE])
+def test_route_table(monkeypatch, dtype, df, n, ndev, seen, run, want):
+    """Every (route, fold, reason) ``fusion._route`` can reach, decided on
+    registers of shapes only: no device program runs, no counter moves."""
+    import contextlib
+
+    import jax
+
+    from quest_tpu import telemetry
+    from quest_tpu.ops import pallas_gates as PG
+    from quest_tpu.ops.pallas_df import DF_SUBLANES
+
+    assert PG.local_qubits(22) == _LQ22
+    assert PG.local_qubits(20, DF_SUBLANES) == _LQ_DF
+    monkeypatch.setenv(_DF_ENV, "1" if df else "0")
+    target, tile_bits, swaps = run
+    prun = _x_run(target, tile_bits, **swaps)
+    mesh = _mesh(ndev, "other" if seen == "other-axis" else None) \
+        if ndev > 1 else None
+    got = []
+
+    def decide(amps):
+        got.append(fusion._route(qt.Qureg(n, False, amps, env=None), prun))
+        return amps
+
+    before = telemetry.snapshot()["counters"]
+    if seen == "traced":
+        # what Circuit.run sets up: the tracer hides the sharding, the
+        # ambient mesh carries it
+        with fusion.pallas_mesh(mesh):
+            jax.eval_shape(decide, shape_register(n, dtype).amps)
+    else:
+        sharding = _canonical(mesh) if mesh is not None else None
+        ctx = qt.explicit_mesh(mesh) if seen == "scheduler" \
+            else contextlib.nullcontext()
+        with ctx:
+            decide(shape_register(n, dtype, sharding).amps)
+    assert telemetry.snapshot()["counters"] == before, "_route counted"
+    route = got[0]
+    assert tuple(route[:4]) == want
+    if route.kind in ("sharded", "sched_df"):
+        assert route.mesh is mesh
+        assert route.n_exec == n - (ndev.bit_length() - 1)
+    elif route.kind != "gatewise":
+        assert route.mesh is None and route.n_exec == n
+    assert route.df == (route.kind != "gatewise" and df)
+
+
+def _fallbacks():
+    from quest_tpu import telemetry
+    return {k: v for k, v in telemetry.snapshot()["counters"].items()
+            if k.startswith("engine_fallback_total") and v}
+
+
+@pytest.mark.parametrize("reason", [
+    "explicit_scheduler", "shard_map_unsupported", "f64_engine",
+    "df_tile_mismatch", "fault_degraded"])
+def test_every_exit_goes_through_gatewise_once(monkeypatch, reason):
+    """Each way out of the kernel routes reaches the device through the one
+    ``_gatewise``, with exactly one count of its reason and nothing else
+    counted, and the state is the gate's."""
+    import contextlib
+
+    import jax
+
+    from quest_tpu import telemetry
+    from quest_tpu.ops import pallas_gates as PG
+    from quest_tpu.ops.pallas_df import DF_SUBLANES
+    from quest_tpu.resilience import fault_plan
+
+    if np.dtype(real_dtype()) != np.dtype("float64"):
+        pytest.skip("needs QUEST_PRECISION=2 (the conftest default)")
+    one = qt.createQuESTEnv(jax.devices()[:1])
+    ctx = contextlib.nullcontext()
+    target, code = 0, None
+    if reason == "explicit_scheduler":
+        mesh = _mesh(8)
+        env, n, tile_bits = qt.createQuESTEnv(), 10, 7
+        ctx = qt.explicit_mesh(mesh)
+    elif reason == "shard_map_unsupported":
+        _mesh(8)
+        env, n, tile_bits = qt.createQuESTEnv(), 10, 7   # 7-qubit shards
+    elif reason == "f64_engine":
+        monkeypatch.setenv(_DF_ENV, "1")
+        env, n, tile_bits = one, 7, 7                    # below one tile
+    elif reason == "df_tile_mismatch":
+        monkeypatch.setenv(_DF_ENV, "1")
+        env, n, tile_bits = one, 18, PG.local_qubits(18)
+        target = PG.local_qubits(18, DF_SUBLANES)        # legal for f32 only
+    else:
+        env, n, tile_bits, code = one, 10, 10, 1
+        ctx = fault_plan("pallas.dispatch:compile:1+")
+    q = qt.createQureg(n, env, precision_code=code)
+    qt.initClassicalState(q, 0)
+    calls = {"gatewise": [], "engine": 0}
+    real_gatewise, real_engine = fusion._gatewise, fusion._apply_ops_via_engine
+
+    def spy_gatewise(qureg, run, why, *a, **kw):
+        calls["gatewise"].append(why)
+        return real_gatewise(qureg, run, why, *a, **kw)
+
+    def spy_engine(qureg, ops):
+        calls["engine"] += 1
+        return real_engine(qureg, ops)
+
+    monkeypatch.setattr(fusion, "_gatewise", spy_gatewise)
+    monkeypatch.setattr(fusion, "_apply_ops_via_engine", spy_engine)
+    telemetry.reset()
+    with ctx:
+        fusion._apply_pallas_run(q, _x_run(target, tile_bits))
+    # the guard counts its own degradation; every other exit hands
+    # _gatewise the reason to count
+    assert calls["gatewise"] == [None if reason == "fault_degraded"
+                                 else reason]
+    assert calls["engine"] == 1
+    assert _fallbacks() == {
+        f"engine_fallback_total{{reason={reason}}}": 1.0}
+    amps = np.asarray(q.amps)
+    assert amps[0, 1 << target] == pytest.approx(1.0)
+    assert amps[0, 0] == pytest.approx(0.0)
+
+
+def test_kernel_routes_count_what_the_plan_did_not_price(monkeypatch):
+    """The two labels that are not exits: a relabeling that misses the
+    register's tile geometry runs beside the kernel (``swap_not_foldable``,
+    once a run), and a df run longer than DF_MAX_OPS splits into chained
+    kernels (``df_max_ops_split``, once an extra pass). Neither leaves the
+    kernel route."""
+    import jax
+
+    from quest_tpu import telemetry
+    from quest_tpu.ops.pallas_df import DF_MAX_OPS
+    from quest_tpu.ops.pallas_gates import HashableMatrix
+
+    if np.dtype(real_dtype()) != np.dtype("float64"):
+        pytest.skip("needs QUEST_PRECISION=2 (the conftest default)")
+    monkeypatch.setattr(fusion, "_gatewise", None)   # any exit would raise
+    one = qt.createQuESTEnv(jax.devices()[:1])
+    n = 12
+    q = qt.createQureg(n, one)
+    qt.initClassicalState(q, 0)
+    telemetry.reset()
+    # planned for a 10-bit tile, run on a register whose tile holds 12
+    fusion._apply_pallas_run(q, _x_run(0, 10, load_swap_k=2, store_swap_k=2))
+    assert _fallbacks() == {
+        "engine_fallback_total{reason=swap_not_foldable}": 1.0}
+    assert telemetry.counter_value("pallas_pass_total",
+                                   kind="frame_swap") == 2
+    assert np.asarray(q.amps)[0, 1] == pytest.approx(1.0)
+
+    monkeypatch.setenv(_DF_ENV, "1")
+    x = HashableMatrix(np.array([[0, 1], [1, 0]], dtype=complex))
+    ops = tuple(("matrix", i % n, (), (), x)
+                for i in range(2 * DF_MAX_OPS + 1))
+    q = qt.createQureg(n, one)
+    qt.initClassicalState(q, 0)
+    telemetry.reset()
+    fusion._apply_pallas_run(q, fusion.PallasRun(ops, n))
+    assert _fallbacks() == {
+        "engine_fallback_total{reason=df_max_ops_split}": 2.0}
+    # 17 X gates over 12 qubits: qubits 0..4 flipped twice, 5..11 once
+    want = sum(1 << b for b in range(5, n))
+    assert np.asarray(q.amps)[0, want] == pytest.approx(1.0, abs=1e-6)

@@ -26,8 +26,7 @@ merely pricing it. This suite pins:
 - QUEST_COMM_PIPELINE_DCN: malformed values warn ONCE via QT210
   (mirroring QT206), the resolution order is explicit arg > env > base
   depth, and fused(comm_pipeline_dcn=) stamps every PallasRun/FrameSwap
-  and round-trips through as_tape/plan_from_tape (pre-round-15 tape
-  entries decode to None).
+  and round-trips through as_tape/plan_from_tape.
 """
 
 import warnings
@@ -311,27 +310,7 @@ def test_fused_comm_pipeline_dcn_stamps_and_roundtrips():
     assert stamped, "sharded pallas plan should carry PallasRun items"
     assert all(i.comm_pipeline == 4 and i.comm_pipeline_dcn == 2
                for i in stamped)
-    # encoder/decoder round-trip preserves the new LAST positional field
+    # the encoder/decoder round trip carries the stamp with the run
     again = fusion.plan_from_tape(fusion.as_tape(plan))
     assert [getattr(i, "comm_pipeline_dcn", None) for i in again.items] \
         == [getattr(i, "comm_pipeline_dcn", None) for i in plan.items]
-
-
-def test_pre_round_15_tape_entries_decode_to_none():
-    # round-14 tapes carry 9-arg PallasRun / 5-arg FrameSwap entries: the
-    # trailing comm_pipeline_dcn must decode to None (env default wins)
-    fz = _fused_12q(comm_pipeline=4)
-    plan = fusion.plan_from_tape(tuple(fz._tape))
-    old = []
-    for fn, a, kw in fusion.as_tape(plan):
-        if getattr(fn, "__name__", "") == "_apply_pallas_run":
-            a = a[:9]
-        elif getattr(fn, "__name__", "") == "_apply_frame_swap":
-            a = a[:5]
-        old.append((fn, a, kw))
-    p2 = fusion.plan_from_tape(old)
-    stamped = [i for i in p2.items
-               if isinstance(i, (fusion.PallasRun, fusion.FrameSwap))]
-    assert stamped
-    assert all(i.comm_pipeline == 4 and i.comm_pipeline_dcn is None
-               for i in stamped)

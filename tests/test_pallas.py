@@ -15,7 +15,7 @@ from quest_tpu.ops import init as ops_init
 from quest_tpu.ops import pallas_gates as PG
 from quest_tpu.precision import real_dtype
 
-from .helpers import TOL, assert_amps_close
+from .helpers import TOL, assert_amps_close, pallas_runs, shape_register
 
 H = np.array([[1, 1], [1, -1]]) / np.sqrt(2)
 X = np.array([[0, 1], [1, 0]], dtype=complex)
@@ -123,9 +123,9 @@ def test_density_tapes_ride_pallas_with_shadow_ops():
     circ.rotateZ(2, 0.4)
     circ.tGate(4)
     fz = circ.fused(max_qubits=3, pallas=True)
-    runs = [a[0] for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
+    runs = pallas_runs(fz)
     assert runs, "density tape produced no PallasRuns"
-    targets = {op[1] for ops in runs for op in ops if op[0] == "matrix"}
+    targets = {op[1] for r in runs for op in r.ops if op[0] == "matrix"}
     assert any(t >= n for t in targets), "no shadow ops in the plan"
 
     env = qt.createQuESTEnv()
@@ -158,8 +158,7 @@ def test_density_channels_fuse_into_pallas_runs():
     c.mixTwoQubitDephasing(0, 1, 0.1)
     c.mixTwoQubitDepolarising(0, 1, 0.1)
     fz = c.fused(max_qubits=4, pallas=True)
-    run_ops = [op for f, a, _ in fz._tape
-               if f.__name__ == "_apply_pallas_run" for op in a[0]]
+    run_ops = [op for r in pallas_runs(fz) for op in r.ops]
     kinds = [op[0] for op in run_ops]
     assert kinds.count("kraus1") == 3
     assert kinds.count("kraus2") == 1  # the 2-target depolarising
@@ -197,8 +196,7 @@ def test_three_target_channel_rides_krausn_kernel_op():
     c.mixMultiQubitKrausMap([0, 1, 2], [k0, k1])
     c.tGate(2)
     fz = c.fused(max_qubits=4, pallas=True)
-    run_ops = [op for f, a, _ in fz._tape
-               if f.__name__ == "_apply_pallas_run" for op in a[0]]
+    run_ops = [op for r in pallas_runs(fz) for op in r.ops]
     kn = [op for op in run_ops if op[0] == "krausn"]
     assert len(kn) == 1, "3-target channel did not lower to krausn"
     assert kn[0][1] == (0, 1, 2) and kn[0][2] == (n, n + 1, n + 2)
@@ -229,8 +227,7 @@ def test_non_tp_three_target_channel_rides_krausn():
     c.controlledNot(0, 2)
     c.mixNonTPMultiQubitKrausMap([0, 2, 4], [k0])
     fz = c.fused(max_qubits=4, pallas=True)
-    kn = [op for f, a, _ in fz._tape
-          if f.__name__ == "_apply_pallas_run" for op in a[0]
+    kn = [op for r in pallas_runs(fz) for op in r.ops
           if op[0] == "krausn"]
     assert len(kn) == 1
 
@@ -295,8 +292,7 @@ def test_density_pallas_with_frame_swaps_matches_oracle():
                     is_density=True)
     fz = Circuit(n, is_density_matrix=True)
     fz._tape = fusion.as_tape(p)
-    anns = [(a[2], a[3]) for f, a, _ in fz._tape
-            if f.__name__ == "_apply_pallas_run"]
+    anns = [(r.load_swap_k, r.store_swap_k) for r in pallas_runs(fz)]
     assert any(lk or sk for lk, sk in anns), "no frame swaps planned"
 
     env = qt.createQuESTEnv()
@@ -371,12 +367,18 @@ def test_folded_production_path_22q():
     circ.hadamard(n - 1)        # grid-bit target: frame B via folded swap
     circ.controlledNot(n - 1, 2)
     fz = circ.fused(max_qubits=5, pallas=True)
-    anns = [(a[1], a[2], a[3]) for f, a, _ in fz._tape
-            if f.__name__ == "_apply_pallas_run"]
-    assert any(lk or sk for _, lk, sk in anns), "plan folded no swaps"
-    from quest_tpu.fusion import _apply_pallas_run  # noqa: F401 (path doc)
+    runs = pallas_runs(fz)
+    assert any(r.load_swap_k or r.store_swap_k for r in runs), \
+        "plan folded no swaps"
     tb = PG.local_qubits(n)
-    assert all(t == tb for t, _, _ in anns), "geometry must match production"
+    assert all(r.tile_bits == tb for r in runs), \
+        "geometry must match production"
+    routes = [fusion._route(shape_register(n, real_dtype()), r)
+              for r in runs]
+    assert all(rt.kind == "local" and rt.reason is None for rt in routes)
+    assert all(rt.fold_load == bool(r.load_swap_k)
+               and rt.fold_store == bool(r.store_swap_k)
+               for rt, r in zip(routes, runs)), "a planned swap did not fold"
 
     amps = fz.as_fn()(ops_init.init_classical(1 << n, real_dtype(), 0))
     ref = circ.as_fn()(ops_init.init_classical(1 << n, real_dtype(), 0))
@@ -440,8 +442,7 @@ def test_folded_plan_agrees_end_to_end():
                     pallas_tile_bits=PG.local_qubits(n, sublanes=4))
     fz = Circuit(n)
     fz._tape = fusion.as_tape(p)
-    anns = [(a[2], a[3]) for f, a, _ in fz._tape
-            if f.__name__ == "_apply_pallas_run"]
+    anns = [(r.load_swap_k, r.store_swap_k) for r in pallas_runs(fz)]
     assert any(lk or sk for lk, sk in anns), "no folded swaps planned"
     mk = lambda: ops_init.init_debug(1 << n, real_dtype())
     assert_amps_close(np.asarray(fz.as_fn()(mk())), np.asarray(circ.as_fn()(mk())))
@@ -507,13 +508,14 @@ def test_sharded_pallas_runs_via_shard_map():
     circ.controlledPhaseShift(n - 1, 0, 0.37)   # sharded control in-kernel
     circ.multiRotateZ(list(range(n)), 0.21)     # parity across shard bits
     fz = circ.fused(max_qubits=5, pallas=True, shard_devices=ndev)
-    runs = [a[0] for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
+    runs = pallas_runs(fz)
     assert runs, "plan produced no PallasRuns"
-    # at least one run is shard-executable end-to-end
+    # a plan built for the shards is shard-executable: every run routes
+    # per shard over the register's own mesh
     shell = qt.Qureg(n, False, qureg.amps, env=None)
-    got_any = any(
-        fusion._shard_map_pallas_run(shell, ops) is not None for ops in runs)
-    assert got_any, "no run took the shard_map path"
+    routes = [fusion._route(shell, r) for r in runs]
+    assert all(rt.kind == "sharded" and rt.mesh.size == ndev
+               and rt.n_exec == n - 2 for rt in routes), routes
 
     fz.run(qureg)
     assert len(qureg.amps.sharding.device_set) == ndev
@@ -576,9 +578,9 @@ def test_sharded_multi_frame_collective_transposes():
         circ.unitary(q, g)
     circ.controlledNot(11, 0)
     fz = circ.fused(max_qubits=5, pallas=True, shard_devices=ndev)
-    runs = [a for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
+    runs = pallas_runs(fz)
     assert runs, "plan produced no PallasRuns"
-    his = {a[4] for a in runs if a[2]}  # load_swap_hi of frame-entering runs
+    his = {r.load_swap_hi for r in runs if r.load_swap_k}  # frame-entering runs
     assert {9, 11} <= his, f"missing grid-block frames: {his}"
 
     env = qt.createQuESTEnv(jax.devices()[:ndev])
@@ -660,8 +662,7 @@ def test_sharded_pallas_inside_jitted_replay():
     circ = Circuit(n)
     _random_layers(circ, n, depth=2)
     fz = circ.fused(max_qubits=5, pallas=True, shard_devices=ndev)
-    runs = [a for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
-    assert runs
+    assert pallas_runs(fz)
 
     fz.run(qureg)  # jitted replay: run() derives the mesh from the register
     assert len(qureg.amps.sharding.device_set) == ndev
@@ -883,8 +884,8 @@ def test_ring_depth_knobs():
     for q in range(n):
         circ.hadamard(q)
     fz = circ.fused(max_qubits=5, pallas=True, ring_depth=4)
-    runs = [a for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
-    assert runs and all(a[6] == 4 for a in runs)
+    runs = pallas_runs(fz)
+    assert runs and all(r.ring_depth == 4 for r in runs)
     # and the stamped depth executes to the same state as the default
     import jax
 

@@ -17,7 +17,7 @@ Contracts under test:
 - hedged dispatch re-issues past the deadline and first-completion-wins
   deterministically (both paths compute the same bits);
 - the QUEST_POOL_REPLICAS / QUEST_HEDGE_MS / QUEST_TENANT_QPS knobs warn
-  once (QT307) on malformed values, like QT205/QT206/QT306;
+  once (QT307) on malformed values, like QT205/QT206;
 - ``Engine.close(drain=True)`` on a quarantined engine resolves queued
   futures promptly with QuESTCancelledError (regression, ISSUE 13).
 """
@@ -319,7 +319,7 @@ def test_hedged_dispatch_winner_determinism():
 
 
 # ---------------------------------------------------------------------------
-# QT307 env knobs (idiom of the QT205/QT206/QT306 tests)
+# QT307 env knobs (idiom of the QT205/QT206 tests)
 # ---------------------------------------------------------------------------
 
 @pytest.fixture

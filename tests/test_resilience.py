@@ -403,7 +403,12 @@ def test_run_segmented_matches_plain_run(tmp_path):
     c.run(ref)
     out = c.run_segmented(ENV, checkpoint_dir=str(tmp_path / "seg"),
                           every_n_items=4)
-    assert np.array_equal(np.asarray(ref.amps), np.asarray(out.amps))
+    # the chain of segment programs against the whole-tape program: two
+    # program granularities, which XLA-CPU contracts differently (~1 ulp,
+    # the segments.py numeric contract); resume-from-snapshot, the SAME
+    # programs both times, is what stays bit-identical (the tests below)
+    np.testing.assert_allclose(np.asarray(out.amps), np.asarray(ref.amps),
+                               rtol=0, atol=4 * np.finfo(out.amps.dtype).eps)
     with pytest.raises(QuESTError, match="QT304"):
         c.run_segmented(ENV, checkpoint_dir=str(tmp_path / "k0"), keep=0)
 

@@ -459,7 +459,7 @@ def test_tapelint_qt005_measurement_in_deferred_window():
     from quest_tpu.analysis import tapelint
     from quest_tpu.sampling.measure import applyMidCollapse
     tb = 9
-    swap = (fusion._apply_frame_swap, (tb, 2, None), {})
+    swap = (fusion._apply_frame_swap, (fusion.FrameSwap(tb, 2),), {})
     tape = [swap, (applyMidCollapse, (0, 0), {}), swap]
     found = tapelint.lint_tape(tape, 6, is_density=True)
     assert any(f.code == "QT005" for f in found)

@@ -3,44 +3,39 @@
 What this suite pins down:
 
 - frame-identity boundaries: ``identity_boundaries`` finds the legal
-  segment seams of a fused plan (starts at 0, ends at len(tape)), and
-  tolerates every tape-codec generation (the pre-round-13
-  ``resilience.segmented`` replay unpacked FrameSwap args as an exact
-  3-tuple and crashed on PR 8's 4-arg comm_pipeline-stamped entries --
-  regression-tested here);
+  segment seams of a fused plan (starts at 0, ends at len(tape)),
+  reading each run and swap off the tape by attribute
+  (``fusion.plan_from_tape``, the one decoder);
 - ``segment_cuts`` greedy coarsest capping: cuts are identity
   boundaries, spans respect ``max_items`` unless a single
   boundary-to-boundary gap is longer, ``max_items < 1`` rejects;
 - the ``seg`` plan stamp: ``Circuit.fused`` stamps every frame-carrying
   item with its segment index, the stamps survive the tape codec
-  roundtrip, pre-round-13 (and pre-round-8) tapes decode ``seg=None``,
-  plancheck re-derives the segmentation and flags corrupted stamps as
-  QT107 (None stamps are skipped -- compat, not an error);
-- the numeric contract of the two execution routes (module docstring of
-  quest_tpu.segments): a fixed segmentation is run-to-run DETERMINISTIC
-  (bit-identical) on every leg; the whole-tape segment program is
-  bit-identical to ``Circuit.compiled()``; on a single device the
-  native-dtype per-item chain (``compiled_segments(max_items=1)``)
-  reproduces item-by-item interpretation bit-for-bit. ACROSS program
-  granularities XLA-CPU contracts fma differently per compiled program
-  (the documented tests/test_sharded_df.py caveat -- on the df route
-  and the CPU mesh even single items embed differently), so those
-  comparisons are asserted at ~ulp allclose, not array_equal; on TPU
-  the Mosaic kernel is opaque to recontraction and the routes coincide;
+  roundtrip, plancheck re-derives the segmentation and flags corrupted
+  stamps as QT107 (an item no planner stamped carries None and is
+  skipped);
+- the numeric contract (module docstring of quest_tpu.segments): a
+  fixed segmentation is run-to-run DETERMINISTIC (bit-identical) on
+  every leg; the whole-tape segment program is bit-identical to
+  ``Circuit.compiled()``, the program ``Circuit.run`` dispatches. ACROSS
+  program granularities XLA-CPU contracts fma differently per compiled
+  program (the documented tests/test_sharded_df.py caveat), so a chain
+  of several programs is held to the whole-tape program at ~ulp
+  allclose, not array_equal; on TPU the Mosaic kernel is opaque to
+  recontraction and the granularities coincide. Both are lowerings of
+  one plan, so each leg is also held to the dense oracle
+  (tests/oracle.py), which shares no code with either;
 - one ``device_dispatch_total{route="segment"}`` per segment program
-  launch, one ``route="item"`` per eagerly interpreted entry, the
-  engine's ``engine_vmap``/``engine_param`` sites, and run_segmented's
-  per-segment accounting;
-- the QUEST_SEGMENT_DISPATCH env knob: warn-once QT306 on malformed
-  values, 0 restores the per-item route, ``force_route`` outranks the
-  env for A/B harnesses;
+  launch, the engine's ``engine_vmap``/``engine_param`` sites, and
+  run_segmented's per-segment accounting;
 - sliced replays journal zero-cost ("segment", lo) markers under the
   explicit scheduler and check_schedule validates them (bad cursor ->
   QT107; mid-layout seam -> QT104).
 """
 
 import contextlib
-import warnings
+import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -56,6 +51,8 @@ from quest_tpu.ops import pallas_gates as PG
 from quest_tpu.ops.pallas_df import DF_SUBLANES
 from quest_tpu.resilience import segmented
 
+from . import oracle
+
 if np.dtype(qt.precision.real_dtype()) != np.dtype("float64"):
     pytest.skip("segments suite needs QUEST_PRECISION=2 (the conftest "
                 "default)", allow_module_level=True)
@@ -67,6 +64,12 @@ ENV1 = qt.createQuESTEnv(jax.devices()[:1])
 # recontraction band (see module docstring), NOT an accuracy tolerance
 ATOL64 = 5e-15
 ATOL32 = 2e-6
+# the df route on XLA-CPU: its compiler duplicates producer expressions
+# and contracts each copy differently, so the error-free transforms are
+# not exact there and a df program holds f32-product accuracy, not the
+# chip's 1e-12 (README "QUEST_PRECISION=2"; chip_smoke.py's df phase and
+# tools/df_verify.py hold the exact budget on hardware)
+ATOL_DF_CPU = 2e-7
 
 
 def _need_mesh(ndev=8):
@@ -103,13 +106,35 @@ def _sharded(n=12):
     return _circuit(n).fused(max_qubits=3, pallas=True, shard_devices=8)
 
 
-def _run_item(circ, env, precision=2, explicit=False):
+def _run_whole(circ, env, precision=2, explicit=False):
+    """``Circuit.run``: the whole tape as one program, what a library
+    caller dispatches."""
     q = qt.createQureg(circ.num_qubits, env, precision_code=precision)
     ctx = qt.explicit_mesh(env.mesh) if explicit \
         else contextlib.nullcontext()
-    with ctx, segments.force_route("item"):
-        segments.run_slice(circ, q)
+    with ctx:
+        circ.run(q)
     return np.asarray(jax.device_get(q.amps))
+
+
+@functools.lru_cache(maxsize=None)
+def _oracle_planes(n=12):
+    """``_circuit(n)`` on |0...0> by the dense oracle's index arithmetic,
+    as the register's (2, 2^n) planes."""
+    h = np.array([[1, 1], [1, -1]]) / np.sqrt(2)
+    x = np.array([[0, 1], [1, 0]])
+    psi = np.zeros(1 << n, dtype=np.complex128)
+    psi[0] = 1.0
+    for q in range(n):
+        psi = oracle.apply_to_statevec_indexed(psi, n, (q,), h)
+    for q in range(n - 1):
+        psi = oracle.apply_to_statevec_indexed(psi, n, (q + 1,), x,
+                                               controls=(q,))
+    for q in range(n):
+        c, s_ = np.cos(0.05 * (q + 1)), np.sin(0.05 * (q + 1))
+        psi = oracle.apply_to_statevec_indexed(psi, n, (q,),
+                                               [[c, -s_], [s_, c]])
+    return np.stack([psi.real, psi.imag])
 
 
 def _run_chain(circ, env, cap=None, precision=2, explicit=False):
@@ -136,21 +161,35 @@ def test_identity_boundaries_cover_fused_plan():
     assert b == sorted(set(b))
 
 
-def test_identity_boundaries_tolerate_extended_codec_args():
-    """Regression: the pre-round-13 boundary replay in
-    resilience.segmented unpacked FrameSwap args as an exact 3-tuple
-    (``tb, k, hi = a``) and raised ValueError on the 4-arg
-    comm_pipeline-stamped entries PR 8 started emitting. The shared
-    ``identity_boundaries`` slice-unpacks, so 3/4/5-arg (and future)
-    codec generations all replay."""
-    tb = 9
-    for extra in ((), (None,), (None, 0)):        # pre-8 / 8-12 / 13+
-        tape = [(fusion._apply_frame_swap, (tb, 2, None) + extra, {}),
-                (fusion._apply_frame_swap, (tb, 2, None) + extra, {})]
-        assert segments.identity_boundaries(tape, 12) == [0, 2]
-        # the resilience checkpoint planner rides the same replay
-        cuts = segmented.segment_plan(tape, 12, 1)
+def test_identity_boundaries_replay_standalone_swaps():
+    """A standalone FrameSwap leaves identity and its twin returns to it,
+    whatever else the swap is stamped with; the resilience checkpoint
+    planner rides the same replay."""
+    for stamps in ({}, {"comm_pipeline": 4}, {"seg": 0,
+                                              "comm_pipeline_dcn": 2}):
+        swap = (fusion._apply_frame_swap,
+                (fusion.FrameSwap(9, 2, **stamps),), {})
+        assert segments.identity_boundaries([swap, swap], 12) == [0, 2]
+        cuts = segmented.segment_plan([swap, swap], 12, 1)
         assert cuts[0] == 0 and cuts[-1] == 2
+
+
+def test_oracle_indexed_application_matches_full_operator():
+    """The matrix-free oracle this suite holds 12-qubit states to is the
+    dense oracle's own arithmetic: equal to ``full_operator @ state`` at
+    a size where that can be built."""
+    rng = np.random.RandomState(7)
+    n = 5
+    psi = oracle.random_statevec(n, rng)
+    for targets, controls, states in (((2,), (), None),
+                                      ((0, 3), (1,), None),
+                                      ((4, 1), (0, 2), [0, 1])):
+        u = oracle.random_unitary(len(targets), rng)
+        np.testing.assert_allclose(
+            oracle.apply_to_statevec_indexed(psi, n, targets, u, controls,
+                                             states),
+            oracle.apply_to_statevec(psi, n, targets, u, controls, states),
+            rtol=0, atol=1e-15)
 
 
 def test_segment_cuts_greedy_coarsest_and_capped():
@@ -173,7 +212,7 @@ def test_segment_cuts_greedy_coarsest_and_capped():
 
 
 # ---------------------------------------------------------------------------
-# plan stamps: codec roundtrip, old tapes, plancheck QT107
+# plan stamps: codec roundtrip, plancheck QT107
 # ---------------------------------------------------------------------------
 
 def _frame_items(p):
@@ -191,24 +230,6 @@ def test_fused_stamps_segments_and_roundtrips():
         "segment indices are monotone in plan order"
     p2 = fusion.plan_from_tape(fusion.as_tape(p))
     assert [i.seg for i in _frame_items(p2)] == [i.seg for i in items]
-
-
-def test_old_tapes_decode_seg_none():
-    _need_mesh()
-    p = fusion.plan_from_tape(tuple(_sharded()._tape))
-    # pre-round-13 (8-arg PallasRun / 4-arg FrameSwap) and pre-round-8
-    # (7-arg / 3-arg) tapes must decode seg=None -- never a crash, never
-    # a fabricated segment index
-    for run_n, swap_n in ((8, 4), (7, 3)):
-        old = []
-        for fn, a, kw in fusion.as_tape(p):
-            if getattr(fn, "__name__", "") == "_apply_pallas_run":
-                a = a[:run_n]
-            elif getattr(fn, "__name__", "") == "_apply_frame_swap":
-                a = a[:swap_n]
-            old.append((fn, a, kw))
-        p2 = fusion.plan_from_tape(old)
-        assert all(i.seg is None for i in _frame_items(p2))
 
 
 def _plan_multi():
@@ -229,14 +250,18 @@ def test_plancheck_flags_corrupt_segment_stamp():
     plan = _plan_multi()
     items = _frame_items(plan)
     assert items
-    items[len(items) // 2].seg = (items[len(items) // 2].seg or 0) + 7
+    mid = plan.items.index(items[len(items) // 2])
+    plan.items[mid] = dataclasses.replace(
+        plan.items[mid], seg=(plan.items[mid].seg or 0) + 7)
     assert "QT107" in _codes(A.error_findings(A.check_plan(plan, 12)))
 
 
 def test_plancheck_skips_none_stamps():
     plan = _plan_multi()
-    for i in _frame_items(plan):
-        i.seg = None                     # a pre-round-13 tape, decoded
+    plan.items[:] = [                    # items no planner stamped
+        dataclasses.replace(i, seg=None)
+        if isinstance(i, (fusion.PallasRun, fusion.FrameSwap)) else i
+        for i in plan.items]
     findings = A.check_plan(plan, 12)
     assert "QT107" not in _codes(findings)
 
@@ -248,48 +273,58 @@ def test_plancheck_skips_none_stamps():
 def test_f32_segment_chain_contract():
     c = _multi_item(dtype=np.float32)
     assert len(c._tape) > 1
-    a = _run_item(c, ENV1, precision=1)
-    assert np.array_equal(a, _run_item(c, ENV1, precision=1)), \
-        "the item route is deterministic"
+    a = _run_whole(c, ENV1, precision=1)
+    assert np.array_equal(a, _run_whole(c, ENV1, precision=1)), \
+        "the whole-tape program is deterministic"
+    np.testing.assert_allclose(a, _oracle_planes(), rtol=0, atol=ATOL32)
     c1 = _run_chain(c, ENV1, cap=1, precision=1)
     assert np.array_equal(c1, _run_chain(c, ENV1, cap=1, precision=1)), \
         "a fixed segmentation is deterministic"
     np.testing.assert_allclose(c1, a, rtol=0, atol=ATOL32)
+    np.testing.assert_allclose(c1, _oracle_planes(), rtol=0, atol=ATOL32)
     w = _run_chain(c, ENV1, cap=None, precision=1)
-    assert np.array_equal(w, _run_chain(c, ENV1, cap=None, precision=1))
-    np.testing.assert_allclose(w, a, rtol=0, atol=ATOL32)
+    assert np.array_equal(w, a), \
+        "the whole-tape segment program IS Circuit.run's program"
 
 
 def test_f64_native_segment_chain_contract():
     c = _multi_item(dtype=np.float64)
-    a = _run_item(c, ENV1)
+    a = _run_whole(c, ENV1)
+    np.testing.assert_allclose(a, _oracle_planes(), rtol=0, atol=ATOL64)
     c1 = _run_chain(c, ENV1, cap=1)
     assert np.array_equal(c1, _run_chain(c, ENV1, cap=1))
     np.testing.assert_allclose(c1, a, rtol=0, atol=ATOL64)
+    np.testing.assert_allclose(c1, _oracle_planes(), rtol=0, atol=ATOL64)
     # whole-tape segment program vs Circuit.compiled(): the SAME program
     # granularity, so bit-identity is exact even on XLA-CPU
     w = _run_chain(c, ENV1, cap=None)
     q = qt.createQureg(12, ENV1, precision_code=2)
     q.put(c.compiled()(q.amps))
     assert np.array_equal(w, np.asarray(jax.device_get(q.amps)))
-    np.testing.assert_allclose(w, a, rtol=0, atol=ATOL64)
+    assert np.array_equal(w, a)
 
 
 def test_df_route_segment_chain_contract(monkeypatch):
     """The df/f64 route. Compensated two-sum arithmetic is the MOST
-    sensitive case for cross-program fma recontraction (even a 1-item
-    tape embeds differently eager vs in-program on XLA-CPU), so the
-    exactness claims here are determinism and same-granularity
-    identity; route agreement is ~1 ulp (test_sharded_df caveat)."""
+    sensitive case for cross-program fma recontraction, and XLA-CPU does
+    not keep it exact at all (ATOL_DF_CPU), so the exactness claims here
+    are determinism and same-granularity identity; the chain and the
+    whole-tape program agree, and sit from the oracle, within what df
+    holds on this backend. The chip's 1e-12 is chip_smoke.py's df
+    phase."""
     monkeypatch.setenv("QUEST_PALLAS_DF", "1")
     c = _multi_item(dtype=np.float64, sublanes=DF_SUBLANES)
-    a = _run_item(c, ENV1)
+    a = _run_whole(c, ENV1)
+    np.testing.assert_allclose(a, _oracle_planes(), rtol=0,
+                               atol=ATOL_DF_CPU)
     w = _run_chain(c, ENV1, cap=None)
     assert np.array_equal(w, _run_chain(c, ENV1, cap=None))
-    np.testing.assert_allclose(w, a, rtol=0, atol=ATOL64)
+    assert np.array_equal(w, a)
     c1 = _run_chain(c, ENV1, cap=1)
     assert np.array_equal(c1, _run_chain(c, ENV1, cap=1))
-    np.testing.assert_allclose(c1, a, rtol=0, atol=ATOL64)
+    np.testing.assert_allclose(c1, a, rtol=0, atol=ATOL_DF_CPU)
+    np.testing.assert_allclose(c1, _oracle_planes(), rtol=0,
+                               atol=ATOL_DF_CPU)
 
 
 @pytest.mark.parametrize("explicit", [False, True],
@@ -298,11 +333,14 @@ def test_mesh8_segment_chain_contract(explicit):
     _need_mesh()
     fz = _sharded()
     assert len(fz._tape) > 1
-    a = _run_item(fz, ENV8, explicit=explicit)
+    a = _run_whole(fz, ENV8, explicit=explicit)
+    np.testing.assert_allclose(a, _oracle_planes(), rtol=0, atol=ATOL64)
     w = _run_chain(fz, ENV8, cap=None, explicit=explicit)
     assert np.array_equal(
         w, _run_chain(fz, ENV8, cap=None, explicit=explicit))
     np.testing.assert_allclose(w, a, rtol=0, atol=ATOL64)
+    c1 = _run_chain(fz, ENV8, cap=1, explicit=explicit)
+    np.testing.assert_allclose(c1, a, rtol=0, atol=ATOL64)
 
 
 # ---------------------------------------------------------------------------
@@ -313,24 +351,9 @@ def test_run_slice_single_dispatch_per_segment():
     c = _multi_item()
     q = qt.createQureg(12, ENV1, precision_code=2)
     telemetry.reset()
-    with segments.force_route("segment"):
-        segments.run_slice(c, q)
+    segments.run_slice(c, q)
     assert telemetry.counter_value(
         "device_dispatch_total", route="segment") == 1.0
-    assert telemetry.counter_value(
-        "device_dispatch_total", route="item") == 0.0
-
-
-def test_item_route_counts_every_entry():
-    c = _multi_item()
-    q = qt.createQureg(12, ENV1, precision_code=2)
-    telemetry.reset()
-    with segments.force_route("item"):
-        segments.run_slice(c, q)
-    assert telemetry.counter_value(
-        "device_dispatch_total", route="item") == len(c._tape)
-    assert telemetry.counter_value(
-        "device_dispatch_total", route="segment") == 0.0
 
 
 def test_chain_counts_num_segments():
@@ -359,15 +382,13 @@ def test_run_segmented_counts_segment_dispatches(tmp_path):
     c = _multi_item()
     cuts = segmented.segment_plan(c._tape, 12, 1)
     telemetry.reset()
-    with segments.force_route("segment"):
-        out = c.run_segmented(ENV1, checkpoint_dir=str(tmp_path / "seg"),
-                              every_n_items=1)
+    out = c.run_segmented(ENV1, checkpoint_dir=str(tmp_path / "seg"),
+                          every_n_items=1)
     assert telemetry.counter_value(
         "device_dispatch_total", route="segment") == len(cuts) - 1
-    ref = qt.createQureg(12, ENV1, precision_code=2)
-    with segments.force_route("item"):
-        segments.run_slice(c, ref)
-    np.testing.assert_allclose(np.asarray(out.amps), np.asarray(ref.amps),
+    np.testing.assert_allclose(np.asarray(out.amps), _run_whole(c, ENV1),
+                               rtol=0, atol=ATOL64)
+    np.testing.assert_allclose(np.asarray(out.amps), _oracle_planes(),
                                rtol=0, atol=ATOL64)
 
 
@@ -395,54 +416,6 @@ def test_engine_dispatch_counters():
         [f.result() for f in eng.submit_many([None] * 4)]
         assert telemetry.counter_value(
             "device_dispatch_total", route="engine_param") > p0
-
-
-# ---------------------------------------------------------------------------
-# QUEST_SEGMENT_DISPATCH env knob + force_route
-# ---------------------------------------------------------------------------
-
-@pytest.fixture
-def seg_env(monkeypatch):
-    monkeypatch.setattr(segments, "_SEG_ENV_WARNED", set())
-    return monkeypatch
-
-
-def test_seg_env_non_integer_warns_once_and_defaults(seg_env):
-    seg_env.setenv(segments._SEG_ENV, "turbo")
-    telemetry.reset()
-    with pytest.warns(RuntimeWarning, match="QT306"):
-        assert segments.segment_dispatch_default() == 1
-    assert telemetry.counter_value(
-        "analysis_findings_total", code="QT306", severity="warning") == 1.0
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")   # second call must stay silent
-        assert segments.segment_dispatch_default() == 1
-
-
-def test_seg_env_zero_restores_item_route(seg_env):
-    seg_env.setenv(segments._SEG_ENV, "0")
-    assert segments.segment_dispatch_default() == 0
-    assert not segments.segment_dispatch_enabled()
-    c = _multi_item()
-    q = qt.createQureg(12, ENV1, precision_code=2)
-    telemetry.reset()
-    segments.run_slice(c, q)
-    assert telemetry.counter_value(
-        "device_dispatch_total", route="item") == len(c._tape)
-    assert telemetry.counter_value(
-        "device_dispatch_total", route="segment") == 0.0
-
-
-def test_force_route_overrides_env(seg_env):
-    seg_env.setenv(segments._SEG_ENV, "0")
-    with segments.force_route("segment"):
-        assert segments.segment_dispatch_enabled()
-        with segments.force_route(None):
-            assert not segments.segment_dispatch_enabled()
-    assert not segments.segment_dispatch_enabled()
-    with pytest.raises(ValueError, match="route"):
-        with segments.force_route("warp"):
-            pass
 
 
 def test_replay_slice_rejects_lifted_params():

@@ -130,10 +130,10 @@ def test_a_relabeling_is_collective_only_where_it_reaches_a_sharded_qubit(
     qt.initDebugState(q)
     before = np.asarray(q.amps)
     telemetry.reset()
-    fusion._apply_frame_swap(q, 6, 3, hi)
+    fusion._apply_frame_swap(q, fusion.FrameSwap(6, 3, hi))
     assert telemetry.counter_value("pallas_pass_total",
                                    kind="frame_swap") == 1
     assert (telemetry.counter_total("fusion_collective_swaps_total")
             == collective)
-    fusion._apply_frame_swap(q, 6, 3, hi)       # its own inverse
+    fusion._apply_frame_swap(q, fusion.FrameSwap(6, 3, hi))   # its own inverse
     np.testing.assert_array_equal(np.asarray(q.amps), before)

@@ -44,6 +44,8 @@ from quest_tpu.ops import pallas_gates as PG
 from quest_tpu.ops import pallas_df as DF
 from quest_tpu.parallel.scheduler import comm_chunks, plan_circuit
 
+from .helpers import pallas_runs
+
 if np.dtype(qt.precision.real_dtype()) != np.dtype("float64"):
     pytest.skip("sharded-df suite needs QUEST_PRECISION=2 (the conftest "
                 "default)", allow_module_level=True)
@@ -214,8 +216,8 @@ def test_collective_swap_stays_explicit_and_counted(df_route):
         circ.unitary(q, g)
     fz = circ.fused(max_qubits=5, pallas=True, shard_devices=ndev,
                     dtype=np.float64)
-    runs = [a for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
-    assert any(a[2] or a[3] for a in runs), "plan folded no frame swaps"
+    assert any(r.load_swap_k or r.store_swap_k for r in pallas_runs(fz)), \
+        "plan folded no frame swaps"
     qureg = qt.createQureg(n, env)
     qt.initPlusState(qureg)
     telemetry.reset()
@@ -338,8 +340,8 @@ def test_sharded_df_density_kraus_parity(df_route):
                                     np.sqrt(p2) * xx])
     fz = circ.fused(max_qubits=4, pallas=True, shard_devices=ndev,
                     dtype=np.float64)
-    runs = [a for f, a, _ in fz._tape if f.__name__ == "_apply_pallas_run"]
-    assert any(op[0].startswith("kraus") for a in runs for op in a[0]), \
+    assert any(op[0].startswith("kraus")
+               for r in pallas_runs(fz) for op in r.ops), \
         "no kraus kernel ops in the sharded df plan"
     rho = qt.createDensityQureg(n, env)
     qt.initPlusState(rho)
@@ -375,7 +377,8 @@ def test_df_tile_mismatch_counts_on_sharded_plans(df_route):
     qureg = qt.createQureg(n, env)
     qt.initClassicalState(qureg, 0)
     telemetry.reset()
-    fusion._apply_pallas_run(qureg, ops, lq_f32)  # must not raise
+    fusion._apply_pallas_run(
+        qureg, fusion.PallasRun(ops, lq_f32))  # must not raise
     assert telemetry.counter_value("engine_fallback_total",
                                    reason="df_tile_mismatch") == 1
     amps = np.asarray(qureg.amps)

@@ -242,7 +242,8 @@ def test_df_tile_mismatch_increments_fallback_not_raises(monkeypatch):
     X = np.array([[0, 1], [1, 0]], dtype=complex)
     ops = (("matrix", target, (), (), PG.HashableMatrix(X)),)
     telemetry.reset()
-    fusion._apply_pallas_run(qureg, ops, lq_f32)  # must not raise
+    fusion._apply_pallas_run(
+        qureg, fusion.PallasRun(ops, lq_f32))  # must not raise
     assert telemetry.counter_value("engine_fallback_total",
                                    reason="df_tile_mismatch") == 1
     amps = np.asarray(qureg.amps)

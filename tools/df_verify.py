@@ -92,8 +92,8 @@ def main():
     # weak #4), each chunk's Mosaic compile time recorded by telemetry
     shell = Qureg(n, False, amps64, env=None)
     with telemetry.span("df_verify.run", n=n, ops=len(ops)):
-        fusion._apply_pallas_run(shell, ops,
-                                 PG.local_qubits(n, DF_SUBLANES))
+        fusion._apply_pallas_run(shell, fusion.PallasRun(
+            ops, PG.local_qubits(n, DF_SUBLANES)))
     out = np.asarray(shell.amps)
     for k, h in telemetry.snapshot("mosaic_compile_seconds")[
             "histograms"].items():

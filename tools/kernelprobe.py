@@ -116,7 +116,7 @@ def dispatch_sweep(n):
     """Items-per-segment sweep (ISSUE 12 operating point): one fused
     Clifford+T circuit executed as segment-program chains capped at
     {1, 2, 4, 8, 16} items per program plus the uncapped whole-tape
-    program and the per-item interpreter rung, each timed end-to-end.
+    program, each timed end-to-end.
     The fixed host dispatch+sync tax amortizes by the mean
     items-per-segment, so the curve flattens once per-segment device
     work dominates -- the per-cap table regenerates from
@@ -124,7 +124,6 @@ def dispatch_sweep(n):
     from bench import build_circuit
 
     import quest_tpu as qt
-    from quest_tpu import segments
 
     env = qt.createQuESTEnv(jax.devices()[:1])
     fused = build_circuit(n, 4).fused(max_qubits=5, pallas=True)
@@ -147,9 +146,6 @@ def dispatch_sweep(n):
         print(f"dispatch {label:14s} segments={nseg:3d} "
               f"{best * 1e3:8.3f} ms")
 
-    with segments.force_route("item"):
-        time_leg(lambda q: segments.run_slice(fused, q), "item-by-item",
-                 items)
     for cap in (1, 2, 4, 8, 16, None):
         fn = fused.compiled_segments(max_items=cap)
         time_leg(lambda q, _f=fn: q.put(_f(q.amps)),

@@ -51,8 +51,9 @@ def main():
     from __graft_entry__ import _random_layers
     from quest_tpu import fusion, telemetry
     from quest_tpu.circuits import Circuit
-    from quest_tpu.ops.pallas_gates import (_fold_zone_ops, fused_local_run,
-                                            local_qubits, swap_bit_blocks)
+    from quest_tpu.ops.pallas_gates import (_fold_zone_ops, local_qubits,
+                                            swap_bit_blocks)
+    from quest_tpu.registers import Qureg
 
     circ = Circuit(n)
     _random_layers(circ, n, 8)
@@ -64,34 +65,26 @@ def main():
     total = 0.0
     for i, item in enumerate(p.items):
         if isinstance(item, fusion.PallasRun):
-            from quest_tpu.ops.pallas_gates import LANE_BITS
             folded = _fold_zone_ops(item.ops, tb)
             comp = Counter(o[0] for o in folded)
-            lk, sk = item.load_swap_k, item.store_swap_k
-            lh, sh = item.load_swap_hi, item.store_swap_hi
-            # same foldability guard as fusion._apply_pallas_run: profile
-            # what production actually runs (explicit swaps otherwise)
-            if max(lk, sk) and tb - LANE_BITS - max(lk, sk) < 3:
-                def run(x, ops=item.ops, lk=lk, sk=sk, lh=lh, sh=sh):
-                    if lk:
-                        x = swap_bit_blocks(x, n=n, lo1=tb - lk,
-                                            lo2=tb if lh is None else lh, k=lk)
-                    x = fused_local_run(x, n=n, ops=ops)
-                    if sk:
-                        x = swap_bit_blocks(x, n=n, lo1=tb - sk,
-                                            lo2=tb if sh is None else sh, k=sk)
-                    return x
-            else:
-                def run(x, ops=item.ops, lk=lk, sk=sk, lh=lh, sh=sh):
-                    return fused_local_run(x, n=n, ops=ops,
-                                           load_swap_k=lk, store_swap_k=sk,
-                                           load_swap_hi=lh, store_swap_hi=sh)
+            route = fusion._route(Qureg(n, False, amps, env=None), item)
+
+            # profile what production actually runs: the run's own tape
+            # entry, which folds the swaps fusion._route says it can and
+            # runs the others as explicit passes
+            def run(x, item=item):
+                shell = Qureg(n, False, x, env=None)
+                fusion._apply_pallas_run(shell, item)
+                return shell.amps
+
             with telemetry.span("runprof.item", index=i, kind="run"):
                 dt, amps = timeit(run, amps)
             telemetry.set_gauge("runprof.item_ms", dt * 1e3, index=i,
                                 kind="run")
             print(f"[{i:2d}] run  {dt*1e3:7.3f} ms  {len(item.ops):3d} ops "
-                  f"ld={lk} st={sk} -> {dict(comp)}")
+                  f"ld={item.load_swap_k}{'f' if route.fold_load else ''} "
+                  f"st={item.store_swap_k}{'f' if route.fold_store else ''}"
+                  f" -> {dict(comp)}")
         elif isinstance(item, fusion.FrameSwap):
             with telemetry.span("runprof.item", index=i, kind="swap"):
                 dt, amps = timeit(
