@@ -4,17 +4,28 @@ The hot loop of a state-vector simulator is "stream 2^n amplitudes through
 an update rule". XLA's GEMM formulation (ops.apply) pays one full HBM
 round-trip per fused block; this kernel applies an arbitrarily long run of
 single-qubit matrices, controlled gates, and parity phases in a single
-read+write of the state: each grid program pulls a (2, S, 128) planar tile
-into VMEM, applies every gate of the run in-register, and writes the tile
-back. The reference's analogous hot loops are one kernel launch per gate
-(statevec_compactUnitaryLocal, QuEST_cpu.c:1682-1739; CUDA variant
-QuEST_gpu.cu:492-554) -- fusing the run is pure TPU-side gain, the same
-bandwidth argument as the dense-fusion layer (quest_tpu/fusion.py) taken to
-its limit for the 1-qubit-dominated parts of a circuit.
+read+write of the state: each grid program pulls a tile of S rows of 128
+lanes from each plane (re, im) into VMEM, applies every gate of the run
+in-register, and writes the tile back. The reference's analogous hot loops
+are one kernel launch per gate (statevec_compactUnitaryLocal,
+QuEST_cpu.c:1682-1739; CUDA variant QuEST_gpu.cu:492-554) -- fusing the run
+is pure TPU-side gain, the same bandwidth argument as the dense-fusion layer
+(quest_tpu/fusion.py) taken to its limit for the 1-qubit-dominated parts of
+a circuit.
 
 Geometry: the flat amplitude index is split (grid, sublane, lane) =
-(i >> (7+log2 S), (i >> 7) & (S-1), i & 127). A gate on qubit q pairs
-amplitude i with i ^ 2^q:
+(i >> (7+log2 S), (i >> 7) & (S-1), i & 127). The PLANE index (re / im;
+four planes in the double-float layout) is the LOWEST row bit of what the
+kernels address: they take the (P, 2^n) register as the row-interleaved
+(rows * P, 128) array, row r * P + p = row r of plane p (_rows_view), and
+read plane p of a block with a sublane-strided slice (_plane_rows). That is
+where the register already lies: the TPU compiler tiles a (P, N) f32 array
+T(P,128), which is byte for byte the interleaved array under T(8,128), so
+XLA compiles the view to a bitcast -- the plane-major (P, rows, 128) view
+cost a relayout copy of the whole state into a program's first kernel and
+another out of its last (PR 34). The ops below never see it: they receive
+and return (S, 128) planes. A gate on qubit q pairs amplitude i with
+i ^ 2^q:
 
 - q < 7 (lane bits): partner = two pltpu.rolls along the lane axis,
   selected per element by bit q of the lane index -- a VPU permute.
@@ -736,6 +747,9 @@ def _make_kernel(ops, s_bits, tile_bits, dtype, local_n=None,
     with zero communication -- the Pallas analogue of the scheduler's
     rank-bit controls (parallel/exchange.py).
 
+    A block is (P * s, 128) rows of the row-interleaved register
+    (_rows_view): plane i is its rows i, i + P, ... (_plane_rows).
+
     ``load_swap``/``store_swap`` = (dk, s_low) fold a frame-swap transpose
     (swap_bit_blocks of the top-k sublane block with a k-bit grid block)
     into this pass: the input block arrives frame-permuted (gathered by the
@@ -750,17 +764,10 @@ def _make_kernel(ops, s_bits, tile_bits, dtype, local_n=None,
     def kernel(x_ref, hi_ref, *refs):
         w_refs = refs[:-1]
         o_ref = refs[-1]
-        if load_swap is not None:
-            # (P, 1, dk, 1, 1, s_low, 128) block: axis 2 is the (old)
-            # grid-bit block, already sitting where the new frame's high
-            # sublane bits belong -- collapsing (dk, s_low) into the sublane
-            # axis IS the bit-block swap, and is layout-free when s_low
-            # fills >= 1 sublane tile (the callers guarantee s_low >= 8)
-            dk, s_low = load_swap
-            planes = [x_ref[i, 0, :, 0, 0].reshape(dk * s_low, _LANES)
-                      for i in range(P)]
-        else:
-            planes = [x_ref[i] for i in range(P)]
+        # a swap block is (1, dk, 1, 1, P*s_low, 128): see _swap_spec
+        planes = _load_planes(
+            x_ref if load_swap is None else x_ref.at[0, :, 0, 0], P,
+            load_swap)
 
         def gbit(q):
             if local_n is not None and q >= local_n:
@@ -779,13 +786,8 @@ def _make_kernel(ops, s_bits, tile_bits, dtype, local_n=None,
                                get_w=lambda i: w_refs[i][:])
             planes = [xr, xi]
 
-        if store_swap is not None:
-            dk, s_low = store_swap
-            for i in range(P):
-                o_ref[i, 0, :, 0, 0] = planes[i].reshape(dk, s_low, _LANES)
-        else:
-            for i in range(P):
-                o_ref[i] = planes[i]
+        _store_planes(o_ref if store_swap is None else o_ref.at[0, :, 0, 0],
+                      planes, store_swap)
 
     return kernel
 
@@ -810,10 +812,17 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
     fused_local_run / QUEST_PALLAS_RING); VMEM cost is linear in depth
     (2 * ring tile buffers), so the caller derates depth on op-heavy runs.
 
+    The operand is the row-interleaved register (_rows_view) cut into
+    chunks: a chunk is ONE contiguous (P * s, 128) piece of HBM holding its
+    P planes row by row, so a ring slot is that piece and plane i its rows
+    i, i + P, ... (_plane_rows: sublane-strided loads, strided stores on the
+    way out).
+
     ``load_swap``/``store_swap`` = (dk, s_low, gm_sz) fold the frame-swap
-    relabeling into the chunk DMAs: the operand arrives as the 7-D
+    relabeling into the chunk DMAs: the operand arrives as the 6-D
     bit-block-swap view (_swap_view) and each chunk load/store is one
-    strided descriptor gathering/scattering the dk sub-blocks.
+    strided descriptor gathering/scattering the dk sub-blocks, each a
+    contiguous (P * s_low, 128) piece.
 
     ``hi_ref`` is the SMEM shard-index scalar (as _make_kernel's): when
     ``local_n`` is set the kernel runs per-device inside shard_map and
@@ -861,20 +870,20 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
                 slot, c = _i32(slot), _i32(c)
                 if load_swap is None:
                     return pltpu.make_async_copy(
-                        x_hbm.at[:, c], ins.at[slot], rsem.at[slot])
+                        x_hbm.at[c], ins.at[slot], rsem.at[slot])
                 hi2, gm, dnew = chunk_coords(load_swap, c)
                 return pltpu.make_async_copy(
-                    x_hbm.at[:, hi2, :, gm, dnew], ins.at[slot],
+                    x_hbm.at[hi2, :, gm, dnew], ins.at[slot],
                     rsem.at[slot])
 
             def store_dma(slot, c):
                 slot, c = _i32(slot), _i32(c)
                 if store_swap is None:
                     return pltpu.make_async_copy(
-                        outs.at[slot], o_hbm.at[:, c], wsem.at[slot])
+                        outs.at[slot], o_hbm.at[c], wsem.at[slot])
                 hi2, gm, dnew = chunk_coords(store_swap, c)
                 return pltpu.make_async_copy(
-                    outs.at[slot], o_hbm.at[:, hi2, :, gm, dnew],
+                    outs.at[slot], o_hbm.at[hi2, :, gm, dnew],
                     wsem.at[slot])
 
             # prologue: fill all but one ring slot, so the steady-state
@@ -889,13 +898,6 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
                     return (c >> (q - tile_bits)) & 1
                 return gbit
 
-            def load_planes(slot):
-                if load_swap is not None:
-                    dk, s_low, _ = load_swap
-                    return [ins[slot, i].reshape(dk * s_low, _LANES)
-                            for i in range(P)]
-                return [ins[slot, i] for i in range(P)]
-
             def compute(planes, gbit):
                 if df:
                     from .pallas_df import _ops_body_df
@@ -909,15 +911,6 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
                                    dtype=dtype, gbit=gbit,
                                    get_w=lambda i: w_refs[i][:])
                 return [xr, xi]
-
-            def store_planes(slot, planes):
-                if store_swap is not None:
-                    dk, s_low, _ = store_swap
-                    for i in range(P):
-                        outs[slot, i] = planes[i].reshape(dk, s_low, _LANES)
-                else:
-                    for i in range(P):
-                        outs[slot, i] = planes[i]
 
             def loop(c, carry):
                 # np.int32 literals: a bare python int materialises as an
@@ -935,7 +928,8 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
                     load_dma(nxt, ahead).start()
 
                 load_dma(slot, c).wait()
-                planes = compute(load_planes(slot), gbit_for(c))
+                planes = compute(_load_planes(ins.at[slot], P, load_swap),
+                                 gbit_for(c))
 
                 @pl.when(c >= ring_i)
                 def _():
@@ -943,7 +937,7 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
                     # drain before the slot's output buffer is overwritten
                     store_dma(slot, c - ring_i).wait()
 
-                store_planes(slot, planes)
+                _store_planes(outs.at[slot], planes, store_swap)
                 store_dma(slot, c).start()
                 return carry
 
@@ -963,16 +957,13 @@ def _make_dma_kernel(ops, s: int, tile_bits: int, dtype,
             for c in range(max(0, nchunks - ring), nchunks):
                 store_dma(c % ring, c).wait()
 
-        if load_swap is not None:
-            dk, s_low, _ = load_swap
-            in_shape = (P, dk, s_low, _LANES)
-        else:
-            in_shape = (P, s, _LANES)
-        if store_swap is not None:
-            dk, s_low, _ = store_swap
-            out_shape = (P, dk, s_low, _LANES)
-        else:
-            out_shape = (P, s, _LANES)
+        def slot_shape(swap):
+            if swap is None:
+                return (P * s, _LANES)
+            dk, s_low, _ = swap
+            return (dk, P * s_low, _LANES)
+
+        in_shape, out_shape = slot_shape(load_swap), slot_shape(store_swap)
         pl.run_scoped(
             body,
             ins=pltpu.VMEM((ring,) + in_shape, dtype),
@@ -1156,27 +1147,84 @@ def _named_jit(impl, name: str, static_argnames: tuple):
                    donate_argnums=(0,))
 
 
+def _rows_view(amps):
+    """The (P, 2^n) register as the kernels read it: (rows * P, 128), row
+    ``r * P + i`` = row ``r`` of plane ``i``. Not a relayout: the TPU
+    compiler tiles a (P, N) f32 array T(P,128), whose bytes ARE this array
+    under T(8,128), and compiles the view (and ``_planes_view``, its
+    inverse) to a bitcast -- where the plane-major (P, rows, 128) view cost
+    a copy of the whole state on the way into a program's first kernel and
+    another out of its last."""
+    P = amps.shape[0]
+    rows = amps.shape[-1] >> LANE_BITS
+    return (amps.reshape(P, rows, _LANES).transpose(1, 0, 2)
+            .reshape(rows * P, _LANES))
+
+
+def _planes_view(x, P: int):
+    """Inverse of ``_rows_view``: any row-interleaved view back to (P, N)."""
+    return x.reshape(-1, P, _LANES).transpose(1, 0, 2).reshape(P, -1)
+
+
+def _plane_rows(i: int, rows: int, P: int):
+    """The ``rows`` rows of plane ``i`` in a row-interleaved block: a
+    sublane-strided slice of a VMEM ref."""
+    return pl.ds(i, rows, stride=P)
+
+
+def _load_planes(ref, P: int, swap=None):
+    """The P (S, 128) planes of a row-interleaved VMEM block: ``ref`` is
+    (P * S, 128), or under a folded swap (``swap`` = (dk, s_low, ...)) the
+    dk gathered row-chunks (dk, P * s_low, 128). There axis 0 is the (old)
+    grid-bit block, already sitting where the new frame's high sublane
+    bits belong -- collapsing (dk, s_low) into the sublane axis IS the
+    bit-block swap, and is layout-free when s_low fills >= 1 sublane tile
+    (the callers guarantee s_low >= 8)."""
+    if swap is None:
+        return [ref[_plane_rows(i, ref.shape[0] // P, P), :]
+                for i in range(P)]
+    dk, s_low = swap[:2]
+    return [ref[:, _plane_rows(i, s_low, P), :].reshape(dk * s_low, _LANES)
+            for i in range(P)]
+
+
+def _store_planes(ref, planes, swap=None):
+    """``_load_planes``' inverse: strided stores of the planes into ``ref``."""
+    P = len(planes)
+    for i, plane in enumerate(planes):
+        if swap is None:
+            ref[_plane_rows(i, ref.shape[0] // P, P), :] = plane
+        else:
+            dk, s_low = swap[:2]
+            ref[:, _plane_rows(i, s_low, P), :] = \
+                plane.reshape(dk, s_low, _LANES)
+
+
 def _swap_view(x, rows: int, s: int, lo2_rel: int, k: int):
-    """(P, rows, 128) -> the 7-D bit-block-swap view
-    (P, high, dg, gmid, ds, s_low, 128): ``dg`` is the k-bit grid block at
+    """(rows * P, 128) -> the 6-D bit-block-swap view
+    (high, dg, gmid, ds, P * s_low, 128): ``dg`` is the k-bit grid block at
     row bits [lo2_rel, lo2_rel+k), ``ds`` the top-k sublane block at
     [s_bits-k, s_bits), ``gmid`` the grid bits between them. Exchanging dg
     and ds relabels amplitudes exactly like swap_bit_blocks(tb-k, lo2, k)
-    -- lo2 may be ANY grid-bit offset, not just the tile boundary. P = 2
-    planar planes (re, im), or 4 in the double-float layout."""
+    -- lo2 may be ANY grid-bit offset, not just the tile boundary. The plane
+    index (P = 2 planar planes re, im, or 4 in the double-float layout) is
+    the LOWEST row bit (_rows_view), so it rides inside the s_low axis and
+    every (P * s_low, 128) piece is contiguous in HBM."""
     s_bits = s.bit_length() - 1
     dk = 1 << k
     gmid = 1 << (lo2_rel - s_bits)
     high = rows // (dk * gmid * (s >> k) * dk)
-    return x.reshape(x.shape[0], high, dk, gmid, dk, s >> k, _LANES)
+    P = x.shape[0] // rows
+    return x.reshape(high, dk, gmid, dk, P * (s >> k), _LANES)
 
 
 def _swap_spec(s: int, lo2_rel: int, k: int, planes: int = 2):
     """BlockSpec gathering/scattering one swap-permuted tile per program:
     for new grid index i, all dk positions of the old grid block, at the
     old-sublane-block position encoded in i's [lo2_rel - s_bits) bits --
-    dk strided (s_low, 128) row-chunks whose concatenation IS the tile in
-    the new frame."""
+    dk strided (planes * s_low, 128) row-chunks, each holding its planes
+    row-interleaved, whose per-plane concatenation IS the tile in the new
+    frame."""
     s_bits = s.bit_length() - 1
     dk = 1 << k
     gm_sz = 1 << (lo2_rel - s_bits)
@@ -1188,9 +1236,9 @@ def _swap_spec(s: int, lo2_rel: int, k: int, planes: int = 2):
         z = np.int32(0)
         gm = i % np.int32(gm_sz)
         rest = i // np.int32(gm_sz)
-        return (z, rest // np.int32(dk), z, gm, rest % np.int32(dk), z, z)
+        return (rest // np.int32(dk), z, gm, rest % np.int32(dk), z, z)
 
-    return pl.BlockSpec((planes, 1, dk, 1, 1, s >> k, _LANES), imap,
+    return pl.BlockSpec((1, dk, 1, 1, planes * (s >> k), _LANES), imap,
                         memory_space=pltpu.VMEM)
 
 
@@ -1251,7 +1299,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
                           np.asarray(o[3].arr if hasattr(o[3], "arr") else o[3])))
         else:
             ops_r.append(o)
-    x = amps.reshape(P, rows, _LANES)
+    x = _rows_view(amps)
     lo2_load = (load_swap_hi if load_swap_hi is not None else tile_bits)
     lo2_store = (store_swap_hi if store_swap_hi is not None else tile_bits)
 
@@ -1272,12 +1320,12 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
         lsw = swap_geo(load_swap_k, lo2_load)
         ssw = swap_geo(store_swap_k, lo2_store)
         x_in = (_swap_view(x, rows, s, lo2_load - LANE_BITS, load_swap_k)
-                if load_swap_k else x.reshape(P, grid, s, _LANES))
+                if load_swap_k else x.reshape(grid, P * s, _LANES))
         if store_swap_k:
             oshape = _swap_view(x, rows, s, lo2_store - LANE_BITS,
                                 store_swap_k).shape
         else:
-            oshape = (P, grid, s, _LANES)
+            oshape = (grid, P * s, _LANES)
         # ring depth: clamp to the chunk count, then derate until the ring
         # buffers (in + out) fit the VMEM budget -- depth must never turn a
         # compiling kernel into a Mosaic OOM
@@ -1299,7 +1347,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
             interpret=interpret,
             name=name,
         )(x_in, shard_index, *ws)
-        return out.reshape(P, -1)
+        return _planes_view(out, P)
 
     kernel = _make_kernel(
         tuple(ops_r), s_bits, tile_bits, np.dtype(amps.dtype),
@@ -1325,11 +1373,11 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
             interpret=interpret,
             name=name,
         )(x, shard_index, *ws)
-        return out.reshape(P, -1)
+        return _planes_view(out, P)
 
     # np.int32 zeros: see _swap_spec (x64 turns a bare 0 into an i64)
-    plain = pl.BlockSpec((P, s, _LANES),
-                         lambda i: (np.int32(0), i, np.int32(0)),
+    plain = pl.BlockSpec((P * s, _LANES),
+                         lambda i: (i, np.int32(0)),
                          memory_space=pltpu.VMEM)
     if load_swap_k:
         x_in = _swap_view(x, rows, s, lo2_load - LANE_BITS, load_swap_k)
@@ -1364,7 +1412,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
         interpret=interpret,
         name=name,
     )(x_in, shard_index, *ws)
-    return out.reshape(P, -1)
+    return _planes_view(out, P)
 
 
 #: the implementation jitted under its own name: what anything that has no

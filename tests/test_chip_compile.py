@@ -26,6 +26,7 @@ test's own process.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -108,39 +109,106 @@ def _one_of_each(ops_folded, lq, must=(), skip_zones=()):
     return tuple(picked)
 
 
-def _compile_fused(one_chip, n, ops, *, planes=2, sublanes=PG._DEF_SUBLANES,
-                   local_n=None, lk=0, sk=0, lh=None, sh=None,
-                   ring=None, must=(), skip_zones=()):
-    """Lower + compile ``_fused_local_run`` for the described chip exactly
-    as ``fused_local_run`` would call it on the TPU (interpret=False).
-
-    The state enters as (planes, rows, 128) and is flattened inside the
-    program: a (planes, 2^n) PARAMETER gets XLA's T(2,128) tiling, and
-    XLA's own relayout copy into the kernel's tile view -- not Mosaic --
-    then dominates the compile (170 s for the 26q plan's k=7 swap view
-    against 6.5 s this way; first v5e compiles, PR 24). Inside a replay the
-    kernels chain on each other's outputs, so this is also the closer
-    model of what Circuit.run compiles."""
-    df = planes == 4
+def _fused_kw(n, ops, *, planes=2, sublanes=PG._DEF_SUBLANES, local_n=None,
+              lk=0, sk=0, lh=None, sh=None, ring=None, must=(),
+              skip_zones=()):
+    """``_fused_local_run``'s static arguments as ``fused_local_run``
+    would pass them on the TPU (interpret=False), the op list cut by
+    ``_one_of_each`` (double-float runs pass theirs as given)."""
     lq = PG.local_qubits(n, sublanes)
-    ops_l = tuple(ops) if df else _one_of_each(
+    ops_l = tuple(ops) if planes == 4 else _one_of_each(
         PG._fold_zone_ops(ops, lq), lq, must, skip_zones)
-    kw = dict(n=n, ops=ops_l, sublanes=sublanes, interpret=False,
-              local_n=local_n, load_swap_k=lk, store_swap_k=sk,
-              load_swap_hi=lh, store_swap_hi=sh,
-              ring_depth=PG.ring_depth_default() if ring is None else ring,
-              df_acc=False)
+    return dict(n=n, ops=ops_l, sublanes=sublanes, interpret=False,
+                local_n=local_n, load_swap_k=lk, store_swap_k=sk,
+                load_swap_hi=lh, store_swap_hi=sh,
+                ring_depth=PG.ring_depth_default() if ring is None else ring,
+                df_acc=False)
 
-    def run(x3, shard_index):
-        out = PG._fused_local_run(x3.reshape(planes, -1), shard_index, **kw)
-        return out.reshape(x3.shape)
 
-    x3 = jax.ShapeDtypeStruct((planes, (1 << n) // 128, 128), jnp.float32,
-                              sharding=one_chip)
+def _compile_chain(one_chip, n, chain, planes=2):
+    """Lower + compile a program of chained ``_fused_local_run`` calls
+    (one ``_fused_kw`` dict each) for the described chip. The state enters
+    as the DONATED (planes, 2^n) PARAMETER, as ``Circuit.compiled`` hands a
+    register's amplitudes to its program: the compiler tiles it T(2,128),
+    and the kernels read those bytes as they lie (``PG._rows_view``)."""
+    def run(amps, shard_index):
+        for kw in chain:
+            amps = PG._fused_local_run(amps, shard_index, **kw)
+        return amps
+
+    amps = jax.ShapeDtypeStruct((planes, 1 << n), jnp.float32,
+                                sharding=one_chip)
     si = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(run).lower(x3, si).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    compiled = jax.jit(run, donate_argnums=(0,)).lower(amps, si).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == len(chain)
     return compiled
+
+
+def _compile_fused(one_chip, n, ops, *, planes=2, **kw):
+    """One ``_fused_local_run`` alone: see ``_fused_kw``, ``_compile_chain``."""
+    return _compile_chain(one_chip, n, [_fused_kw(n, ops, planes=planes, **kw)],
+                          planes=planes)
+
+
+#: an instruction may be as large as the state only if it moves nothing
+_NO_TRAFFIC = {"parameter", "bitcast", "custom-call", "tuple",
+               "get-tuple-element"}
+_HLO_OP = re.compile(r"\s([a-z][\w\-]*)\(")
+_HLO_SHAPE = re.compile(r"\b[a-z]+\d+\[([\d,]+)\]")
+
+
+def _state_sized_traffic(hlo_text, state_elems):
+    """(opcode, instruction name) of every instruction of the compiled
+    program whose result is at least a quarter of the state and which is
+    not a view of it or a kernel: XLA's relayout ``copy`` /
+    ``copy_bitcast_fusion`` of the register, under whatever name."""
+    found = []
+    for line in hlo_text.splitlines():
+        name, eq, rest = line.strip().partition(" = ")
+        op = _HLO_OP.search(" " + rest) if eq else None
+        if op is None or op.group(1) in _NO_TRAFFIC:
+            continue
+        result_type = (" " + rest)[:op.start()]
+        if any(np.prod([int(d) for d in dims.split(",")]) * 4 >= state_elems
+               for dims in _HLO_SHAPE.findall(result_type)):
+            found.append((op.group(1), name.removeprefix("ROOT ")))
+    return found
+
+
+def _cell_chain(case):
+    """(state qubits, the fused runs) of a library cell's plan."""
+    if case == "sv26-three-runs-k7":       # sv26.block: 57, 20 (k=7), 2 ops
+        n, runs = 26, _planned_runs(_random_circuit(26, depth=2))
+        assert [(r.load_swap_k, r.store_swap_k) for r in runs] \
+            == [(0, 0), (7, 7), (0, 0)]
+    elif case == "density14-two-runs":     # density14.block: 2^28 amplitudes
+        import bench
+
+        n, runs = 28, _planned_runs(bench._density_circuit(
+            14, with_krausn=True))
+        assert len(runs) == 2
+    else:                                  # sv20.block's first two runs
+        n, runs = 20, _planned_runs(_random_circuit(20))[:2]
+        assert len(runs) == 2 and any(r.load_swap_k for r in runs)
+    return n, runs
+
+
+@pytest.mark.parametrize("case", ["sv26-three-runs-k7", "density14-two-runs",
+                                  "sv20-two-runs"])
+def test_chained_runs_read_the_register_where_it_lies(one_chip, case):
+    """A program shaped like a library cell's -- its fused runs chained at
+    the cell's real size, with their load and store swaps, on the donated
+    (2, 2^n) parameter -- holds its kernels and NO state-sized copy: the
+    view each kernel takes of the register is a bitcast of the T(2,128)
+    parameter, and its inverse at the root one too (PR 34; before, a
+    relayout copy of the whole state stood on either side)."""
+    n, runs = _cell_chain(case)
+    chain = [_fused_kw(n, r.ops, lk=r.load_swap_k, sk=r.store_swap_k,
+                       lh=r.load_swap_hi, sh=r.store_swap_hi,
+                       skip_zones=("sublane",)) for r in runs]
+    compiled = _compile_chain(one_chip, n, chain)
+    assert _state_sized_traffic(compiled.as_text(), 2 << n) == []
 
 
 def test_f32_fused_run_26q_plan_pass(one_chip):

@@ -91,6 +91,97 @@ def test_bf16x3_zone_dots_f32_numerics():
     assert err < 3e-5, f"bf16x3 relative error {err}"
 
 
+def _np_swap_bit_blocks(v, n, lo1, lo2, k):
+    """Exchange index bit blocks [lo1, lo1+k) and [lo2, lo2+k) of a 2^n
+    vector, in plain numpy index arithmetic."""
+    i = np.arange(1 << n)
+    m = (1 << k) - 1
+    b1, b2 = (i >> lo1) & m, (i >> lo2) & m
+    j = (i & ~(m << lo1) & ~(m << lo2)) | (b2 << lo1) | (b1 << lo2)
+    return v[j]
+
+
+#: every fused-run kernel kind, plain and with the frame swap folded into
+#: its load and store: (kind, planes, shard index given, ring depth, swap
+#: k). n = 12 at sublanes = 8 is 4 chunks of a (8, 128) tile; ``df1`` is
+#: the one-tile double-float call (n = 10, no grid bits, so no swap exists).
+_VIEW_CASES = {
+    "dma-ring2": ("dma", 2, False, 2, 0),
+    "dma-ring2-swap": ("dma", 2, False, 2, 2),
+    "dma-ring3": ("dma", 2, False, 3, 0),
+    "dma-ring3-swap": ("dma", 2, False, 3, 2),
+    "grid": ("grid", 2, True, 2, 0), "grid-swap": ("grid", 2, True, 2, 2),
+    "df-dma": ("dma", 4, False, 2, 0),
+    "df-dma-swap": ("dma", 4, False, 3, 2),
+    "df1": ("df1", 4, False, 2, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_VIEW_CASES))
+def test_interleaved_view_parity_vs_oracle(case):
+    """Each kernel kind reads the (P, 2^n) register through the
+    row-interleaved view (PG._rows_view: plane i of a block = its rows
+    i, i+P, ...) and agrees with the dense oracle, plain and under a
+    folded load + store swap."""
+    import jax.numpy as jnp
+
+    from . import oracle
+
+    kind, planes, sharded, ring, k = _VIEW_CASES[case]
+    n, sublanes = (10 if kind == "df1" else 12), 8
+    tb = PG.local_qubits(n, sublanes)
+    rz = _rz(0.7)
+    gates = [((0,), H, (), None), ((8,), X, (n - 1,), [1]),
+             ((5,), H, (7,), [0]), ((n - 1,), rz, (), None)]
+    ops = tuple(("matrix", t[0], tuple(c), tuple(st or ()),
+                 PG.HashableMatrix(m)) for t, m, c, st in gates)
+    rng = np.random.RandomState(11)
+    psi = oracle.random_statevec(n, rng)
+    amps = jnp.asarray(np.stack([psi.real, psi.imag]), jnp.float64)
+
+    kw = dict(n=n, ops=ops, sublanes=sublanes, interpret=True,
+              ring_depth=ring, load_swap_k=k, store_swap_k=k)
+    if sharded:
+        kw["shard_index"] = jnp.zeros((), jnp.int32)
+    assert PG._kernel_kind(PG._tile_geometry(1 << n, sublanes)[2],
+                           n if sharded else None, planes == 4) == kind
+    if planes == 4:
+        from quest_tpu.ops.pallas_df import df_join, df_split
+        got = np.asarray(df_join(PG.fused_local_run(df_split(amps), **kw)))
+        tol = 5e-8   # XLA:CPU cannot keep the error-free transforms exact
+    else:
+        got = np.asarray(PG.fused_local_run(amps, **kw))
+        tol = 1e-12
+
+    ref = psi
+    if k:
+        ref = _np_swap_bit_blocks(ref, n, tb - k, tb, k)
+    for t, m, c, st in gates:
+        ref = oracle.apply_to_statevec_indexed(ref, n, list(t), m, list(c),
+                                               st)
+    if k:
+        ref = _np_swap_bit_blocks(ref, n, tb - k, tb, k)
+    np.testing.assert_allclose(got[0] + 1j * got[1], ref, atol=tol)
+
+
+@pytest.mark.parametrize("planes", [2, 4])
+def test_rows_view_and_its_inverse_are_the_identity(planes):
+    """PG._rows_view interleaves the planes row by row (row r * P + i =
+    row r of plane i) and PG._planes_view undoes it, from the flat view
+    and from the reshaped ones the kernels return."""
+    rows = 16
+    a = np.arange(planes * rows * 128, dtype=np.float32).reshape(planes, -1)
+    v = np.asarray(PG._rows_view(a))
+    assert v.shape == (rows * planes, 128)
+    for i in range(planes):
+        np.testing.assert_array_equal(
+            v[i::planes], a[i].reshape(rows, 128))
+    np.testing.assert_array_equal(np.asarray(PG._planes_view(v, planes)), a)
+    chunks = v.reshape(4, planes * 4, 128)    # the DMA kernel's out_shape
+    np.testing.assert_array_equal(
+        np.asarray(PG._planes_view(chunks, planes)), a)
+
+
 def test_kernel_rejects_grid_bit_target():
     amps = ops_init.init_debug(1 << 10, real_dtype())
     ops = (("matrix", 9, (), (), PG.HashableMatrix(H)),)
