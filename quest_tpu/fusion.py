@@ -617,6 +617,20 @@ class PallasRun:
     #: QUEST_COMM_PIPELINE_DCN env, else the base depth)
     comm_pipeline_dcn: int | None = None
 
+    @property
+    def matched(self) -> bool:
+        """The load and the store relabeling are the same one (or there is
+        none): chunk ``c`` of the kernel then reads and writes the same
+        addresses, so the pass is sound in place
+        (``pallas_gates._fused_local_run_impl`` aliases its output to its
+        operand). Every run the planner emits is matched
+        (``_FramePlanner._emit_run``)."""
+        from .ops.pallas_gates import writes_in_place
+
+        return writes_in_place(self.tile_bits, self.load_swap_k,
+                               self.load_swap_hi, self.store_swap_k,
+                               self.store_swap_hi)
+
 
 @dataclass(frozen=True)
 class FrameSwap:
@@ -761,12 +775,14 @@ class _FramePlanner:
     plans (34q sharded, density tapes)."""
 
     def __init__(self, out: FusePlan, tile_bits: int, k: int, nsv: int,
-                 boundary: int | None = None):
+                 boundary: int | None = None, n_exec: int | None = None):
         self.out = out
         self.tb = tile_bits
         self.k = k
         self.nsv = nsv
         self.boundary = boundary  # shard-local qubit count (or None)
+        #: qubits of the array a kernel sees: the register, or one shard
+        self.n_exec = nsv if n_exec is None else n_exec
         #: candidate frames: identity + one per k-wide grid block. Block
         #: edges align to ``boundary`` (the shard-local qubit count) so
         #: frames stay entirely below it where possible -- their
@@ -777,14 +793,28 @@ class _FramePlanner:
         if boundary is not None and tile_bits < boundary < nsv:
             edges.insert(1, boundary)
         for lo, hi_edge in zip(edges, edges[1:]):
-            hi = lo
-            while k > 0 and hi < hi_edge:
-                self.frames.append((hi, min(k, hi_edge - hi)))
-                hi += k
-        self.cur_frame = None        # physical frame of the amps stream
+            hi, w = lo, self.width(hi_edge)
+            while w > 0 and hi < hi_edge:
+                self.frames.append((hi, min(w, hi_edge - hi)))
+                hi += w
         self.runs = []               # ordered pending [frame, [_POp]]
 
     # -- frame geometry -----------------------------------------------------
+
+    def width(self, end: int) -> int:
+        """The widest frame whose grid block ends at qubit ``end``. A block
+        inside the array the kernel sees rides the kernel's DMA, and is
+        never wider than what folds there (:func:`_fold_width`): a wider
+        one would run as two explicit passes over the whole state beside
+        its kernel. One that reaches a sharded qubit is a collective
+        transpose whatever its width, and keeps the planner's ``k``; so
+        does every frame of a tile too small for any to fold (under 16
+        sublanes: an explicit ``sublanes=`` only), each an explicit pass
+        whatever its width."""
+        fold = _fold_width(self.tb)
+        if end <= self.n_exec and fold > 0:
+            return min(self.k, fold)
+        return self.k
 
     def phys(self, q: int, frame) -> int:
         if frame is None:
@@ -848,7 +878,7 @@ class _FramePlanner:
         for a0, w in cands:
             # the displaced region [tb-w, tb) must stay above every low
             # target, and the block must fit the frame width and register
-            if w <= 0 or w > self.k or w >= self.tb - max_lo \
+            if w <= 0 or w > self.width(a0 + w) or w >= self.tb - max_lo \
                     or a0 + w > self.nsv:
                 continue
             f = (a0, w)
@@ -862,42 +892,24 @@ class _FramePlanner:
 
     # -- emission -----------------------------------------------------------
 
-    def _leave_cur_frame(self):
-        """Fold the undo of the current frame into the last run's output
-        scatter, or emit an explicit FrameSwap."""
-        if self.cur_frame is None:
-            return
-        hi, kf = self.cur_frame
-        last = self.out.items[-1] if self.out.items else None
-        if isinstance(last, PallasRun) and last.store_swap_k == 0:
-            self.out.items[-1] = dataclasses.replace(
-                last, store_swap_k=kf, store_swap_hi=hi)
-        else:  # pragma: no cover - a run always precedes a non-identity frame
-            self.out.items.append(FrameSwap(self.tb, kf, hi))
-        self.cur_frame = None
-
     def _emit_run(self, frame, ops: list):
-        if not ops:
-            return
-        load_k, load_hi = 0, None
-        if self.cur_frame != frame:
-            # leaving one non-identity frame for another: the undo folds
-            # into the PREVIOUS run's store DMA, the new frame's swap into
-            # THIS run's load DMA -- still zero extra HBM passes
-            self._leave_cur_frame()
-            if frame is not None:
-                load_hi, load_k = frame
-            self.cur_frame = frame
+        """One PallasRun a ``_RUN_OP_CAP`` ops of the pending run, each
+        entering ``frame`` on its load DMA and leaving it on its store DMA
+        (zero extra HBM passes): between two items the register is always
+        in the identity frame, and a run's load and store relabelings are
+        the same one (``PallasRun.matched``) -- what lets its kernel write
+        over its operand."""
+        hi, k = (None, 0) if frame is None else frame
         # cap ops per kernel: Mosaic compile time explodes past a few
         # hundred ops in one program (20q mono-kernel probe: >20 min at
-        # 316 ops), so over-long runs split into consecutive passes; only
-        # the first carries the folded frame-entry swap
+        # 316 ops), so over-long runs split into consecutive passes
         phys = [self._phys_op(op, frame) for op in ops]
         for i in range(0, len(phys), _RUN_OP_CAP):
-            self.out.items.append(PallasRun(
-                tuple(phys[i:i + _RUN_OP_CAP]), self.tb,
-                load_swap_k=load_k if i == 0 else 0,
-                load_swap_hi=load_hi if i == 0 else None))
+            run = PallasRun(tuple(phys[i:i + _RUN_OP_CAP]), self.tb,
+                            load_swap_k=k, load_swap_hi=hi,
+                            store_swap_k=k, store_swap_hi=hi)
+            assert run.matched, run
+            self.out.items.append(run)
 
     def _phys_op(self, op: _POp, frame):
         from .ops.pallas_gates import HashableMatrix
@@ -920,10 +932,9 @@ class _FramePlanner:
         return ("parity", t, c, op.data)
 
     def flush(self):
-        """Emit every pending run in order and return to the identity."""
+        """Emit every pending run in order."""
         for frame, ops in self.runs:
             self._emit_run(frame, ops)
-        self._leave_cur_frame()
         self.runs = []
 
     # -- scheduling ---------------------------------------------------------
@@ -975,7 +986,6 @@ class _FramePlannerTwoSlot(_FramePlanner):
         self._emit_run(*self.open)
         if self.next[0] is not Ellipsis:
             self._emit_run(*self.next)
-        self._leave_cur_frame()
         self.open = [None, []]
         self.next = [Ellipsis, []]
 
@@ -1034,6 +1044,8 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         diag_blocks=sum(isinstance(i, DiagBlock) for i in p.items),
         frame_transposes=folded + explicit,
         ops_per_run=[len(r.ops) for r in runs],
+        inplace_runs=sum(r.matched for r in runs),
+        frame_widths=[r.load_swap_k for r in runs],
         fused_gates=p.num_fused_gates, barriers=p.num_barriers,
         **sharded)
 
@@ -1370,7 +1382,8 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
     k = min(max(nsv - tile_bits, 0), tile_bits - LANE_BITS)
 
     def make_planner(cls):
-        return cls(FusePlan(), tile_bits, k, nsv, boundary=shard_boundary)
+        return cls(FusePlan(), tile_bits, k, nsv, boundary=shard_boundary,
+                   n_exec=score_shard_qubits)
 
     probe = make_planner(_FramePlanner)  # frame geometry only
 
@@ -1510,7 +1523,9 @@ class Route(NamedTuple):
     through the gate-by-gate appliers). ``fold_load`` / ``fold_store``:
     the run's frame relabeling rides the kernel's DMA; one that does not
     runs as an explicit pass. ``reason``: the ``engine_fallback_total``
-    label the decision counts, or None. The rest is what the executor runs
+    label the decision counts, or None; ``unfolded``: how many of the
+    run's relabelings lie inside the array and still did not fold
+    (``fusion_unfolded_swaps_total``). The rest is what the executor runs
     on: the mesh of a per-shard route, the qubits and tile sublanes of the
     array the kernel sees, and whether that kernel is the df one."""
     kind: str
@@ -1521,6 +1536,19 @@ class Route(NamedTuple):
     n_exec: int = 0
     sublanes: int = 0
     df: bool = False
+    unfolded: int = 0
+
+
+def _fold_width(tile_bits: int) -> int:
+    """The widest relabeling a kernel's DMA folds at this tile: the low
+    part of the split sublane axis keeps one sublane tile of 8 rows
+    (``tile_bits - LANE_BITS - k >= 3``), so that the gathered
+    (P * s_low, 128) pieces stay layout-free (``pallas_gates._load_planes``).
+    The one statement of the number: :func:`_folded` routes by it and
+    ``_FramePlanner.width`` holds its frames to it."""
+    from .ops.pallas_gates import LANE_BITS
+
+    return tile_bits - LANE_BITS - 3
 
 
 def _folded(route: Route, run: PallasRun) -> Route:
@@ -1530,24 +1558,24 @@ def _folded(route: Route, run: PallasRun) -> Route:
     (``hi + k <= n_exec``: on a shard, a block reaching sharded bits is
     the collective transpose, explicit by design and no fallback), the
     plan's tile is that array's (``tile_bits == local_qubits(n_exec,
-    sublanes)``) and the low part of the split sublane axis keeps one
-    sublane tile (``tile_bits - LANE_BITS - k >= 3``: the gathered chunks
-    stay layout-free). A block inside the array that misses the geometry
-    is the counted ``swap_not_foldable``: the kernel still runs, the
-    relabeling beside it."""
+    sublanes)``) and the block is no wider than :func:`_fold_width`. A
+    block inside the array that misses the geometry is the counted
+    ``swap_not_foldable``: the kernel still runs, the relabeling beside
+    it (``fusion_unfolded_swaps_total``). The planner emits none on the
+    tile it planned for (``_FramePlanner.width``)."""
     from .ops import pallas_gates as PG
 
     fits = run.tile_bits == PG.local_qubits(route.n_exec, route.sublanes)
-    folds, missed = [], False
+    folds, missed = [], 0
     for k, hi in ((run.load_swap_k, run.load_swap_hi),
                   (run.store_swap_k, run.store_swap_hi)):
         hi = run.tile_bits if hi is None else hi
         inside = k > 0 and hi + k <= route.n_exec
-        folds.append(inside and fits
-                     and run.tile_bits - PG.LANE_BITS - k >= 3)
-        missed |= inside and not folds[-1]
+        folds.append(inside and fits and k <= _fold_width(run.tile_bits))
+        missed += inside and not folds[-1]
     return route._replace(fold_load=folds[0], fold_store=folds[1],
-                          reason="swap_not_foldable" if missed else None)
+                          reason="swap_not_foldable" if missed else None,
+                          unfolded=missed)
 
 
 def _route(qureg, run: PallasRun) -> Route:
@@ -1708,6 +1736,8 @@ def _apply_pallas_run(qureg, run: PallasRun) -> None:
         return
     if route.reason is not None:
         telemetry.inc("engine_fallback_total", reason=route.reason)
+    if route.unfolded:
+        telemetry.inc("fusion_unfolded_swaps_total", route.unfolded)
     sched_df = route.kind == "sched_df"
     # under the scheduler both relabelings ride inside the attempt, on the
     # 4-plane state; elsewhere those the kernel's DMA folds

@@ -1069,6 +1069,9 @@ def fused_local_run(amps, *, n: int, ops: tuple, sublanes: int = _DEF_SUBLANES,
     # trace of the program, not once per launch (the launches are counted
     # in the device trace: launches_per_circuit)
     telemetry.inc("pallas_pass_total", kind="fused_run", dtype=kind)
+    if writes_in_place(lq, load_swap_k, load_swap_hi, store_swap_k,
+                       store_swap_hi):
+        telemetry.inc("fusion_inplace_runs_total")
     # the requested operating point (pre clamp/derate -- the knob value)
     telemetry.set_gauge("pallas_ring_depth", ring)
     sig = (n, ops_l, sublanes, int(load_swap_k), int(store_swap_k),
@@ -1111,6 +1114,24 @@ def kernel_name(kind: str, planes: int, dtype, nops: int,
     dt = "df" if planes == 4 else f"f{8 * np.dtype(dtype).itemsize}"
     return (f"qt_fused_{kind}_{dt}_ops{nops}"
             f"_ls{load_swap_k}_ss{store_swap_k}")
+
+
+def writes_in_place(tile_bits: int, load_swap_k: int, load_swap_hi,
+                    store_swap_k: int, store_swap_hi) -> bool:
+    """Whether a fused run's kernel writes over its operand
+    (``input_output_aliases``): where it leaves on its store the frame it
+    entered on its load, or has neither relabeling. Chunk ``c`` then reads
+    and writes the SAME addresses, the chunks' address sets are disjoint,
+    and a load running ahead of the compute touches only chunks not yet
+    written -- so a chain of such runs on a donated register holds no
+    state-sized temporary. Where the two relabelings differ a chunk's
+    store lands in other chunks' unread input, and the pass keeps an
+    output of its own. Where the operand is still live (an undonated
+    call) XLA keeps a copy by itself."""
+    def geo(k, hi):
+        return (k, tile_bits if hi is None else hi) if k else None
+
+    return geo(load_swap_k, load_swap_hi) == geo(store_swap_k, store_swap_hi)
 
 
 def _tile_geometry(num: int, sublanes: int):
@@ -1302,6 +1323,9 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
     x = _rows_view(amps)
     lo2_load = (load_swap_hi if load_swap_hi is not None else tile_bits)
     lo2_store = (store_swap_hi if store_swap_hi is not None else tile_bits)
+    aliases = {0: 0} if writes_in_place(
+        tile_bits, load_swap_k, load_swap_hi, store_swap_k,
+        store_swap_hi) else {}
 
     if kind == "dma":
         # manual double-buffered-DMA kernel (see _make_dma_kernel): one
@@ -1344,6 +1368,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
+            input_output_aliases=aliases,
             interpret=interpret,
             name=name,
         )(x_in, shard_index, *ws)
@@ -1370,6 +1395,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
+            input_output_aliases=aliases,
             interpret=interpret,
             name=name,
         )(x, shard_index, *ws)
@@ -1409,6 +1435,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
         # 16 MiB scoped-VMEM budget; the physical VMEM is far larger
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
+        input_output_aliases=aliases,
         interpret=interpret,
         name=name,
     )(x_in, shard_index, *ws)

@@ -178,7 +178,11 @@ def _state_sized_traffic(hlo_text, state_elems):
 
 def _cell_chain(case):
     """(state qubits, the fused runs) of a library cell's plan."""
-    if case == "sv26-three-runs-k7":       # sv26.block: 57, 20 (k=7), 2 ops
+    if case == "sv30-four-runs-k9-k2":     # sv30.block: 60, 23, 6, 2 ops
+        n, runs = 30, _planned_runs(_random_circuit(30, depth=2))
+        assert [(r.load_swap_k, r.load_swap_hi) for r in runs] \
+            == [(0, None), (9, 19), (2, 28), (0, None)]
+    elif case == "sv26-three-runs-k7":     # sv26.block: 57, 20 (k=7), 2 ops
         n, runs = 26, _planned_runs(_random_circuit(26, depth=2))
         assert [(r.load_swap_k, r.store_swap_k) for r in runs] \
             == [(0, 0), (7, 7), (0, 0)]
@@ -194,8 +198,57 @@ def _cell_chain(case):
     return n, runs
 
 
-@pytest.mark.parametrize("case", ["sv26-three-runs-k7", "density14-two-runs",
-                                  "sv20-two-runs"])
+#: the compiled chain of a cell, once a module (6-25 s a case)
+_CHAINS = {}
+
+
+def _compiled_cell_chain(one_chip, case):
+    if case not in _CHAINS:
+        n, runs = _cell_chain(case)
+        chain = [_fused_kw(n, r.ops, lk=r.load_swap_k, sk=r.store_swap_k,
+                           lh=r.load_swap_hi, sh=r.store_swap_hi,
+                           skip_zones=("sublane",)) for r in runs]
+        _CHAINS[case] = (n, _compile_chain(one_chip, n, chain))
+    return _CHAINS[case]
+
+
+@pytest.mark.parametrize("case", ["sv30-four-runs-k9-k2", "sv26-three-runs-k7",
+                                  "density14-two-runs"])
+def test_a_chain_of_matched_runs_holds_no_state_sized_temporary(one_chip,
+                                                                case):
+    """Every run of these plans leaves the frame it entered, so its kernel
+    writes over its operand (``PG.writes_in_place``): on the donated
+    register the whole chain is the argument, aliased to the output, and
+    temporaries under an eighth of the state -- where each kernel's output
+    was a state-sized buffer of its own (1 GiB for ``sv26``'s shape, 2 GiB
+    for ``density14``'s), and 8 GiB beside the 8 GiB of the 30-qubit
+    register no chip of 16 GB holds. It also shows that Mosaic and XLA take
+    2^31 elements in one array and the two new frame geometries."""
+    n, compiled = _compiled_cell_chain(one_chip, case)
+    state = 8 << n
+    mem = compiled.memory_analysis()
+    assert state <= mem.argument_size_in_bytes < state + (1 << 20)
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < state // 8
+    assert mem.output_size_in_bytes == state
+
+
+def test_create_qureg_30q_is_one_buffer(one_chip):
+    """``createQureg(30)``'s |0...0> (``ops.init.init_classical``, what
+    ``registers._alloc`` calls on one device) is ONE 8 GiB buffer and no
+    second: the zeros are written where the result lives."""
+    from quest_tpu.ops import init as ops_init
+
+    compiled = jax.jit(
+        lambda: ops_init.init_classical(1 << 30, jnp.dtype("float32"), 0),
+        out_shardings=one_chip).lower().compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 8 << 30
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("case", ["sv30-four-runs-k9-k2", "sv26-three-runs-k7",
+                                  "density14-two-runs", "sv20-two-runs"])
 def test_chained_runs_read_the_register_where_it_lies(one_chip, case):
     """A program shaped like a library cell's -- its fused runs chained at
     the cell's real size, with their load and store swaps, on the donated
@@ -203,11 +256,7 @@ def test_chained_runs_read_the_register_where_it_lies(one_chip, case):
     view each kernel takes of the register is a bitcast of the T(2,128)
     parameter, and its inverse at the root one too (PR 34; before, a
     relayout copy of the whole state stood on either side)."""
-    n, runs = _cell_chain(case)
-    chain = [_fused_kw(n, r.ops, lk=r.load_swap_k, sk=r.store_swap_k,
-                       lh=r.load_swap_hi, sh=r.store_swap_hi,
-                       skip_zones=("sublane",)) for r in runs]
-    compiled = _compile_chain(one_chip, n, chain)
+    n, compiled = _compiled_cell_chain(one_chip, case)
     assert _state_sized_traffic(compiled.as_text(), 2 << n) == []
 
 
