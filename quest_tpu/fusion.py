@@ -1013,7 +1013,8 @@ class _FramePlannerTwoSlot(_FramePlanner):
 
 def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
                            tile_bits: int | None,
-                           shard_qubits: int | None = None) -> None:
+                           shard_qubits: int | None = None,
+                           df: bool = False) -> None:
     """Flight-record a finished plan's shape: item mix, frame-transpose
     counts, tile geometry. One counter per plan plus a structured event
     (the per-plan detail bench.py ships in BENCH_DETAIL.json)."""
@@ -1037,6 +1038,15 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         sharded = transpose_stats(p, shard_qubits)
         sharded.update(collective_swaps=sharded["collective_transposes"],
                        sharded_runs=len(runs))
+    kernel = {}
+    if mode != "dense":
+        # what the kernels will hold, by kind: each run's zones folded as
+        # fused_local_run folds them at the same tile, but for a
+        # double-float plan, whose kernels take the ops as they are
+        from .ops import pallas_gates as PG
+        kernel = dict(kernel_op_kinds=PG.kernel_op_kinds(
+            op for r in runs for op in (
+                r.ops if df else PG._fold_zone_ops(r.ops, r.tile_bits))))
     telemetry.event(
         "fusion.plan", mode=mode, nsv=nsv, tile_bits=tile_bits,
         items=len(p.items), pallas_runs=len(runs),
@@ -1047,7 +1057,7 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         inplace_runs=sum(r.matched for r in runs),
         frame_widths=[r.load_swap_k for r in runs],
         fused_gates=p.num_fused_gates, barriers=p.num_barriers,
-        **sharded)
+        **sharded, **kernel)
 
 
 def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
@@ -1081,7 +1091,8 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
             p = _plan_pallas(tape, num_qubits, dtype, max_qubits,
                              pallas_tile_bits, is_density=is_density,
                              shard_boundary=shard_boundary)
-        _record_plan_telemetry(p, "pallas", nsv, pallas_tile_bits)
+        _record_plan_telemetry(p, "pallas", nsv, pallas_tile_bits,
+                               df=_df_route(dtype))
         return p
     import time as _time
     _t0 = _time.perf_counter()
@@ -1358,7 +1369,7 @@ def plan_pallas_sharded(tape, num_qubits: int, dtype, max_qubits: int,
             transpose_stats(p, n_local)["collective_transposes"],
             len(p.items)))
     _record_plan_telemetry(best, "pallas_sharded", nsv, tile_bits,
-                           shard_qubits=n_local)
+                           shard_qubits=n_local, df=_df_route(dtype))
     return best
 
 

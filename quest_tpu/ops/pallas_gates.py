@@ -85,6 +85,9 @@ from .. import telemetry
 
 LANE_BITS = 7          # minor dim fixed at 128 lanes
 _LANES = 1 << LANE_BITS
+#: sublanes of one f32 vreg, the (8, 128) tile: a sublane bit below
+#: LANE_BITS + log2(_VREG_ROWS) pairs rows INSIDE a vreg (_partner)
+_VREG_ROWS = 8
 #: (2, 4096, 128) f32 tile = 4 MiB. Round-4 re-sweep of the manual-DMA
 #: kernel's chunk size at 2^26 amps (tools/kernelprobe, min-of-3): the
 #: per-PASS floor is per-chunk-overhead-bound at the old S=2048 default
@@ -228,15 +231,27 @@ def _grid_bit(q: int, tile_bits: int):
 
 
 def _partner(arr, q: int):
-    """arr[i ^ 2^q] within the tile.
+    """arr[i ^ 2^q] within the tile, one of three exchanges by where bit q
+    lies (ms a plain complex gate at 2^26 amplitudes, S = 4096: PR 36's
+    chip call 1, PERF.md section 6):
 
-    Lane bits (q < 7) use two circular rolls + per-bit select (intra-lane
-    shuffles, ~free). Sublane bits use a reshape/slice half-exchange
-    instead: splitting the sublane axis at the target bit and swapping the
-    halves is a pure sub-array copy -- measured ~0.05-0.2 ms per gate at
-    2^26 amps vs ~8 ms for the same butterfly as sublane pltpu.rolls
-    (Mosaic lowers cross-sublane rolls to very slow shuffle sequences;
-    round-3 microbench, the single biggest kernel cost discovered)."""
+    - a lane bit (q < 7): two circular lane rotates and a select on the
+      bit -- 0.50.
+    - a sublane bit INSIDE a vreg (7 <= q < 10: the partner row is 1, 2 or
+      4 sublanes away in the same (8, 128) tile): the same recipe one level
+      up, on the plane seen as whole vregs -- 0.90, 0.90, 0.78 (q9's two
+      rotates are one, by 4 of 8, and the select then costs nothing:
+      0.774 with it, 0.778 without). Until PR 36 these took the slice
+      exchange below, whose reshape cuts every vreg when m < 8: 3.48,
+      1.71, 1.27 (Mosaic relayouts the plane in and out), and 20 / 192 s
+      of compile for one / three of them on q7 where the rotates take
+      2.3 s. A tile of fewer than 8 sublanes (tests only) holds no whole
+      vreg and keeps the slices.
+    - a sublane bit across vregs (q >= 10): split the sublane axis at the
+      bit and swap the halves, whole vregs changing places -- 0.785 on
+      every bit from 10 to 18. (Round 3 read ~8 ms for the same butterfly
+      as pltpu.rolls along the WHOLE sublane axis; PR 36 read 1.02 for
+      that on q7: still the dearest of the candidates.)"""
     if q < LANE_BITS:
         # np.int32 shifts: under jax x64 a python int would trace as i64,
         # which Mosaic's tpu.dynamic_rotate rejects (round-5 df path find)
@@ -248,6 +263,14 @@ def _partner(arr, q: int):
         return jnp.where(bit == 0, up, dn)
     m = 1 << (q - LANE_BITS)
     s, lanes = arr.shape
+    if m < _VREG_ROWS <= s:
+        # the partner lies INSIDE the (8, 128) vreg: the lane recipe one
+        # level up, on the plane seen as whole vregs (a layout-free view)
+        v = arr.reshape(s // _VREG_ROWS, _VREG_ROWS, lanes)
+        dn = pltpu.roll(v, np.int32(m), 1)               # dn[i] = v[i - m]
+        up = pltpu.roll(v, np.int32(_VREG_ROWS - m), 1)  # up[i] = v[i + m]
+        bit = _bit_mask(q, arr.shape).reshape(v.shape)
+        return jnp.where(bit == 0, up, dn).reshape(s, lanes)
     v = arr.reshape(s // (2 * m), 2, m, lanes)
     return jnp.stack([v[:, 1], v[:, 0]], axis=1).reshape(s, lanes)
 
@@ -347,28 +370,73 @@ def _op_is_diag(op):
 #: with bf16x3 dots and the 8192-row tile, a lane butterfly (two
 #: cross-lane rolls + selects over the whole tile) costs MORE than the
 #: whole folded lane dot, so the lane zone folds from the first dense
-#: gate, while sublane slice-butterflies stay cheaper than the per-slab
+#: gate, while sublane butterflies stay cheaper than the per-slab
 #: window dots until a zone accumulates several of them.
+#: PR 36 read every entry on the v5e at S = 4096 (PERF.md section 6, chip
+#: calls 1 and 3) and the readings disagree with most of them. None is put
+#: in here: a re-priced model moves ops from exact f32 butterflies into
+#: bf16x3 dots, a change of arithmetic that wants a before and after of
+#: its own (ROADMAP A1).
 _FOLD_LANE_DOT_MS = 0.47    # lane_u: 3 Karatsuba bf16x3 dot triples
 _FOLD_WINDOW_DOT_MS = 0.87  # sublane window: per-slab (2D,2D) dots
+#: one partner exchange and its arithmetic, by how _partner exchanges
+#: (_exchange_kind). ``invreg`` is the cheapest entry and should be
+#: ``rows``': the slice exchange it was set for read 1.3-3.5 ms alone
+#: (call 1), the in-vreg rotates that replaced it what a whole-vreg
+#: exchange reads (0.42 against 0.41 among the ops of sv26.block's first
+#: kernel, call 3). At 0.25 zone [7, 12) folds from its fourth butterfly
+#: and four cells ride one or two more window dots an application
+#: (tests/test_kernel_op_kinds.py pins today's counts): held with the rest.
+_BUTTERFLY_MS = {"lane": 0.76, "invreg": 0.07, "rows": 0.25}
+
+#: the kinds a folded run's ops are counted under (the ``kernel_op_kinds``
+#: field of a pallas plan's ``fusion.plan`` event)
+KERNEL_OP_KINDS = ("lane_u", "window", "diag", "butterfly_lane",
+                   "butterfly_invreg", "butterfly_rows", "kraus")
+
+
+def _exchange_kind(q: int) -> str:
+    """How _partner exchanges across in-tile bit q: ``lane`` (two lane
+    rotates and a select), ``invreg`` (the same on the sublanes inside a
+    vreg), ``rows`` (whole vregs change places)."""
+    if q < LANE_BITS:
+        return "lane"
+    return "invreg" if (1 << (q - LANE_BITS)) < _VREG_ROWS else "rows"
+
+
+def kernel_op_kind(op) -> str:
+    """The one of KERNEL_OP_KINDS a kernel op (after zone folding) is
+    counted as. A butterfly on two dense targets (``swap``) counts as
+    ``invreg`` if either exchanges inside a vreg, else as ``lane`` if
+    either is a lane bit, else as ``rows``: by the exchanges' shapes in
+    that fixed order, whatever the model's prices."""
+    if op[0] in ("lane_u", "window"):
+        return op[0]
+    if op[0] in ("kraus1", "kraus2", "krausn"):
+        return "kraus"
+    if _op_is_diag(op):
+        return "diag"
+    kinds = set(map(_exchange_kind, op_dense_targets(op)))
+    return "butterfly_" + next(
+        k for k in ("invreg", "lane", "rows") if k in kinds)
+
+
+def kernel_op_kinds(ops_folded) -> dict:
+    """Count of every kind of KERNEL_OP_KINDS over a folded run's ops."""
+    counts = dict.fromkeys(KERNEL_OP_KINDS, 0)
+    for op in ops_folded:
+        counts[kernel_op_kind(op)] += 1
+    return counts
 
 
 def _op_cost_ms(op) -> float:
     """Estimated in-kernel cost of one un-folded op (see table above):
-    diagonals are ~free; sublane slice butterflies are cheap (the low-m
-    ones especially); lane butterflies pay cross-lane rolls over the
-    whole tile."""
+    diagonals are ~free; a butterfly pays one exchange a dense target."""
     if _op_is_diag(op):
         return 0.01
-    def tcost(q):
-        if q < LANE_BITS:
-            return 0.76
-        m = q - LANE_BITS
-        return 0.07 if m < 3 else 0.25
-    if op[0] == "matrix":
-        return tcost(op[1])
-    if op[0] == "swap":
-        return tcost(op[1]) + tcost(op[2])
+    if op[0] in ("matrix", "swap"):
+        return sum(_BUTTERFLY_MS[_exchange_kind(q)]
+                   for q in op_dense_targets(op))
     # kraus ops never reach this model: zone_of() bars them from accumulators
     return 0.02
 
@@ -393,7 +461,7 @@ def _fold_zone_ops(ops, tile_bits: int) -> tuple:
     (_op_cost_ms) exceeds the zone's dense-dot cost. Under the round-4
     measurements (bf16x3 dots, S=8192 tiles) lane butterflies cost more
     than the whole folded lane dot -- the lane zone folds from the first
-    dense gate -- while sublane slice-butterflies stay cheaper than the
+    dense gate -- while sublane butterflies stay cheaper than the
     window dots until a zone accumulates several of them."""
     from ..fusion import event_matrix
 

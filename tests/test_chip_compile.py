@@ -84,22 +84,31 @@ def _random_circuit(n, depth=8):
     return circ
 
 
-def _one_of_each(ops_folded, lq, must=(), skip_zones=()):
+#: the zone of ``_one_of_each`` a ``matrix`` op of each kind stands for
+_ZONE_OF_KIND = {"diag": "diag", "butterfly_lane": "lane",
+                 "butterfly_invreg": "invreg", "butterfly_rows": "sublane"}
+
+
+def _one_of_each(ops_folded, lq, must=()):
     """A short run that still holds every op KIND of the folded run (the
     folded zone dots ``lane_u`` / ``window`` included), a ``matrix`` op of
-    each target zone it has (lane-roll butterfly, sublane-roll butterfly,
-    grid-bit diagonal; minus ``skip_zones``), and the ``must`` ops --
-    compile time is per op, coverage is per kind. One sublane-roll
-    butterfly alone costs Mosaic ~100 s at the 4 MiB tile, so only the
-    first test keeps it."""
+    each target zone it has (``lane``: lane rotates; ``invreg``: the
+    sublane rotates inside a vreg, q in 7..9; ``sublane``: the slice
+    exchange of whole vregs; ``diag``; ``grid``: a grid-bit diagonal), and
+    the ``must`` ops -- compile time is per op, coverage is per kind.
+    One-op kernels at the 4 MiB tile in this sandbox (PR 36): an
+    ``invreg`` butterfly 2.7 s, a ``sublane`` one 1.0 s. The slice exchange
+    that q in 7..9 took before cost Mosaic 9.2 s for one, 43 s for two,
+    102 s for three and 347 s for four of them, which is why these tests
+    used to leave the sublane zone out."""
     picked, kinds, zones = list(must), set(), set()
     for op in ops_folded:
         if op in picked:
             continue
         if op[0] == "matrix":
-            zone = ("lane" if op[1] < PG.LANE_BITS
-                    else "sublane" if op[1] < lq else "grid")
-            if zone in zones or zone in skip_zones:
+            zone = ("grid" if op[1] >= lq
+                    else _ZONE_OF_KIND[PG.kernel_op_kind(op)])
+            if zone in zones:
                 continue
             zones.add(zone)
         elif op[0] in kinds:
@@ -110,14 +119,13 @@ def _one_of_each(ops_folded, lq, must=(), skip_zones=()):
 
 
 def _fused_kw(n, ops, *, planes=2, sublanes=PG._DEF_SUBLANES, local_n=None,
-              lk=0, sk=0, lh=None, sh=None, ring=None, must=(),
-              skip_zones=()):
+              lk=0, sk=0, lh=None, sh=None, ring=None, must=()):
     """``_fused_local_run``'s static arguments as ``fused_local_run``
     would pass them on the TPU (interpret=False), the op list cut by
     ``_one_of_each`` (double-float runs pass theirs as given)."""
     lq = PG.local_qubits(n, sublanes)
     ops_l = tuple(ops) if planes == 4 else _one_of_each(
-        PG._fold_zone_ops(ops, lq), lq, must, skip_zones)
+        PG._fold_zone_ops(ops, lq), lq, must)
     return dict(n=n, ops=ops_l, sublanes=sublanes, interpret=False,
                 local_n=local_n, load_swap_k=lk, store_swap_k=sk,
                 load_swap_hi=lh, store_swap_hi=sh,
@@ -206,8 +214,8 @@ def _compiled_cell_chain(one_chip, case):
     if case not in _CHAINS:
         n, runs = _cell_chain(case)
         chain = [_fused_kw(n, r.ops, lk=r.load_swap_k, sk=r.store_swap_k,
-                           lh=r.load_swap_hi, sh=r.store_swap_hi,
-                           skip_zones=("sublane",)) for r in runs]
+                           lh=r.load_swap_hi, sh=r.store_swap_hi)
+                 for r in runs]
         _CHAINS[case] = (n, _compile_chain(one_chip, n, chain))
     return _CHAINS[case]
 
@@ -270,6 +278,9 @@ def test_f32_fused_run_26q_plan_pass(one_chip):
     assert (first.tile_bits, first.load_swap_k, first.store_swap_k) \
         == (PG.local_qubits(n), 0, 0)
     assert PG.ring_depth_default() == 3
+    compiled_ops = _fused_kw(n, first.ops)["ops"]
+    assert {"butterfly_invreg", "butterfly_rows", "window"} \
+        <= {PG.kernel_op_kind(op) for op in compiled_ops}
     _compile_fused(one_chip, n, first.ops)
 
 
@@ -287,7 +298,7 @@ def test_f32_fused_run_26q_folded_frame_swap(one_chip):
     assert route[:4] == ("local", True, True, None), route
     _compile_fused(one_chip, n, run.ops, lk=run.load_swap_k,
                    sk=run.store_swap_k, lh=run.load_swap_hi,
-                   sh=run.store_swap_hi, skip_zones=("sublane",))
+                   sh=run.store_swap_hi)
 
 
 def test_f32_per_shard_run_28q_over_4(one_chip):
@@ -306,8 +317,10 @@ def test_f32_per_shard_run_28q_over_4(one_chip):
         q >= n_local for op in r.ops for q in _op_qubits(op)))
     sharded_role = [op for op in plain.ops
                     if any(q >= n_local for q in _op_qubits(op))][:1]
+    assert "butterfly_invreg" in {PG.kernel_op_kind(op) for op in _fused_kw(
+        n_local, plain.ops, local_n=n_local)["ops"]}
     _compile_fused(one_chip, n_local, plain.ops, local_n=n_local,
-                   must=sharded_role, skip_zones=("sublane",))
+                   must=sharded_role)
     # ... and a run whose frame swap is SHARD-LOCAL, so it folds into the
     # per-shard kernel's BlockSpec index maps (the depth-3 plan has one;
     # swaps reaching sharded bits are collective transposes, not kernels)
@@ -355,7 +368,12 @@ def test_df_run_20q(one_chip, monkeypatch):
     lq = PG.local_qubits(n, DF_SUBLANES)
     assert all(q < lq for op in runs[0].ops
                for q in PG.op_dense_targets(op))
-    ops = runs[0].ops[:DF_MAX_OPS][:_DF_OPS]
+    # ... one of them an in-vreg butterfly: the df body exchanges through
+    # the same PG._partner, on four planes
+    invreg = next(op for op in runs[0].ops
+                  if PG.kernel_op_kind(op) == "butterfly_invreg")
+    ops = (invreg,) + tuple(op for op in runs[0].ops[:DF_MAX_OPS]
+                            if op != invreg)[:_DF_OPS - 1]
     assert len(ops) == _DF_OPS
     _compile_fused(one_chip, n, ops, planes=4, sublanes=DF_SUBLANES)
 
@@ -371,7 +389,7 @@ def test_density_kraus_run_14q(one_chip):
     kinds = {op[0] for r in runs for op in r.ops}
     assert {"kraus1", "krausn"} <= kinds, kinds
     run = next(r for r in runs if any(op[0] == "krausn" for op in r.ops))
-    _compile_fused(one_chip, nsv, run.ops, skip_zones=("sublane",))
+    _compile_fused(one_chip, nsv, run.ops)
 
 
 def test_window_dot_26q(one_chip):

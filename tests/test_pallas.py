@@ -182,6 +182,136 @@ def test_rows_view_and_its_inverse_are_the_identity(planes):
         np.asarray(PG._planes_view(chunks, planes)), a)
 
 
+#: the in-vreg exchange (PG._partner on a sublane bit q in 7..9, whose
+#: partner rows lie INSIDE one (8, 128) vreg): every op form that calls it,
+#: on each of the three bits. Geometries (n, sublanes): the smallest tile
+#: that holds the exchange (8 sublanes: ONE vreg a plane, q in 7..9 its
+#: whole sublane axis), 16 sublanes, and the chip's 4096.
+_INVREG_FORMS = ("complex", "real", "zone_control", "lane_control",
+                 "grid_control", "swap", "kraus1")
+_INVREG_GEOMETRY = {"complex": (14, 8), "real": (15, 16),
+                    "zone_control": (14, 8), "lane_control": (20, 4096),
+                    "grid_control": (15, 16), "swap": (20, 4096),
+                    "kraus1": (17, 512)}
+
+
+def _invreg_case(form, q, rng, oracle):
+    """(n, sublanes, kernel ops, the oracle's map of a state vector)."""
+    n, sublanes = _INVREG_GEOMETRY[form]
+    tile_bits = PG.local_qubits(n, sublanes)
+    ind = oracle.apply_to_statevec_indexed
+    u = oracle.random_unitary(1, rng)
+    if form == "real":
+        th = rng.uniform(0.3, 2.8)
+        u = np.array([[np.cos(th), -np.sin(th)], [np.sin(th), np.cos(th)]])
+    if form in ("complex", "real"):
+        return n, sublanes, (("matrix", q, (), (), PG.HashableMatrix(u)),), \
+            lambda v: ind(v, n, [q], u)
+    if form.endswith("_control"):
+        # another in-vreg bit of zone [7, 12); a lane bit; the first grid bit
+        c = {"zone_control": 7 + (q - 6) % 3, "lane_control": 3,
+             "grid_control": tile_bits}[form]
+        assert (c >= tile_bits) == (form == "grid_control") and n > c != q
+        return n, sublanes, (("matrix", q, (c,), (1,),
+                              PG.HashableMatrix(u)),), \
+            lambda v: ind(v, n, [q], u, controls=[c], control_states=[1])
+    if form == "swap":
+        # with q8 (q8 itself with a whole-vreg row bit): both partners of
+        # a swap go through the exchange, one after the other
+        q2 = 8 if q != 8 else 12
+        sw = np.eye(4)[[0, 2, 1, 3]]
+        return n, sublanes, (("swap", q, q2, (), ()),), \
+            lambda v: ind(v, n, [q, q2], sw)
+    # kraus1: K on the row bit q, conj(K) on a column bit, summed over terms
+    col = 12
+    ks = oracle.random_kraus(1, 2, rng)
+    terms = tuple((1.0, PG.HashableMatrix(k)) for k in ks)
+    return n, sublanes, (("kraus1", q, col, terms),), \
+        lambda v: sum(ind(ind(v, n, [q], k), n, [col], np.conj(k))
+                      for k in ks)
+
+
+@pytest.mark.parametrize("q", [7, 8, 9])
+@pytest.mark.parametrize("form", _INVREG_FORMS)
+def test_invreg_exchange_parity_vs_oracle(form, q, monkeypatch):
+    """A dense op on q in 7..9 exchanges partners by sublane rotates inside
+    the (8, 128) vreg (the lane bits' recipe one level up) and agrees with
+    the dense oracle in every form that reaches PG._partner. The fold model
+    is held off, so that the butterfly itself runs whatever its price."""
+    import jax.numpy as jnp
+
+    from . import oracle
+
+    rng = np.random.RandomState(360 + 10 * _INVREG_FORMS.index(form) + q)
+    n, sublanes, ops, ref_of = _invreg_case(form, q, rng, oracle)
+    monkeypatch.setattr(PG, "_fold_zone_ops", lambda ops, lq: tuple(ops))
+    rolled = _spy_vreg_rolls(monkeypatch)
+    psi = oracle.random_statevec(n, rng)
+    got = np.asarray(PG.fused_local_run(
+        jnp.asarray(np.stack([psi.real, psi.imag]), real_dtype()),
+        n=n, ops=ops, sublanes=sublanes, interpret=True))
+    ref = ref_of(psi)
+    assert_amps_close(got, np.stack([ref.real, ref.imag]))
+    assert rolled, "the op on q%d took the slice exchange" % q
+
+
+def _spy_vreg_rolls(monkeypatch):
+    """The shifts of every sublane rotate on the vreg view (groups of 8
+    rows) traced from here on."""
+    rolled = []
+    roll = PG.pltpu.roll
+
+    def spy(x, shift, axis, **kw):
+        if x.ndim == 3 and axis == 1:
+            assert x.shape[1:] == (8, 128)
+            rolled.append(int(shift))
+        return roll(x, shift, axis, **kw)
+
+    monkeypatch.setattr(PG.pltpu, "roll", spy)
+    return rolled
+
+
+@pytest.mark.parametrize("case", ["df-q8", "df-q7-controlled",
+                                  "under-8-sublanes-keeps-slices"])
+def test_invreg_exchange_other_tiles(case, monkeypatch):
+    """The double-float kernel body (pallas_df._ops_body_df) exchanges
+    through the same PG._partner; a tile of fewer than 8 sublanes holds no
+    whole vreg to rotate in and keeps the slice exchange."""
+    import jax.numpy as jnp
+
+    from quest_tpu.ops.pallas_df import df_join, df_split
+
+    from . import oracle
+
+    rng = np.random.RandomState(3600 + len(case))
+    u = oracle.random_unitary(1, rng)
+    rolled = _spy_vreg_rolls(monkeypatch)
+    if case == "under-8-sublanes-keeps-slices":
+        n, sublanes, q, ctrl = 10, 4, 8, ()
+    else:
+        n, sublanes = 14, 8
+        q, ctrl = (8, ()) if case == "df-q8" else (7, (9,))
+    ops = (("matrix", q, ctrl, (1,) * len(ctrl), PG.HashableMatrix(u)),)
+    psi = oracle.random_statevec(n, rng)
+    planes = jnp.asarray(np.stack([psi.real, psi.imag]), jnp.float64)
+    ref = oracle.apply_to_statevec_indexed(
+        psi, n, [q], u, controls=list(ctrl), control_states=[1] * len(ctrl))
+    ref = np.stack([ref.real, ref.imag])
+    if case.startswith("df"):
+        got = np.asarray(df_join(PG.fused_local_run(
+            df_split(planes), n=n, ops=ops, sublanes=sublanes,
+            interpret=True)))
+        # what XLA:CPU leaves of the error-free transforms (see
+        # test_df_kernel_matches_native_f64_interpreter)
+        np.testing.assert_allclose(got, ref, atol=5e-8)
+        assert rolled
+    else:
+        got = np.asarray(PG.fused_local_run(
+            planes, n=n, ops=ops, sublanes=sublanes, interpret=True))
+        np.testing.assert_allclose(got, ref, atol=1e-12)
+        assert rolled == []
+
+
 def test_kernel_rejects_grid_bit_target():
     amps = ops_init.init_debug(1 << 10, real_dtype())
     ops = (("matrix", 9, (), (), PG.HashableMatrix(H)),)

@@ -12,6 +12,19 @@ run, not from subtracting separately-measured floors):
   3. the bf16x3 zone-dot costs (lane_u, window) the fold thresholds
      compare against.
 
+PR 36 adds two per-kind tables, each op timed AS GIVEN -- ``fused_local_run``
+folds what it is handed (16 butterflies of one zone become one window dot),
+so both hold ``_fold_zone_ops`` off -- and launched eagerly, so that Mosaic
+compiles each kernel once. ``python tools/kernelprobe.py 26 ops``
+(``op_slopes``): an op ALONE, the slope between a run of 4 and a run of 16
+of one kind. ``python tools/kernelprobe.py 26 context`` (``op_context``): an
+op AMONG OTHERS, 8 more of one kind spread through the first kernel of the
+26q depth-2 random-layer plan. ``ops`` is NOT for pricing the fold model
+(``pallas_gates._op_cost_ms``): alone, an op costs 2 to 6 times what it adds
+to a mixed kernel and the kinds do not rank alike; put into the model its
+prices made sv30.block's first kernel 24% slower (PERF.md section 6, PR 36,
+call 2). ``context`` is the reading a re-pricing would start from.
+
 Round 8 adds the comm-pipeline sweep (multi-device hosts only): every
 pipelined collective kind x depth {1,2,4,8}, with each eager launch
 self-observing into the ``comm_collective_ms{kind,pipeline}`` histogram
@@ -152,8 +165,113 @@ def dispatch_sweep(n):
                  f"cap={cap}", fn.num_segments)
 
 
+def _op_probe(n, sublanes):
+    """(timed, makers): ``timed(ops)`` = ms a launch of the kernel holding
+    ``ops`` as given (min of 3 timings of 10 eager launches and one sync);
+    ``makers[label](i)`` = the i-th op of a kind the fold model prices."""
+    from quest_tpu.ops import pallas_gates as PG
+    from quest_tpu.ops.pallas_gates import HashableMatrix, fused_local_run
+
+    PG._fold_zone_ops = lambda ops, lq: tuple(ops)   # time the ops as given
+    rng = np.random.RandomState(36)
+
+    def ru(d=2):
+        q, _ = np.linalg.qr(rng.randn(d, d) + 1j * rng.randn(d, d))
+        return HashableMatrix(q)
+
+    def rot(i):
+        th = rng.uniform(0.3, 2.8)
+        return HashableMatrix(np.array([[np.cos(th), -np.sin(th)],
+                                        [np.sin(th), np.cos(th)]]))
+
+    def window(lo, span):
+        u = ru(1 << span).arr
+        return ("window", lo, span, HashableMatrix(
+            np.block([[u.real, -u.imag], [u.imag, u.real]])))
+
+    state = jax.random.normal(jax.random.PRNGKey(36), (2, 1 << n),
+                              jnp.float32) * np.float32(2.0 ** (-(n + 1) / 2))
+
+    def timed(ops, reps=10):
+        ops = tuple(ops)
+        x = fused_local_run(state + 0, n=n, ops=ops, sublanes=sublanes)
+        sync(x)
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                x = fused_local_run(x, n=n, ops=ops, sublanes=sublanes)
+            sync(x)
+            best = min(best, (time.perf_counter() - t0) / reps)
+        return best * 1e3
+
+    lq = PG.local_qubits(n, sublanes)
+    makers = {}
+    for q in (3, 7, 8, 9, 10, 13, lq - 2, lq - 1):
+        kind = PG.kernel_op_kind(("matrix", q, (), (), ru()))
+        makers[f"complex q{q} ({kind})"] = \
+            lambda i, q=q: ("matrix", q, (), (), ru())
+    for q in (3, 8, 13):
+        makers[f"real q{q}"] = lambda i, q=q: ("matrix", q, (), (), rot(i))
+    makers["diagonal"] = lambda i: ("matrix", i % lq, (), (), HashableMatrix(
+        np.diag(np.exp(1j * rng.uniform(0, 6, 2)))))
+    makers["lane_u"] = lambda i: ("lane_u", HashableMatrix(np.stack(
+        [ru(128).arr.real.T, ru(128).arr.real.T, ru(128).arr.real.T])))
+    for lo in range(PG.LANE_BITS, lq, PG._ZONE_SPAN):
+        span = min(PG._ZONE_SPAN, lq - lo)
+        makers[f"window span{span} lo{lo}"] = \
+            lambda i, lo=lo, span=span: window(lo, span)
+    print(f"n={n} S={sublanes} backend={jax.default_backend()}")
+    return timed, makers
+
+
+def op_slopes(n, sublanes=1 << 12):
+    """ms an op of each kind ALONE: the slope between a run of 4 and a run
+    of 16 of them, at 2^n amplitudes f32 (PR 36's chip call 1). Compares
+    exchanges of one op with each other; not the fold model's prices."""
+    timed, makers = _op_probe(n, sublanes)
+    for label, mk in makers.items():
+        t_lo = timed(mk(i) for i in range(4))
+        t_hi = timed(mk(i) for i in range(16))
+        print(f"{label:32s} x4 {t_lo:8.3f} ms  x16 {t_hi:8.3f} ms"
+              f"  -> {(t_hi - t_lo) / 12:7.3f} ms/op", flush=True)
+
+
+def op_context(n, sublanes=1 << 12, extra=8):
+    """ms an op of each kind adds AMONG OTHERS: the first kernel of the nq
+    depth-2 random-layer plan (the benchmark's sv26.block at n = 26) as the
+    fold model folds it today, against the same with ``extra`` more ops of
+    one kind spread through it (PR 36's chip call 3)."""
+    from __graft_entry__ import _random_layers
+
+    from quest_tpu import fusion
+    from quest_tpu.circuits import Circuit
+    from quest_tpu.ops import pallas_gates as PG
+
+    circ = Circuit(n)
+    _random_layers(circ, n, 2)
+    run = next(i for i in fusion.plan_from_tape(circ.fused(
+        max_qubits=5, pallas=True, dtype=np.float32)._tape).items
+        if isinstance(i, fusion.PallasRun))
+    base = list(PG._fold_zone_ops(run.ops, run.tile_bits))
+    timed, makers = _op_probe(n, sublanes)
+    t_base = timed(base)
+    print(f"{'base':32s} {len(base)} ops {t_base:8.3f} ms  "
+          f"{PG.kernel_op_kinds(base)}", flush=True)
+    step = len(base) // extra
+    for label, mk in makers.items():
+        ops = list(base)
+        for j in range(extra):
+            ops.insert(len(base) - j * step, mk(j))
+        t = timed(ops)
+        print(f"{label:32s} +{extra} {t:8.3f} ms"
+              f"  -> {(t - t_base) / extra:7.3f} ms/op", flush=True)
+
+
 def main():
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 26
+    if sys.argv[2:] in (["ops"], ["context"]):
+        return (op_slopes if sys.argv[2] == "ops" else op_context)(n)
     from quest_tpu.ops import pallas_gates as PG
     from quest_tpu.ops.pallas_gates import HashableMatrix, fused_local_run
 
@@ -226,29 +344,7 @@ def main():
                              load_swap_k=6, store_swap_k=6),
                          amps, "ld=6 st=6 S=8192")
 
-    # --- per-op slopes: x4 vs x16 of one kind ---------------------------
-    def slope(label, mk, **kw):
-        nonlocal amps
-        o4 = [mk(i) for i in range(4)]
-        o16 = [mk(i) for i in range(16)]
-        amps, t4 = timeit(run(o4, **kw), amps, f"{label} x4")
-        amps, t16 = timeit(run(o16, **kw), amps, f"{label} x16")
-        print(f"{'':30s} -> {1e3 * (t16 - t4) / 12:8.3f} ms/op slope")
-
-    slope("lane butterfly H", lambda i: ("matrix", i % 7, (), (), H))
-    slope("sublane q7-9 H", lambda i: ("matrix", 7 + i % 3, (), (), H))
-    slope("sublane q10+ H", lambda i: ("matrix", 10 + i % 8, (), (), H))
-    slope("diag T", lambda i: ("matrix", i % 18, (), (), T))
-    W3 = [HashableMatrix(np.stack([ru(128).real.T, ru(128).real.T,
-                                   ru(128).real.T])) for _ in range(16)]
-    slope("lane_u bf16x3", lambda i: ("lane_u", W3[i]))
-    W5 = []
-    for _ in range(16):
-        u32 = ru(32)
-        W5.append(HashableMatrix(np.block([[u32.real, -u32.imag],
-                                           [u32.imag, u32.real]])))
-    slope("window span5 lo7", lambda i: ("window", 7, 5, W5[i]))
-    slope("window span5 lo12", lambda i: ("window", 12, 5, W5[i]))
+    op_slopes(n)
 
 
 if __name__ == "__main__":
