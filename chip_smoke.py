@@ -653,10 +653,11 @@ def phase_df(args, size) -> dict:
     kernels = _require_compiled_kernels(args)
     fb = {k: v for k, v in
           telemetry.counters("engine_fallback_total").items()}
-    # df_max_ops_split counts extra kernel passes (runs longer than
-    # DF_MAX_OPS chain several df kernels), not a departure to the engine
-    left = {k: v for k, v in fb.items() if "df_max_ops_split" not in k and v}
-    _require(not left, f"df runs left for the engine: {left}")
+    # a plan built for its register is cut where a df kernel ends
+    # (fusion._run_op_cap), so not even df_max_ops_split is counted
+    left = {k: v for k, v in fb.items() if v}
+    _require(not left, f"df runs left for the engine, or cut again as "
+                       f"they ran: {left}")
     ctr = _counters("fusion_pallas_runs_total", "pallas_pass_total")
     row = {"qubits": n, "depth": depth, "gates": len(circ),
            "plan_items": len(fused), "compile_s": round(first_s - run_s, 3),

@@ -750,6 +750,24 @@ def _lower_event(ev: GateEvent):
 _RUN_OP_CAP = 96
 
 
+def _run_op_cap(dtype, sharded: bool) -> int:
+    """The most ops one emitted PallasRun holds: the one statement of a
+    plan's cap. A one-device plan for the double-float route
+    (:func:`_df_route`) cuts at ``pallas_df.DF_MAX_OPS``, the longest run a
+    df kernel takes, so that every df kernel is one PallasRun: one pass the
+    plan states, one in-place launch, its frame on its own DMA, and the
+    executor's chunk loop (:func:`_kernel_fn`) never sees more than one
+    chunk of a plan built for its register. A SHARDED df plan keeps
+    ``_RUN_OP_CAP``: a frame that reaches a sharded qubit is a collective,
+    and a piece that carried it in and out would pay it twice; its runs
+    are cut where they execute, and counted (``df_max_ops_split``)."""
+    if not sharded and _df_route(dtype):
+        from .ops.pallas_df import DF_MAX_OPS
+
+        return DF_MAX_OPS
+    return _RUN_OP_CAP
+
+
 class _FramePlanner:
     """Greedy multi-frame scheduler over an ordered list of pending runs
     (see the Scheduling paragraph below; the eager two-slot variant lives
@@ -775,11 +793,14 @@ class _FramePlanner:
     plans (34q sharded, density tapes)."""
 
     def __init__(self, out: FusePlan, tile_bits: int, k: int, nsv: int,
-                 boundary: int | None = None, n_exec: int | None = None):
+                 boundary: int | None = None, n_exec: int | None = None,
+                 run_op_cap: int = _RUN_OP_CAP):
         self.out = out
         self.tb = tile_bits
         self.k = k
         self.nsv = nsv
+        #: ops an emitted run holds at most (:func:`_run_op_cap`)
+        self.run_op_cap = run_op_cap
         self.boundary = boundary  # shard-local qubit count (or None)
         #: qubits of the array a kernel sees: the register, or one shard
         self.n_exec = nsv if n_exec is None else n_exec
@@ -893,7 +914,7 @@ class _FramePlanner:
     # -- emission -----------------------------------------------------------
 
     def _emit_run(self, frame, ops: list):
-        """One PallasRun a ``_RUN_OP_CAP`` ops of the pending run, each
+        """One PallasRun a ``run_op_cap`` ops of the pending run, each
         entering ``frame`` on its load DMA and leaving it on its store DMA
         (zero extra HBM passes): between two items the register is always
         in the identity frame, and a run's load and store relabelings are
@@ -902,10 +923,11 @@ class _FramePlanner:
         hi, k = (None, 0) if frame is None else frame
         # cap ops per kernel: Mosaic compile time explodes past a few
         # hundred ops in one program (20q mono-kernel probe: >20 min at
-        # 316 ops), so over-long runs split into consecutive passes
+        # 316 ops; a df kernel past DF_MAX_OPS), so over-long runs split
+        # into consecutive passes
         phys = [self._phys_op(op, frame) for op in ops]
-        for i in range(0, len(phys), _RUN_OP_CAP):
-            run = PallasRun(tuple(phys[i:i + _RUN_OP_CAP]), self.tb,
+        for i in range(0, len(phys), self.run_op_cap):
+            run = PallasRun(tuple(phys[i:i + self.run_op_cap]), self.tb,
                             load_swap_k=k, load_swap_hi=hi,
                             store_swap_k=k, store_swap_hi=hi)
             assert run.matched, run
@@ -1014,7 +1036,8 @@ class _FramePlannerTwoSlot(_FramePlanner):
 def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
                            tile_bits: int | None,
                            shard_qubits: int | None = None,
-                           df: bool = False) -> None:
+                           df: bool = False,
+                           run_op_cap: int | None = None) -> None:
     """Flight-record a finished plan's shape: item mix, frame-transpose
     counts, tile geometry. One counter per plan plus a structured event
     (the per-plan detail bench.py ships in BENCH_DETAIL.json)."""
@@ -1030,6 +1053,12 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
     telemetry.inc("fusion_pallas_runs_total", len(runs), mode=mode)
     telemetry.inc("fusion_frame_transposes_total", folded + explicit,
                   mode=mode)
+    df_passes = 0
+    if df:
+        # the df kernels the plan states: its runs, but where a run is
+        # still cut as it executes (_kernel_fn)
+        df_passes = sum(len(_df_chunks(r.ops)) for r in runs)
+        telemetry.inc("fusion_df_passes_total", df_passes, mode=mode)
     sharded = {}
     if shard_qubits is not None:
         # what the plan prices, under the names of the counters that say
@@ -1046,7 +1075,11 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         from .ops import pallas_gates as PG
         kernel = dict(kernel_op_kinds=PG.kernel_op_kinds(
             op for r in runs for op in (
-                r.ops if df else PG._fold_zone_ops(r.ops, r.tile_bits))))
+                r.ops if df else PG._fold_zone_ops(r.ops, r.tile_bits))),
+            # the cap the runs were cut at, and for a double-float plan
+            # the kernels it states (pallas_pass_total{dtype=df} then
+            # counts as many a trace)
+            df=df, run_op_cap=run_op_cap, df_passes=df_passes)
     telemetry.event(
         "fusion.plan", mode=mode, nsv=nsv, tile_bits=tile_bits,
         items=len(p.items), pallas_runs=len(runs),
@@ -1091,8 +1124,9 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
             p = _plan_pallas(tape, num_qubits, dtype, max_qubits,
                              pallas_tile_bits, is_density=is_density,
                              shard_boundary=shard_boundary)
-        _record_plan_telemetry(p, "pallas", nsv, pallas_tile_bits,
-                               df=_df_route(dtype))
+        _record_plan_telemetry(
+            p, "pallas", nsv, pallas_tile_bits, df=_df_route(dtype),
+            run_op_cap=_run_op_cap(dtype, shard_boundary is not None))
         return p
     import time as _time
     _t0 = _time.perf_counter()
@@ -1369,7 +1403,8 @@ def plan_pallas_sharded(tape, num_qubits: int, dtype, max_qubits: int,
             transpose_stats(p, n_local)["collective_transposes"],
             len(p.items)))
     _record_plan_telemetry(best, "pallas_sharded", nsv, tile_bits,
-                           shard_qubits=n_local, df=_df_route(dtype))
+                           shard_qubits=n_local, df=_df_route(dtype),
+                           run_op_cap=_run_op_cap(dtype, True))
     return best
 
 
@@ -1392,9 +1427,12 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
     nsv = (2 if is_density else 1) * num_qubits
     k = min(max(nsv - tile_bits, 0), tile_bits - LANE_BITS)
 
+    cap = _run_op_cap(dtype, sharded=(shard_boundary is not None
+                                      or score_shard_qubits is not None))
+
     def make_planner(cls):
         return cls(FusePlan(), tile_bits, k, nsv, boundary=shard_boundary,
-                   n_exec=score_shard_qubits)
+                   n_exec=score_shard_qubits, run_op_cap=cap)
 
     probe = make_planner(_FramePlanner)  # frame geometry only
 
@@ -1820,16 +1858,17 @@ def _kernel_fn(run: PallasRun, route: Route, on_planes: bool = False):
             shard_index=shard_index(), load_swap_k=lk, load_swap_hi=lh,
             store_swap_k=sk, store_swap_hi=sh, ring_depth=run.ring_depth)
 
-    from .ops.pallas_df import DF_MAX_OPS, df_join, df_split
-
-    # Mosaic compile time is superlinear in op count and df ops carry
-    # ~15x the arithmetic, so long runs split into short kernels chained
-    # on the (4, N) planes -- extra HBM passes are cheap next to the
-    # compile blowup (a 27-op df kernel exceeded 9 minutes; 8-op kernels
-    # compile in seconds); folded swaps ride the first / last chunk's DMA
-    ops = run.ops
-    chunks = ([ops[i:i + DF_MAX_OPS]
-               for i in range(0, len(ops), DF_MAX_OPS)] or [ops])
+    # a df kernel takes at most DF_MAX_OPS ops (Mosaic compile time is
+    # superlinear in op count and a df op carries ~15x the arithmetic: a
+    # 27-op df kernel exceeded 9 minutes, 8-op kernels compile in
+    # seconds). A one-device plan built for this register is already cut
+    # there (:func:`_run_op_cap`): one chunk, nothing counted. What still
+    # arrives longer is cut here into kernels chained on the (4, N)
+    # planes, folded swaps riding the first / last chunk's DMA: a run of a
+    # SHARDED df plan (its frame may be a collective, which a piece of its
+    # own would pay twice), and a plan replayed on a register it was not
+    # built for
+    chunks = _df_chunks(run.ops)
     if len(chunks) > 1:
         # each extra chunk is one extra HBM pass the plan did not price
         # in -- visible, not silent (ISSUE 1 tentpole)
@@ -1852,7 +1891,35 @@ def _kernel_fn(run: PallasRun, route: Route, on_planes: bool = False):
 
     if on_planes:
         return planes_fn
-    return lambda x: df_join(planes_fn(df_split(x)))
+    return lambda x: _df_join(planes_fn(_df_split(x)))
+
+
+def _df_chunks(ops: tuple) -> list:
+    """``ops`` in pieces of at most ``DF_MAX_OPS``, one df kernel each (an
+    empty run is one empty piece)."""
+    from .ops.pallas_df import DF_MAX_OPS
+
+    return ([ops[i:i + DF_MAX_OPS]
+             for i in range(0, len(ops), DF_MAX_OPS)] or [ops])
+
+
+def _df_split(amps64):
+    """``pallas_df.df_split`` around a fused run, counted once a trace
+    (``fusion_df_conversions_total{dir=split}``): a pass over the f64
+    state and its planes that the plan's kernels do not state."""
+    from .ops.pallas_df import df_split
+
+    telemetry.inc("fusion_df_conversions_total", dir="split")
+    return df_split(amps64)
+
+
+def _df_join(planes):
+    """``pallas_df.df_join`` around a fused run, counted as
+    :func:`_df_split` (``dir=join``)."""
+    from .ops.pallas_df import df_join
+
+    telemetry.inc("fusion_df_conversions_total", dir="join")
+    return df_join(planes)
 
 
 def _per_shard(fn, mesh):
@@ -1887,7 +1954,6 @@ def _sched_df_run(qureg, run: PallasRun, route: Route):
     (exchange.dist_permute_bits carries all four planes natively;
     chunk-units price at the df 2x scale --
     scheduler.DistributedScheduler.apply_frame_permute)."""
-    from .ops.pallas_df import df_join, df_split
     from .parallel import scheduler as _dist
 
     sched = _dist.active()
@@ -1902,11 +1968,11 @@ def _sched_df_run(qureg, run: PallasRun, route: Route):
             lo2=lo2, k=k, pipeline=run.comm_pipeline,
             pipeline_dcn=run.comm_pipeline_dcn)
 
-    planes = permute(df_split(qureg.amps), run.load_swap_k,
+    planes = permute(_df_split(qureg.amps), run.load_swap_k,
                      run.load_swap_hi)
     planes = _per_shard(_kernel_fn(run, route, on_planes=True),
                         route.mesh)(planes)
-    return df_join(permute(planes, run.store_swap_k, run.store_swap_hi))
+    return _df_join(permute(planes, run.store_swap_k, run.store_swap_hi))
 
 
 def _apply_ops_via_engine(qureg, ops: tuple) -> None:

@@ -44,6 +44,7 @@ from __future__ import annotations
 import math
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -67,8 +68,6 @@ def df_wanted() -> bool:
     -- the switch the CPU-mesh parity suite and the driver dryrun flip so
     the sharded df route executes in CI exactly as it does on-chip.
     Off-TPU default stays the native-f64 interpreter/engine routing."""
-    import jax
-
     if jax.default_backend() == "tpu":
         return True
     return os.environ.get(_DF_ENV, "").strip() == "1"
@@ -203,14 +202,17 @@ def _sel_consts(pred, va, vb, shape):
 
 def df_split(amps64):
     """(2, N) f64 planar state -> (4, N) f32 [re_hi, im_hi, re_lo, im_lo]."""
-    hi = amps64.astype(jnp.float32)
-    lo = (amps64 - hi.astype(jnp.float64)).astype(jnp.float32)
-    return jnp.concatenate([hi, lo], axis=0)
+    with jax.named_scope("df_split"):
+        hi = amps64.astype(jnp.float32)
+        lo = (amps64 - hi.astype(jnp.float64)).astype(jnp.float32)
+        return jnp.concatenate([hi, lo], axis=0)
 
 
 def df_join(planes):
     """(4, N) f32 df planes -> (2, N) f64 planar state."""
-    return planes[:2].astype(jnp.float64) + planes[2:].astype(jnp.float64)
+    with jax.named_scope("df_join"):
+        return (planes[:2].astype(jnp.float64)
+                + planes[2:].astype(jnp.float64))
 
 
 # ---------------------------------------------------------------------------
@@ -241,8 +243,6 @@ def df_total_prob(planes, accurate: bool | None = None):
         h2 = hi.reshape(-1, 2)
         l2 = lo.reshape(-1, 2)
         hi, lo = add((h2[:, 0], l2[:, 0]), (h2[:, 1], l2[:, 1]))
-    import jax
-
     if jax.config.jax_enable_x64:
         return jnp.sum(hi.astype(jnp.float64)) + jnp.sum(lo.astype(jnp.float64))
     return jnp.sum(hi) + jnp.sum(lo)

@@ -378,6 +378,48 @@ def test_df_run_20q(one_chip, monkeypatch):
     _compile_fused(one_chip, n, ops, planes=4, sublanes=DF_SUBLANES)
 
 
+def test_df26_program_holds_the_register_and_one_set_of_planes(one_chip,
+                                                               monkeypatch):
+    """``df26.block``'s whole tape program (26 qubits, PRECISION=2) for the
+    described chip: twelve df kernels of at most ``DF_MAX_OPS`` ops, one a
+    planned run; the donated 1 GiB f64 register aliased to the output; and
+    temporaries of ONE set of planes (1 GiB), which every kernel writes in
+    place -- the f64 <-> planes conversions between them do not add a
+    second. The route is steered here as the chip steers it on its own
+    (``jax.default_backend`` reads ``tpu`` there: the df route, and kernels
+    lowered for Mosaic, not the interpreter). About 30 s."""
+    import sys
+
+    from quest_tpu.circuits import named_program
+
+    n = 26
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "circuits")
+    sys.path.insert(0, bench)
+    try:
+        import random_layers
+    finally:
+        sys.path.remove(bench)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    circ = Circuit(n)
+    random_layers.build(circ, num_qubits=n, depth=2, circuit_seed=2026)
+    fused = circ.fused(max_qubits=5, pallas=True, dtype=np.float64)
+    runs = pallas_runs(fused)
+    assert len(runs) == 12 and max(len(r.ops) for r in runs) == DF_MAX_OPS
+    amps = jax.ShapeDtypeStruct((2, 1 << n), jnp.float64, sharding=one_chip)
+    compiled = jax.jit(named_program(fused.as_fn(), fused, "circuit"),
+                       donate_argnums=(0,)).lower(amps).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(runs)
+    assert len(set(re.findall(r"qt_fused_dma_df_ops\d+_ls\d+_ss\d+",
+                              text))) == 6
+    state = 16 << n
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == mem.output_size_in_bytes \
+        == mem.alias_size_in_bytes == state
+    assert state <= mem.temp_size_in_bytes < state + (1 << 20)
+
+
 def test_density_kraus_run_14q(one_chip):
     """The 14q density circuit (2^28 amplitudes, 2 GiB): the run holding
     the kraus1 (mixDepolarising / mixKrausMap) and krausn
