@@ -1078,8 +1078,14 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
                 r.ops if df else PG._fold_zone_ops(r.ops, r.tile_bits))),
             # the cap the runs were cut at, and for a double-float plan
             # the kernels it states (pallas_pass_total{dtype=df} then
-            # counts as many a trace)
-            df=df, run_op_cap=run_op_cap, df_passes=df_passes)
+            # counts as many a trace) and, on one device, the runs that
+            # stand right behind a run and so take its planes
+            # (fusion_df_carried_total: _df_local_run)
+            df=df, run_op_cap=run_op_cap, df_passes=df_passes,
+            df_carried=sum(
+                isinstance(a, PallasRun) and isinstance(b, PallasRun)
+                for a, b in zip(p.items, p.items[1:]))
+            if df and shard_qubits is None else 0)
     telemetry.event(
         "fusion.plan", mode=mode, nsv=nsv, tile_bits=tile_bits,
         items=len(p.items), pallas_runs=len(runs),
@@ -1838,8 +1844,9 @@ def _kernel_fn(run: PallasRun, route: Route, on_planes: bool = False):
     (the register, or inside shard_map one shard of it, where op roles on
     sharded qubits resolve against the shard index), folded relabelings
     riding its DMA. A df route splits to the 4-plane layout, runs the df
-    kernels and joins back (split / join are exact and shard-local);
-    ``on_planes`` leaves both to the caller."""
+    kernels and joins back (split / join are exact and shard-local: the
+    ``sharded`` route's way, a run at a time); ``on_planes`` leaves both
+    to the caller (:func:`_df_local_run`, :func:`_sched_df_run`)."""
     import jax
 
     from .environment import AMP_AXIS
@@ -1894,6 +1901,35 @@ def _kernel_fn(run: PallasRun, route: Route, on_planes: bool = False):
     return lambda x: _df_join(planes_fn(_df_split(x)))
 
 
+def _df_local_run(qureg, run: PallasRun, route: Route):
+    """Executor of the ``df_local`` route: the df kernel on the (4, N)
+    planes, returned as the f64 array they join to. The planes come from
+    the df run before this one where that run's result is still what the
+    register holds (``Qureg.df_planes``, told by identity; counted
+    ``fusion_df_carried_total``) and from a split of the register
+    otherwise, so a chain of df runs splits once and joins once: whatever
+    else reads ``qureg.amps`` between two runs reads the joined array, and
+    the next run then splits what it left.
+
+    Inside a trace the join of a run whose planes were taken is dead code
+    that XLA drops, and the taker's join stands in its place: it is not
+    counted a second time, so ``fusion_df_conversions_total`` counts what
+    the compiled program holds. Run eagerly every join has executed: the
+    carry saves the split alone, and each join counts."""
+    import jax
+
+    kept = qureg.df_planes
+    carried = kept is not None and kept[0] is qureg.amps
+    if carried:
+        telemetry.inc("fusion_df_carried_total")
+    planes = _kernel_fn(run, route, on_planes=True)(
+        kept[1] if carried else _df_split(qureg.amps))
+    amps = _df_join(planes, count=not (
+        carried and isinstance(planes, jax.core.Tracer)))
+    qureg.df_planes = (amps, planes)
+    return amps
+
+
 def _df_chunks(ops: tuple) -> list:
     """``ops`` in pieces of at most ``DF_MAX_OPS``, one df kernel each (an
     empty run is one empty piece)."""
@@ -1913,12 +1949,14 @@ def _df_split(amps64):
     return df_split(amps64)
 
 
-def _df_join(planes):
+def _df_join(planes, count: bool = True):
     """``pallas_df.df_join`` around a fused run, counted as
-    :func:`_df_split` (``dir=join``)."""
+    :func:`_df_split` (``dir=join``) unless it takes the place of a join
+    already counted (:func:`_df_local_run`)."""
     from .ops.pallas_df import df_join
 
-    telemetry.inc("fusion_df_conversions_total", dir="join")
+    if count:
+        telemetry.inc("fusion_df_conversions_total", dir="join")
     return df_join(planes)
 
 
@@ -1938,6 +1976,8 @@ def _per_shard(fn, mesh):
 def _kernel_run(qureg, run: PallasRun, route: Route):
     """Executor of the ``local``, ``df_local`` and ``sharded`` routes: the
     register's new amplitudes."""
+    if route.kind == "df_local":
+        return _df_local_run(qureg, run, route)
     fn = _kernel_fn(run, route)
     if route.mesh is None:
         return fn(qureg.amps)

@@ -44,6 +44,10 @@ class Qureg:
     qasm_log: Optional[QASMLogger] = None
     #: lazily-created host planar mirror for copyState{To,From}GPU
     host_amps: Optional[np.ndarray] = None
+    #: ``(amps, planes)``: the (4, N) double-float planes a fused df run
+    #: joined ``amps`` from, for the df run after it to take in place of a
+    #: split while ``self.amps is amps`` (``fusion._df_local_run``)
+    df_planes: Optional[tuple] = None
 
     @property
     def state_vec(self) -> np.ndarray:

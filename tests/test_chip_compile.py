@@ -384,10 +384,14 @@ def test_df26_program_holds_the_register_and_one_set_of_planes(one_chip,
     described chip: twelve df kernels of at most ``DF_MAX_OPS`` ops, one a
     planned run; the donated 1 GiB f64 register aliased to the output; and
     temporaries of ONE set of planes (1 GiB), which every kernel writes in
-    place -- the f64 <-> planes conversions between them do not add a
-    second. The route is steered here as the chip steers it on its own
-    (``jax.default_backend`` reads ``tpu`` there: the df route, and kernels
-    lowered for Mosaic, not the interpreter). About 30 s."""
+    place. The planes are carried from kernel to kernel (PR 38): between
+    the first and the last kernel nothing but bitcasts stands, ONE fusion
+    makes the planes (the split; before it the X64 rewrite's custom calls
+    and the fusion that takes the f64 words' high part) and ONE fusion
+    after the last kernel reads them (the join). The route is steered here
+    as the chip steers it on its own (``jax.default_backend`` reads ``tpu``
+    there: the df route, and kernels lowered for Mosaic, not the
+    interpreter). About 40 s."""
     import sys
 
     from quest_tpu.circuits import named_program
@@ -413,6 +417,16 @@ def test_df26_program_holds_the_register_and_one_set_of_planes(one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == len(runs)
     assert len(set(re.findall(r"qt_fused_dma_df_ops\d+_ls\d+_ss\d+",
                               text))) == 6
+    entry = text[text.index("ENTRY"):].splitlines()
+    kernels = [i for i, line in enumerate(entry) if "tpu_custom_call" in line]
+    assert len(kernels) == len(runs)
+    between = re.findall(r"[})] ([a-z-]+)\(",
+                         "\n".join(entry[kernels[0]:kernels[-1] + 1]))
+    assert set(between) == {"custom-call", "bitcast"}, between
+    fusions = [i for i, line in enumerate(entry) if " fusion(" in line]
+    assert sum(i < kernels[0] for i in fusions) == 2 \
+        and sum(i > kernels[-1] for i in fusions) == 1, fusions
+    assert f" = f32[4,{1 << n}]" in entry[fusions[1]]
     state = 16 << n
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == mem.output_size_in_bytes \
