@@ -22,6 +22,16 @@ Architecture map (reference -> here):
   MPI exchange (L1 distributed)   -> parallel/ + XLA SPMD collectives
 """
 
+import sys as _sys
+import time as _time
+
+#: the package's own import is timed where it runs (the gauge
+#: ``quest_tpu_import_seconds``, set on the last line): a process that
+#: imported JAX first -- ``jax`` in ``sys.modules`` here -- reads the
+#: package alone, any other JAX's import with it (``jax_included`` = 1)
+_IMPORT_T0 = _time.perf_counter()
+_IMPORT_JAX = "jax" not in _sys.modules
+
 from .datatypes import (  # noqa: F401
     PAULI_I, PAULI_X, PAULI_Y, PAULI_Z,
     DiagonalOp, PauliHamil, SubDiagonalOp, Vector,
@@ -80,3 +90,7 @@ from . import gradients  # noqa: F401
 from .gradients import gradient_executable, parameter_shift  # noqa: F401
 
 __version__ = "0.1.0"
+
+telemetry.set_gauge("quest_tpu_import_seconds",
+                    _time.perf_counter() - _IMPORT_T0,
+                    jax_included=int(_IMPORT_JAX))

@@ -219,8 +219,42 @@ def named_program(fn, circuit, route: str, *extra) -> object:
     kind = "dm" if circuit.is_density_matrix else "sv"
     name = "_".join([f"qt_{route}_{kind}_n{circuit.num_qubits}"
                      f"_g{len(circuit._tape)}", *map(str, extra)])
+    fn = _on_a_wide_frame(fn)
     fn.__name__ = fn.__qualname__ = name
     return fn
+
+
+#: interpreter stack slots the body of a traced program declares (8 MiB
+#: and a little: the chunk it forces is the next power of two, 16 MiB)
+_TRACE_STACK_SLOTS = (1 << 20) + 64
+
+
+def _on_a_wide_frame(fn):
+    """``fn`` behind a frame that gets a data-stack chunk of its own.
+
+    CPython keeps interpreter frames on a per-thread stack of 16 KiB
+    chunks: a call whose frame does not fit the current chunk maps a new
+    one, and its return unmaps it at once, so a call site that sits at
+    such a depth pays two system calls every time it is reached. A JAX
+    trace runs a hundred frames deep across several chunk ends, and on
+    the chip's host, where a system call is dear, that is most of a warm
+    first call (``df26.block``: 68 s of trace for 3; ``PERF.md`` section
+    6, PR 39). A code object that declares a stack of
+    :data:`_TRACE_STACK_SLOTS` slots must be given one chunk large enough
+    for itself, and the half of it that stays free holds every frame
+    below -- pages nobody touches are never faulted. The body of a jitted
+    program runs only when JAX traces it: a warm call never comes here."""
+    def body(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    try:
+        wide = type(body)(
+            body.__code__.replace(co_stacksize=_TRACE_STACK_SLOTS),
+            body.__globals__, body.__name__, None, body.__closure__)
+        wide.__signature__ = inspect.signature(fn)   # JAX names the arguments
+    except (AttributeError, TypeError, ValueError):  # no such code objects
+        return fn
+    return wide
 
 
 class Circuit:
@@ -397,6 +431,7 @@ class Circuit:
                 with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
                     return _inner(amps)
 
+            fn.__name__ = inner.__name__     # what a first call's record says
             return fn
 
         return _ec.executables().get_or_create(key, build)
@@ -485,6 +520,7 @@ class Circuit:
                 with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
                     return _inner(amps, values)
 
+            fn.__name__ = inner.__name__
             return fn
 
         return ParamExecutable(_ec.executables().get_or_create(key, build),
@@ -680,10 +716,17 @@ class Circuit:
         # is spent: cache lookup, mesh context, the jitted call, the put.
         # It ends before any sync, and is on every application's path: a
         # region (aggregate + profiler annotation), never a ring event
-        with telemetry.region("circuit.run"), \
+        mark = telemetry.compile_mark()
+        with telemetry.region("circuit.run") as rg, \
                 fusion.pallas_mesh(_register_mesh(qureg)):
             telemetry.inc("device_dispatch_total", route="circuit")
-            qureg.put(self.compiled()(qureg.amps))
+            program = self.compiled()
+            qureg.put(program(qureg.amps))
+        if telemetry.compile_mark() is not mark:
+            # a call that traced or compiled is a first call: one record
+            # that names the program and tiles the region (a warm call
+            # pays the two thread-local reads)
+            telemetry.first_call(mark, rg, program.__name__, "circuit")
         return qureg
 
     def run_segmented(self, target, *, checkpoint_dir: str,

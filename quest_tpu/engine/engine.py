@@ -778,6 +778,7 @@ class Engine:
                 with _dist.explicit_mesh(None), fusion.pallas_mesh(None):
                     return _inner(amps, *packed)
 
+            fn.__name__ = jitted.__name__
             return fn
 
         return _cache.executables().get_or_create(self._batch_key(), build)
@@ -1140,22 +1141,22 @@ class Engine:
             tr.event("error", type=type(exc).__name__)
             tr.end(status="error")
 
-    def _launch(self, batch, call):
-        """Run ``call`` (one program launch) as the ``engine.launch``
-        region and charge it to the traced requests of ``batch``:
-        ``compile`` when the launch retraced (the retrace-counter delta
-        decides), ``dispatch`` otherwise."""
-        traced = any(req.trace is not None for req in batch)
-        if traced:
-            before = telemetry.counter_value("engine_trace_total",
-                                             kind="param_replay")
+    def _launch(self, batch, call, program, route: str):
+        """Run ``call`` (one launch of the executable ``program`` on
+        ``route``) as the ``engine.launch`` region and charge it to the
+        traced requests of ``batch``: ``compile`` when the launch
+        retraced, ``dispatch`` otherwise. A launch retraced when JAX's
+        compile path reported anything on this thread inside it (two
+        thread-local reads, ``telemetry.compile_mark``); it then leaves one
+        ``program.first_call`` record -- in the middle of a served
+        window too, which is how an operator learns which program
+        recompiled and what that cost."""
+        mark = telemetry.compile_mark()
         with telemetry.region("engine.launch") as rg:
             out = call()
-        if traced:
-            retraced = telemetry.counter_value(
-                "engine_trace_total", kind="param_replay") > before
-            self._charge(batch, "compile" if retraced else "dispatch",
-                         rg.t1)
+        retraced = telemetry.compile_mark() is not mark and \
+            telemetry.first_call(mark, rg, program.__name__, route)
+        self._charge(batch, "compile" if retraced else "dispatch", rg.t1)
         return out
 
     def _sync(self, batch, out) -> None:
@@ -1194,7 +1195,7 @@ class Engine:
                 # batch mates is this request's in-batch queueing
                 req.trace.charge("queue_wait", time.perf_counter())
             res = self._launch(one, lambda: self._maybe_corrupt(
-                x.with_values(self.initial_amps + 0, req.values)))
+                x.with_values(self.initial_amps + 0, req.values)), x, "param")
             if req.trace is not None:
                 # an explicit sync (the device phase) separates dispatch
                 # from device drain. Tracing-armed requests only -- the
@@ -1223,7 +1224,7 @@ class Engine:
                           route=self._route or "engine_param")
             x = self._lookup(batch, self._exec1)
             out = self._launch(batch, lambda: self._maybe_corrupt(
-                x.with_values(self.initial_amps + 0, ())))
+                x.with_values(self.initial_amps + 0, ())), x, "param")
             if traced:
                 self._sync(batch, out)
             self._sentinel_gate(out)
@@ -1253,7 +1254,8 @@ class Engine:
         telemetry.inc("device_dispatch_total",
                       route=self._route or "engine_vmap")
         telemetry.inc("engine_launch_args_total", 1 + len(packed))
-        out = self._launch(batch, lambda: fnB(self.initial_amps, *packed))
+        out = self._launch(batch, lambda: fnB(self.initial_amps, *packed),
+                           fnB, "engine_vmap")
         if defer:
             # ASYNC ISSUE: park the in-flight result on the completion
             # ring and return to coalescing -- the device executes batch k

@@ -373,6 +373,7 @@ class ParamExecutable:
 
     def __init__(self, fn, lifted: LiftedTape, fingerprint: str):
         self._fn = fn
+        self.__name__ = fn.__name__      # the jitted program's
         self.lifted = lifted
         self.fingerprint = fingerprint
 

@@ -1122,8 +1122,6 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
     more high qubits for the frame machinery to relabel (the round-2 build
     excluded density tapes entirely; VERDICT r2 missing #1).
     """
-    from .ops.apply import _MIN_MINOR, MAX_LOW_WINDOW_TOP
-
     nsv = (2 if is_density else 1) * num_qubits
     if pallas_tile_bits is not None:
         with telemetry.span("fusion.plan", mode="pallas"):
@@ -1134,8 +1132,18 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
             p, "pallas", nsv, pallas_tile_bits, df=_df_route(dtype),
             run_op_cap=_run_op_cap(dtype, shard_boundary is not None))
         return p
-    import time as _time
-    _t0 = _time.perf_counter()
+    with telemetry.span("fusion.plan", mode="dense"):
+        out = _plan_dense(tape, num_qubits, dtype, max_qubits,
+                          max_diag_qubits)
+    _record_plan_telemetry(out, "dense", nsv, None)
+    return out
+
+
+def _plan_dense(tape, num_qubits: int, dtype, max_qubits: int,
+                max_diag_qubits: int) -> FusePlan:
+    """The dense arm of :func:`plan`: window and diagonal blocks."""
+    from .ops.apply import _MIN_MINOR, MAX_LOW_WINDOW_TOP
+
     out = FusePlan()
     cur = None  # None | FusedBlock | DiagBlock (mutable accumulators)
 
@@ -1244,9 +1252,6 @@ def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
                 add_dense(ev)
             out.num_fused_gates += 1
     flush()
-    telemetry.observe("fusion.plan_seconds", _time.perf_counter() - _t0,
-                      mode="dense")
-    _record_plan_telemetry(out, "dense", nsv, None)
     return out
 
 

@@ -1108,7 +1108,11 @@ def fused_local_run(amps, *, n: int, ops: tuple, sublanes: int = _DEF_SUBLANES,
     else:
         shard_index = jnp.asarray(shard_index, jnp.int32).reshape(1)
         local_n = n
+    # the compile record's clock starts before the zone fold, which is
+    # part of what a new kernel costs the host (_compile_record)
+    t0 = time.perf_counter()
     ops_l = tuple(ops) if df else _fold_zone_ops(ops, lq)
+    t_fold = time.perf_counter()
     ring = (max(2, int(ring_depth)) if ring_depth is not None
             else ring_depth_default())
     from .pallas_df import accurate_add_enabled
@@ -1116,11 +1120,12 @@ def fused_local_run(amps, *, n: int, ops: tuple, sublanes: int = _DEF_SUBLANES,
 
     # one jitted function a kernel name, so that the device trace tells
     # the kernels of a program apart (_named_jit)
-    run = _named_jit(_fused_local_run_impl, kernel_name(
+    name = kernel_name(
         _kernel_kind(_tile_geometry(amps.shape[-1], sublanes)[2], local_n,
                      df),
         amps.shape[0], amps.dtype, len(ops_l), int(load_swap_k),
-        int(store_swap_k)), _FUSED_STATIC)
+        int(store_swap_k))
+    run = _named_jit(_fused_local_run_impl, name, _FUSED_STATIC)
 
     def call():
         return run(
@@ -1147,25 +1152,43 @@ def fused_local_run(amps, *, n: int, ops: tuple, sublanes: int = _DEF_SUBLANES,
            amps.shape, bool(interpret), ring, df_acc)
     if sig in _SEEN_KERNEL_SIGS:
         return call()
-    # first dispatch of a new kernel signature: wall time here is Mosaic
-    # trace+compile (eager call) or just tracing (inside an outer jit);
-    # either way it is the host-side cost a new signature charges
     _SEEN_KERNEL_SIGS.add(sig)
-    t0 = time.perf_counter()
+    mark = telemetry.compile_mark()
     out = call()
-    dt = time.perf_counter() - t0
-    telemetry.observe("mosaic_compile_seconds", dt, kind=kind)
-    telemetry.event("pallas.compile", kind=kind, n=n, ops=len(ops_l),
+    _compile_record(kind, name, t0, t_fold, mark, n=n, ops=len(ops_l),
                     sublanes=min(sublanes, max(amps.shape[-1] >> LANE_BITS,
                                                1)),
                     load_swap_k=int(load_swap_k),
                     store_swap_k=int(store_swap_k), ring=ring,
-                    interpret=bool(interpret), seconds=round(dt, 4))
+                    interpret=bool(interpret))
     return out
 
 
 #: kernel signatures already dispatched once (compile timing recorded)
 _SEEN_KERNEL_SIGS: set = set()
+
+
+def _compile_record(kind: str, name: str, t0: float, t_fold: float, mark,
+                    **fields) -> None:
+    """The record of a new kernel signature's first dispatch, made as it
+    returns: ``mosaic_compile_seconds{kind}`` and one ``pallas.compile``
+    event that says which ``kernel`` (the ``pallas_call``'s ``name=``,
+    which the device trace shows; the event's own ``name`` is taken: the
+    benchmark finds the record by it). ``fold_s`` is the zone fold (``t0`` to
+    ``t_fold``), ``trace_s`` -- and ``seconds``, its older name -- the
+    dispatch after it: Mosaic trace and compile on an eager call; inside
+    an outer ``jit``, where nothing compiles yet, the kernel body's Python
+    trace. The histogram takes both. ``nested_traces`` counts the JAX
+    trace events that fired inside since ``mark``
+    (``telemetry.compile_mark``, read before the dispatch). Either way it
+    is the host-side cost a new signature charges."""
+    t1 = time.perf_counter()
+    nested = telemetry.kernel_traced(name, mark)
+    telemetry.observe("mosaic_compile_seconds", t1 - t0, kind=kind)
+    trace_s = round(t1 - t_fold, 4)
+    telemetry.event("pallas.compile", kind=kind, kernel=name, **fields,
+                    seconds=trace_s, fold_s=round(t_fold - t0, 4),
+                    trace_s=trace_s, nested_traces=nested)
 
 
 def kernel_name(kind: str, planes: int, dtype, nops: int,
@@ -1541,10 +1564,24 @@ def window_dot(amps, matrix, *, n: int, lo: int, hi: int, conj: bool = False,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     telemetry.inc("pallas_pass_total", kind="window_dot")
-    run = _named_jit(_window_dot_impl, _window_dot_name(amps.dtype, lo, hi),
-                     _WINDOW_STATIC)
-    return run(amps, matrix, n=n, lo=lo, hi=hi, conj=conj,
-               interpret=bool(interpret))
+    name = _window_dot_name(amps.dtype, lo, hi)
+    run = _named_jit(_window_dot_impl, name, _WINDOW_STATIC)
+
+    def call():
+        return run(amps, matrix, n=n, lo=lo, hi=hi, conj=conj,
+                   interpret=bool(interpret))
+
+    sig = (name, n, bool(conj), amps.shape, bool(interpret))
+    if not telemetry.enabled() or sig in _SEEN_KERNEL_SIGS:
+        return call()
+    # a kernel of its own: the record a fused run's new signature gets
+    _SEEN_KERNEL_SIGS.add(sig)
+    t0 = time.perf_counter()
+    mark = telemetry.compile_mark()
+    out = call()
+    _compile_record("window_dot", name, t0, t0, mark, n=n, lo=lo, hi=hi,
+                    interpret=bool(interpret))
+    return out
 
 
 def _make_window_dot_kernel(ac: int, d: int):

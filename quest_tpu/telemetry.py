@@ -26,11 +26,16 @@ of them report into and every artifact is derived from:
   the engine's per-batch ``engine.*`` regions): aggregate and annotate
   only -- no ring event, no wall-clock read -- and the handle keeps the
   window's ``perf_counter`` stamps for the engine's phase attribution.
-- **Compile events from inside JAX** (:func:`watch_jax_compiles`): two
+- **Compile events from inside JAX** (:func:`watch_jax_compiles`):
   ``jax.monitoring`` listeners feed ``jax_trace_seconds``,
   ``jax_lower_seconds``, ``jax_backend_compile_seconds``,
   ``jax_cache_retrieval_seconds`` and ``jax_cache_{hits,misses}_total``;
-  nested intervals on one thread are charged once.
+  nested intervals on one thread are charged once, however many nest
+  (:func:`_own_time`). A window in which a program is first called reads
+  the thread's totals at both ends (:func:`compile_mark`) and, where they
+  moved, emits one ``program.first_call`` event that names the program
+  and tiles the window into trace, lowering, compile, cache load and the
+  rest (:func:`first_call`).
 - **Snapshots**: :func:`snapshot` returns the whole registry as one nested
   JSON-ready dict -- ``benchmark/run.py`` takes one where set-up ends and
   one after the window, and every per-layer reader is a pure function of
@@ -87,7 +92,8 @@ import time
 
 __all__ = [
     "enabled", "disabled", "inc", "set_gauge", "observe", "span", "region",
-    "event", "watch_jax_compiles",
+    "event", "watch_jax_compiles", "compile_mark", "kernel_traced",
+    "first_call",
     "counter_value", "counter_total", "counters", "snapshot", "reset",
     "export_jsonl", "events",
     "PHASES", "TraceContext", "trace_on", "trace_mode", "trace_policy",
@@ -1116,24 +1122,85 @@ _JAX_EVENTS = {
     "/jax/compilation_cache/cache_hits": "jax_cache_hits_total",
     "/jax/compilation_cache/cache_misses": "jax_cache_misses_total",
 }
-#: intervals one thread keeps to find the nested ones (see below)
-_JAX_INTERVALS_MAX = 1024
+#: the intervals JAX announces where they START (a ``record_scalar`` of the
+#: event's name from ``dispatch.log_elapsed_time``) as well as where they
+#: end; a cache retrieval is only reported once it is over, and holds none
+_JAX_ANNOUNCED = frozenset(e for e in _JAX_DURATIONS
+                           if "/compilation_cache/" not in e)
 _JAX_WATCHING = False
 
+#: one thread's running totals (:func:`compile_mark`), in order: the four
+#: phases are the four series' own-time sums, in ``_JAX_DURATIONS``' order
+_MARK_FIELDS = ("events", "traces", "trace_s", "lower_s", "compile_s",
+                "cache_load_s", "cache_hits", "cache_misses", "kernels",
+                "kernel_trace_s")
+_MARK_ZERO = (0, 0, 0.0, 0.0, 0.0, 0.0, 0, 0, 0, 0.0)
+#: duration event -> (histogram, its phase's place in the totals)
+_JAX_PHASE = {event: (name, 2 + i)
+              for i, (event, name) in enumerate(_JAX_DURATIONS.items())}
 
-def _own_time(seen, start: float, end: float) -> float:
-    """What the interval [start, end] is charged: its length less the
-    intervals of ``seen`` (one thread's, in the order they ended) nested
-    in it, which it then replaces there. Intervals end in the order they
-    are reported and nest like a stack, so everything nested in this one
-    sits at the tail. ``start`` comes from a duration JAX measured on
-    another clock: a nested interval may seem to begin a moment early."""
+
+class _CompileState(threading.local):
+    """What one thread's listeners keep: ``seen``, the stack of
+    :func:`_own_time`; ``mark``, the thread's running totals as one
+    immutable tuple (:data:`_MARK_FIELDS`), replaced -- never changed --
+    by every compile-path event on the thread, so that whoever holds an
+    earlier one tells by identity that something compiled since;
+    ``kernels``, the names of the kernels traced since the last
+    ``program.first_call`` record, in order."""
+
+    def __init__(self):
+        self.seen: list = []
+        self.mark: tuple = _MARK_ZERO
+        self.kernels: list = []
+
+    def add(self, *grown) -> None:
+        """Replace the totals: ``grown`` is (place, by) pairs."""
+        mark = list(self.mark)
+        for place, by in grown:
+            mark[place] += by
+        self.mark = tuple(mark)
+
+
+_COMPILING = _CompileState()
+
+
+def _own_time(seen: list, duration: float, announced: bool) -> float:
+    """What a finished interval of ``duration`` seconds is charged: its
+    length less what the intervals nested in it on this thread were
+    charged already.
+
+    ``seen`` is one thread's stack. ``None`` marks an interval JAX has
+    announced and not yet reported (:func:`_jax_started`); a number is what
+    the intervals reported since the entry below it were charged, all of
+    them: finished siblings merge into one number as they are reported
+    (their charged time is all a parent needs), so the stack is as deep as
+    the nest, however many intervals nest. When an ``announced`` interval
+    ends, what stands above its marker is what nested in it; an interval
+    that is not announced (a retrieval) holds nothing. No clock is
+    compared. Every number is at most the wall time its intervals covered,
+    so on one thread the sums of all series never exceed the wall time
+    covered. (An interval that was open when the listeners were registered
+    finds no marker and claims the whole stack: too little, never too
+    much.)"""
     inner = 0.0
-    while seen and seen[-1][0] >= start - 1e-4:
-        a, b = seen.pop()
-        inner += b - a
-    seen.append((start, end))
-    return max(0.0, end - start - inner)
+    while announced and seen:
+        top = seen.pop()
+        if top is None:
+            break
+        inner += top
+    own = max(0.0, duration - inner)
+    if seen and seen[-1] is not None:
+        seen[-1] += own + inner      # a sibling of the tail: one entry
+    else:
+        seen.append(own + inner)
+    return own
+
+
+def _jax_started(event: str, _value=None, **_kw) -> None:
+    """JAX announces that an interval of its compile path begins."""
+    if event in _JAX_ANNOUNCED:
+        _COMPILING.seen.append(None)
 
 
 def _jax_duration(event: str, duration: float, **_kw) -> None:
@@ -1142,26 +1209,31 @@ def _jax_duration(event: str, duration: float, **_kw) -> None:
     (and a cache retrieval inside the backend compile that made it): an
     interval is charged its own time LESS what the intervals nested in it
     on this thread were already charged, whatever their series, so that
-    the four sums together never exceed the wall time of the thread."""
-    name = _JAX_DURATIONS.get(event)
-    if name is None or not REGISTRY.enabled:
+    the four sums together never exceed the wall time of the thread
+    (:func:`_own_time`). The stack is kept while recording is
+    :func:`disabled` too; only the observation is not made."""
+    series = _JAX_PHASE.get(event)
+    if series is None:
         return
-    end = time.perf_counter()
-    seen = getattr(REGISTRY._local, "jax_intervals", None)
-    if seen is None:
-        seen = REGISTRY._local.jax_intervals = collections.deque(
-            maxlen=_JAX_INTERVALS_MAX)
-    REGISTRY.observe(name, _own_time(seen, end - duration, end))
+    name, place = series
+    state = _COMPILING
+    own = _own_time(state.seen, duration, event in _JAX_ANNOUNCED)
+    if not REGISTRY.enabled:
+        return
+    REGISTRY.observe(name, own)
+    state.add((0, 1), (1, place == 2), (place, own))
 
 
 def _jax_event(event: str, **_kw) -> None:
     name = _JAX_EVENTS.get(event)
-    if name is not None:
+    if name is not None and REGISTRY.enabled:
         REGISTRY.inc(name)
+        # jax_cache_hits_total -> cache_hits
+        _COMPILING.add((_MARK_FIELDS.index(name[4:-6]), 1))
 
 
 def watch_jax_compiles() -> bool:
-    """Register the two ``jax.monitoring`` listeners that feed
+    """Register the ``jax.monitoring`` listeners that feed
     ``jax_trace_seconds``, ``jax_lower_seconds``,
     ``jax_backend_compile_seconds``, ``jax_cache_retrieval_seconds``
     (histograms: count, sum, max) and ``jax_cache_hits_total`` /
@@ -1173,11 +1245,75 @@ def watch_jax_compiles() -> bool:
         return True
     try:
         from jax import monitoring
-    except ImportError:  # pragma: no cover - JAX is a hard dependency
+        # an interval's start has to be announced (_own_time): JAX 0.9
+        monitoring.register_scalar_listener(_jax_started)
+    except (ImportError, AttributeError):  # pragma: no cover
         return False
     monitoring.register_event_duration_secs_listener(_jax_duration)
     monitoring.register_event_listener(_jax_event)
     _JAX_WATCHING = True
+    return True
+
+
+# ---------------------------------------------------------------------------
+# a first call: which program compiled, which kernels, what each phase cost
+# ---------------------------------------------------------------------------
+
+def compile_mark() -> tuple:
+    """This thread's running compile totals (:data:`_MARK_FIELDS`): an
+    immutable tuple that every compile-path event on the thread replaces.
+    Held across a window, ``compile_mark() is mark`` says that nothing
+    traced, lowered, compiled or loaded inside it -- one thread-local read
+    at each end, which is all a warm call pays."""
+    return _COMPILING.mark
+
+
+def kernel_traced(name: str, mark: tuple) -> int:
+    """Note that the kernel ``name`` was traced on this thread since
+    ``mark`` was read (``ops.pallas_gates``, once a new kernel signature):
+    what JAX's trace events were charged inside goes to the thread's
+    ``kernel_trace_s``. Returns how many of them fired there."""
+    state = _COMPILING
+    grown = dict(zip(_MARK_FIELDS, (b - a for a, b in zip(mark, state.mark))))
+    state.kernels.append(name)
+    state.add((_MARK_FIELDS.index("kernels"), 1),
+              (_MARK_FIELDS.index("kernel_trace_s"), grown["trace_s"]))
+    return grown["traces"]
+
+
+def first_call(mark: tuple, rg, program: str, route: str) -> bool:
+    """Close a window in which a program may have been called for the
+    first time: ``mark`` is :func:`compile_mark` read where the region
+    ``rg`` began (a caller that holds the same mark again has nothing to
+    close, and need not make the name). When, and only when, a
+    compile-path event fired on this thread since, emit ONE
+    ``program.first_call`` event -- ``program`` (the jitted function's
+    name, ``circuits.named_program``'s), ``route``, the region's own
+    ``dur_s``, and the phases ``trace_s``, ``lower_s``, ``compile_s``,
+    ``cache_load_s`` (the listeners' own-time sums over the window) and
+    ``rest_s`` (the remainder: transfer, launch, the put), which so add up
+    to ``dur_s`` by construction; beside them ``kernel_trace_s`` (the part
+    of ``trace_s`` inside the kernels' compile records), ``kernels`` (the
+    kernels first traced here, in program order) and ``cache_hits`` /
+    ``cache_misses``. Returns whether it did. A retrace in the middle of a served window
+    makes the same record."""
+    state = _COMPILING
+    now = state.mark
+    if now is mark or not REGISTRY.enabled:
+        return False
+    grown = dict(zip(_MARK_FIELDS, (b - a for a, b in zip(mark, now))))
+    dur_s = round(rg.t1 - rg.t0, 6)
+    phases = {k: round(grown[k], 6)
+              for k in ("trace_s", "lower_s", "compile_s", "cache_load_s")}
+    phases["rest_s"] = round(dur_s - sum(phases.values()), 6)
+    kernels = state.kernels[len(state.kernels) - grown["kernels"]:]
+    del state.kernels[:]         # named once; the list stays short
+    REGISTRY.event(
+        "program.first_call", program=program, route=route, dur_s=dur_s,
+        **phases,
+        kernel_trace_s=round(grown["kernel_trace_s"], 6), kernels=kernels,
+        nested_traces=grown["traces"], cache_hits=grown["cache_hits"],
+        cache_misses=grown["cache_misses"])
     return True
 
 
@@ -1214,6 +1350,10 @@ if not _ENV_ENABLED:  # pragma: no cover - exercised via subprocess test
 
     def watch_jax_compiles():                          # noqa: F811
         return False
+    compile_mark = _noop                               # noqa: F811
+    first_call = _false                                # noqa: F811
+
+    kernel_traced = _zero                              # noqa: F811
     counter_value = counter_total = _zero              # noqa: F811
     counters = _empty_dict                             # noqa: F811
 

@@ -13,7 +13,8 @@ tolerance that a loaded host could miss):
   different op count or swap;
 - the ``jax.monitoring`` listeners count one trace, one lowering and one
   compile for a first call and nothing for a second, and charge an interval
-  nested in another once;
+  nested in another once, however many nest (PR 39), in a stack as deep as
+  the nest;
 - the seven phases tile ``dur_ms`` within 1% on every engine route, with
   delays injected between the batcher's regions;
 - the ``device`` phase is the stream-ordered estimate (a synthetic
@@ -269,29 +270,127 @@ def test_listeners_are_registered_once():
 
 
 def test_nested_intervals_are_charged_once():
-    seen = deque()
+    seen = []
     own = telemetry._own_time
-    # an inner jit's trace [1, 2] and a second [3, 3.5], then the caller's
-    # trace [0.5, 6] that holds both
-    assert own(seen, 1.0, 2.0) == pytest.approx(1.0)
-    assert own(seen, 3.0, 3.5) == pytest.approx(0.5)
-    assert own(seen, 0.5, 6.0) == pytest.approx(5.5 - 1.5)
-    assert list(seen) == [(0.5, 6.0)]
+    # the caller's trace [0.5, 6] begins, then an inner jit's trace [1, 2]
+    # and a second [3, 3.5]
+    seen.append(None)
+    seen.append(None)
+    assert own(seen, 1.0, True) == pytest.approx(1.0)
+    seen.append(None)
+    assert own(seen, 0.5, True) == pytest.approx(0.5)
+    assert seen == [None, 1.5]              # siblings: one entry
+    assert own(seen, 5.5, True) == pytest.approx(5.5 - 1.5)
+    assert seen == [5.5]
     # a later, disjoint interval is charged whole; all of it sums to the
     # wall time covered, never more
-    assert own(seen, 7.0, 8.0) == pytest.approx(1.0)
+    seen.append(None)
+    assert own(seen, 1.0, True) == pytest.approx(1.0)
+    assert seen == [6.5]
     assert 1.0 + 0.5 + 4.0 + 1.0 == pytest.approx((6.0 - 0.5) + 1.0)
-    # a retrieval nested in a compile call, whatever the series
+    # a retrieval, which JAX does not announce, in the compile call that
+    # made it, whatever the series
     seen.clear()
-    assert own(seen, 10.2, 10.5) == pytest.approx(0.3)
-    assert own(seen, 10.0, 11.0) == pytest.approx(0.7)
+    seen.append(None)
+    assert own(seen, 0.3, False) == pytest.approx(0.3)
+    assert own(seen, 1.0, True) == pytest.approx(0.7)
+    assert seen == [1.0]
+
+
+def test_5000_nested_siblings_charge_their_parent_s_wall_time_once():
+    """What the deque of 1024 got wrong (PR 39): siblings past the cap had
+    been evicted when their parent ended, were already charged, and were
+    charged again with it -- 1.9 times the wall time on ``df26.block``."""
+    seen, own, charged = [None], telemetry._own_time, 0.0   # the parent began
+    for _ in range(5000):
+        seen.append(None)                   # 1 ms of the parent's own Python,
+        charged += own(seen, 0.004, True)   # then a child of 4 ms
+        assert len(seen) <= 2               # memory: the nesting depth
+    wall = 5000 * 0.005 + 0.001
+    parent = own(seen, wall, True)
+    assert parent == pytest.approx(5001 * 0.001)
+    assert charged + parent == pytest.approx(wall)
+    assert seen == [pytest.approx(wall)]
+
+
+def _random_tree(rng, t, depth):
+    """(intervals in the order they end, end time) of a random nest."""
+    out, start = [], t
+    t += rng.uniform(0.001, 0.01)
+    for _ in range(rng.integers(0, 4) if depth < 4 else 0):
+        inner, t = _random_tree(rng, t, depth + 1)
+        out += inner
+        t += rng.uniform(0.001, 0.01)
+    return out + [(start, t, depth)], t
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_any_nest_is_charged_the_wall_time_it_covers(seed):
+    """Fed the starts as JAX announces them, the charge is the wall time
+    covered, to rounding, and the stack is as deep as the nest; leaves
+    that are not announced (retrievals) change neither."""
+    rng = np.random.default_rng(seed)
+    order, t, wall = [], 0.0, 0.0
+    for _ in range(6):                      # six top-level programs
+        tree, end = _random_tree(rng, t, 0)
+        wall += end - t
+        order += tree
+        t = end + rng.uniform(0.001, 0.01)
+    leaf = [all(not (a < c and d < b) for c, d, _ in order)
+            and rng.integers(2) == 0 for a, b, _ in order]
+    # replay starts and ends in time order
+    moments = sorted([(a, 0, i) for i, (a, _, _) in enumerate(order)]
+                     + [(b, 1, i) for i, (_, b, _) in enumerate(order)],
+                     key=lambda m: (m[0], m[1] == 0, m[2] if m[1] else -m[2]))
+    seen, charged, own = [], 0.0, telemetry._own_time
+    for when, ends, i in moments:
+        if ends:
+            charged += own(seen, when - order[i][0], not leaf[i])
+        elif not leaf[i]:
+            seen.append(None)
+        assert len(seen) <= 2 * 5 + 1
+    assert charged == pytest.approx(wall)
+    assert seen == [pytest.approx(wall)]
+
+
+def test_a_real_nest_is_announced_and_leaves_one_entry():
+    """JAX 0.9 announces a trace where it begins (``record_scalar``), so
+    the listeners read real nests exactly: 300 inner jits under one outer
+    trace leave the thread's stack one entry deep and are charged, with
+    the outer program, no more than the call took."""
+    inner = [jax.jit(lambda v, k=k: jax.lax.add(v, float(k)))
+             for k in range(300)]
+
+    def outer(v):
+        for f in inner:
+            v = f(v)
+        return v
+
+    x = jnp.arange(4.0)
+    state = telemetry._COMPILING
+    del state.seen[:]
+    telemetry.reset()
+    mark = telemetry.compile_mark()
+    t0 = time.perf_counter()
+    jax.jit(outer)(x).block_until_ready()
+    wall = time.perf_counter() - t0
+    grown = [b - a for a, b in zip(mark, telemetry.compile_mark())]
+    assert grown[1] == 301                          # traces
+    assert _hist_counts()["jax_trace_seconds"] == 301
+    assert len(state.seen) == 1 and 0.0 < state.seen[0] <= wall
+    assert 0.0 < sum(grown[2:6]) <= wall
+    hists = telemetry.snapshot("jax_")["histograms"]
+    assert sum(h["sum"] for h in hists.values()) == pytest.approx(
+        sum(grown[2:6]), abs=1e-5)
+    telemetry.reset()
 
 
 def test_jax_events_feed_the_registry():
     telemetry.reset()
     # this thread's real intervals of a moment ago would nest in the
     # quarter second reported below
-    telemetry.REGISTRY._local.__dict__.pop("jax_intervals", None)
+    del telemetry._COMPILING.seen[:]
+    mark = telemetry.compile_mark()
     telemetry._jax_event("/jax/compilation_cache/cache_hits")
     telemetry._jax_event("/jax/compilation_cache/cache_misses")
     telemetry._jax_event("/jax/compilation_cache/cache_misses")
@@ -304,6 +403,16 @@ def test_jax_events_feed_the_registry():
     hists = telemetry.snapshot("jax_")["histograms"]
     assert list(hists) == ["jax_cache_retrieval_seconds"]
     assert hists["jax_cache_retrieval_seconds"]["sum"] == pytest.approx(0.25)
+    # the thread's own totals moved with them: a new tuple, by identity
+    now = telemetry.compile_mark()
+    assert now is not mark
+    grown = dict(zip(telemetry._MARK_FIELDS,
+                     (b - a for a, b in zip(mark, now))))
+    assert grown == {"events": 1, "traces": 0, "trace_s": 0.0,
+                     "lower_s": 0.0, "compile_s": 0.0,
+                     "cache_load_s": pytest.approx(0.25), "cache_hits": 1,
+                     "cache_misses": 2, "kernels": 0, "kernel_trace_s": 0.0}
+    assert telemetry.compile_mark() is now      # and stays, with no event
     telemetry.reset()
 
 

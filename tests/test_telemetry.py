@@ -112,6 +112,14 @@ def test_env_zero_swaps_in_noop_stubs():
         "t.inc('x'); t.observe('h', 1.0); t.event('e')\n"
         "assert t.counter_total('x') == 0.0\n"
         "assert t.span('s') is t._NULL_SPAN\n"
+        # PR 39: no listener on JAX's compile path, no first-call record,
+        # no kernel record, no import gauge
+        "import quest_tpu\n"
+        "from jax._src import monitoring as m\n"
+        "assert t._jax_duration not in m.get_event_duration_listeners()\n"
+        "assert t.compile_mark() is None\n"
+        "assert t.first_call(None, None, 'p', 'circuit') is False\n"
+        "assert t.kernel_traced('k', None) == 0\n"
         "assert t.snapshot() == {'counters': {}, 'gauges': {},"
         " 'histograms': {}, 'spans': {}}\n"
         "print('STUBS-OK')\n")
@@ -205,11 +213,18 @@ def _fused_run(n):
 def test_disabled_telemetry_is_bit_identical():
     n = 10
     base_amps, base_plan = _fused_run(n)
+    telemetry.reset()
     with telemetry.disabled():
         off_amps, off_plan = _fused_run(n)
     assert base_plan == off_plan          # same fused plan structure
     assert base_amps.dtype == off_amps.dtype
     assert np.array_equal(base_amps, off_amps)  # bit-identical amplitudes
+    # the second plan is a new circuit, so its program traced anew: with
+    # recording off there is no first-call record, no compile series and
+    # no planner span of it (PR 39)
+    assert telemetry.events() == []
+    assert telemetry.snapshot() == {"counters": {}, "gauges": {},
+                                    "histograms": {}, "spans": {}}
 
 
 # ---------------------------------------------------------------------------
