@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -588,10 +588,10 @@ class PallasRun:
     wider than 2*tile_bits - LANE_BITS qubits -- e.g. a sharded 34q state
     -- are fully covered by multiple frames). When the executing register
     cannot take the folded path (sharded, mismatched tile geometry), the
-    swap runs as an explicit swap_bit_blocks pass instead -- same
-    semantics; on a sharded register GSPMD lowers it to ONE collective
-    (all-to-all) transpose, the analogue of the reference's swap-to-local
-    exchanges (QuEST_cpu_distributed.c:1526-1568)."""
+    swap runs as an explicit pass instead (:func:`_explicit_swap`) -- same
+    semantics; where its block reaches a sharded qubit that is ONE
+    collective (all-to-all) transpose, the analogue of the reference's
+    swap-to-local exchanges (QuEST_cpu_distributed.c:1526-1568)."""
     ops: tuple
     tile_bits: int
     load_swap_k: int = 0
@@ -1564,9 +1564,11 @@ def _amp_shards(qureg) -> int:
 def _count_frame_swap(qureg, lo2: int, k: int) -> None:
     """Count one explicit relabeling pass (a LOWERING, like every counter
     inside a replay). Where the moved block [lo2, lo2 + k) reaches a
-    sharded qubit the pass is a transpose over the mesh -- GSPMD's
-    all-to-all, or the scheduler's grouped permute -- and is counted as
-    ``fusion_collective_swaps_total`` too; a shard-local one is not."""
+    sharded qubit the pass is a transpose over the mesh -- the stated
+    all-to-all of :func:`_swap_per_shard`, the one GSPMD finds for a
+    ``swap_bit_blocks`` of the whole array, or the scheduler's grouped
+    permute -- and is counted as ``fusion_collective_swaps_total`` too; a
+    shard-local one is not."""
     telemetry.inc("pallas_pass_total", kind="frame_swap")
     shard_bits = _amp_shards(qureg).bit_length() - 1
     if lo2 + k > qureg.num_qubits_in_state_vec - shard_bits:
@@ -1815,18 +1817,126 @@ def _apply_pallas_run(qureg, run: PallasRun) -> None:
 def _explicit_swaps(qureg, run: PallasRun, load: bool = False,
                     store: bool = False) -> None:
     """The run's load and/or store relabeling as an explicit, counted
-    swap_bit_blocks pass (nothing where the run has none): what a route
-    that did not fold it runs in its place."""
-    from .ops.pallas_gates import swap_bit_blocks
-
+    pass (:func:`_explicit_swap`; nothing where the run has none): what a
+    route that did not fold it runs in its place."""
     for wanted, k, hi in ((load, run.load_swap_k, run.load_swap_hi),
                           (store, run.store_swap_k, run.store_swap_hi)):
         if wanted and k:
-            lo2 = run.tile_bits if hi is None else hi
-            _count_frame_swap(qureg, lo2, k)
-            qureg.put(swap_bit_blocks(
-                qureg.amps, n=qureg.num_qubits_in_state_vec,
-                lo1=run.tile_bits - k, lo2=lo2, k=k))
+            _explicit_swap(qureg, run.tile_bits, k, hi)
+
+
+def _explicit_swap(qureg, tile_bits: int, k: int, hi: int | None) -> None:
+    """One counted relabeling pass outside any kernel: the blocks
+    [tile_bits - k, tile_bits) and [lo2, lo2 + k) of the index change
+    places (``lo2`` = ``hi``, or ``tile_bits``). ONE permutation, stated
+    to the compiler one of two ways by where the register lies
+    (:func:`_swap_mesh`): per shard with its all-to-all written out where
+    the block reaches a sharded qubit of a register on the canonical amps
+    mesh (:func:`_swap_per_shard`), as ``swap_bit_blocks`` of the whole
+    array everywhere else."""
+    from .ops.pallas_gates import swap_bit_blocks
+
+    lo1, lo2 = tile_bits - k, tile_bits if hi is None else hi
+    nsv = qureg.num_qubits_in_state_vec
+    _count_frame_swap(qureg, lo2, k)
+    mesh = _swap_mesh(qureg, lo1, lo2, k)
+    if mesh is None:
+        qureg.put(swap_bit_blocks(qureg.amps, n=nsv, lo1=lo1, lo2=lo2, k=k))
+        return
+    telemetry.inc("fusion_per_shard_swaps_total")
+    qureg.put(_swap_per_shard(mesh, nsv, lo1, lo2, k)(qureg.amps))
+
+
+def _swap_mesh(qureg, lo1: int, lo2: int, k: int):
+    """The mesh over which the relabeling [lo1, lo1 + k) <-> [lo2, lo2 + k)
+    is stated per shard, or None where ``swap_bit_blocks`` of the whole
+    array states it -- pure, like :func:`_route`. Per shard: the block
+    reaches a sharded qubit (what :func:`_count_frame_swap` counts a
+    collective by), the register lies on the canonical power-of-two amps
+    mesh (inside a trace the ambient one, as :func:`_route` reads it), and
+    what it changes places with is whole lane rows of one shard
+    (``LANE_BITS <= lo1``, ``lo1 + k <= n_local``: every planned frame).
+    Otherwise -- one device, a shard-local block that did not
+    fold, a non-canonical sharding, a block below the lanes, and under
+    the explicit scheduler, whose relabelings are its own counted
+    permutes -- the whole-array form, as before."""
+    import jax
+
+    from .environment import AMP_AXIS
+    from .ops.pallas_gates import LANE_BITS
+    from .parallel import scheduler as _dist
+
+    if _dist.active() is not None or lo1 < LANE_BITS:
+        return None
+    mesh = (active_pallas_mesh() if isinstance(qureg.amps, jax.core.Tracer)
+            else _canonical_amps_mesh(qureg))
+    if mesh is None or tuple(mesh.shape.keys()) != (AMP_AXIS,):
+        return None
+    ndev = mesh.size
+    n_local = qureg.num_qubits_in_state_vec - (ndev.bit_length() - 1)
+    if ndev & (ndev - 1) or lo2 + k <= n_local or lo1 + k > n_local:
+        return None
+    return mesh
+
+
+@lru_cache(maxsize=None)
+def _swap_per_shard(mesh, n: int, lo1: int, lo2: int, k: int):
+    """``swap_bit_blocks(n, lo1, lo2, k)`` of a (P, 2^n) register sharded
+    over ``mesh``, for a block [lo2, lo2 + k) that reaches a sharded
+    qubit, as ``amps -> amps`` (jitted, the operand donated): the same
+    permutation of the index, bit for bit, written per shard on the view
+    the kernels use and with the collective stated.
+
+    Of the block's k bits the top ``c`` are device bits (``c <= log2
+    devices``: bits [d0, d0 + c) of the shard index) and the low ``kl`` lie
+    on the shard, so a shard's rows (``pallas_gates._rows_view``, a
+    bitcast) are ``in[B2, M, X, B1, U]``: B2 the block's local bits, M the
+    bits between the blocks, X and B1 the top c and low kl bits of
+    [lo1, lo1 + k), U the (2^(lo1 - 7) * P, 128) row group below them,
+    which moves whole. Device x of the 2^c that differ in those device
+    bits must end with ``out[B1', M, Y, B2', U] = in_Y[B2', M, x, B1', U]``:
+
+    1. ``T[X, B1, M, B2, U]``: the local part, one transposition that
+       leaves the bits that cross as the major axis;
+    2. one ``all_to_all`` over those 2^c devices, split and concatenated
+       on that axis: a piece is a contiguous 2^-c of the shard;
+    3. the received device index put where the new frame wants it,
+       ``(Y, B1, M, ..) -> (B1, M, Y, ..)``, and ``_planes_view`` back
+       (again a bitcast), so the next per-shard kernel reads the result
+       where it lies.
+
+    The v5e's compiler makes ``copy``, ``all-to-all``, ``copy`` of it: two
+    passes over the shard beside the collective, where it finds four
+    around the collective of the whole-array form (plane-major there, so
+    two of them only undo and redo the kernels' view;
+    ``tests/test_chip_compile.py`` pins the count)."""
+    import jax
+
+    from .environment import AMP_AXIS
+    from .ops import pallas_gates as PG
+    from .parallel.mesh import device_groups
+
+    ndev = mesh.shape[AMP_AXIS]
+    n_local = n - (ndev.bit_length() - 1)
+    cut = max(lo2, n_local)
+    c, d0 = lo2 + k - cut, cut - n_local
+    kl = k - c
+    # the devices that differ in the block's device bits only
+    groups = (device_groups(ndev, ((1 << c) - 1) << d0) if 1 << c < ndev
+              else None)
+
+    def swap(shard):
+        planes = shard.shape[0]
+        x = PG._rows_view(shard).reshape(
+            1 << kl, 1 << (min(lo2, n_local) - lo1 - k), 1 << c, 1 << kl,
+            planes << (lo1 - PG.LANE_BITS), PG._LANES)
+        x = x.transpose(2, 3, 1, 0, 4, 5)
+        x = jax.lax.all_to_all(x, AMP_AXIS, 0, 0, axis_index_groups=groups,
+                               tiled=True)
+        x = x.transpose(1, 2, 0, 3, 4, 5)
+        return PG._planes_view(x.reshape(-1, PG._LANES), planes)
+
+    return jax.jit(_per_shard(swap, mesh), donate_argnums=(0,))
 
 
 def _gatewise(qureg, run: PallasRun, reason: str | None,
@@ -2425,29 +2535,27 @@ _apply_deferred_block._lift_positions = _lift_positions
 
 
 def _apply_frame_swap(qureg, swap: FrameSwap) -> None:
-    """Tape-entry wrapper for FrameSwap: one relabeling transpose. Works on
-    every backend (plain XLA); on a sharded register GSPMD lowers it to the
-    all-to-all the relabeling implies (shard-local when [hi, hi+k) avoids
-    the sharded qubits). Under an active explicit scheduler the transpose
-    rides the scheduler's COUNTED grouped permute instead
+    """Tape-entry wrapper for FrameSwap: one relabeling transpose
+    (:func:`_explicit_swap`). Works on every backend (plain XLA); where
+    [hi, hi+k) reaches a sharded qubit it is a transpose over the mesh
+    (per shard around one stated all-to-all on the canonical amps mesh),
+    shard-local otherwise. Under an active explicit scheduler the
+    transpose rides the scheduler's COUNTED grouped permute instead
     (apply_frame_permute), so the plan_circuit comm model and the
     frame_transpose telemetry series stay exact."""
-    from .ops.pallas_gates import swap_bit_blocks
     from .parallel import scheduler as _dist
 
     tile_bits, k = swap.tile_bits, swap.k
-    lo2 = tile_bits if swap.hi is None else swap.hi
-    _count_frame_swap(qureg, lo2, k)
-    nsv = qureg.num_qubits_in_state_vec
     sched = _dist.active()
     if sched is not None and sched.mesh is not None and sched.mesh.size > 1:
+        lo2 = tile_bits if swap.hi is None else swap.hi
+        _count_frame_swap(qureg, lo2, k)
         qureg.put(sched.apply_frame_permute(
-            qureg.amps, n=nsv, lo1=tile_bits - k, lo2=lo2, k=k,
-            pipeline=swap.comm_pipeline,
+            qureg.amps, n=qureg.num_qubits_in_state_vec, lo1=tile_bits - k,
+            lo2=lo2, k=k, pipeline=swap.comm_pipeline,
             pipeline_dcn=swap.comm_pipeline_dcn))
         return
-    qureg.put(swap_bit_blocks(qureg.amps, n=nsv, lo1=tile_bits - k,
-                              lo2=lo2, k=k))
+    _explicit_swap(qureg, tile_bits, k, swap.hi)
 
 
 def as_tape(p: FusePlan) -> list:

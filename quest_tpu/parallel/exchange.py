@@ -67,7 +67,7 @@ from jax import shard_map
 from ..environment import AMP_AXIS
 from ..ops import apply as K
 from ..ops.layout import grouped_axes
-from .mesh import local_qubit_count
+from .mesh import device_groups, local_qubit_count
 
 __all__ = ["dist_apply_matrix1", "dist_apply_x", "dist_apply_diag_phase",
            "dist_apply_parity_phase", "dist_apply_local_matrix", "dist_swap",
@@ -595,12 +595,7 @@ def dist_permute_bits(amps, *, n: int, source, mesh: Mesh, pipeline=None):
 
     groups = None
     if m:
-        qbits = [q - nl for q in Q_c]
-        gmask = sum(1 << b for b in qbits)
-        by_base: dict[int, list[int]] = {}
-        for r in range(size):
-            by_base.setdefault(r & ~gmask, []).append(r)
-        groups = [sorted(v) for _, v in sorted(by_base.items())]
+        groups = device_groups(size, sum(1 << (q - nl) for q in Q_c))
 
     def kernel(chunk):
         # grouped view: axis 0 = the P planes (re/im, or the df 4-plane

@@ -23,6 +23,17 @@ def local_qubit_count(n: int, mesh: Mesh | None) -> int:
     return n - d
 
 
+def device_groups(size: int, mask: int) -> list[list[int]]:
+    """The ``size`` device indices in groups that differ only in the bits
+    of ``mask``, each group ascending (so a member's place is what its
+    masked bits spell, low bit first) and the groups by their first member:
+    the ``axis_index_groups`` of a collective over those shard bits."""
+    by_rest: dict[int, list[int]] = {}
+    for r in range(size):
+        by_rest.setdefault(r & ~mask, []).append(r)
+    return list(by_rest.values())
+
+
 def shard_info(n: int, mesh: Mesh | None):
     """(num_local_qubits, num_shard_qubits, axis_name)."""
     nl = local_qubit_count(n, mesh)

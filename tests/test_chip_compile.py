@@ -27,6 +27,7 @@ test's own process.
 
 import os
 import re
+from functools import partial
 
 import numpy as np
 import pytest
@@ -48,32 +49,58 @@ _DF_OPS = 4
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """SingleDeviceSharding on a described (not attached) v5e chip, with
-    the persistent compile cache off around the module: an AOT compile is
-    written to the cache but cannot be read back without a chip."""
+def topo():
+    """A described (not attached) v5e:2x2, with the persistent compile
+    cache off around the module: an AOT compile is written to the cache
+    but cannot be read back without a chip."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or its lock is held elsewhere
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """SingleDeviceSharding on one chip of the described host."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described host's four chips as the canonical amps mesh."""
+    return Mesh(np.array(topo.devices), (AMP_AXIS,))
 
 
 def _planned_runs(circ, **fused_kw):
     """The PallasRuns of ``circ.fused(pallas=True, ...)``."""
     return pallas_runs(circ.fused(max_qubits=5, pallas=True,
                                   dtype=np.float32, **fused_kw))
+
+
+def _cell_layers():
+    """The benchmark's ``circuits/random_layers.py``: the library cells' tape."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "random_layers", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "circuits", "random_layers.py"))
+    layers = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(layers)
+    return layers
 
 
 def _random_circuit(n, depth=8):
@@ -182,6 +209,12 @@ def _state_sized_traffic(hlo_text, state_elems):
                for dims in _HLO_SHAPE.findall(result_type)):
             found.append((op.group(1), name.removeprefix("ROOT ")))
     return found
+
+
+def _entry(hlo_text):
+    """The ENTRY computation of a compiled program's text: what runs, each
+    fusion one instruction (its body stands above, and would count twice)."""
+    return hlo_text[hlo_text.index("\nENTRY "):]
 
 
 def _cell_chain(case):
@@ -340,6 +373,116 @@ def test_f32_per_shard_run_28q_over_4(one_chip):
                    sk=run.store_swap_k, sh=run.store_swap_hi)
 
 
+def _relabeling_passes(compiled, shard_elems):
+    """(whole-shard passes that are not the collective, all-to-alls) of a
+    compiled program on one device of the mesh."""
+    moved = [op for op, _ in _state_sized_traffic(
+        _entry(compiled.as_text()), shard_elems)]
+    return (sum(op != "all-to-all" for op in moved),
+            moved.count("all-to-all"))
+
+
+def _between_rows_views(swap, mesh, planes):
+    """``swap`` (a relabeling of the sharded (P, 2^n) register) as it stands
+    between two per-shard kernels: its operand comes as a kernel hands its
+    result over and its result goes as the next kernel takes it, each
+    shard the row-interleaved (rows * P, 128) array (``PG._rows_view``)."""
+    from jax import shard_map
+
+    rows, amps = P(AMP_AXIS, None), P(None, AMP_AXIS)
+    to_planes = shard_map(partial(PG._planes_view, P=planes), mesh=mesh,
+                          in_specs=rows, out_specs=amps)
+    to_rows = shard_map(PG._rows_view, mesh=mesh, in_specs=amps,
+                        out_specs=rows)
+    return lambda r: to_rows(swap(to_planes(r)))
+
+
+@pytest.mark.parametrize("n,lo1,lo2,k,planes", [
+    (27, 7, 17, 10, 2), (27, 7, 17, 10, 4), (28, 11, 19, 8, 2),
+    (28, 11, 19, 8, 4), (31, 7, 19, 12, 2)],
+    ids=["27q-k10-P2", "27q-k10-P4", "28q-k8-P2", "28q-k8-P4",
+         "31q-k12-the-cell"])
+def test_a_collective_relabeling_is_two_passes_and_one_all_to_all(
+        four_chips, n, lo1, lo2, k, planes):
+    """The relabeling between two rows-view neighbours over the four
+    described chips: per shard with its all-to-all stated
+    (``fusion._swap_per_shard``) the chip's compiler makes ``copy``,
+    ``all-to-all``, ``copy`` of it. The whole-array ``swap_bit_blocks`` it
+    replaces on this route reshapes plane-major and leaves the collective
+    to GSPMD, which finds copy, copy, all-to-all, copy, copy: at least FOUR
+    passes over the shard, of which two only undo and redo the kernels'
+    view (on the chip 12.6-13.06 ms each at 4 GiB a shard, ``PERF.md``
+    section 6, PR 40). Temporaries: twice a shard either way. 31 qubits,
+    k = 12, lo1 = 7, lo2 = 19 is ``sv31x4.block``'s own relabeling (its
+    whole-array form alone does not compile here in minutes and is read at
+    27 and 28 qubits; its whole program is the next test); P = 4 is the
+    double-float planes. Under a second a compile."""
+    sharding = NamedSharding(four_chips, P(None, AMP_AXIS))
+    shard = (planes << n) // 4
+    rows = jax.ShapeDtypeStruct(
+        ((planes << n) >> PG.LANE_BITS, PG._LANES), jnp.float32,
+        sharding=NamedSharding(four_chips, P(AMP_AXIS, None)))
+
+    def compiled(swap):
+        return jax.jit(_between_rows_views(swap, four_chips, planes),
+                       donate_argnums=(0,)).lower(rows).compile()
+
+    per_shard = compiled(fusion._swap_per_shard(four_chips, n, lo1, lo2, k))
+    assert _relabeling_passes(per_shard, shard) == (2, 1)
+    temp = per_shard.memory_analysis().temp_size_in_bytes
+    assert temp <= 2 * 4 * shard
+    if n == 31:
+        return
+    whole = compiled(lambda amps: jax.lax.with_sharding_constraint(
+        PG.swap_bit_blocks(amps, n=n, lo1=lo1, lo2=lo2, k=k), sharding))
+    passes, collectives = _relabeling_passes(whole, shard)
+    assert passes >= 4 and collectives == 1
+    assert temp <= whole.memory_analysis().temp_size_in_bytes
+
+
+def test_sv31x4_program_holds_two_passes_a_relabeling(four_chips,
+                                                      monkeypatch):
+    """``sv31x4.block``'s whole tape program at its real size for the four
+    described chips (31 qubits, 4 GiB a shard; the kernels cut by
+    ``_one_of_each`` and lowered for Mosaic, the two collective
+    relabelings between them as they really stand): three per-shard
+    kernels, two all-to-alls and FOUR whole-shard passes beside them,
+    where the whole-array relabeling left eight (PR 40); the donated shard
+    aliased to the output, temporaries of two shards (8 GiB), which is
+    what the parent's program held. About 10 s."""
+    from quest_tpu.circuits import named_program
+
+    n = 31
+    circ = Circuit(n)
+    _cell_layers().build(circ, num_qubits=n, depth=2, circuit_seed=2026)
+    fused = circ.fused(max_qubits=5, pallas=True, dtype=np.float32,
+                       shard_devices=4)
+    runs = pallas_runs(fused)
+    assert [(len(r.ops), r.load_swap_k, r.load_swap_hi, r.store_swap_k)
+            for r in runs] == [(62, 0, None, 0), (31, 12, 19, 12),
+                               (1, 0, None, 0)]
+    fold, run = PG._fold_zone_ops, PG.fused_local_run
+    monkeypatch.setattr(PG, "_fold_zone_ops",
+                        lambda ops, lq: _one_of_each(fold(ops, lq), lq))
+    monkeypatch.setattr(PG, "fused_local_run",
+                        lambda amps, **kw: run(amps, **{**kw,
+                                                        "interpret": False}))
+    amps = jax.ShapeDtypeStruct(
+        (2, 1 << n), jnp.float32,
+        sharding=NamedSharding(four_chips, P(None, AMP_AXIS)))
+    with fusion.pallas_mesh(four_chips):
+        compiled = jax.jit(named_program(fused.as_fn(), fused, "circuit"),
+                           donate_argnums=(0,)).lower(amps).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == len(runs)
+    shard = (2 << n) // 4
+    assert _relabeling_passes(compiled, shard) == (4, 2)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == mem.output_size_in_bytes \
+        == mem.alias_size_in_bytes == 4 * shard
+    assert mem.temp_size_in_bytes <= 2 * 4 * shard + (1 << 20)
+
+
 def _op_qubits(op):
     kind = op[0]
     if kind == "matrix":
@@ -392,21 +535,12 @@ def test_df26_program_holds_the_register_and_one_set_of_planes(one_chip,
     as the chip steers it on its own (``jax.default_backend`` reads ``tpu``
     there: the df route, and kernels lowered for Mosaic, not the
     interpreter). About 40 s."""
-    import sys
-
     from quest_tpu.circuits import named_program
 
     n = 26
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "circuits")
-    sys.path.insert(0, bench)
-    try:
-        import random_layers
-    finally:
-        sys.path.remove(bench)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     circ = Circuit(n)
-    random_layers.build(circ, num_qubits=n, depth=2, circuit_seed=2026)
+    _cell_layers().build(circ, num_qubits=n, depth=2, circuit_seed=2026)
     fused = circ.fused(max_qubits=5, pallas=True, dtype=np.float64)
     runs = pallas_runs(fused)
     assert len(runs) == 12 and max(len(r.ops) for r in runs) == DF_MAX_OPS

@@ -137,3 +137,168 @@ def test_a_relabeling_is_collective_only_where_it_reaches_a_sharded_qubit(
             == collective)
     fusion._apply_frame_swap(q, fusion.FrameSwap(6, 3, hi))   # its own inverse
     np.testing.assert_array_equal(np.asarray(q.amps), before)
+
+
+# -- the collective relabeling, per shard on the rows view (PR 40) ----------
+
+#: state qubits of the two register shapes: a 13-qubit state-vector and a
+#: 7-qubit density matrix (flattened, 14). The tile leaves a grid block of
+#: three bits, all of them sharded over 8 devices, two over 4, one over 2
+_SHAPES = {"sv": 13, "dm": 14}
+_GRID = 3
+#: (k, the block's offset above the tile): every block of the grid
+_BLOCKS = [(1, 0), (1, 1), (1, 2), (2, 0), (2, 1), (3, 0)]
+
+
+def _local(nsv: int, devices: int) -> int:
+    return nsv - (devices.bit_length() - 1)
+
+
+def _swap_case(kind: str, k: int, off: int):
+    """(state qubits, tile bits, lo2) of a block of ``_BLOCKS``."""
+    nsv = _SHAPES[kind]
+    return nsv, nsv - _GRID, nsv - _GRID + off
+
+
+@pytest.mark.parametrize("kind", ["sv", "dm"])
+@pytest.mark.parametrize("k,off", _BLOCKS)
+@pytest.mark.parametrize("devices", [2, 4, 8])
+def test_a_frame_swap_of_a_sharded_register_is_swap_bit_blocks_to_the_bit(
+        devices, k, off, kind):
+    """Every block of the grid, on a state-vector and on a density
+    register over 2, 4 and 8 devices: blocks that reach one, some and all
+    of the sharded qubits run per shard around one stated all-to-all,
+    shard-local ones as the whole-array transpose, and either way the
+    register holds what ``swap_bit_blocks`` makes of the gathered array --
+    a relabeling moves amplitudes and rounds nothing."""
+    from quest_tpu.ops.pallas_gates import swap_bit_blocks
+
+    env = _env(devices)
+    nsv, tile_bits, lo2 = _swap_case(kind, k, off)
+    make = qt.createQureg if kind == "sv" else qt.createDensityQureg
+    q = make(nsv if kind == "sv" else nsv // 2, env)
+    qt.initDebugState(q)
+    before = np.asarray(q.amps)
+    want = np.asarray(swap_bit_blocks(jax.numpy.asarray(before), n=nsv,
+                                      lo1=tile_bits - k, lo2=lo2, k=k))
+    collective = lo2 + k > _local(nsv, devices)
+    mesh = fusion._swap_mesh(q, tile_bits - k, lo2, k)
+    assert (mesh is q.amps.sharding.mesh) if collective else (mesh is None)
+    telemetry.reset()
+    fusion._apply_frame_swap(q, fusion.FrameSwap(tile_bits, k, lo2))
+    np.testing.assert_array_equal(np.asarray(q.amps), want)
+    assert q.amps.sharding.is_equivalent_to(env.sharding(1 << nsv), 2)
+    assert ({s.data.shape for s in q.amps.addressable_shards}
+            == {(2, (1 << nsv) // devices)})
+    for series in ("fusion_collective_swaps_total",
+                   "fusion_per_shard_swaps_total"):
+        assert telemetry.counter_total(series) == collective
+    assert telemetry.counter_value("pallas_pass_total",
+                                   kind="frame_swap") == 1
+
+
+_COLLECTIVE = [(d, kind, k, off) for d in (2, 4, 8) for kind in _SHAPES
+               for k, off in _BLOCKS
+               if _swap_case(kind, k, off)[2] + k
+               > _local(_SHAPES[kind], d)]
+
+
+@pytest.mark.parametrize("planes", [2, 4])
+@pytest.mark.parametrize("devices,kind,k,off", _COLLECTIVE)
+def test_the_per_shard_form_carries_any_plane_count(devices, kind, k, off,
+                                                    planes):
+    """The per-shard form itself on a (P, 2^n) array, P = 2 and the
+    double-float layout's 4, inside a jitted program as a replay holds it.
+    The low block stands as high as the shard lets it (next under the
+    moved block, or at the shard's top), so that what moves whole below it
+    is a row group of more than one row wherever the block starts above
+    the tile."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from quest_tpu.ops.pallas_gates import swap_bit_blocks
+
+    mesh = _env(devices).mesh
+    nsv, _, lo2 = _swap_case(kind, k, off)
+    lo1 = min(lo2, _local(nsv, devices)) - k
+    x = np.arange(planes << nsv, dtype=np.float32).reshape(planes, -1)
+    want = np.asarray(swap_bit_blocks(jax.numpy.asarray(x), n=nsv, lo1=lo1,
+                                      lo2=lo2, k=k))
+    sharding = NamedSharding(mesh, P(None, AMP_AXIS))
+    swap = fusion._swap_per_shard(mesh, nsv, lo1, lo2, k)
+    got = jax.jit(lambda a: swap(a + 0.0))(jax.device_put(x, sharding))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert got.sharding.is_equivalent_to(sharding, 2)
+
+
+@pytest.mark.parametrize("case,devices,lo1,lo2,k,per_shard", [
+    ("collective", 4, 7, 10, 2, True),
+    ("collective-traced", 4, 7, 10, 2, True),
+    ("shard-local", 4, 7, 9, 1, False),
+    ("one-device", 1, 7, 10, 2, False),
+    ("explicit-scheduler", 4, 7, 10, 2, False),
+    ("other-sharding", 4, 7, 10, 2, False),
+    ("other-axis-traced", 4, 7, 10, 2, False),
+    ("below-the-lanes", 4, 3, 10, 2, False),
+    ("traced-no-mesh", 4, 7, 10, 2, False)])
+def test_how_a_relabeling_is_stated_follows_where_the_register_lies(
+        case, devices, lo1, lo2, k, per_shard):
+    """``fusion._swap_mesh``, decided on registers of shapes only, as
+    ``tests/test_fusion.py`` decides ``_route``: per shard where the block
+    reaches a sharded qubit of a register on the canonical amps mesh;
+    today's whole-array ``swap_bit_blocks`` for a shard-local block, one
+    device, the explicit scheduler, any other sharding, and a block below
+    the lane rows. Nothing runs and nothing is counted."""
+    import contextlib
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from .helpers import shape_register
+
+    n = 12
+    mesh = _env(devices).mesh
+    spec = P(None, AMP_AXIS)
+    if case.startswith("other"):
+        mesh = Mesh(np.array(jax.devices()[:devices]), ("other",))
+        spec = P(None, "other")
+    got = []
+
+    def decide(amps):
+        got.append(fusion._swap_mesh(qt.Qureg(n, False, amps, env=None),
+                                     lo1, lo2, k))
+        return amps
+
+    before = telemetry.snapshot()["counters"]
+    if case.endswith("traced"):
+        with fusion.pallas_mesh(mesh):
+            jax.eval_shape(decide, shape_register(n, np.float32).amps)
+    elif case == "traced-no-mesh":
+        jax.eval_shape(decide, shape_register(n, np.float32).amps)
+    else:
+        ctx = (qt.explicit_mesh(mesh) if case == "explicit-scheduler"
+               else contextlib.nullcontext())
+        sharding = None if mesh is None else NamedSharding(mesh, spec)
+        with ctx:
+            decide(shape_register(n, np.float32, sharding).amps)
+    assert telemetry.snapshot()["counters"] == before, "_swap_mesh counted"
+    assert (got[0] is mesh) if per_shard else (got[0] is None)
+
+
+@pytest.mark.parametrize("devices", [2, 4])
+def test_a_sharded_plan_runs_its_collective_relabelings_per_shard(bench,
+                                                                  devices):
+    """The cell's plan at a CPU size: every relabeling that reaches a
+    sharded qubit ran per shard on the rows view (``sv31x4.block``: 2 of
+    2), and under the explicit scheduler none did."""
+    env = _env(devices)
+    telemetry.reset()
+    _, fused, plan, local = _planned(bench, 14, 2, devices)
+    q, _ = _seeded_register(bench, 14, env)
+    fused.run(q)
+    swaps = telemetry.counter_total("fusion_collective_swaps_total")
+    assert swaps == fusion.transpose_stats(plan, local)[
+        "collective_transposes"] > 0
+    assert telemetry.counter_total("fusion_per_shard_swaps_total") == swaps
+    telemetry.reset()
+    with qt.explicit_mesh(env.mesh):
+        fused.run(q)
+    assert telemetry.counter_total("fusion_collective_swaps_total") > 0
+    assert telemetry.counter_total("fusion_per_shard_swaps_total") == 0
