@@ -90,7 +90,7 @@ def _op_overlap_findings(op: tuple, where: str) -> list[Finding]:
         overlap = set(targets) & set(controls)
         if overlap:
             bad(f"{kind} targets {sorted(overlap)} are also controls")
-    # kraus1/kraus2/krausn/lane_u/window: target disjointness is
+    # kraus1/kraus2/krausn/depol/lane_u/window: target disjointness is
     # structural in their tuple layouts (validated at lowering)
     return findings
 

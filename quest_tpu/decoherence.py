@@ -64,7 +64,7 @@ def mixDepolarising(qureg: Qureg, target: int, prob: float) -> None:
     V.validate_one_qubit_depol_prob(prob, func)
     superop = DN.kraus_superoperator(DN.depolarising_kraus(prob))
     qureg.put(DN.apply_channel(qureg.amps, superop, n=qureg.num_qubits_represented,
-                               targets=(target,)))
+                               targets=(target,), depol=float(prob)))
     _record(qureg, f"mixDepolarising({prob:g}) on q[{target}]")
 
 
@@ -89,7 +89,7 @@ def mixTwoQubitDepolarising(qureg: Qureg, q1: int, q2: int, prob: float) -> None
     V.validate_two_qubit_depol_prob(prob, func)
     superop = DN.two_qubit_depolarising_superop(prob)
     qureg.put(DN.apply_channel(qureg.amps, superop, n=qureg.num_qubits_represented,
-                               targets=(q1, q2)))
+                               targets=(q1, q2), depol=float(prob)))
     _record(qureg, f"mixTwoQubitDepolarising({prob:g}) on q[{q1}],q[{q2}]")
 
 

@@ -79,6 +79,10 @@ class GateEvent:
     diag: Optional[np.ndarray] = None     # 'diag':   (2^t,) complex
     theta: Optional[float] = 0.0          # 'parity'; None: deferred
     superop: Optional[np.ndarray] = None  # 'channel': (4^t, 4^t) complex
+    #: 'channel' recorded by mixDepolarising / mixTwoQubitDepolarising: the
+    #: call's probability (the channel's name is its target count), which
+    #: selects the closed-form kernel op (:func:`_lower_channel`)
+    depol: Optional[float] = None
     extended: bool = False                # targets already in 2n coords
     source: Optional[tuple] = None        # (entry, event index, count)
 
@@ -138,10 +142,11 @@ def _channel_recorders(events: list) -> dict:
     Kraus channels (via apply_channel) and dephasing diagonals (via
     _diag_dispatch) -- both in flattened 2n coordinates."""
 
-    def cap_channel(amps, superop, *, n, targets):
+    def cap_channel(amps, superop, *, n, targets, depol=None):
         events.append(GateEvent(
             "channel", tuple(targets),
-            superop=np.asarray(superop, dtype=complex), extended=True))
+            superop=np.asarray(superop, dtype=complex), depol=depol,
+            extended=True))
         return amps
 
     def cap_dens_diag(amps, d, *, n, targets):
@@ -616,6 +621,15 @@ class PallasRun:
     #: instead of ``comm_pipeline`` (None = inherit --
     #: QUEST_COMM_PIPELINE_DCN env, else the base depth)
     comm_pipeline_dcn: int | None = None
+    #: the kernel is cut at THIS run's ``tile_bits``, a tile narrower than
+    #: the register's own: the planner narrowed it so that a frame there
+    #: holds an op whose targets straddle the register's tile edge
+    #: (``_FramePlanner._synth_frame``). :func:`_route` then runs the kernel
+    #: at that many sublanes and its frame folds as any other. False, a run
+    #: whose ``tile_bits`` differs from the register's is a plan made for
+    #: another register: the kernel runs at the register's tile and the
+    #: relabelings beside it (``swap_not_foldable``).
+    own_tile: bool = False
 
     @property
     def matched(self) -> bool:
@@ -687,7 +701,8 @@ def _window(qubits) -> tuple:
 @dataclass
 class _POp:
     """A primitive op in LOGICAL coordinates plus its diagonality roles."""
-    kind: str            # 'matrix' | 'swap' | 'diagw' | 'parity'
+    kind: str            # 'matrix' | 'swap' | 'diagw' | 'parity' |
+    #                      'kraus1' | 'kraus2' | 'krausn' | 'depol'
     targets: tuple
     controls: tuple
     states: tuple
@@ -775,7 +790,11 @@ class _FramePlanner:
 
     A *frame* is a qubit relabeling: ``None`` is the identity; ``(hi, kf)``
     means the grid-bit block [hi, hi+kf) is swapped with the sublane block
-    [tb-kf, tb). The candidate frames tile the grid bits in k-sized blocks
+    [tb-kf, tb); ``(hi, kf, tb')`` is the same at a NARROWED tile of
+    ``tb' < tb`` bits (only ever synthesized, for an op no frame of the
+    register's tile holds: :meth:`_synth_frame`), and its runs carry
+    ``tb'`` as their own tile (``PallasRun.own_tile``). The candidate
+    frames tile the grid bits in k-sized blocks
     from tb upward, so EVERY qubit of an arbitrarily wide (e.g. sharded)
     register is in-tile in some frame -- the round-4 generalisation that
     lets a sharded 34q register execute fused PallasRuns per shard with
@@ -822,8 +841,9 @@ class _FramePlanner:
 
     # -- frame geometry -----------------------------------------------------
 
-    def width(self, end: int) -> int:
-        """The widest frame whose grid block ends at qubit ``end``. A block
+    def width(self, end: int, tb: int | None = None) -> int:
+        """The widest frame whose grid block ends at qubit ``end`` (at the
+        tile of ``tb`` bits; None: the planner's). A block
         inside the array the kernel sees rides the kernel's DMA, and is
         never wider than what folds there (:func:`_fold_width`): a wider
         one would run as two explicit passes over the whole state beside
@@ -832,25 +852,37 @@ class _FramePlanner:
         does every frame of a tile too small for any to fold (under 16
         sublanes: an explicit ``sublanes=`` only), each an explicit pass
         whatever its width."""
-        fold = _fold_width(self.tb)
+        from .ops.pallas_gates import LANE_BITS
+
+        tb = self.tb if tb is None else tb
+        k = self.k if tb == self.tb else min(max(self.nsv - tb, 0),
+                                             tb - LANE_BITS)
+        fold = _fold_width(tb)
         if end <= self.n_exec and fold > 0:
-            return min(self.k, fold)
-        return self.k
+            return min(k, fold)
+        return k
+
+    def tile(self, frame) -> int:
+        """The tile bits of ``frame``'s runs: the planner's, or a narrowed
+        frame's own."""
+        return self.tb if frame is None or len(frame) == 2 else frame[2]
 
     def phys(self, q: int, frame) -> int:
         if frame is None:
             return q
-        hi, kf = frame
-        if self.tb - kf <= q < self.tb:
-            return q - (self.tb - kf) + hi
+        hi, kf = frame[:2]
+        tb = self.tile(frame)
+        if tb - kf <= q < tb:
+            return q - (tb - kf) + hi
         if hi <= q < hi + kf:
-            return q - hi + (self.tb - kf)
+            return q - hi + (tb - kf)
         return q
 
     def feasible(self, op: _POp, frame) -> bool:
         if op.kind in ("parity", "diagw") or (op.kind == "matrix" and op.diag_targets):
             return True
-        return all(self.phys(t, frame) < self.tb for t in op.targets)
+        tb = self.tile(frame)
+        return all(self.phys(t, frame) < tb for t in op.targets)
 
     def _frame_for(self, op: _POp, exclude):
         for f in self.frames:
@@ -881,11 +913,33 @@ class _FramePlanner:
         bits), so a straddling frame -- whose reuse by later ops would pay
         collective transposes they don't need -- is accepted only when no
         clipped anchor localises the op."""
+        f = self._synth_at(op, self.tb)
+        if f is not None:
+            return f
+        # No frame of this tile holds the op: its targets straddle the
+        # tile's edge, one in the sublane block [tb-k, tb) that every
+        # frame bringing the other in displaces -- on every density
+        # register of 10 qubits or more the kraus2 / depol of the pair
+        # whose columns are bits tb-1 and tb. A NARROWER tile puts both
+        # above its edge, where one block [tb', ...) brings them in
+        # together: the widest such tile, the run carrying it
+        # (``PallasRun.own_tile``), its frame folded like any other --
+        # no state-sized pass, where the entry was a barrier before.
+        from .ops.pallas_gates import LANE_BITS
+
+        for tb in range(self.tb - 1, LANE_BITS, -1):
+            f = self._synth_at(op, tb)
+            if f is not None:
+                return f
+        return None
+
+    def _synth_at(self, op: _POp, tb: int):
+        """:meth:`_synth_frame` at a tile of ``tb`` bits."""
         targs = tuple(op.targets)
-        high = sorted(t for t in targs if t >= self.tb)
+        high = sorted(t for t in targs if t >= tb)
         if not high or self.k <= 0:
             return None
-        lo_t = [t for t in targs if t < self.tb]
+        lo_t = [t for t in targs if t < tb]
         max_lo = max(lo_t, default=-1)
         hi0 = high[0]
         kf = high[-1] + 1 - hi0
@@ -899,10 +953,10 @@ class _FramePlanner:
         for a0, w in cands:
             # the displaced region [tb-w, tb) must stay above every low
             # target, and the block must fit the frame width and register
-            if w <= 0 or w > self.width(a0 + w) or w >= self.tb - max_lo \
+            if w <= 0 or w > self.width(a0 + w, tb) or w >= tb - max_lo \
                     or a0 + w > self.nsv:
                 continue
-            f = (a0, w)
+            f = (a0, w) if tb == self.tb else (a0, w, tb)
             if self.feasible(op, f):
                 return f
         return None
@@ -920,16 +974,18 @@ class _FramePlanner:
         in the identity frame, and a run's load and store relabelings are
         the same one (``PallasRun.matched``) -- what lets its kernel write
         over its operand."""
-        hi, k = (None, 0) if frame is None else frame
+        hi, k = (None, 0) if frame is None else frame[:2]
+        tb = self.tile(frame)
         # cap ops per kernel: Mosaic compile time explodes past a few
         # hundred ops in one program (20q mono-kernel probe: >20 min at
         # 316 ops; a df kernel past DF_MAX_OPS), so over-long runs split
         # into consecutive passes
         phys = [self._phys_op(op, frame) for op in ops]
         for i in range(0, len(phys), self.run_op_cap):
-            run = PallasRun(tuple(phys[i:i + self.run_op_cap]), self.tb,
+            run = PallasRun(tuple(phys[i:i + self.run_op_cap]), tb,
                             load_swap_k=k, load_swap_hi=hi,
-                            store_swap_k=k, store_swap_hi=hi)
+                            store_swap_k=k, store_swap_hi=hi,
+                            own_tile=tb != self.tb)
             assert run.matched, run
             self.out.items.append(run)
 
@@ -946,9 +1002,9 @@ class _FramePlanner:
             return ("kraus1", t[0], t[1], op.data)
         if op.kind == "kraus2":
             return ("kraus2", t[0], t[1], t[2], t[3], op.data)
-        if op.kind == "krausn":
+        if op.kind in ("krausn", "depol"):
             h = len(t) // 2
-            return ("krausn", t[:h], t[h:], op.data)
+            return (op.kind, t[:h], t[h:], op.data)
         if op.kind == "diagw":
             return ("diagw", t, c, HashableMatrix(op.data))
         return ("parity", t, c, op.data)
@@ -1069,6 +1125,11 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
                        sharded_runs=len(runs))
     kernel = {}
     if mode != "dense":
+        # the channels the plan's kernels hold, by lowering: the terms an
+        # op applies a pass (a Kraus sum its terms, the closed form one)
+        channels = channel_terms(runs)
+        for kind, terms in channels.items():
+            telemetry.inc("fusion_channel_terms_total", terms, kind=kind)
         # what the kernels will hold, by kind: each run's zones folded as
         # fused_local_run folds them at the same tile, but for a
         # double-float plan, whose kernels take the ops as they are
@@ -1082,6 +1143,11 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
             # stand right behind a run and so take its planes
             # (fusion_df_carried_total: _df_local_run)
             df=df, run_op_cap=run_op_cap, df_passes=df_passes,
+            channel_ops=sum(op[0] in _CHANNEL_OPS
+                            for r in runs for op in r.ops),
+            channel_terms=sum(channels.values()),
+            # a run at a tile of its own says so (PallasRun.own_tile)
+            run_tile_bits=[r.tile_bits for r in runs],
             df_carried=sum(
                 isinstance(a, PallasRun) and isinstance(b, PallasRun)
                 for a, b in zip(p.items, p.items[1:]))
@@ -1097,6 +1163,29 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         frame_widths=[r.load_swap_k for r in runs],
         fused_gates=p.num_fused_gates, barriers=p.num_barriers,
         **sharded, **kernel)
+
+
+#: the kernel ops that are channels (non-unitary: on a density register)
+_CHANNEL_OPS = ("kraus1", "kraus2", "krausn", "depol")
+
+
+def channel_terms(runs) -> dict:
+    """Terms the channel ops of ``runs`` apply a pass, by how each was
+    lowered (``fusion_channel_terms_total{kind}``): a ``kraus1`` /
+    ``kraus2`` / ``krausn`` op its Kraus terms, two matrix sweeps each; a
+    closed-form depolarising op 1, under ``depol1`` / ``depol2`` by its
+    targets. Kinds the plan does not hold are left out."""
+    out = {}
+    for run in runs:
+        for op in run.ops:
+            if op[0] == "depol":
+                kind, terms = f"depol{len(op[1])}", 1
+            elif op[0] in _CHANNEL_OPS:
+                kind, terms = op[0], len(op[-1])
+            else:
+                continue
+            out[kind] = out.get(kind, 0) + terms
+    return out
 
 
 def plan(tape, num_qubits: int, dtype, max_qubits: int = 5,
@@ -1262,30 +1351,38 @@ _KRAUSN_MAX_TARGETS = 3
 
 
 def _lower_channel(ev: GateEvent, n: int):
-    """'channel' event -> [_POp('kraus1'|'kraus2'|'krausn', extended
-    targets, ...)] for <= _KRAUSN_MAX_TARGETS-target Kraus maps, or None
-    (wider channels stay barriers and run the engine path). The op's data
-    is the hashable Kraus-term tuple ((sign, K), ...) from the
-    superoperator's Choi decomposition -- ALL arities ride the one-pass
-    kernel, mirroring the reference's single superoperator mechanism for
-    every channel width (QuEST_common.c:581-638)."""
+    """'channel' event -> [_POp('depol'|'kraus1'|'kraus2'|'krausn', extended
+    targets, ...)] for <= _KRAUSN_MAX_TARGETS-target channels, or None
+    (wider channels stay barriers and run the engine path).
+
+    An event recorded by ``mixDepolarising`` / ``mixTwoQubitDepolarising``
+    (``ev.depol``: the call's probability, not guessed from the
+    superoperator's numbers) lowers to the family's CLOSED FORM, the
+    'depol' kernel op: ``rho -> (1 - l) rho + l (I/d (x) Tr_T rho)`` with
+    ``l = 4p/3`` on one target and ``16p/15`` on two -- the same channel,
+    exactly, as one masked sum over the group's diagonal where the Kraus
+    sum is 4 or 16 terms of two matrix sweeps each (the reference's
+    dedicated depolarising kernels, QuEST_gpu.cu:2423-2600). Its data is
+    ``l``. Every other channel's data is the hashable Kraus-term tuple
+    ((sign, K), ...) from the superoperator's Choi decomposition -- ALL
+    arities ride the one-pass kernel, mirroring the reference's single
+    superoperator mechanism for every channel width
+    (QuEST_common.c:581-638)."""
     from .ops.density import choi_kraus
     from .ops.pallas_gates import HashableMatrix
 
     if not 1 <= len(ev.targets) <= _KRAUSN_MAX_TARGETS:
         return None
+    rows = tuple(ev.targets)
+    ext = rows + tuple(q + n for q in rows)
+    if ev.depol is not None:
+        d2 = 4 ** len(rows)
+        return [_POp("depol", ext, (), (), float(ev.depol) * d2 / (d2 - 1),
+                     False)]
     terms = tuple((float(s), HashableMatrix(k))
                   for s, k in choi_kraus(ev.superop))
-    if len(ev.targets) == 1:
-        t = ev.targets[0]
-        return [_POp("kraus1", (t, t + n), (), (), terms, False)]
-    if len(ev.targets) == 2:
-        t1, t2 = ev.targets
-        return [_POp("kraus2", (t1, t2, t1 + n, t2 + n), (), (), terms,
-                     False)]
-    rows = tuple(ev.targets)
-    return [_POp("krausn", rows + tuple(q + n for q in rows), (), (),
-                 terms, False)]
+    kind = {1: "kraus1", 2: "kraus2"}.get(len(rows), "krausn")
+    return [_POp(kind, ext, (), (), terms, False)]
 
 
 def _shadow_pop(op: _POp, n: int) -> _POp:
@@ -1682,7 +1779,8 @@ def _route(qureg, run: PallasRun) -> Route:
     nsv = qureg.num_qubits_in_state_vec
     if not df and _mosaic_supports(qureg.dtype):
         return _folded(Route("local", n_exec=nsv,
-                             sublanes=PG._DEF_SUBLANES), run)
+                             sublanes=_run_sublanes(run, PG._DEF_SUBLANES)),
+                       run)
     if ((mesh is None or mesh.size == 1)
             and np.dtype(qureg.dtype) == np.dtype("float64")
             and (1 << nsv) >= 2 * PG._LANES):
@@ -1694,7 +1792,8 @@ def _route(qureg, run: PallasRun) -> Route:
         # (ops/pallas_df).
         from .ops.pallas_df import DF_SUBLANES
 
-        lq_df = PG.local_qubits(nsv, DF_SUBLANES)
+        sublanes = _run_sublanes(run, DF_SUBLANES)
+        lq_df = PG.local_qubits(nsv, sublanes)
         if any(q >= lq_df for op in run.ops
                for q in PG.op_dense_targets(op)):
             # a plan built with non-DF tile geometry (e.g.
@@ -1704,10 +1803,21 @@ def _route(qureg, run: PallasRun) -> Route:
             # ValueError from fused_local_run -- is the contract for
             # f64 registers (ADVICE round 5)
             return Route("gatewise", reason="df_tile_mismatch")
-        return _folded(Route("df_local", n_exec=nsv, sublanes=DF_SUBLANES,
+        return _folded(Route("df_local", n_exec=nsv, sublanes=sublanes,
                              df=True), run)
     # the genuinely unsupported f64 residue: sub-tile registers
     return Route("gatewise", reason="f64_engine")
+
+
+def _run_sublanes(run: PallasRun, sublanes: int) -> int:
+    """The sublanes of the tile ``run``'s kernel is cut at: the route's
+    own (``sublanes``), but for a run the planner narrowed
+    (``PallasRun.own_tile``), whose ``tile_bits`` say it."""
+    from .ops.pallas_gates import LANE_BITS
+
+    if run.own_tile:
+        return min(sublanes, 1 << (run.tile_bits - LANE_BITS))
+    return sublanes
 
 
 def _canonical_amps_mesh(qureg):
@@ -1768,6 +1878,7 @@ def _shard_route(qureg, run: PallasRun, mesh, kind: str) -> Route:
         if (1 << n_local) < 2 * PG._LANES:
             return unsupported
         sublanes = PG._DEF_SUBLANES
+    sublanes = _run_sublanes(run, sublanes)
     lq = PG.local_qubits(n_local, sublanes)
     if any(q >= lq for op in run.ops for q in PG.op_dense_targets(op)):
         return Route("gatewise", reason=("df_tile_mismatch" if df
@@ -2168,16 +2279,19 @@ def _apply_ops_via_engine(qureg, ops: tuple) -> None:
                 raise ValueError("swap with 0-controls has no engine route")
             qureg.put(K.apply_swap(qureg.amps, n=nsv, qb1=q1, qb2=q2,
                                    controls=controls))
-        elif op[0] in ("kraus1", "kraus2", "krausn"):
+        elif op[0] in _CHANNEL_OPS:
             from .ops.density import _acc_kraus_term
 
-            if op[0] == "kraus1":
+            if op[0] == "depol":
+                _, rows, cols, lam = op
+                terms = _depol_kraus_terms(len(rows), lam)
+            elif op[0] == "kraus1":
                 _, t, c, terms = op
                 rows, cols = (t,), (c,)
             elif op[0] == "kraus2":
                 _, t1, t2, c1, c2, terms = op
                 rows, cols = (t1, t2), (c1, c2)
-            else:
+            elif op[0] == "krausn":
                 _, rows, cols, terms = op
             amps0 = qureg.amps
             out = None
@@ -2189,6 +2303,21 @@ def _apply_ops_via_engine(qureg, ops: tuple) -> None:
             qureg.put(out)
         else:  # pragma: no cover
             raise ValueError(f"unknown pallas op {op[0]!r}")
+
+
+def _depol_kraus_terms(num_targets: int, lam: float) -> list:
+    """The Kraus terms ``[(1.0, K), ...]`` of the closed-form 'depol' op
+    on ``num_targets`` targets with mixing weight ``lam``, in a Kraus op's
+    own form -- the canonical table's operators (:mod:`.channels`) at
+    ``p = lam (d^2 - 1) / d^2``: what the gate-by-gate exit replays in the
+    op's place."""
+    from . import channels
+    from .ops.pallas_gates import HashableMatrix
+
+    d2 = 4 ** num_targets
+    ks = (channels.depolarising_kraus if num_targets == 1
+          else channels.two_qubit_depolarising_kraus)(lam * (d2 - 1) / d2)
+    return [(1.0, HashableMatrix(np.asarray(k, dtype=complex))) for k in ks]
 
 
 def _mosaic_supports(dtype) -> bool:

@@ -70,9 +70,15 @@ def choi_kraus(superop) -> list[tuple[float, np.ndarray]]:
 
 
 @records
-def apply_channel(amps, superop, *, n: int, targets: tuple[int, ...]):
+def apply_channel(amps, superop, *, n: int, targets: tuple[int, ...],
+                  depol: float | None = None):
     """Apply a (numpy complex) superoperator to density targets: qubits
     (T..., T+n...) of the flattened 2n-qubit state.
+
+    ``depol`` is what ``mixDepolarising`` / ``mixTwoQubitDepolarising`` say
+    of their call beside its superoperator: the probability. Nothing here
+    reads it; a planner that captures the call does (``fusion.GateEvent``),
+    and lowers the channel to its closed form (``fusion._lower_channel``).
 
     Large registers use the Kraus-sum formulation: rho' = sum_i s_i K_i rho
     K_i^dagger, each term two layout-clean single-group passes (row bits,

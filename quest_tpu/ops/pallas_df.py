@@ -463,6 +463,24 @@ def _ops_body_df(ops, xr, xi, *, tile_bits, gbit, accurate_add=False):
                 acc_i = yi if acc_i is None else df_add(acc_i, yi)
             xr, xi = acc_r, acc_i
 
+        elif op[0] == "depol":
+            # the closed-form depolarising op (pallas_gates._ops_body's,
+            # in double-float): the mean of a group's diagonal by one
+            # paired exchange a target, masked onto that diagonal
+            _, rows_q, cols_q, lam = op
+            sr, si, same = xr, xi, None
+            for t, c in zip(rows_q, cols_q):
+                sr = df_add(sr, partner(partner(sr, t), c))
+                si = df_add(si, partner(partner(si, t), c))
+                eq = 1 - (_bit_mask(t, shape) ^ _bit_mask(c, shape))
+                same = eq if same is None else same * eq
+            same = same.astype(f32)
+            keep = const_pair(1.0 - lam)
+            mh, ml = _fsplit(lam / (1 << len(rows_q)))
+            mean = (same * mh, same * ml)
+            xr = df_add(df_mul(keep, xr), df_mul(mean, sr))
+            xi = df_add(df_mul(keep, xi), df_mul(mean, si))
+
         elif op[0] == "diagw":
             _, targets, controls, D = op
             d = np.asarray(D.arr if hasattr(D, "arr") else D).reshape(-1)

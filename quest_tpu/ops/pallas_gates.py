@@ -336,7 +336,7 @@ def op_dense_targets(op) -> tuple:
         return (op[1], op[2])
     if op[0] == "kraus2":
         return tuple(op[1:5])
-    if op[0] == "krausn":
+    if op[0] in ("krausn", "depol"):
         return (*op[1], *op[2])
     return ()  # parity / diagw / lane_u / window: no dense roles above tile
 
@@ -348,7 +348,7 @@ def _op_support(op):
         return {op[1], op[2], *(op[3] if op[0] == "swap" else ())}
     if op[0] == "kraus2":
         return {op[1], op[2], op[3], op[4]}
-    if op[0] in ("diagw", "parity", "krausn"):
+    if op[0] in ("diagw", "parity", "krausn", "depol"):
         return {*op[1], *op[2]}
     return set(range(LANE_BITS))  # lane_u acts on the lane zone
 
@@ -392,7 +392,7 @@ _BUTTERFLY_MS = {"lane": 0.76, "invreg": 0.07, "rows": 0.25}
 #: the kinds a folded run's ops are counted under (the ``kernel_op_kinds``
 #: field of a pallas plan's ``fusion.plan`` event)
 KERNEL_OP_KINDS = ("lane_u", "window", "diag", "butterfly_lane",
-                   "butterfly_invreg", "butterfly_rows", "kraus")
+                   "butterfly_invreg", "butterfly_rows", "kraus", "depol")
 
 
 def _exchange_kind(q: int) -> str:
@@ -414,6 +414,8 @@ def kernel_op_kind(op) -> str:
         return op[0]
     if op[0] in ("kraus1", "kraus2", "krausn"):
         return "kraus"
+    if op[0] == "depol":
+        return "depol"
     if _op_is_diag(op):
         return "diag"
     kinds = set(map(_exchange_kind, op_dense_targets(op)))
@@ -437,7 +439,8 @@ def _op_cost_ms(op) -> float:
     if op[0] in ("matrix", "swap"):
         return sum(_BUTTERFLY_MS[_exchange_kind(q)]
                    for q in op_dense_targets(op))
-    # kraus ops never reach this model: zone_of() bars them from accumulators
+    # channel ops never reach this model: zone_of() bars them from
+    # accumulators
     return 0.02
 
 
@@ -475,7 +478,7 @@ def _fold_zone_ops(ops, tile_bits: int) -> tuple:
     accum = {z: [] for z in zones}   # zone -> [op]
 
     def zone_of(op):
-        if op[0] in ("kraus1", "kraus2", "krausn"):
+        if op[0] in ("kraus1", "kraus2", "krausn", "depol"):
             return None  # non-unitary: must never enter a zone's dense fold
         s = _op_support(op)
         for z in zones:
@@ -772,6 +775,29 @@ def _ops_body(ops, xr, xi, *, tile_bits, dtype, gbit, get_w):
                 acc_r = yr if acc_r is None else acc_r + yr
                 acc_i = yi if acc_i is None else acc_i + yi
             xr, xi = acc_r, acc_i
+
+        elif op[0] == "depol":
+            # the depolarising family in closed form, on one target or
+            # two: rho -> (1 - l) rho + l (I/d (x) Tr_T rho). Of a group
+            # (the 4^t elements that differ in the row targets ``rows_q``
+            # and their column twins ``cols_q``) only the 2^t on its
+            # diagonal -- row bit == column bit on every target -- take
+            # anything in, and what they take is the mean of those 2^t:
+            # one paired exchange of (t, t + n) a target sums them, where
+            # the Kraus sum is 4^t terms of two matrix sweeps each. The
+            # reference's dedicated kernels (QuEST_gpu.cu:2423-2600,
+            # densmatr_mixDepolarising / mixTwoQubitDepolarising).
+            _, rows_q, cols_q, lam = op
+            sr, si, same = xr, xi, None
+            for t, c in zip(rows_q, cols_q):
+                sr = sr + _partner(_partner(sr, t), c)
+                si = si + _partner(_partner(si, t), c)
+                eq = _bit_mask(t, shape) == _bit_mask(c, shape)
+                same = eq if same is None else same & eq
+            keep = dtype.type(1.0 - lam)
+            mean = jnp.where(same, dtype.type(lam / (1 << len(rows_q))),
+                             dtype.type(0.0))
+            xr, xi = keep * xr + mean * sr, keep * xi + mean * si
 
         elif op[0] == "diagw":
             _, targets, controls, D = op
@@ -1124,7 +1150,7 @@ def fused_local_run(amps, *, n: int, ops: tuple, sublanes: int = _DEF_SUBLANES,
         _kernel_kind(_tile_geometry(amps.shape[-1], sublanes)[2], local_n,
                      df),
         amps.shape[0], amps.dtype, len(ops_l), int(load_swap_k),
-        int(store_swap_k))
+        int(store_swap_k), _narrowed_tile_bits(amps.shape, sublanes))
     run = _named_jit(_fused_local_run_impl, name, _FUSED_STATIC)
 
     def call():
@@ -1192,19 +1218,38 @@ def _compile_record(kind: str, name: str, t0: float, t_fold: float, mark,
 
 
 def kernel_name(kind: str, planes: int, dtype, nops: int,
-                load_swap_k: int = 0, store_swap_k: int = 0) -> str:
+                load_swap_k: int = 0, store_swap_k: int = 0,
+                narrowed_tile_bits: int | None = None) -> str:
     """The ``name=`` of a fused-run ``pallas_call``, which the device
     trace shows in place of ``_fused_local_run.<n>``: kernel kind (``dma``
     the manual-DMA chunk loop, ``grid`` the BlockSpec grid, ``df1`` the
     gridless one-tile double-float call), ``df`` for the 4-plane layout
     or the dtype, the op count after zone folding, and the folded load
-    and store swap ``k``: ``qt_fused_dma_f32_ops57_ls0_ss7``. A pure
+    and store swap ``k``: ``qt_fused_dma_f32_ops57_ls0_ss7``; a kernel cut
+    at a narrower tile than its layout's own (``_narrowed_tile_bits``) says
+    so, ``..._ss2_tb18``, or two kernels of one program that differ in
+    nothing else would share a name and the device trace merge them. A pure
     function of the call's static arguments, in letters, digits and ``_``:
     the name enters the kernel's lowering and so the compile-cache key,
     and must be the same in every process (no counter, id or hash)."""
     dt = "df" if planes == 4 else f"f{8 * np.dtype(dtype).itemsize}"
+    tile = "" if narrowed_tile_bits is None else f"_tb{narrowed_tile_bits}"
     return (f"qt_fused_{kind}_{dt}_ops{nops}"
-            f"_ls{load_swap_k}_ss{store_swap_k}")
+            f"_ls{load_swap_k}_ss{store_swap_k}{tile}")
+
+
+def _narrowed_tile_bits(shape: tuple, sublanes: int) -> int | None:
+    """The tile bits of a call whose ``sublanes`` cut a narrower tile than
+    its layout's own (``_DEF_SUBLANES``, or the double-float layout's
+    ``DF_SUBLANES``) would on the same array -- a run the planner narrowed
+    (``fusion.PallasRun.own_tile``) -- or None."""
+    from .pallas_df import DF_SUBLANES
+
+    own = DF_SUBLANES if shape[0] == 4 else _DEF_SUBLANES
+    s = _tile_geometry(shape[-1], sublanes)[1]
+    if s >= _tile_geometry(shape[-1], own)[1]:
+        return None
+    return LANE_BITS + s.bit_length() - 1
 
 
 def writes_in_place(tile_bits: int, load_swap_k: int, load_swap_hi,
@@ -1374,7 +1419,7 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
     tile_bits = LANE_BITS + s_bits
     kind = _kernel_kind(grid, local_n, df)
     name = kernel_name(kind, P, amps.dtype, len(ops), load_swap_k,
-                       store_swap_k)
+                       store_swap_k, _narrowed_tile_bits(amps.shape, sublanes))
     for k, hi in ((load_swap_k, load_swap_hi), (store_swap_k, store_swap_hi)):
         if k:
             hi = tile_bits if hi is None else hi

@@ -103,6 +103,23 @@ def _cell_layers():
     return layers
 
 
+def _noisy_circuit(n, depth=2):
+    """The benchmark's ``circuits/noisy_layers.py`` on a density register:
+    ``density15.noise``'s tape (46 gates, a depolarising channel after each)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "noisy_layers", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "circuits", "noisy_layers.py"))
+    noisy = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(noisy)
+    circ = Circuit(n, is_density_matrix=True)
+    noisy.build(circ, num_qubits=n, depth=depth, circuit_seed=2026,
+                p1=1e-3, p2=1e-2)
+    return circ
+
+
 def _random_circuit(n, depth=8):
     from __graft_entry__ import _random_layers
 
@@ -227,6 +244,12 @@ def _cell_chain(case):
         n, runs = 26, _planned_runs(_random_circuit(26, depth=2))
         assert [(r.load_swap_k, r.store_swap_k) for r in runs] \
             == [(0, 0), (7, 7), (0, 0)]
+    elif case == "density15-noise-twelve-runs":   # density15.noise: 2^30
+        n, runs = 30, _planned_runs(_noisy_circuit(15))
+        # one run at a tile of its own: the pair whose columns straddle 2^19
+        assert [(r.tile_bits, r.load_swap_k, r.load_swap_hi)
+                for r in runs if r.own_tile] == [(18, 2, 18)]
+        assert len(runs) == 12 and all(r.matched for r in runs)
     elif case == "density14-two-runs":     # density14.block: 2^28 amplitudes
         import bench
 
@@ -247,14 +270,16 @@ def _compiled_cell_chain(one_chip, case):
     if case not in _CHAINS:
         n, runs = _cell_chain(case)
         chain = [_fused_kw(n, r.ops, lk=r.load_swap_k, sk=r.store_swap_k,
-                           lh=r.load_swap_hi, sh=r.store_swap_hi)
+                           lh=r.load_swap_hi, sh=r.store_swap_hi,
+                           sublanes=fusion._run_sublanes(r, PG._DEF_SUBLANES))
                  for r in runs]
         _CHAINS[case] = (n, _compile_chain(one_chip, n, chain))
     return _CHAINS[case]
 
 
 @pytest.mark.parametrize("case", ["sv30-four-runs-k9-k2", "sv26-three-runs-k7",
-                                  "density14-two-runs"])
+                                  "density14-two-runs",
+                                  "density15-noise-twelve-runs"])
 def test_a_chain_of_matched_runs_holds_no_state_sized_temporary(one_chip,
                                                                 case):
     """Every run of these plans leaves the frame it entered, so its kernel
@@ -288,8 +313,32 @@ def test_create_qureg_30q_is_one_buffer(one_chip):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def test_create_density_qureg_15q_and_its_trace(one_chip):
+    """``createDensityQureg(15)``'s |0><0| is ``registers._alloc(env, 30,
+    ...)``: ONE 8 GiB buffer, as ``createQureg(30)``'s. ``calcTotalProb`` of
+    it (``ops.reduce.total_prob_density``) reads the diagonal where it lies
+    and holds nothing of a plane's size -- where the ``reshape(2, dim, dim)``
+    and ``jnp.diagonal`` it replaces held two temporaries of 4 GiB, 16 GiB in
+    all, and did not compile for the chip."""
+    from quest_tpu.ops import init as ops_init
+    from quest_tpu.ops import reduce as ops_reduce
+
+    compiled = jax.jit(
+        lambda: ops_init.init_classical(1 << 30, jnp.dtype("float32"), 0),
+        out_shardings=one_chip).lower().compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 8 << 30
+    assert mem.temp_size_in_bytes < 1 << 20
+    rho = jax.ShapeDtypeStruct((2, 1 << 30), jnp.float32, sharding=one_chip)
+    mem = ops_reduce.total_prob_density.lower(rho, n=15).compile() \
+        .memory_analysis()
+    assert mem.argument_size_in_bytes == 8 << 30
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("case", ["sv30-four-runs-k9-k2", "sv26-three-runs-k7",
-                                  "density14-two-runs", "sv20-two-runs"])
+                                  "density14-two-runs", "sv20-two-runs",
+                                  "density15-noise-twelve-runs"])
 def test_chained_runs_read_the_register_where_it_lies(one_chip, case):
     """A program shaped like a library cell's -- its fused runs chained at
     the cell's real size, with their load and store swaps, on the donated
@@ -580,6 +629,31 @@ def test_density_kraus_run_14q(one_chip):
     assert {"kraus1", "krausn"} <= kinds, kinds
     run = next(r for r in runs if any(op[0] == "krausn" for op in r.ops))
     _compile_fused(one_chip, nsv, run.ops)
+
+
+def test_closed_form_depolarising_run_15q(one_chip):
+    """The run of ``density15.noise``'s plan at a tile of its own (2^18: the
+    frame k=2 @18 that holds the pair whose columns straddle 2^19), with a
+    closed-form depolarising op on one target and one on two among its ops
+    (``_one_of_each`` keeps one op a kind): the 'depol' body's paired
+    exchanges, its masks and the narrowed tile's DMA lower through Mosaic
+    at 2^30 elements, in place."""
+    runs = _planned_runs(_noisy_circuit(15))
+    depol = [op for r in runs for op in r.ops if op[0] == "depol"]
+    assert sorted({len(op[1]) for op in depol}) == [1, 2]
+    assert not any(op[0].startswith("kraus") for r in runs for op in r.ops)
+    run = next(r for r in runs if r.own_tile)
+    lq = run.tile_bits
+    must = tuple(next(op for op in depol if len(op[1]) == t
+                      and all(q < lq for q in PG.op_dense_targets(op)))
+                 for t in (1, 2))
+    compiled = _compile_fused(
+        one_chip, 30, run.ops, must=must, lk=run.load_swap_k,
+        sk=run.store_swap_k, lh=run.load_swap_hi, sh=run.store_swap_hi,
+        sublanes=fusion._run_sublanes(run, PG._DEF_SUBLANES))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 8 << 30
+    assert mem.temp_size_in_bytes < 1 << 30
 
 
 def test_window_dot_26q(one_chip):

@@ -43,6 +43,8 @@ _OPS = {
     "kraus1": (("kraus1", 8, 15, _K), "kraus"),
     "kraus2": (("kraus2", 0, 1, 14, 15, _K), "kraus"),
     "krausn": (("krausn", (0, 1, 2), (14, 15, 16), _K), "kraus"),
+    "depol-one-target": (("depol", (8,), (15,), 4e-3 / 3), "depol"),
+    "depol-two-targets": (("depol", (3, 4), (16, 17), 0.16 / 15), "depol"),
 }
 
 

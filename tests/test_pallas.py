@@ -363,7 +363,8 @@ def test_density_tapes_ride_pallas_with_shadow_ops():
 def test_density_channels_fuse_into_pallas_runs():
     """Round-3 channel fast path: single-target Kraus channels capture as
     'kraus1' kernel ops, two-target ones as 'kraus2', dephasing as
-    extended diagonals -- all riding the same PallasRun as the unitaries.
+    extended diagonals, the depolarising family as its closed form
+    ('depol', PR 41) -- all riding the same PallasRun as the unitaries.
     Replay matches the eager engine."""
     n = 5
     c = Circuit(n, is_density_matrix=True)
@@ -378,11 +379,14 @@ def test_density_channels_fuse_into_pallas_runs():
     c.mixDephasing(3, 0.2)
     c.mixTwoQubitDephasing(0, 1, 0.1)
     c.mixTwoQubitDepolarising(0, 1, 0.1)
+    cx = np.eye(4)[[0, 1, 3, 2]]
+    c.mixTwoQubitKrausMap(2, 3, [0.8 * np.eye(4), 0.6 * cx])
     fz = c.fused(max_qubits=4, pallas=True)
     run_ops = [op for r in pallas_runs(fz) for op in r.ops]
     kinds = [op[0] for op in run_ops]
-    assert kinds.count("kraus1") == 3
-    assert kinds.count("kraus2") == 1  # the 2-target depolarising
+    assert kinds.count("kraus1") == 2  # damping, the one-qubit Kraus map
+    assert kinds.count("kraus2") == 1  # the two-qubit Kraus map
+    assert [len(op[1]) for op in run_ops if op[0] == "depol"] == [1, 2]
     assert kinds.count("diagw") == 2  # both dephasings, extended coords
     assert all(f.__name__ == "_apply_pallas_run" for f, _, _ in fz._tape)
 
