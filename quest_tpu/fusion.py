@@ -570,6 +570,9 @@ class FusePlan:
     items: list = field(default_factory=list)
     num_fused_gates: int = 0
     num_barriers: int = 0
+    #: times the list scheduler widened a pending run's frame to take an op
+    #: that fitted no pending run (``_FramePlanner._grown``)
+    frames_grown: int = 0
 
 
 @dataclass(frozen=True)
@@ -800,21 +803,37 @@ class _FramePlanner:
     lets a sharded 34q register execute fused PallasRuns per shard with
     each frame switch one (collective) transpose (VERDICT r3 missing #1).
 
-    Scheduling (round-4b): an ordered list of PENDING runs, each pinned
-    to a frame. A new op joins the EARLIEST run whose frame localises it
+    Scheduling (round-4b): an ordered list of PENDING runs, each in a
+    frame. A new op joins the EARLIEST run whose frame localises it
     and whose every LATER pending op commutes past it (runs execute in
     list order; an op placed in run i runs before everything in runs
     j > i, so it must commute with what is already there -- and later
-    arrivals into runs j < i check against it symmetrically). Ops that
-    fit nowhere open a new run. Holding every run open until flush lets
-    late ops join early runs, which cuts frame alternations well below
-    the two-slot (open + one lookahead) round-4a scheme on >=3-frame
-    plans (34q sharded, density tapes)."""
+    arrivals into runs j < i check against it symmetrically). Holding
+    every run open until flush lets late ops join early runs, which cuts
+    frame alternations well below the two-slot (open + one lookahead)
+    round-4a scheme on >=3-frame plans (34q sharded, density tapes).
+
+    A run's frame is pinned at FLUSH, not at birth: an op that no pending
+    run localises is offered, before it opens a run of its own, to each
+    pending run in order with the run's block GROWN to reach the op's high
+    targets (:meth:`_grown`: still within what folds, still holding every
+    op the run has, never across the shard boundary), under the same
+    commutation test. ``_emit_run`` derives the relabeling and the
+    physical ops from whatever frame the run has by then. So the column
+    ops of a density layer, each of which synthesizes the minimal block
+    for its own targets (``k=1 @25``, ``@26``, ``k=2 @27`` ...: a pass
+    over the state apiece), collect in ONE run whose block widens as they
+    arrive (``k=5 @25``). Identity runs have no block to grow and a
+    narrowed-tile frame exists for one straddling op: neither grows.
+    ``grow=False`` is the schedule with frames fixed at birth, which
+    :func:`_plan_pallas` keeps among its candidates."""
 
     def __init__(self, out: FusePlan, tile_bits: int, k: int, nsv: int,
                  boundary: int | None = None, n_exec: int | None = None,
-                 run_op_cap: int = _RUN_OP_CAP):
+                 run_op_cap: int = _RUN_OP_CAP, grow: bool = True):
         self.out = out
+        #: may a pending run's block widen for an op no run holds
+        self.grow = grow
         self.tb = tile_bits
         self.k = k
         self.nsv = nsv
@@ -904,7 +923,10 @@ class _FramePlanner:
         high targets, with kf kept small enough that the displaced
         sublane region avoids the op's low targets, restores coverage.
         The synthesized frame joins ``self.frames`` so later ops (and
-        the run scheduler) reuse it.
+        the run scheduler) reuse it. It is the MINIMAL block for this op:
+        what the run it opens is emitted under is decided at flush, after
+        the list scheduler has widened it for the ops that followed
+        (:meth:`_grown`).
 
         When a shard boundary is set and the minimal span block straddles
         it, boundary-CLIPPED anchors are tried first (round 6, closing the
@@ -1017,15 +1039,51 @@ class _FramePlanner:
 
     # -- scheduling ---------------------------------------------------------
 
+    def _grown(self, frame, ops: list, op: _POp):
+        """``frame`` with its block widened to cover ``op``'s high targets,
+        ``[min(hi, min(high)), max(hi + kf, max(high) + 1))``, or None
+        where that is no frame this run can take: wider than the kernel's
+        DMA folds there (:meth:`width`); a target of the run's own ``ops``
+        or of ``op`` inside the sublane block the wider frame displaces;
+        across the shard boundary (a shard-local block stays below it, a
+        collective one above it, a block that straddles it stays as it
+        is). Identity and narrowed-tile frames do not grow."""
+        if frame is None or len(frame) != 2:
+            return None
+        hi, kf = frame
+        high = [t for t in op.targets if t >= self.tb]
+        if not high:
+            return None
+        lo, end = min(hi, min(high)), max(hi + kf, max(high) + 1)
+        w = end - lo
+        if w == kf or w > self.width(end):
+            return None
+        if any(e is not None and lo < e < end
+               for e in (self.boundary, self.n_exec)):
+            return None
+        wide = (lo, w)
+        if all(self.feasible(o, wide) for o in (*ops, op)):
+            return wide
+        return None
+
     def add(self, op: _POp):
         # earliest run that localises the op AND whose every later op
         # commutes past it (see class docstring for the ordering argument)
+        def commutes_past(i):
+            return all(self._commutes(op, other)
+                       for _, later in self.runs[i + 1:] for other in later)
+
         for i, (frame, ops) in enumerate(self.runs):
-            if not self.feasible(op, frame):
-                continue
-            if all(self._commutes(op, other)
-                   for _, later in self.runs[i + 1:] for other in later):
+            if self.feasible(op, frame) and commutes_past(i):
                 ops.append(op)
+                return
+        # ... else the earliest run whose block can grow to localise it
+        for i, run in enumerate(self.runs if self.grow else ()):
+            wide = self._grown(*run, op)
+            if wide is not None and commutes_past(i):
+                run[0] = wide
+                run[1].append(op)
+                self.out.frames_grown += 1
                 return
         f = self._frame_for(op, exclude=Ellipsis)
         if f is Ellipsis:  # pragma: no cover - callers pre-check
@@ -1109,6 +1167,8 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
     telemetry.inc("fusion_pallas_runs_total", len(runs), mode=mode)
     telemetry.inc("fusion_frame_transposes_total", folded + explicit,
                   mode=mode)
+    if p.frames_grown:
+        telemetry.inc("fusion_frames_grown_total", p.frames_grown, mode=mode)
     df_passes = 0
     if df:
         # the df kernels the plan states: its runs, but where a run is
@@ -1161,6 +1221,7 @@ def _record_plan_telemetry(p: FusePlan, mode: str, nsv: int,
         ops_per_run=[len(r.ops) for r in runs],
         inplace_runs=sum(r.matched for r in runs),
         frame_widths=[r.load_swap_k for r in runs],
+        frames_grown=p.frames_grown,
         fused_gates=p.num_fused_gates, barriers=p.num_barriers,
         **sharded, **kernel)
 
@@ -1525,7 +1586,12 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
     then schedule the lowered stream with BOTH frame schedulers (the
     ordered-list _FramePlanner and the two-slot variant) and keep the
     cheaper plan: fewer passes single-chip, fewer collective transposes
-    first when ``score_shard_qubits`` is set. Density tapes
+    first when ``score_shard_qubits`` is set. Where the list scheduler
+    widened a frame, its schedule with frames fixed at birth is a
+    candidate too, and the grown one the last: a tape on which an early
+    growth shuts a later op out of a block cannot come out with more
+    passes than before, and on a tie the plan is the one it was. Density
+    tapes
     (``is_density``) plan over the flattened 2n-qubit state: every
     lowered row op is paired with its conj-shadow twin and both are
     scheduled; the emitted PallasRuns then carry EXPLICIT shadow ops, and
@@ -1538,9 +1604,9 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
     cap = _run_op_cap(dtype, sharded=(shard_boundary is not None
                                       or score_shard_qubits is not None))
 
-    def make_planner(cls):
+    def make_planner(cls, **kw):
         return cls(FusePlan(), tile_bits, k, nsv, boundary=shard_boundary,
-                   n_exec=score_shard_qubits, run_op_cap=cap)
+                   n_exec=score_shard_qubits, run_op_cap=cap, **kw)
 
     probe = make_planner(_FramePlanner)  # frame geometry only
 
@@ -1588,8 +1654,8 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
             resolved.append(("events", list(zip(events, lowered))))
 
     # -- pass 2: schedule with each planner, keep the cheaper plan --------
-    def schedule(cls):
-        sched = make_planner(cls)
+    def schedule(cls, **kw):
+        sched = make_planner(cls, **kw)
         out = sched.out
         for kind, payload in resolved:
             if kind == "barrier":
@@ -1620,8 +1686,10 @@ def _plan_pallas(tape, num_qubits: int, dtype, max_qubits: int,
             return (st["collective_transposes"], len(p.items))
         return (len(p.items), st["local_transposes"])
 
-    return min((schedule(cls)
-                for cls in (_FramePlanner, _FramePlannerTwoSlot)), key=score)
+    grown = schedule(_FramePlanner)
+    fixed = schedule(_FramePlanner, grow=False) if grown.frames_grown \
+        else grown
+    return min((fixed, schedule(_FramePlannerTwoSlot), grown), key=score)
 
 
 def _df_route(dtype) -> bool:

@@ -163,13 +163,18 @@ def _one_of_each(ops_folded, lq, must=()):
 
 
 def _fused_kw(n, ops, *, planes=2, sublanes=PG._DEF_SUBLANES, local_n=None,
-              lk=0, sk=0, lh=None, sh=None, ring=None, must=()):
+              lk=0, sk=0, lh=None, sh=None, ring=None, must=(), whole=False):
     """``_fused_local_run``'s static arguments as ``fused_local_run``
-    would pass them on the TPU (interpret=False), the op list cut by
-    ``_one_of_each`` (double-float runs pass theirs as given)."""
+    would pass them on the TPU (interpret=False), the folded op list cut by
+    ``_one_of_each`` unless ``whole`` (double-float runs pass theirs as
+    given)."""
     lq = PG.local_qubits(n, sublanes)
-    ops_l = tuple(ops) if planes == 4 else _one_of_each(
-        PG._fold_zone_ops(ops, lq), lq, must)
+    if planes == 4:
+        ops_l = tuple(ops)
+    else:
+        ops_l = PG._fold_zone_ops(ops, lq)
+        if not whole:
+            ops_l = _one_of_each(ops_l, lq, must)
     return dict(n=n, ops=ops_l, sublanes=sublanes, interpret=False,
                 local_n=local_n, load_swap_k=lk, store_swap_k=sk,
                 load_swap_hi=lh, store_swap_hi=sh,
@@ -244,12 +249,15 @@ def _cell_chain(case):
         n, runs = 26, _planned_runs(_random_circuit(26, depth=2))
         assert [(r.load_swap_k, r.store_swap_k) for r in runs] \
             == [(0, 0), (7, 7), (0, 0)]
-    elif case == "density15-noise-twelve-runs":   # density15.noise: 2^30
+    elif case == "density15-noise-six-runs":   # density15.noise: 2^30
         n, runs = 30, _planned_runs(_noisy_circuit(15))
         # one run at a tile of its own: the pair whose columns straddle 2^19
         assert [(r.tile_bits, r.load_swap_k, r.load_swap_hi)
                 for r in runs if r.own_tile] == [(18, 2, 18)]
-        assert len(runs) == 12 and all(r.matched for r in runs)
+        # ... and one under the block that grew (PR 42; twelve runs before)
+        assert (29, 5, 25) in [(len(r.ops), r.load_swap_k, r.load_swap_hi)
+                               for r in runs]
+        assert len(runs) <= 7 and all(r.matched for r in runs)
     elif case == "density14-two-runs":     # density14.block: 2^28 amplitudes
         import bench
 
@@ -279,7 +287,7 @@ def _compiled_cell_chain(one_chip, case):
 
 @pytest.mark.parametrize("case", ["sv30-four-runs-k9-k2", "sv26-three-runs-k7",
                                   "density14-two-runs",
-                                  "density15-noise-twelve-runs"])
+                                  "density15-noise-six-runs"])
 def test_a_chain_of_matched_runs_holds_no_state_sized_temporary(one_chip,
                                                                 case):
     """Every run of these plans leaves the frame it entered, so its kernel
@@ -338,7 +346,7 @@ def test_create_density_qureg_15q_and_its_trace(one_chip):
 
 @pytest.mark.parametrize("case", ["sv30-four-runs-k9-k2", "sv26-three-runs-k7",
                                   "density14-two-runs", "sv20-two-runs",
-                                  "density15-noise-twelve-runs"])
+                                  "density15-noise-six-runs"])
 def test_chained_runs_read_the_register_where_it_lies(one_chip, case):
     """A program shaped like a library cell's -- its fused runs chained at
     the cell's real size, with their load and store swaps, on the donated
@@ -654,6 +662,26 @@ def test_closed_form_depolarising_run_15q(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 8 << 30
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_grown_frame_run_15q_whole(one_chip):
+    """The run of ``density15.noise``'s plan under the block that grew
+    (``k=5 @25``: bits 25-29 in, 14-18 out), WHOLE: its 29 ops, not one of
+    each kind -- eight closed-form channels on one target and three on two
+    among them, behind a load and a store relabeling of 2^5 row chunks --
+    lower through Mosaic at 2^30 elements within the kernel's VMEM, in
+    place (PR 42; the seven kernels it replaces held one to ten ops).
+    About 20 s."""
+    run = next(r for r in _planned_runs(_noisy_circuit(15))
+               if (r.load_swap_k, r.load_swap_hi) == (5, 25))
+    assert len(run.ops) == 29 and run.matched and not run.own_tile
+    depol = [len(op[1]) for op in run.ops if op[0] == "depol"]
+    assert (depol.count(1), depol.count(2)) == (8, 3)
+    compiled = _compile_fused(one_chip, 30, run.ops, whole=True, lk=5, sk=5,
+                              lh=25, sh=25)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 8 << 30
+    assert mem.temp_size_in_bytes < 1 << 20
 
 
 def test_window_dot_26q(one_chip):

@@ -9,7 +9,11 @@ depolarising channel after each on 15 qubits, 2^30 elements, 8 GiB):
   'depol' op), the same channel as the Kraus sum, in float32, native float64
   and double-float;
 - the plans of the cells the benchmark already had stay what they were, item
-  for item.
+  for item;
+- a pending run's frame grows to hold the next column op (PR 42): the cell's
+  tape plans to six passes where it planned to twelve, every op still in a
+  frame that holds it and in its tape order, never more passes than with
+  frames fixed at birth.
 
 Plans only at the real sizes; execution at rehearsal sizes whose tile is cut
 (``PG._DEF_SUBLANES``) so that a pair straddles it. (What the chip's compiler
@@ -131,6 +135,14 @@ def test_a_kraus_pair_that_straddles_the_tile_finds_a_frame_too():
             for r in runs] == [("kraus2", 18, 2, 18), ("krausn", 17, 3, 17)]
 
 
+def _plan_events():
+    """The ``fusion.plan`` events of the pallas plans made since the last
+    ``telemetry.reset()`` (the span of the same name carries no ``items``)."""
+    return [e for e in telemetry.events() if e.get("name") == "fusion.plan"
+            and e.get("mode") in ("pallas", "pallas_sharded")
+            and "items" in e]
+
+
 def _cell_plan(case, monkeypatch):
     def layers(n, depth, **kw):
         circ = Circuit(n)
@@ -173,10 +185,15 @@ def test_the_cells_plans_are_the_parent_s_item_for_item(case, monkeypatch):
     channel op by its qubits, so that ``density14.block``'s two
     ``mixDepolarising`` may change their lowering and nothing around them
     may move). No run narrows its tile: none had an op no frame held."""
+    telemetry.reset()
     fz = _cell_plan(case, monkeypatch)
     plan = fusion.plan_from_tape(fz._tape)
     assert plan_digest(plan) == _PARENT_PLANS[case]
     assert not any(r.own_tile for r in pallas_runs(fz))
+    # ... and no frame of theirs grew (PR 42): the counter has no series
+    assert not [k for k in telemetry.snapshot()["counters"]
+                if k.startswith("fusion_frames_grown_total")]
+    assert [e["frames_grown"] for e in _plan_events()] == [0]
     if case == "density14.block":
         kinds = [op[0] for r in pallas_runs(fz) for op in r.ops
                  if op[0] in fusion._CHANNEL_OPS]
@@ -213,8 +230,7 @@ def test_the_plan_event_counts_channel_terms():
              if k.startswith("fusion_channel_terms_total")}
     assert terms == {"depol1": 12, "depol2": 7, "kraus1": 2, "kraus2": 16}
     assert counters["fusion_barriers_total{mode=pallas}"] == 0
-    (event,) = [e for e in telemetry.events() if e.get("name") == "fusion.plan"
-                and "mode" in e and e.get("items")]
+    (event,) = _plan_events()
     assert event["channel_ops"] == 21 and event["channel_terms"] == 37
     assert event["barriers"] == 0
     assert event["run_tile_bits"] == [r.tile_bits for r in plan.items]
@@ -435,3 +451,242 @@ def test_the_whole_noisy_tape_fused_unfused_and_oracle(sublanes, monkeypatch):
     assert np.max(np.abs(get_density(fused) - want)) < 1e-12
     assert np.max(np.abs(get_density(plain) - want)) < 1e-12
     assert abs(qt.calcTotalProb(fused) - 1.0) < 1e-12
+
+
+# -- (d) a frame that grows to hold the next column op (PR 42) ----------------
+
+def _frames(runs):
+    return [(len(r.ops), r.load_swap_k, r.load_swap_hi, r.tile_bits)
+            for r in runs]
+
+
+def test_the_cells_tape_plans_in_six_passes_where_it_took_twelve():
+    """``density15.noise``'s tape at the register's own tile: at most 7 fused
+    runs (6: the parent planned 12, seven of them of one to five ops under
+    frames ``k=1 @25``, ``@26``, ``@27``, ``k=2 @24`` to ``@28``), every one
+    matched, exactly one at a tile of its own; the column ops of qubits 10
+    to 13 ride ONE run whose block grew to ``k=5 @25``. The counter and the
+    event say how often a block grew."""
+    telemetry.reset()
+    fz = _noisy(15).fused(max_qubits=5, pallas=True, dtype=np.float32)
+    runs = _only_runs(fusion.plan_from_tape(fz._tape))
+    assert len(runs) <= 7 and all(r.matched for r in runs)
+    assert [r.tile_bits for r in runs if r.own_tile] == [18]
+    assert _frames(runs) == [(43, 0, None, 19), (48, 9, 19, 19),
+                             (29, 5, 25, 19), (16, 2, 28, 19),
+                             (1, 2, 18, 18), (1, 2, 24, 19)]
+    assert sum(len(r.ops) for r in runs) == 138
+    (event,) = _plan_events()
+    assert event["frames_grown"] == 4
+    assert event["frame_widths"] == [0, 9, 5, 2, 2, 2]
+    assert telemetry.snapshot()["counters"][
+        "fusion_frames_grown_total{mode=pallas}"] == 4
+
+
+def test_the_schedule_with_frames_fixed_is_the_parent_s(monkeypatch):
+    """With growth refused the same tape plans as on the parent commit:
+    twelve runs. The fixed schedule is what :func:`fusion._plan_pallas`
+    keeps among its candidates."""
+    monkeypatch.setattr(fusion._FramePlanner, "_grown", lambda *a: None)
+    fz = _noisy(15).fused(max_qubits=5, pallas=True, dtype=np.float32)
+    runs = _only_runs(fusion.plan_from_tape(fz._tape))
+    assert [f[:3] for f in _frames(runs)] == [
+        (43, 0, None), (48, 9, 19), (1, 1, 25), (3, 1, 26), (1, 1, 27),
+        (15, 2, 28), (9, 2, 25), (10, 2, 27), (1, 2, 18), (1, 2, 24),
+        (1, 2, 26), (5, 2, 28)]
+
+
+def _random_tape(n, density, seed, gates=48):
+    """A seeded tape of one- and two-qubit gates on any pair (dense,
+    diagonal, controlled, swaps), on a density register most of them
+    followed by a channel on the qubits they touched."""
+    rng = np.random.RandomState(seed)
+    circ = Circuit(n, is_density_matrix=density)
+    for _ in range(gates):
+        q = int(rng.randint(n))
+        r = int((q + 1 + rng.randint(n - 1)) % n)
+        kind = rng.randint(6)
+        if kind == 0:
+            circ.hadamard(q)
+        elif kind == 1:
+            circ.rotateX(q, float(rng.uniform(0, 2 * np.pi)))
+        elif kind == 2:
+            circ.tGate(q)
+        elif kind == 3:
+            circ.controlledNot(q, r)
+        elif kind == 4:
+            circ.swapGate(q, r)
+        else:
+            circ.controlledPhaseShift(q, r, 0.7)
+        if density and rng.rand() < 0.7:
+            if kind >= 3:
+                circ.mixTwoQubitDepolarising(q, r, P2)
+            elif rng.rand() < 0.7:
+                circ.mixDepolarising(q, P1)
+            else:
+                circ.mixDamping(q, 0.1)
+    return circ
+
+
+def _plan_random(circ, tile_bits):
+    return fusion.plan(tuple(circ._tape), circ.num_qubits, np.dtype("float32"),
+                       max_qubits=5, pallas_tile_bits=tile_bits,
+                       is_density=circ.is_density_matrix)
+
+
+def _watch_list_scheduler(monkeypatch):
+    """Every list scheduler with growth on, as :func:`fusion._plan_pallas`
+    drives it: the ops in the order they arrive and the pending runs as
+    they stand at each flush (the two-slot scheduler has its own ``add`` and
+    ``flush`` and is not seen)."""
+    seen = {}
+    add, flush = fusion._FramePlanner.add, fusion._FramePlanner.flush
+
+    def record(planner):
+        return seen.setdefault(id(planner), dict(
+            planner=planner, arrived=[], flushed=[]))
+
+    def spy_add(self, op):
+        record(self)["arrived"].append(op)
+        add(self, op)
+
+    def spy_flush(self):
+        record(self)["flushed"].append([(f, list(ops)) for f, ops in self.runs])
+        flush(self)
+
+    monkeypatch.setattr(fusion._FramePlanner, "add", spy_add)
+    monkeypatch.setattr(fusion._FramePlanner, "flush", spy_flush)
+    return seen
+
+
+_RANDOM_CASES = [(True, n, tb) for n in (6, 7, 8) for tb in (10, 11, 12, 13)
+                 if tb < 2 * n] + \
+                [(False, n, tb) for n in (11, 12, 13, 14)
+                 for tb in (10, 11, 12, 13) if tb < n]
+
+
+@pytest.mark.parametrize("density,n,tile_bits", _RANDOM_CASES, ids=[
+    f"{'dm' if d else 'sv'}{n}-tile{tb}" for d, n, tb in _RANDOM_CASES])
+def test_a_grown_schedule_keeps_every_op_in_a_frame_and_in_order(
+        density, n, tile_bits, monkeypatch):
+    """Seeded random tapes (noisy on 6 to 8 qubit density registers, plain on
+    11 to 14 qubit state-vectors) at tiles of 2^10 to 2^13. Of the list
+    scheduler with growth on: every op is ``feasible`` in the FINAL frame of
+    the run that holds it, a run's ops stand in the order they arrived, and
+    two ops that do not commute keep their tape order across runs. Of the
+    plan: every run matched, every dense target inside its run's tile, no
+    frame wider than the planner's ``width``, and no more items than the
+    schedule with frames fixed at birth gives."""
+    for seed in range(4):
+        circ = _random_tape(n, density, 100 * n + seed)
+        with monkeypatch.context() as patch:
+            seen = _watch_list_scheduler(patch)
+            plan = _plan_random(circ, tile_bits)
+        (rec,) = [rec for rec in seen.values() if rec["planner"].grow]
+        planner = rec["planner"]
+        when = {id(op): i for i, op in enumerate(rec["arrived"])}
+        placed = 0
+        for runs in rec["flushed"]:
+            where = {}
+            for i, (frame, ops) in enumerate(runs):
+                assert [when[id(op)] for op in ops] \
+                    == sorted(when[id(op)] for op in ops)
+                for op in ops:
+                    assert planner.feasible(op, frame), (seed, op, frame)
+                    where[id(op)] = i
+                if frame is not None and len(frame) == 2:
+                    assert frame[1] <= planner.width(sum(frame)), frame
+            held = [op for _, ops in runs for op in ops]
+            placed += len(held)
+            for a in held:
+                for b in held:
+                    if when[id(a)] < when[id(b)] \
+                            and not planner._commutes(a, b):
+                        assert where[id(a)] <= where[id(b)], (seed, a, b)
+        assert placed == len(rec["arrived"])
+        for run in plan.items:
+            if isinstance(run, fusion.PallasRun):
+                assert run.matched
+                assert all(q < run.tile_bits for op in run.ops
+                           for q in PG.op_dense_targets(op))
+        with monkeypatch.context() as patch:
+            patch.setattr(fusion._FramePlanner, "_grown", lambda *a: None)
+            fixed = _plan_random(circ, tile_bits)
+        assert fixed.frames_grown == 0
+        assert len(plan.items) <= len(fixed.items), seed
+        assert (plan.num_barriers, plan.num_fused_gates) \
+            == (fixed.num_barriers, fixed.num_fused_gates)
+
+
+@pytest.mark.parametrize("density,n,tile_bits,seed", [
+    (True, 7, 10, 700), (True, 7, 10, 704), (True, 8, 13, 803),
+    (True, 8, 13, 807), (False, 14, 10, 1402), (False, 14, 10, 1405)])
+def test_growth_engages_on_random_tapes(density, n, tile_bits, seed,
+                                        monkeypatch):
+    """Tapes of the property test above on which a block did grow: fewer
+    items than with frames fixed at birth, and the plan says so."""
+    circ = _random_tape(n, density, seed)
+    plan = _plan_random(circ, tile_bits)
+    monkeypatch.setattr(fusion._FramePlanner, "_grown", lambda *a: None)
+    fixed = _plan_random(circ, tile_bits)
+    assert plan.frames_grown > 0 and len(plan.items) < len(fixed.items)
+
+
+def test_a_block_never_grows_across_the_shard_boundary():
+    """The planner of a register split over 4 devices (17 qubits of density,
+    34 bits, 32 a shard): a block below the boundary grows up to it and not
+    across, one above it stays above, and the widths are those of
+    ``width``: what folds below the boundary, the planner's ``k`` for a
+    collective."""
+    planner = fusion._FramePlanner(fusion.FusePlan(), 19, 12, 34,
+                                   boundary=32, n_exec=32)
+
+    def depol(q):
+        return fusion._POp("depol", (q, q + 17), (), (), 0.1, False)
+
+    def column(bit):     # a gate's shadow: one dense target, a column bit
+        return fusion._POp("matrix", (bit,), (), (), np.eye(2)[::-1], False)
+
+    assert planner._grown((27, 1), [depol(10)], depol(13)) == (27, 4)
+    assert planner._grown((27, 4), [depol(10)], column(31)) == (27, 5)
+    assert planner._grown((27, 5), [depol(10)], column(32)) is None
+    assert planner._grown((32, 1), [column(32)], column(33)) == (32, 2)
+    assert planner._grown((32, 1), [column(32)], column(31)) is None
+    # the same block on one device, where nothing is sharded, may take it
+    one = fusion._FramePlanner(fusion.FusePlan(), 19, 12, 34)
+    assert one._grown((27, 5), [depol(10)], column(32)) == (27, 6)
+    # an identity run and a narrowed-tile frame do not grow; nor a block
+    # whose wider form would displace a target the run holds (row 14) or
+    # would be wider than what folds (9 at this tile)
+    assert one._grown(None, [], depol(10)) is None
+    assert one._grown((18, 2, 18), [], depol(10)) is None
+    assert one._grown((27, 4), [depol(13)], depol(14)) is None
+    assert one._grown((19, 9), [], column(28)) is None
+
+
+@pytest.mark.parametrize("precision,tol", [(1, 2e-6), (2, 1e-12)])
+def test_a_grown_frame_plan_runs_to_the_oracle_s_result(precision, tol,
+                                                        monkeypatch):
+    """The noisy tape on 9 qubits at a tile of 2^13 (64 sublanes: frames of
+    up to 3 bits fold): a plan in which a block grew (``k=3 @15`` holds 30
+    ops), run through ``Circuit.run`` in float32 and in float64, against
+    the oracle's gate-by-gate, channel-by-channel replay."""
+    n, sublanes = 9, 64
+    monkeypatch.setattr(PG, "_DEF_SUBLANES", sublanes)
+    circ = _noisy(n)
+    dtype = np.float32 if precision == 1 else np.float64
+    plan, fz = _plan_at(circ, PG.local_qubits(2 * n, sublanes), dtype=dtype)
+    runs = _only_runs(plan)
+    assert plan.frames_grown == 1
+    assert (30, 3, 15, 13) in _frames(runs)
+    register = shape_register(2 * n, dtype)
+    assert all(fusion._route(register, r).unfolded == 0 for r in runs)
+    env = qt.createQuESTEnv(jax.devices()[:1])
+    q = qt.createDensityQureg(n, env, precision_code=precision)
+    assert q.dtype == dtype
+    rho = oracle.random_density(n, np.random.RandomState(6))
+    set_density(q, rho)
+    fz.run(q)
+    want, _ = _oracle_replay(rho, n)
+    assert np.max(np.abs(get_density(q) - want)) < tol
+    assert abs(qt.calcTotalProb(q) - 1.0) < 10 * tol
