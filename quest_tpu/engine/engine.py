@@ -297,6 +297,9 @@ class Engine:
         # own dispatch route label -- grad_request traffic stays countable
         # apart from plain engine_param/engine_vmap dispatches
         self._route = getattr(finalize, "dispatch_route", None)
+        # ... and so do the traces its requests leave: a label ``route`` on
+        # a gradient engine's, nothing on a replay engine's
+        self._trace_labels = {"route": self._route} if self._route else {}
         # round 20: the observable whose gradient submit_grad serves; the
         # companion gradient engine (same ansatz, grad_reduce finalize)
         # builds lazily on first use
@@ -450,11 +453,12 @@ class Engine:
                     ctx = None
                 elif adopt is not None and len(values_list) == 1:
                     ctx = adopt.child("engine.request",
-                                      engine=self.fingerprint[:8])
+                                      engine=self.fingerprint[:8],
+                                      **self._trace_labels)
                 else:
                     ctx = telemetry.start_trace(
                         "request", t0=now, kind="engine",
-                        engine=self.fingerprint[:8])
+                        engine=self.fingerprint[:8], **self._trace_labels)
                 self._q.append(
                     _Request(values, fut, now, deadline, poison, ctx))
                 futs.append(fut)
@@ -1155,7 +1159,8 @@ class Engine:
         with telemetry.region("engine.launch") as rg:
             out = call()
         retraced = telemetry.compile_mark() is not mark and \
-            telemetry.first_call(mark, rg, program.__name__, route)
+            telemetry.first_call(mark, rg, program.__name__,
+                                 self._route or route)
         self._charge(batch, "compile" if retraced else "dispatch", rg.t1)
         return out
 

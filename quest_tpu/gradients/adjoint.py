@@ -341,7 +341,10 @@ def _plan_cached(lifted, num_qubits, dtype_str):
     hit = _PLAN_CACHE.get(key)
     if hit is not None:
         return hit[1], hit[2]
-    plans, stop = _plan_build(lifted, num_qubits, dtype_str)
+    # the build captures every concrete entry through the planner's spy:
+    # set-up time of a gradient program, read as a span of its own
+    with telemetry.span("grad.plan_backward"):
+        plans, stop = _plan_build(lifted, num_qubits, dtype_str)
     _PLAN_CACHE[key] = (lifted, plans, stop)
     return plans, stop
 
@@ -434,10 +437,23 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
     plans, stop = _plan_cached(lifted, num_qubits, dtype_str)
     slots = lifted.slots
     slot_count = len(slots)
+    telemetry.event(
+        "grad.plan", num_qubits=num_qubits, entries=len(plans),
+        slots=slot_count, terms=len(codes),
+        param_entries=sum(1 for p in plans if p.param),
+        concrete_events=sum(len(p.events) for p in plans),
+        first_slot=stop)
+
+    def applied(sweep, count=1):
+        # what ONE gradient's program applies to a whole register, counted
+        # as this body walks: once a trace, like fusion_df_passes_total
+        if count:
+            telemetry.inc("grad_sweep_entries_total", count, sweep=sweep)
 
     def grad_fn(amps, values):
         lam = apply_hamiltonian(amps, codes=codes, coeffs=coeffs,
                                 num_qubits=num_qubits)
+        applied("hamiltonian", len(codes))
         value = expectation_value(amps, lam)
         grads = [None] * slot_count
         phi = Qureg(num_qubits, False, amps, env=None)
@@ -457,11 +473,16 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
                                         num_qubits, part)
                     _accumulate(grads, view[field], g, comp)
                 _dagger_param(lamq, plan.name, vals)
+                applied("bracket", len(plan.post) + len(plan.pre))
+                applied("backward_phi")
+                applied("backward_lambda")
             else:
                 for ev in reversed(plan.events):
                     _apply_event_dagger(phi, ev)
                 for ev in reversed(plan.events):
                     _apply_event_dagger(lamq, ev)
+                applied("backward_phi", len(plan.events))
+                applied("backward_lambda", len(plan.events))
         slot_grads = tuple(
             g if g is not None else jnp.real(values[i]) * 0.0
             for i, g in enumerate(grads))
