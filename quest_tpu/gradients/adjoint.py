@@ -32,6 +32,23 @@ Chain rule through the slot graph: contributions accumulate per *slot*
 (so a constant-folded anonymous slot gets its own derivative) and named
 slots sharing one Param sum into that Param's gradient.
 
+The backward half walks the tape's DENSE PLAN, not its gates (PR 45): the
+tape from the first slot on is planned with the call the serving Engine
+makes (``fusion.plan`` at ``ops.apply.DENSE_WINDOW_QUBITS``), and the
+recurrence steps block by block -- φ ← B†φ, one window contraction
+T[a, b] = Σ_rest conj(λ[a, rest])·φ[b, rest], λ ← B†λ -- with every
+derivative a block holds read off that ONE contraction:
+dE/dθ_k = 2·Re Σ_ab T[a, b]·∂B[a, b]/∂θ_k, the VJP of the block's
+in-program composition (``fusion._resolve_factors`` + ``_compose_dense`` /
+``_compose_diag``) at the cotangent (2 Re T, -2 Im T); blocks that are
+one function of their values (a layer's window in every layer) are
+composed and differentiated together (:class:`_Composed`). B† is the product
+of the daggers below, nothing approximate; the rules above stay the gate
+walk's, which an entry the planner passes through (no window holds it)
+still takes, and which EVERY entry takes under an active explicit
+scheduler, where each gate routes through the scheduler one by one
+(docs/gradients.md).
+
 Inverses ride the ordinary routes: parameterized families dagger through
 their own public gate functions (negated angle / (α,β) → (α*, -β), traced
 branches included), concrete entries dagger through the fusion planner's
@@ -45,17 +62,23 @@ entries) raises a typed QuESTError at lift time naming the site.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import fusion
 from .. import gates as G
 from .. import matrices as M
 from .. import telemetry
-from ..engine.params import _SlotRef
+from ..engine.cache import _canon
+from ..engine.params import Param, _SlotRef, materialize_entry
 from ..fusion import gatewise
 from ..ops import reduce as R
+from ..ops.apply import DENSE_WINDOW_QUBITS, _MIN_MINOR, _mxu_precision
+from ..parallel import scheduler as _dist
 from ..registers import Qureg
 from ..validation import QuESTError
 from .expectation import apply_hamiltonian, expectation_value, hamiltonian_terms
@@ -340,13 +363,14 @@ def _plan_cached(lifted, num_qubits, dtype_str):
     key = (id(lifted), num_qubits, dtype_str)
     hit = _PLAN_CACHE.get(key)
     if hit is not None:
-        return hit[1], hit[2]
+        return hit[1:]
     # the build captures every concrete entry through the planner's spy:
     # set-up time of a gradient program, read as a span of its own
     with telemetry.span("grad.plan_backward"):
         plans, stop = _plan_build(lifted, num_qubits, dtype_str)
-    _PLAN_CACHE[key] = (lifted, plans, stop)
-    return plans, stop
+        items = _plan_blocks(lifted, plans, stop, num_qubits, dtype_str)
+    _PLAN_CACHE[key] = (lifted, plans, stop, items)
+    return plans, stop, items
 
 
 def _plan_build(lifted, num_qubits, dtype_str):
@@ -394,13 +418,61 @@ def _plan_build(lifted, num_qubits, dtype_str):
     return tuple(plans[first_slot:]), first_slot
 
 
+@dataclass(frozen=True)
+class _BlockPlan:
+    """One block of the tape's dense plan, as the backward walk undoes it:
+    a static block holds its DAGGERED operator, a block with Param factors
+    its :class:`..fusion.DeferredBlock`, for each of the spec's value
+    slots the tape slot it reads, and ``like``, the spec's content key:
+    blocks with one key are ONE function of their values (a layer's window
+    in every layer of an ansatz), composed and differentiated together."""
+    kind: str                         # 'dense' | 'diag'
+    qubits: tuple
+    dagger: Optional[np.ndarray] = None
+    spec: Optional[object] = None
+    slots: tuple = ()
+    like: Optional[tuple] = None
+
+
+def _plan_blocks(lifted, plans, stop, num_qubits, dtype_str):
+    """The backward walk's items: the tape from the first slot on, planned
+    with the call ``Engine._plan_program`` makes. A block becomes a
+    :class:`_BlockPlan`; an entry the planner passes through (no block
+    holds it) keeps its :class:`_EntryPlan`. EVERY slot enters the planner
+    as a Param named by its index, so a constant angle's gate is a factor
+    composed in the program like a named one and keeps its derivative."""
+    marks = tuple(Param(str(s.index)) for s in lifted.slots)
+    tape = tuple(materialize_entry(e, marks) for e in lifted.entries[stop:])
+    plan = fusion.plan(tape, num_qubits, np.dtype(dtype_str),
+                       max_qubits=DENSE_WINDOW_QUBITS)
+    items, at = [], 0
+    for item in plan.items:
+        if isinstance(item, tuple):
+            while tape[at][1] is not item[1]:
+                at += 1
+            items.append(plans[at])
+            at += 1
+            continue
+        diag = isinstance(item, fusion.DiagBlock)
+        kind = "diag" if diag else "dense"
+        if item.factors is None:
+            op = np.conj(item.diag) if diag else np.conj(item.matrix).T
+            items.append(_BlockPlan(kind, tuple(item.qubits), dagger=op))
+            continue
+        _, (spec, *values), _ = fusion._deferred_entry(item)
+        items.append(_BlockPlan(kind, tuple(item.qubits), spec=spec,
+                                slots=tuple(int(v.name) for v in values),
+                                like=_canon(spec)))
+    return tuple(items)
+
+
 def plan_backward(lifted, num_qubits: int, dtype=None):
     """``(plans, stop)``: per-entry backward plans for entries ``stop..P-1``
     (``stop`` = first slot-bearing entry; the prefix is the effective
     initial state). Raises a typed :class:`QuESTError` naming the first
     non-invertible site."""
     dt = np.dtype(dtype if dtype is not None else jnp.result_type(float))
-    return _plan_cached(lifted, num_qubits, dt.str)
+    return _plan_cached(lifted, num_qubits, dt.str)[:2]
 
 
 def check_differentiable(circuit, dtype=None) -> int:
@@ -415,6 +487,154 @@ def check_differentiable(circuit, dtype=None) -> int:
     lifted = gatewise(circuit).lifted()
     plan_backward(lifted, circuit.num_qubits, dtype)
     return len(lifted.slots)
+
+
+# ---------------------------------------------------------------------------
+# a block undone: one contraction gives every derivative it holds
+# ---------------------------------------------------------------------------
+
+def _window_contraction(lam, phi, n, lo, hi):
+    """``(re, im)`` of T[a, b] = sum_rest conj(lam[a, rest]) phi[b, rest]
+    over the contiguous window [lo, hi] (qubit lo is bit 0 of a and b): one
+    pass over the two registers, contracted over every qubit outside the
+    window in the views :func:`..ops.apply._apply_matrix_window` applies a
+    block in, so no register is transposed. A window that starts below the
+    lane boundary is contracted over the low ``w`` qubits whole and the
+    qubits of ``w`` outside the window traced out of the small result
+    (4 * 4^w numbers a lane, w at most MAX_LOW_WINDOW_TOP: what the
+    expanded matrix of that block's own application holds)."""
+    mm = partial(jnp.einsum, precision=_mxu_precision(phi.dtype))
+    dim = 1 << (hi - lo + 1)
+    if lo >= _MIN_MINOR:
+        view = (2, -1, dim, 1 << lo)
+        m = mm("pgak,qgbk->pqab", lam.reshape(view), phi.reshape(view))
+    else:
+        w = min(max(hi + 1, _MIN_MINOR), n)
+        view = (2, -1, 1 << w)
+        m = mm("pri,qrj->pqij", lam.reshape(view), phi.reshape(view))
+        above, below = 1 << (w - 1 - hi), 1 << lo
+        if above * below > 1:
+            m = jnp.einsum("pqhalhbl->pqab", m.reshape(
+                (2, 2) + 2 * (above, dim, below)))
+    return m[0, 0] + m[1, 1], m[0, 1] - m[1, 0]
+
+
+def _diag_contraction(lam, phi, n, qubits):
+    """``(re, im)`` of T[s] = sum_rest conj(lam[s, rest]) phi[s, rest] over
+    the (possibly scattered) ``qubits`` (qubits[j] is bit j of s): the
+    diagonal of the window contraction, which is all a diagonal block's
+    derivative reads. One elementwise pass, then the rest summed away in
+    views whose minor dimension stays a lane row: the qubits from the lane
+    boundary up one at a time from the top (each sum leaves at most what
+    it read), the ones below it by ONE small 0/1 matrix on the lane axis
+    (the grouped view of every qubit at once pads its 2-sized minor axes
+    to whole tiles on the chip: 268 MB for an 8 MiB register)."""
+    lanes = min(n, _MIN_MINOR)
+    high = [q for q in qubits if q >= lanes]
+    low = [q for q in qubits if q < lanes]
+    pick = np.zeros((1 << lanes, 1 << len(low)), dtype=phi.dtype)
+    rows = np.arange(1 << lanes)
+    pick[rows, sum(((rows >> q) & 1) << j for j, q in enumerate(low))] = 1
+
+    def down(c):
+        c, top = c.reshape(1, -1), n
+        for q in reversed(high):
+            c = c.reshape(c.shape[0], 1 << (top - 1 - q), 2, 1 << q).sum(1)
+            c, top = c.reshape(-1, 1 << q), q
+        c = c.reshape(c.shape[0], -1, 1 << lanes).sum(1)
+        return jnp.matmul(c, pick, precision=_mxu_precision(c.dtype)
+                          ).reshape(-1)
+
+    return (down(lam[0] * phi[0] + lam[1] * phi[1]),
+            down(lam[0] * phi[1] - lam[1] * phi[0]))
+
+
+class _Composed:
+    """The operators of the plan's blocks with Params and, after the walk,
+    their slots' derivatives. Blocks that are one function of their values
+    (``_BlockPlan.like``) are composed by ONE ``vmap`` of
+    ``fusion._resolve_factors`` + ``_compose_dense`` / ``_compose_diag``
+    over their stacked values and differentiated by ONE VJP at their
+    stacked cotangents: the trace and the program hold a composition a
+    structure, not one a block (three for the twelve blocks of a four-layer
+    ansatz on 20 qubits)."""
+
+    def __init__(self, items, values, n, dtype):
+        alike = {}
+        for item in items:
+            if isinstance(item, _BlockPlan) and item.spec is not None:
+                alike.setdefault(item.like, []).append(item)
+        self._row, self._groups = {}, []
+        for members in alike.values():
+            head = members[0]
+
+            def compose(local, head=head):
+                events = fusion._resolve_factors(head.spec, local, n, dtype)
+                return (fusion._compose_diag if head.kind == "diag"
+                        else fusion._compose_dense)(events, head.qubits,
+                                                    dtype)
+
+            stacked = tuple(jnp.stack([values[m.slots[k]] for m in members])
+                            for k in range(len(head.slots)))
+            (re, im), vjp = jax.vjp(jax.vmap(compose), stacked)
+            for row, m in enumerate(members):
+                self._row[id(m)] = (len(self._groups), row)
+            self._groups.append((members, re, im, vjp, [None] * len(members)))
+
+    def operator(self, item):
+        """``(re, im)`` planes of the block's operator B."""
+        group, row = self._row[id(item)]
+        _, re, im, _, _ = self._groups[group]
+        return re[row], im[row]
+
+    def contracted(self, item, t_re, t_im):
+        """The block's T: dE/dtheta = 2 Re sum T dB/dtheta, so the
+        cotangent of B's planes is (2 Re T, -2 Im T)."""
+        group, row = self._row[id(item)]
+        self._groups[group][4][row] = (2.0 * t_re, -2.0 * t_im)
+
+    def harvest(self, grads):
+        """Every slot's derivative, added into ``grads`` by tape slot.
+        Complex slots come back in ``jax.grad``'s convention
+        (``_CPLX_IM``), which is the VJP's own."""
+        for members, _, _, vjp, cts in self._groups:
+            (local,) = vjp((jnp.stack([c[0] for c in cts]),
+                            jnp.stack([c[1] for c in cts])))
+            for row, m in enumerate(members):
+                for i, g in zip(m.slots, local):
+                    grads[i] = g[row] if grads[i] is None \
+                        else grads[i] + g[row]
+
+
+def _undo_block(item, phi: Qureg, lamq: Qureg, composed) -> int:
+    """Undo one block of the plan on both registers; returns the
+    contractions made (one for a block with Param factors, none for a
+    static one).
+
+    With B the block's operator, phi_pre = B^dagger phi_post and
+    dE/dtheta = 2 Re <lam_post| dB/dtheta |phi_pre>
+              = 2 Re sum_ab T[a, b] dB[a, b]/dtheta,
+    T contracted from the two registers once a BLOCK; every slot's
+    derivative is then the composition's VJP at T's cotangent
+    (:class:`_Composed`): O(4^|W|) a factor, no pass over a register."""
+    n = phi.num_qubits_represented
+    diag = item.kind == "diag"
+    apply = G._apply_gate_diag if diag else G._apply_gate_matrix
+    if item.spec is None:
+        apply(phi, item.dagger, item.qubits)
+        apply(lamq, item.dagger, item.qubits)
+        return 0
+    re, im = composed.operator(item)
+    dagger = jax.lax.complex(re, -im) if diag else jax.lax.complex(re.T, -im.T)
+    apply(phi, dagger, item.qubits)
+    if diag:
+        t = _diag_contraction(lamq.amps, phi.amps, n, item.qubits)
+    else:
+        t = _window_contraction(lamq.amps, phi.amps, n,
+                                item.qubits[0], item.qubits[-1])
+    apply(lamq, dagger, item.qubits)
+    composed.contracted(item, *t)
+    return 1
 
 
 # ---------------------------------------------------------------------------
@@ -434,15 +654,17 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
     hit = _REDUCE_CACHE.get(key)
     if hit is not None:
         return hit[1]
-    plans, stop = _plan_cached(lifted, num_qubits, dtype_str)
+    plans, stop, items = _plan_cached(lifted, num_qubits, dtype_str)
     slots = lifted.slots
     slot_count = len(slots)
+    blocks = [i for i in items if isinstance(i, _BlockPlan)]
     telemetry.event(
         "grad.plan", num_qubits=num_qubits, entries=len(plans),
         slots=slot_count, terms=len(codes),
         param_entries=sum(1 for p in plans if p.param),
         concrete_events=sum(len(p.events) for p in plans),
-        first_slot=stop)
+        first_slot=stop, blocks=len(blocks),
+        param_blocks=sum(1 for b in blocks if b.spec is not None))
 
     def applied(sweep, count=1):
         # what ONE gradient's program applies to a whole register, counted
@@ -458,8 +680,16 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
         grads = [None] * slot_count
         phi = Qureg(num_qubits, False, amps, env=None)
         lamq = Qureg(num_qubits, False, lam, env=None)
-        for plan in reversed(plans):
-            if plan.param:
+        # the items of the dense plan; gate by gate where an explicit
+        # scheduler routes every gate itself (docs/gradients.md)
+        walk = plans if _dist.active() is not None else items
+        composed = _Composed(walk, values, num_qubits, phi.dtype)
+        for plan in reversed(walk):
+            if isinstance(plan, _BlockPlan):
+                applied("bracket", _undo_block(plan, phi, lamq, composed))
+                applied("backward_phi")
+                applied("backward_lambda")
+            elif plan.param:
                 view = dict(plan.view)
                 vals = {f: (values[v.index] if isinstance(v, _SlotRef)
                             else v) for f, v in view.items()}
@@ -483,6 +713,7 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
                     _apply_event_dagger(lamq, ev)
                 applied("backward_phi", len(plan.events))
                 applied("backward_lambda", len(plan.events))
+        composed.harvest(grads)
         slot_grads = tuple(
             g if g is not None else jnp.real(values[i]) * 0.0
             for i, g in enumerate(grads))

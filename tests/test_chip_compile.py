@@ -701,3 +701,105 @@ def test_window_dot_26q(one_chip):
     m = jax.ShapeDtypeStruct((2, d, d), jnp.float32, sharding=one_chip)
     compiled = jax.jit(run).lower(x4, m).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _dot_generals(jaxpr, state, found):
+    """``dot_general`` equations of ``jaxpr`` and of every jaxpr nested in
+    it, told apart by what they touch: an ``application`` writes a
+    register (a result of ``state`` elements or more), a ``contraction``
+    reads two and writes something small."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            def size(v):
+                return int(np.prod(v.aval.shape))
+            if size(eqn.outvars[0]) >= state:
+                found["application"] += 1
+            elif sum(size(v) >= state for v in eqn.invars) == 2:
+                found["contraction"] += 1
+        for param in eqn.params.values():
+            for inner in (param if isinstance(param, (list, tuple))
+                          else [param]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _dot_generals(inner, state, found)
+    return found
+
+
+def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
+    """``ansatz20.grad-closed8``'s batch program
+    (``jit_qt_engine_vmap_sv_n20_g202_b8``: the 20-qubit depth-4 ansatz,
+    six Pauli strings, eight lanes under ``vmap``) for the described chip.
+
+    What ONE lane's reduce holds, by its jaxpr: the backward half walks the
+    30 blocks of the tape's dense plan (PR 45), 26 of them dense windows
+    undone by one GEMM on ``phi`` and one on ``lambda`` (the four
+    diagonals are elementwise passes), and harvests all 160 derivatives
+    from 12 window contractions; the costate's 68 one-qubit Pauli
+    applications stand as they did (the gate walk held 384 applications
+    there and no contraction). Then the chip compiler's
+    ``memory_analysis`` of the whole batch program: the gate walk read
+    10.6 GB of temporaries and 3.24 GB of generated code (PERF.md section
+    7, PR 44), and the ceilings here stand under 60% of both. The lanes
+    are steered to ``vmap`` as the chip steers them (``jax.default_backend``
+    reads ``tpu`` there; the CPU runs them as a ``lax.map`` scan). About
+    four minutes."""
+    import sys
+
+    import quest_tpu as qt
+    from quest_tpu import fusion as F
+    from quest_tpu.engine import Engine, P as Param
+    from quest_tpu.gradients import apply_hamiltonian
+    from quest_tpu.parallel import scheduler as _dist
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from circuits import serving_ansatz
+
+    n, depth, lanes = 20, 4, 8
+    rng = np.random.RandomState(20)
+    codes = rng.randint(0, 4, size=(6, n)).astype(np.int32)
+    coeffs = rng.normal(size=6)
+    circ = Circuit(n)
+    serving_ansatz.build(circ, angle=Param, num_qubits=n, depth=depth)
+    env = qt.createQuESTEnv(jax.devices()[:1])
+    engine = Engine(circ, env, precision_code=1, hamiltonian=(codes, coeffs),
+                    max_batch=lanes, max_delay_ms=0.5)
+    try:
+        grad = engine.grad_engine()
+        reduce = grad._finalize
+        state = 2 << n
+        amps = jax.ShapeDtypeStruct((2, 1 << n), jnp.float32)
+        values = tuple(jax.ShapeDtypeStruct((), jnp.float32)
+                       for _ in range(reduce.num_slots))
+
+        def count(fn, *args):
+            return _dot_generals(jax.make_jaxpr(fn)(*args).jaxpr, state,
+                                 {"application": 0, "contraction": 0})
+
+        h_codes, h_coeffs = reduce.hamiltonian
+        costate = count(lambda a: apply_hamiltonian(
+            a, codes=h_codes, coeffs=h_coeffs, num_qubits=n), amps)
+        assert costate == {"application": 68, "contraction": 0}
+        assert count(reduce, amps, values) == {
+            "application": 68 + 2 * 26, "contraction": 12}
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        batch = grad._execB()
+        monkeypatch.undo()
+        assert batch.__name__ == "qt_engine_vmap_sv_n20_g202_b8"
+        jitted = batch.__kwdefaults__["_inner"]
+        args = [jax.ShapeDtypeStruct((2, 1 << n), jnp.float32,
+                                     sharding=one_chip)]
+        args += [jax.ShapeDtypeStruct((lanes, len(cols)), jnp.float32,
+                                      sharding=one_chip)
+                 for _, cols in grad._packs]
+        with _dist.explicit_mesh(None), F.pallas_mesh(None):
+            compiled = jitted.lower(*args).compile()
+    finally:
+        engine.close()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.6e9, mem.temp_size_in_bytes
+    assert mem.generated_code_size_in_bytes < 1.6e9, \
+        mem.generated_code_size_in_bytes

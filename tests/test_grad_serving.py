@@ -10,8 +10,10 @@ random Pauli strings, seeded angles), in float32 and, under x64, float64:
   parameter-shift rule (b) on every component;
 - a request served alone equals, bit for bit, the same request served
   coalesced (both ride a lane of the one padded batch program);
-- ``grad_sweep_entries_total{sweep}`` reads what a hand count of the tape and
-  the Hamiltonian gives, counted once a trace: ten warm steps add nothing,
+- ``grad_sweep_entries_total{sweep}`` reads what a hand count of the tape's
+  dense plan and the Hamiltonian gives (an item undone on each register, a
+  contraction a block with Params; PR 45: the gate walk read the tape's
+  entries and its angles), counted once a trace: ten warm steps add nothing,
   retrace nothing, and dispatch once a batch;
 - the ``grad.plan`` event's fields and the ``grad.plan_backward`` span;
 - the ``route`` label on a gradient request's trace and on the companion's
@@ -52,6 +54,14 @@ ATOL = {1: 1e-5, 2: 1e-10}
 #: serving_ansatz(n, 2) by hand: 4 n rotations, controlledNot on the even
 #: pairs then on the odd pairs, one controlledPhaseFlip a layer
 CONCRETE = {6: 3 + 2 + 2, 8: 4 + 3 + 2}
+
+#: the dense plan of that tape at ``DENSE_WINDOW_QUBITS`` 7 by hand, (blocks,
+#: blocks with Params). 6 qubits: one window holds the whole tape. 8 qubits:
+#: a layer's rotations fall into [0-6] (14 angles) and [7] (2); the bricks
+#: of layer 0 into [0-5] and [6-7], then its phase flip, a diagonal on
+#: (0, 7); layer 1's [7] takes the odd bricks with it as [1-7], and its
+#: phase flip closes: 3 + 2 static blocks, 4 with Params
+BLOCKS = {6: (1, 1), 8: (8, 4)}
 
 CASES = [pytest.param(n, code, id=f"{n}q-f{32 * code}")
          for n in (6, 8) for code in (1, 2)]
@@ -173,10 +183,10 @@ def test_a_request_served_alone_is_the_same_request_served_coalesced(
 @pytest.mark.parametrize("n, code", CASES)
 def test_the_sweep_counter_reads_the_hand_count_once_a_trace(served, n, code):
     s = served(n, code)
-    entries = 4 * n + CONCRETE[n]
+    blocks, with_params = BLOCKS[n]
     # one trace of the one batch program: build and warm-up counted once
-    assert s.sweeps == {"hamiltonian": TERMS, "backward_phi": entries,
-                        "backward_lambda": entries, "bracket": 4 * n}
+    assert s.sweeps == {"hamiltonian": TERMS, "backward_phi": blocks,
+                        "backward_lambda": blocks, "bracket": with_params}
     before, sweeps = counts(), sweep_counts()
     base = s.angles(seed=3, count=1)[0]
     for step in range(10):
@@ -194,10 +204,11 @@ def test_the_grad_plan_event_and_the_plan_s_span(served, n, code):
     [plan] = [e for e in s.events if e["name"] == "grad.plan"]
     fields = {k: plan[k] for k in ("num_qubits", "entries", "slots", "terms",
                                    "param_entries", "concrete_events",
-                                   "first_slot")}
+                                   "first_slot", "blocks", "param_blocks")}
     assert fields == {"num_qubits": n, "entries": 4 * n + CONCRETE[n],
                       "slots": 4 * n, "terms": TERMS, "param_entries": 4 * n,
-                      "concrete_events": CONCRETE[n], "first_slot": 0}
+                      "concrete_events": CONCRETE[n], "first_slot": 0,
+                      "blocks": BLOCKS[n][0], "param_blocks": BLOCKS[n][1]}
     # the backward plan was built once, under its span
     assert s.plan_spans == 1
     # the companion replays the raw tape: no block in its plan

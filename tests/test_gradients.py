@@ -97,7 +97,9 @@ def _oracle(circ, codes, coeffs, amps, values, dtype=np.float64):
                                     density=False)
 
     jvals = tuple(jnp.asarray(v) for v in values)
-    return value_fn(jvals), jax.grad(value_fn)(jvals)
+    # one program for the value and its gradient: two compiles a case
+    # were a third of this file's and tests/test_grad_blocks*.py's time
+    return jax.value_and_grad(value_fn)(jvals)
 
 
 def _check_adjoint(circ, params=None, atol=1e-12, dtype=np.float64,
