@@ -24,10 +24,10 @@ their own angles) arriving concurrently. Three mechanisms make that cheap:
   same bits whether or not it was coalesced (batch lanes are independent
   and identical). A batch crosses to the device in one piece: the
   initial state and one packed array per slot kind in, the lanes as the
-  program's own outputs (:meth:`Engine._execB`). Sharded registers
-  replay sequentially with donated buffers inside the one dispatch
-  instead (a (B, 2, N) batch axis would fight the amplitude sharding
-  for the mesh).
+  program's own outputs (ONE array of them where the finalize states a
+  lane as a vector: :meth:`Engine._execB`). Sharded registers replay
+  sequentially with donated buffers inside the one dispatch instead (a
+  (B, 2, N) batch axis would fight the amplitude sharding for the mesh).
 - **Executable reuse across structures**: executables are fetched from the
   process-global LRU (:mod:`quest_tpu.engine.cache`) per dispatch, keyed by
   the circuit's structure fingerprint -- a second Engine over a
@@ -293,12 +293,12 @@ class Engine:
         # gates are bypassed (the result is not a state). Must be a
         # stable (cached) callable: it keys the executable LRU.
         self._finalize = finalize
-        # a values-aware finalize (the adjoint gradient reduce) carries its
-        # own dispatch route label -- grad_request traffic stays countable
-        # apart from plain engine_param/engine_vmap dispatches
+        # what a finalize says of itself (the adjoint gradient reduce): its
+        # dispatch route label, so that grad_request traffic and its traces
+        # count apart from engine_param/engine_vmap, and the host-side
+        # ``unpack`` of a result that is ONE vector (:meth:`_lanes`)
         self._route = getattr(finalize, "dispatch_route", None)
-        # ... and so do the traces its requests leave: a label ``route`` on
-        # a gradient engine's, nothing on a replay engine's
+        self._unpack = getattr(finalize, "unpack", None)
         self._trace_labels = {"route": self._route} if self._route else {}
         # round 20: the observable whose gradient submit_grad serves; the
         # companion gradient engine (same ansatz, grad_reduce finalize)
@@ -563,8 +563,8 @@ class Engine:
         from ..fusion import gatewise
         from ..gradients import grad_reduce
 
-        # the adjoint sweep walks the tape gate by gate: a plan the caller
-        # fused is spelled out again, a raw tape is taken as it is
+        # the reduce replays the raw tape and plans its own backward half: a
+        # plan the caller fused is spelled out again, a raw tape is taken
         circuit = gatewise(self.circuit)
         red = grad_reduce(circuit, self._hamiltonian, dtype=self.dtype)
         eng = Engine(circuit, self.env,
@@ -589,8 +589,8 @@ class Engine:
         """Queue one optimizer step: a Future resolving to ``(value,
         grads)`` -- E = ⟨ψ(θ)|H|ψ(θ)⟩ and the full adjoint gradient as a
         Param-name -> derivative dict (shared-Param slots already summed
-        by the chain rule). Warm steps perform zero retraces and ONE
-        device dispatch per coalesced batch."""
+        by the chain rule), host scalars both. Warm steps perform zero
+        retraces and ONE device dispatch per coalesced batch."""
         eng = self.grad_engine()
         telemetry.inc("grad_requests_total")
         telemetry.inc("grad_slots_total",
@@ -730,9 +730,9 @@ class Engine:
         ``(max_batch, n)`` array per slot kind the tape has
         (params._pack_layout), from which the per-slot values tuple is
         rebuilt by static column index before the vmap; OUT, a tuple of
-        ``max_batch`` lanes, each a result of its own (a state, or
-        whatever an armed ``finalize`` -- composed inside the vmapped
-        body -- makes of one), so retiring a batch dispatches nothing."""
+        ``max_batch`` lanes, each a result of its own (a state, or what an
+        armed ``finalize``, composed inside the vmapped body, makes of one),
+        or ONE ``(max_batch, k)`` array of vectors (:meth:`_lanes`)."""
         import jax
         import jax.numpy as jnp
 
@@ -740,7 +740,7 @@ class Engine:
         from ..parallel import scheduler as _dist
 
         circuit, width, packs = self._program, self.max_batch, self._packs
-        finalize = self._finalize
+        finalize, apart = self._finalize, self._unpack is None  # or ONE array
 
         def build():
             inner = circuit._replay_fn(circuit.lifted())
@@ -772,7 +772,7 @@ class Engine:
                 amps_b = jnp.broadcast_to(amps[None], (width,) + amps.shape)
                 out = batched(amps_b, _unpack_columns(packs, packed))
                 return tuple(jax.tree_util.tree_map(lambda a: a[i], out)
-                             for i in range(width))
+                             for i in range(width)) if apart else out
 
             from ..circuits import named_program
             jitted = jax.jit(named_program(program, circuit, "engine_vmap",
@@ -1084,11 +1084,27 @@ class Engine:
         from ..resilience import guard as _guard
         return _guard.corrupt_amps(amps)
 
-    def _lane(self, out, i: int):
-        """Lane ``i`` of a batch result: the batch program returns its
-        lanes as outputs of their own (:meth:`_execB`), so this is an
-        index, not a device computation."""
-        return out[i]
+    def _named(self, res):
+        """One finalized result as its future resolves to it: a vector the
+        finalize names (``unpack``) is fetched and named on the host,
+        anything else stays what the program returned."""
+        return res if self._unpack is None else self._unpack(np.asarray(res))
+
+    def _lanes(self, out, count: int):
+        """The first ``count`` lanes of a batch result, each as its future
+        resolves to it. The batch program returns its lanes as outputs of
+        their own (:meth:`_execB`), so a lane is an index and retiring a
+        batch dispatches nothing. Where the finalize states a lane as a
+        vector and carries the ``unpack`` that names its entries (the
+        adjoint gradient reduce) the batch is ONE array, a row a lane: an
+        output is a buffer and a ``jax.Array`` the launch pays for before
+        the program is enqueued, and eight lanes of a value and 2 x 160
+        derivatives were 2,568 of them. The array is fetched in ONE
+        transfer (after the sync the path already made) and named row by
+        row on the host: such a future resolves to host scalars."""
+        if self._unpack is None:
+            return out[:count]
+        return [self._unpack(row) for row in np.asarray(out)[:count]]
 
     @staticmethod
     def _charge(batch, phase: str, t_end: float) -> None:
@@ -1212,10 +1228,12 @@ class Engine:
             # callback runs inside resolve_future and copies the phase
             # vector when it closes the root)
             self._trace_done(req)
-            _sync.resolve_future(req.fut, result=res,
+            _sync.resolve_future(req.fut, result=self._named(res),
                                  site="engine.dispatch")
 
     def _dispatch_vmap(self, batch: list, defer: bool = False) -> bool:
+        import jax
+
         for req in batch:
             # an injected poisoned request fails the whole batched program
             # (the real-world analogue: one NaN-producing parameter set or
@@ -1234,6 +1252,7 @@ class Engine:
                 self._sync(batch, out)
             self._sentinel_gate(out)
             with telemetry.region("engine.resolve"):
+                out = self._named(out)
                 for req in batch:
                     self._trace_done(req)
                     _sync.resolve_future(req.fut, result=out,
@@ -1261,6 +1280,9 @@ class Engine:
         telemetry.inc("engine_launch_args_total", 1 + len(packed))
         out = self._launch(batch, lambda: fnB(self.initial_amps, *packed),
                            fnB, "engine_vmap")
+        # ... and what it handed back: an array a lane, or one a batch
+        telemetry.inc("engine_launch_results_total",
+                      len(jax.tree_util.tree_leaves(out)))
         if defer:
             # ASYNC ISSUE: park the in-flight result on the completion
             # ring and return to coalescing -- the device executes batch k
@@ -1284,8 +1306,8 @@ class Engine:
         # The windows deliberately overlap -- phases tile each request's
         # own end-to-end latency, they are not a global partition.
         with telemetry.region("engine.resolve"):
-            for i, req in enumerate(batch):
-                lane = self._maybe_corrupt(self._lane(out, i))
+            for req, lane in zip(batch, self._lanes(out, len(batch))):
+                lane = self._maybe_corrupt(lane)
                 self._sentinel_gate(lane)
                 self._trace_done(req)
                 _sync.resolve_future(req.fut, result=lane,
@@ -1443,8 +1465,9 @@ class Engine:
                 self._ring.popleft()
                 telemetry.set_gauge("engine_async_inflight", len(self._ring))
                 with telemetry.region("engine.resolve"):
-                    for i, req in enumerate(batch):
-                        lane = self._maybe_corrupt(self._lane(out, i))
+                    for req, lane in zip(batch,
+                                         self._lanes(out, len(batch))):
+                        lane = self._maybe_corrupt(lane)
                         self._sentinel_gate(lane, tick=tick)
                         self._trace_done(req)
                         _sync.resolve_future(req.fut, result=lane,

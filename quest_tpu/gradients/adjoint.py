@@ -74,7 +74,7 @@ from .. import gates as G
 from .. import matrices as M
 from .. import telemetry
 from ..engine.cache import _canon
-from ..engine.params import Param, _SlotRef, materialize_entry
+from ..engine.params import _CPLX, Param, _SlotRef, materialize_entry
 from ..fusion import gatewise
 from ..ops import reduce as R
 from ..ops.apply import DENSE_WINDOW_QUBITS, _MIN_MINOR, _mxu_precision
@@ -641,6 +641,51 @@ def _undo_block(item, phi: Qureg, lamq: Qureg, composed) -> int:
 # the reduce: forward value + backward sweep, one traceable program
 # ---------------------------------------------------------------------------
 
+class _Parcel:
+    """How ONE gradient's result leaves the program: one real vector,
+    ``[value, a derivative a slot, a derivative a Param name]`` in slot
+    order then first-appearance order of the names, a complex slot's (or
+    name's) derivative as two entries, real then imaginary. A program that
+    hands its numbers back as outputs of their own pays for each at the
+    host-device boundary (a buffer, a ``jax.Array``: 2,568 a batch of
+    eight on a 160-angle ansatz); the vector crosses in one transfer and
+    :meth:`unpack` names its entries on the host."""
+
+    def __init__(self, slots):
+        kinds = {}
+        for s in slots:
+            if s.name is not None:
+                kinds[s.name] = kinds.get(s.name, False) or s.kind == _CPLX
+        self.num_slots, self.names = len(slots), tuple(kinds)
+        #: per entry, whether it takes two columns of the vector
+        self.complex = (False, *(s.kind == _CPLX for s in slots),
+                        *kinds.values())
+        self._all_real = not any(self.complex)
+
+    def pack(self, tree):
+        """The vector of a ``{"value", "grads", "slot_grads"}`` tree,
+        inside the program."""
+        parts = []
+        for e, cplx in zip((tree["value"], *tree["slot_grads"],
+                            *tree["grads"].values()), self.complex):
+            parts += [jnp.real(e), jnp.imag(e)] if cplx else [e]
+        return jnp.stack(parts)
+
+    def unpack(self, vector):
+        """The tree of one lane's vector, on the host: numpy scalars at the
+        program's width, no device array among them."""
+        row = np.asarray(vector)
+        got = list(row)
+        if not self._all_real:
+            ctype = np.result_type(row.dtype, np.complex64).type
+            cols = iter(got)
+            got = [ctype(complex(next(cols), next(cols))) if cplx
+                   else next(cols) for cplx in self.complex]
+        cut = 1 + self.num_slots
+        return {"value": got[0], "grads": dict(zip(self.names, got[cut:])),
+                "slot_grads": tuple(got[1:cut])}
+
+
 def _accumulate(grads, ref, g, comp):
     idx = ref.index
     if comp == "im":
@@ -672,7 +717,7 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
         if count:
             telemetry.inc("grad_sweep_entries_total", count, sweep=sweep)
 
-    def grad_fn(amps, values):
+    def sweep(amps, values):
         lam = apply_hamiltonian(amps, codes=codes, coeffs=coeffs,
                                 num_qubits=num_qubits)
         applied("hamiltonian", len(codes))
@@ -723,9 +768,19 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
                 named[s.name] = named[s.name] + g if s.name in named else g
         return {"value": value, "grads": named, "slot_grads": slot_grads}
 
+    parcel = _Parcel(slots)
+
+    def grad_fn(amps, values):
+        return parcel.pack(sweep(amps, values))
+
     grad_fn.wants_values = True
     grad_fn.dispatch_route = "grad_request"
     grad_fn.num_slots = slot_count
+    # the result is stated once, as a vector: whoever composes this reduce
+    # fetches it and names its entries on the host with ``unpack``
+    # (``tree`` is the sweep's own dict, which the vector is tested against)
+    grad_fn.unpack = parcel.unpack
+    grad_fn.tree = sweep
     grad_fn.hamiltonian = (codes, coeffs)
     _REDUCE_CACHE[key] = (lifted, grad_fn)
     return grad_fn
@@ -733,9 +788,11 @@ def _cached_reduce(lifted, num_qubits, codes, coeffs, dtype_str):
 
 def grad_reduce(circuit, hamiltonian, *, dtype=None):
     """The values-aware finalize lowering a circuit's adjoint gradient into
-    its parameterized replay: ``reduce(ψ, values) -> {"value", "grads",
-    "slot_grads"}``. Cached per (tape structure, Hamiltonian, dtype) so
-    warm optimizer loops share one compiled program (zero retraces)."""
+    its parameterized replay: ``reduce(ψ, values)`` is ONE real vector
+    (:class:`_Parcel`) and ``reduce.unpack(vector)``, on the host, the
+    ``{"value", "grads", "slot_grads"}`` it stands for. Cached per (tape
+    structure, Hamiltonian, dtype) so warm optimizer loops share one
+    compiled program (zero retraces)."""
     codes, coeffs = hamiltonian_terms(hamiltonian, circuit.num_qubits)
     check_differentiable(circuit, dtype)
     dt = np.dtype(dtype if dtype is not None else jnp.result_type(float))
@@ -751,8 +808,9 @@ class GradExecutable:
     """A compiled gradient program bound to one circuit's slot layout.
 
     ``__call__(amps, params)`` runs forward + backward + accumulation as
-    ONE device dispatch (``device_dispatch_total{route="grad_request"}``)
-    and returns ``{"value", "grads", "slot_grads"}``.
+    ONE device dispatch (``device_dispatch_total{route="grad_request"}``),
+    fetches the program's one result vector and returns ``{"value",
+    "grads", "slot_grads"}`` of host scalars.
     """
 
     def __init__(self, ex, reduce_fn):
@@ -776,7 +834,7 @@ class GradExecutable:
         telemetry.inc("grad_requests_total")
         telemetry.inc("grad_slots_total", self._reduce.num_slots)
         telemetry.inc("device_dispatch_total", route="grad_request")
-        return self._ex.with_values(amps, values)
+        return self._reduce.unpack(self._ex.with_values(amps, values))
 
     def __call__(self, amps, params=None):
         return self.with_values(amps, self.bind(params))

@@ -741,8 +741,9 @@ def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
     10.6 GB of temporaries and 3.24 GB of generated code (PERF.md section
     7, PR 44), and the ceilings here stand under 60% of both. The lanes
     are steered to ``vmap`` as the chip steers them (``jax.default_backend``
-    reads ``tpu`` there; the CPU runs them as a ``lax.map`` scan). About
-    four minutes."""
+    reads ``tpu`` there; the CPU runs them as a ``lax.map`` scan). And
+    what it hands back: one ``(8, 321)`` array (PR 46). About four
+    minutes."""
     import sys
 
     import quest_tpu as qt
@@ -796,9 +797,15 @@ def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
                                       sharding=one_chip)
                  for _, cols in grad._packs]
         with _dist.explicit_mesh(None), F.pallas_mesh(None):
-            compiled = jitted.lower(*args).compile()
+            lowered = jitted.lower(*args)
+            compiled = lowered.compile()
     finally:
         engine.close()
+    # what the program hands back a launch (PR 46): ONE array, a row a lane
+    # of the value, 160 slot derivatives and 160 named ones; the lanes' 321
+    # numbers as outputs of their own were 2,568 arrays
+    assert [out.shape for out in jax.tree_util.tree_leaves(
+        lowered.out_info)] == [(lanes, 1 + 2 * reduce.num_slots)]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.6e9, mem.temp_size_in_bytes
     assert mem.generated_code_size_in_bytes < 1.6e9, \

@@ -842,6 +842,9 @@ def test_finalize_returning_a_dict_resolves_per_lane_dicts():
         outs = [f.result() for f in eng.submit_many(sweep)]
     for out, state in zip(outs, states):
         assert set(out) == {"p0", "head", "nested"}
+        # no ``unpack`` on this finalize: a lane stays device arrays
+        assert all(isinstance(leaf, jax.Array)
+                   for leaf in jax.tree_util.tree_leaves(out))
         assert np.asarray(out["head"]).shape == (2, 2)
         assert np.array_equal(np.asarray(out["head"]), state[:, :2])
         assert float(out["p0"]) == pytest.approx(

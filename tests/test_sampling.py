@@ -368,6 +368,7 @@ def test_engine_finalize_returns_shot_tables():
         futs = eng.submit_many([{"theta": 0.1}, {"theta": 0.2}])
         outs = [f.result() for f in futs]
     for out, th in zip(outs, (0.1, 0.2)):
+        assert isinstance(out["shots"], jax.Array)
         assert np.asarray(out["shots"]).shape == (64,)
         assert float(out["expec"]) == pytest.approx(0.0, abs=1e-9)
 
