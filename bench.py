@@ -547,26 +547,26 @@ def plan_34q_distributed() -> dict:
     (the driver's virtual-mesh dryrun separately validates the sharded
     path executes).
 
-    Round-4: the plan is the MULTI-FRAME PALLAS plan (fusion._FramePlanner
+    Round-4: the plan is the MULTI-FRAME PALLAS plan (planner._FramePlanner
     over the 30-qubit shard tile) -- every gate rides a per-shard fused
     kernel run, with frame relabelings lowered to bit-block transposes
     (collective all-to-alls when the swapped block includes sharded
     qubits, shard-local otherwise). Round 3 planned 122 window GEMMs and
     zero PallasRuns here (VERDICT r3 missing #1)."""
-    from quest_tpu import fusion
+    from quest_tpu import planner
     from quest_tpu.ops.pallas_gates import local_qubits
     from quest_tpu.precision import real_dtype
 
     n, depth, ndev = 34, 8, 16
     n_local = n - (ndev.bit_length() - 1)
     circ = build_circuit(n, depth)
-    p = fusion.plan_pallas_sharded(tuple(circ._tape), n, real_dtype(), 5,
+    p = planner.plan_pallas_sharded(tuple(circ._tape), n, real_dtype(), 5,
                                    local_qubits(n_local), n_local)
-    runs = [i for i in p.items if isinstance(i, fusion.PallasRun)]
-    dense = sum(isinstance(i, fusion.FusedBlock) for i in p.items)
+    runs = [i for i in p.items if isinstance(i, planner.PallasRun)]
+    dense = sum(isinstance(i, planner.FusedBlock) for i in p.items)
     detail = {"gates": len(circ), "pallas_runs": len(runs),
               "dense_blocks": dense,
-              **fusion.transpose_stats(p, n_local),
+              **planner.transpose_stats(p, n_local),
               "examples": "examples/distributed_34q.py"}
     try:
         detail["comm_plan_16dev"] = _dist_comm_plan(circ)
@@ -652,7 +652,7 @@ def plan_34q_f64() -> dict:
     QUEST_PRECISION=2 process (main() re-execs)."""
     import numpy as np
 
-    from quest_tpu import fusion
+    from quest_tpu import planner
     from quest_tpu.ops.pallas_df import DF_SUBLANES
     from quest_tpu.ops.pallas_gates import local_qubits
 
@@ -660,16 +660,16 @@ def plan_34q_f64() -> dict:
     n_local = n - (ndev.bit_length() - 1)
     circ = build_circuit(n, depth)
     tile = local_qubits(n_local, DF_SUBLANES)
-    p = fusion.plan_pallas_sharded(tuple(circ._tape), n,
+    p = planner.plan_pallas_sharded(tuple(circ._tape), n,
                                    np.dtype(np.float64), 5, tile, n_local)
-    runs = [i for i in p.items if isinstance(i, fusion.PallasRun)]
+    runs = [i for i in p.items if isinstance(i, planner.PallasRun)]
     detail = {
         "gates": len(circ),
         "df_tile_bits": tile,
         "pallas_runs": len(runs),
-        "dense_blocks": sum(isinstance(i, fusion.FusedBlock)
+        "dense_blocks": sum(isinstance(i, planner.FusedBlock)
                             for i in p.items),
-        **fusion.transpose_stats(p, n_local),
+        **planner.transpose_stats(p, n_local),
     }
     try:
         detail["comm_plan_16dev"] = _dist_comm_plan(circ, dtype=np.float64)
@@ -734,7 +734,7 @@ def plan_17q_density_distributed() -> dict:
     protocol, QuEST_cpu_distributed.c:724-749 (single-qubit) and :778-868
     (two-qubit depolarising, 3-exchange); the dryrun executes a scaled
     replica (>=8q density on the 8-device CPU mesh)."""
-    from quest_tpu import fusion
+    from quest_tpu import fusion, planner
 
     n, ndev = 17, 16
     circ = _density_circuit(n, with_krausn=True)
@@ -743,7 +743,7 @@ def plan_17q_density_distributed() -> dict:
     circ.mixDepolarising(n - 2, 0.03)
     fz = circ.fused(max_qubits=4, pallas=True, shard_devices=ndev)
     runs = [i for i in fusion.plan_from_tape(fz._tape).items
-            if isinstance(i, fusion.PallasRun)]
+            if isinstance(i, planner.PallasRun)]
     kraus_ops = [op for r in runs for op in r.ops
                  if op[0].startswith("kraus")]
     tstats = fusion.tape_transpose_stats(

@@ -654,7 +654,7 @@ def phase_df(args, size) -> dict:
     fb = {k: v for k, v in
           telemetry.counters("engine_fallback_total").items()}
     # a plan built for its register is cut where a df kernel ends
-    # (fusion._run_op_cap), so not even df_max_ops_split is counted
+    # (planner._run_op_cap), so not even df_max_ops_split is counted
     left = {k: v for k, v in fb.items() if v}
     _require(not left, f"df runs left for the engine, or cut again as "
                        f"they ran: {left}")

@@ -80,10 +80,10 @@ def main():
     # at seams where the frame is at identity. compiled_segments bypasses
     # Circuit.run, so build it under the execution mesh (the segment
     # executables pin the ambient contexts at build time)
-    from quest_tpu import fusion as _fusion
+    from quest_tpu import environment
     from quest_tpu.circuits import _register_mesh
 
-    with _fusion.pallas_mesh(_register_mesh(qureg)):
+    with environment.pallas_mesh(_register_mesh(qureg)):
         fn = fused.compiled_segments(max_items=24, donate=True)
 
     t0 = time.time()

@@ -731,7 +731,7 @@ class InterleavingExplorer:
 
 def _demo_circuit():
     from ..circuits import Circuit
-    from ..engine.params import Param
+    from ..params import Param
 
     c = Circuit(2)
     c.hadamard(0)

@@ -6,7 +6,7 @@ Two symbolic replays, both zero-device:
 (ops pre-relabeled into PHYSICAL coordinates), folded load/store frame
 swaps, standalone ``FrameSwap`` transposes, and non-Pallas items that
 require the identity frame (the planner's contract -- see the FrameSwap
-docstring in :mod:`..fusion`). The checker composes every bit-block swap
+docstring in :mod:`..planner`). The checker composes every bit-block swap
 over an explicit position permutation and proves
 
 - every dense kernel-op target lands below ``tile_bits`` in its run's
@@ -109,7 +109,7 @@ def check_plan(plan, nsv: int, *, dtype=None,
     qubits)."""
     import numpy as np
 
-    from ..fusion import DiagBlock, FrameSwap, FusedBlock, PallasRun
+    from ..planner import DiagBlock, FrameSwap, FusedBlock, PallasRun
     from ..ops.pallas_gates import (LANE_BITS, _LANES, op_dense_targets,
                                     ring_depth_default)
 

@@ -22,7 +22,7 @@ function is classified into per-fact columns:
 - ``df``        -- called from a test module exercising the f32/double-float
   route (``precision_code=1`` registers or ``QUEST_PALLAS_DF``),
 - ``grad``      -- a parameter position is adjoint-liftable
-  (:data:`quest_tpu.engine.params._LIFTABLE`, the QT006 audit's registry),
+  (:data:`quest_tpu.params._LIFTABLE`, the QT006 audit's registry),
 - ``tape``      -- composable onto a :class:`~quest_tpu.circuits.Circuit`
   tape (:func:`quest_tpu.circuits._resolve` accepts it),
 - ``oracle``    -- the generated conformance harness
@@ -422,7 +422,7 @@ def scan_documented(docs_root: Optional[Path] = None) -> frozenset[str]:
 
 
 def _grad_names() -> frozenset[str]:
-    from ..engine import params
+    from .. import params
     return frozenset(params._LIFTABLE)
 
 

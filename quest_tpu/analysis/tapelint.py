@@ -13,7 +13,7 @@ resolved. Four lints:
   controls, axes) separated only by support-disjoint entries -- one
   rotation of the summed angle does the same work in half the passes.
 - **QT003** constant angles at liftable positions: every anonymous slot
-  :func:`..engine.params.lift_tape` would create is a parameter the
+  :func:`..params.lift_tape` would create is a parameter the
   circuit could have recorded as ``engine.P(...)``; as plain constants
   they bake into the structure fingerprint, so structure-equal circuits
   compile separate executables instead of sharing one
@@ -116,7 +116,7 @@ def _events_cancel(a, b) -> bool:
 def _structure_key(name: str, args, kwargs) -> tuple:
     """A tape entry with its liftable value positions masked out -- two
     entries with the same key differ only in angles."""
-    from ..engine.params import _LIFTABLE, is_value
+    from ..params import _LIFTABLE, is_value
 
     spec = _LIFTABLE.get(name, {})
     masked_args = tuple(
@@ -176,8 +176,8 @@ def lint_tape(tape, num_qubits: int, *, is_density: bool = False,
     the module docstring for the lint classes. ``differentiate=True``
     additionally runs QT006 (non-differentiable sites) for tapes headed
     to :meth:`..circuits.Circuit.gradient`."""
-    from ..engine.params import _LIFTABLE, lift_slot_census
-    from ..fusion import capture
+    from ..capture import capture
+    from ..params import _LIFTABLE, lift_slot_census
     from ..precision import real_dtype
     from ..validation import QuESTError
 
@@ -274,7 +274,7 @@ def lint_tape(tape, num_qubits: int, *, is_density: bool = False,
             live_entries.append((idx, None, support))
 
     # QT003: aggregate param-lift candidacy -- the count comes from
-    # lift_tape itself (engine.params.lift_slot_census), so the lint and
+    # lift_tape itself (params.lift_slot_census), so the lint and
     # the serving engine agree by construction
     try:
         anon, named = lift_slot_census(tape)

@@ -26,14 +26,18 @@ so eviction frees the jit cache via the executable's refcount.
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import os
 from collections import OrderedDict
 
+import jax
 import numpy as np
 
-from .. import telemetry
-from ..resilience import sync as _sync
+from . import telemetry
+from .compile_cache import CACHE_ENV
+from .params import Param, _SlotRef, lift_tape
+from .resilience import sync as _sync
 
 __all__ = ["LRUCache", "executables", "structure_fingerprint",
            "enable_persistent_cache"]
@@ -143,10 +147,6 @@ def _canon(x):
     """Canonical hashable form of one tape operand: value slots collapse to
     their kind, baked operands hash by content, unknown objects by identity
     (unique -- never wrongly shared)."""
-    import dataclasses
-
-    from .params import Param, _SlotRef
-
     if isinstance(x, _SlotRef):
         return ("slot",)
     if isinstance(x, Param):  # un-lifted tape: still a value slot
@@ -188,8 +188,6 @@ def structure_fingerprint(tape, num_qubits: int, is_density: bool,
     so two tapes differing in those values collide (by design: they share
     one executable); anything else differing -- gate names, targets,
     controls, baked matrices, channel probabilities -- changes the hash."""
-    from .params import lift_tape
-
     lifted = lift_tape(tuple(tape))
     tokens = [("hdr", int(num_qubits), bool(is_density), _canon(tuple(extra)))]
     for fn, args, kwargs in lifted.entries:
@@ -215,10 +213,6 @@ def enable_persistent_cache(path: str | None = None,
     ``JAX_COMPILATION_CACHE_DIR`` outranks both: where it is set the cache
     is placed from outside and no directory is set here (the returned
     path is then that variable's)."""
-    import jax
-
-    from ..compile_cache import CACHE_ENV
-
     path = path or os.environ.get("QUEST_COMPILE_CACHE")
     if not path:
         return None

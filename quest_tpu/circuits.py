@@ -24,11 +24,23 @@ circuits.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import inspect
 
 import jax
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
+from . import cache as _ec
+from . import fusion
+from . import params as _prm
+from . import planner
 from . import telemetry
+from .capture import capture
+from .environment import AMP_AXIS, active_pallas_mesh, pallas_mesh
+from .ops.pallas_df import DF_SUBLANES, df_wanted
+from .parallel import scheduler as _dist
+from .precision import real_dtype
 from .registers import Qureg
 
 #: API names that can be recorded on a tape: mutate qureg.amps, need no host
@@ -68,7 +80,6 @@ def _tape_compatible(fn) -> bool:
 
 
 def _resolve(name):
-    import importlib
     for mod_name in _TAPEABLE_MODULES:
         mod = importlib.import_module(f".{mod_name}", __package__)
         fn = getattr(mod, name, None)
@@ -103,7 +114,6 @@ def _defer_safe(f) -> bool:
     fused dense/diag blocks route through the same gate primitives.
     Everything else (inits, full-state diagonals, Pallas runs and frame
     swaps) assumes the identity layout and forces reconciliation."""
-    from . import fusion
 
     if getattr(f, "__module__", None) in _DEFER_SAFE_MODULES:
         return getattr(f, "__name__", "") not in _DEFER_BARRIER_NAMES
@@ -122,9 +132,6 @@ def _tape_accesses(tape, num_qubits, is_density, dtype):
     never do. Dense/diag fused blocks expose their qubits directly; raw
     gate entries are spy-captured; density row events gain their
     conj-shadow column coordinates."""
-    import numpy as np
-
-    from . import fusion
 
     def event_dense(ev):
         """The event's relocation-forcing qubits (row coordinates)."""
@@ -164,7 +171,7 @@ def _tape_accesses(tape, num_qubits, is_density, dtype):
             out.append(frozenset(qs))
             dense_out.append(frozenset(qs if block[1] else ()))
             continue
-        events = fusion.capture(f, args, kwargs, num_qubits, dtype,
+        events = capture(f, args, kwargs, num_qubits, dtype,
                                 is_density=is_density, aux=True)
         if events is None:
             out.append(None)
@@ -190,10 +197,6 @@ def _tape_accesses(tape, num_qubits, is_density, dtype):
 def _amps_mesh(amps):
     """The 1-D amps mesh a (concrete) amplitude array is sharded over, or
     None for single-device / traced arrays."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from .environment import AMP_AXIS
-
     sharding = getattr(amps, "sharding", None)
     if (isinstance(sharding, NamedSharding)
             and sharding.spec == PartitionSpec(None, AMP_AXIS)
@@ -278,7 +281,7 @@ class Circuit:
         # identity of this tape revision: executable-cache keys carry it, so
         # mutating the tape invalidates them without any per-circuit dict
         # (compiled replays live in the BOUNDED process-global LRU,
-        # engine.cache.executables(), with uniform hit/miss/evict telemetry)
+        # cache.executables(), with uniform hit/miss/evict telemetry)
         self._cache_token = object()
         self._lifted_cache = None
         self._fp_cache = None
@@ -323,7 +326,7 @@ class Circuit:
 
     def _replay_fn(self, lifted, lo: int = 0, hi: int | None = None):
         """The replay body behind :meth:`as_fn` (``lifted=None``) and the
-        parameterized executables (``lifted`` an engine.params.LiftedTape):
+        parameterized executables (``lifted`` an params.LiftedTape):
         with a lifted tape the returned ``fn(amps, values)`` substitutes the
         bound -- typically traced -- scalars into the slotted entries before
         each application, so gate matrices assemble from runtime values
@@ -338,7 +341,6 @@ class Circuit:
         sound because segment boundaries are frame-identity points.
         Slicing composes with plain replay only (``lifted`` entries are
         indexed against the whole tape)."""
-        from .parallel import scheduler as _dist
 
         if lifted is not None and (lo != 0 or hi is not None):
             raise ValueError("sliced replay requires lifted=None")
@@ -353,9 +355,8 @@ class Circuit:
             if entries is None:
                 steps = tape
             else:
-                from .engine.params import materialize_entry
                 telemetry.inc("engine_trace_total", kind="param_replay")
-                steps = [materialize_entry(e, values) for e in entries]
+                steps = [_prm.materialize_entry(e, values) for e in entries]
             shell = Qureg(num_qubits, is_density, amps, env=None)
             sched = _dist.active()
             # sliced replays label their defer span with the slice origin
@@ -397,7 +398,7 @@ class Circuit:
 
     def compiled(self, donate: bool = True):
         """The tape as one jitted executable, cached per execution mode in
-        the process-global bounded LRU (engine.cache.executables(): uniform
+        the process-global bounded LRU (cache.executables(): uniform
         eviction + ``plan_cache_{hit,miss,evict}_total`` telemetry -- the
         per-circuit dict of earlier rounds grew without limit per
         (mode, mesh) key).
@@ -407,12 +408,9 @@ class Circuit:
         mesh -- entering/leaving ``explicit_mesh`` retraces rather than
         silently replaying the other mode's executable.
         """
-        from . import fusion
-        from .engine import cache as _ec
-        from .parallel import scheduler as _dist
         sched = _dist.active()
         mesh = sched.mesh if sched else None
-        pmesh = fusion.active_pallas_mesh()
+        pmesh = active_pallas_mesh()
         key = ("circuit", self._cache_token, donate, mesh, pmesh)
 
         def build():
@@ -428,7 +426,7 @@ class Circuit:
                 # behaves like run() (Pallas/Kraus paths would otherwise
                 # trace meshless and GSPMD-gather the shards onto one device)
                 pm = _pmesh if _pmesh is not None else _amps_mesh(amps)
-                with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
+                with _dist.explicit_mesh(_mesh), pallas_mesh(pm):
                     return _inner(amps)
 
             fn.__name__ = inner.__name__     # what a first call's record says
@@ -439,10 +437,9 @@ class Circuit:
     # -- parameterized execution (the serving engine's entry points) --------
 
     def lifted(self):
-        """This tape's :class:`~quest_tpu.engine.params.LiftedTape` (value
+        """This tape's :class:`~quest_tpu.params.LiftedTape` (value
         slots factored out of Params AND constant angles/Complex scalars),
         memoized per tape revision."""
-        from .engine import params as _prm
         tok = self._cache_token
         if self._lifted_cache is None or self._lifted_cache[0] is not tok:
             self._lifted_cache = (tok, _prm.lift_tape(tuple(self._tape)))
@@ -450,7 +447,7 @@ class Circuit:
 
     @property
     def param_names(self) -> tuple:
-        """Ordered unique :class:`~quest_tpu.engine.params.Param` names
+        """Ordered unique :class:`~quest_tpu.params.Param` names
         recorded on the tape."""
         return self.lifted().param_names
 
@@ -458,8 +455,7 @@ class Circuit:
         """Structure fingerprint of the tape (gate names, targets/controls,
         value-slot kinds -- never the lifted values): the executable-cache
         key under which structure-equal circuits share compiled replays.
-        See engine.cache.structure_fingerprint."""
-        from .engine import cache as _ec
+        See cache.structure_fingerprint."""
         tok = self._cache_token
         if self._fp_cache is None or self._fp_cache[0] is not tok:
             self._fp_cache = (tok, _ec.structure_fingerprint(
@@ -469,7 +465,7 @@ class Circuit:
     def parameterized(self, donate: bool = True, reduce=None):
         """The tape as ONE jitted executable whose lifted values (Params and
         constant angles/Complex scalars) are runtime arguments: a
-        :class:`~quest_tpu.engine.params.ParamExecutable` called as
+        :class:`~quest_tpu.params.ParamExecutable` called as
         ``exe(amps, {"theta": 0.3})``. Changing values never retraces --
         gate matrices assemble from the traced scalars inside the program
         (matrices.py traced branches), including between the static kernel
@@ -486,13 +482,9 @@ class Circuit:
         meshes): two structure-equal circuits -- same ansatz, different
         recorded angles -- share one compiled executable
         (``plan_cache_hit_total``)."""
-        from . import fusion
-        from .engine import cache as _ec
-        from .engine.params import ParamExecutable
-        from .parallel import scheduler as _dist
         sched = _dist.active()
         mesh = sched.mesh if sched else None
-        pmesh = fusion.active_pallas_mesh()
+        pmesh = active_pallas_mesh()
         lifted = self.lifted()
         fp = self.fingerprint()
         key = ("param", fp, donate, mesh, pmesh, reduce)
@@ -517,14 +509,14 @@ class Circuit:
 
             def fn(amps, values, _inner=inner, _mesh=mesh, _pmesh=pmesh):
                 pm = _pmesh if _pmesh is not None else _amps_mesh(amps)
-                with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
+                with _dist.explicit_mesh(_mesh), pallas_mesh(pm):
                     return _inner(amps, values)
 
             fn.__name__ = inner.__name__
             return fn
 
-        return ParamExecutable(_ec.executables().get_or_create(key, build),
-                               lifted, fp)
+        return _prm.ParamExecutable(
+            _ec.executables().get_or_create(key, build), lifted, fp)
 
     def gradient(self, hamiltonian, *, donate: bool = True, dtype=None):
         """Compile the tape's adjoint-state gradient against a Pauli-sum
@@ -538,6 +530,8 @@ class Circuit:
         Non-invertible tape items (measurement, trajectory noise,
         channels) raise a typed :class:`QuESTError` here, at lift time,
         naming the offending site."""
+        # lazy: a facade method that reaches up (gradients stands on
+        # circuits)
         from .gradients import gradient_executable
         return gradient_executable(self, hamiltonian, donate=donate,
                                    dtype=dtype)
@@ -558,12 +552,12 @@ class Circuit:
         Pallas kernel (ops.pallas_gates) with two-frame scheduling: one HBM
         pass per run instead of one GEMM pass per dense block. Density
         tapes plan over the flattened 2n-qubit state with explicit
-        conj-shadow ops (fusion._shadow_pop). ``shard_devices`` plans for execution on a register
+        conj-shadow ops (planner._shadow_pop). ``shard_devices`` plans for execution on a register
         sharded over that many devices: the tile limit shrinks to the
         shard-local size so every emitted run is per-shard executable under
         shard_map (fusion._shard_route); Circuit.run keeps that
         per-shard path active inside the jitted replay by deriving the
-        execution mesh from the register it is given (fusion.pallas_mesh).
+        execution mesh from the register it is given (environment.pallas_mesh).
 
         ``ring_depth`` is the PLAN-level knob for the manual-DMA ring
         (ops.pallas_gates._make_dma_kernel): stamped onto every emitted
@@ -583,18 +577,14 @@ class Circuit:
         ``comm_pipeline``. None defers to QUEST_COMM_PIPELINE_DCN, then to
         the base depth (parallel.exchange.resolve_pipeline_dcn).
         """
-        import numpy as np
-
-        from . import fusion
-        from .precision import real_dtype
-
         tile_bits = None
         shard_boundary = None
         if pallas:
-            from .ops.pallas_gates import LANE_BITS, local_qubits
+            from .ops.pallas_gates import (  # lazy: Pallas
+                LANE_BITS, local_qubits)
             # density tapes plan over the flattened 2n-qubit state: the
             # conj-shadow column qubits are explicit ops in the plan
-            # (fusion._shadow_pop), so the tile geometry is the state's
+            # (planner._shadow_pop), so the tile geometry is the state's
             n_eff = (2 if self.is_density_matrix else 1) * self.num_qubits
             if shard_devices and shard_devices > 1:
                 d = int(shard_devices)
@@ -609,7 +599,6 @@ class Circuit:
             # below 2^LANE_BITS amplitudes there is no lane tile to build;
             # the ordinary fusion path handles such registers
             if n_eff > LANE_BITS:
-                from .ops.pallas_df import df_wanted
                 dt_plan = np.dtype(dtype) if dtype else real_dtype()
                 if dt_plan == np.dtype("float64") and df_wanted():
                     # f64 on the df route (TPU always; elsewhere opt-in
@@ -619,7 +608,6 @@ class Circuit:
                     # here use the SAME geometry per shard, so the
                     # local/dense split matches the df executor; the
                     # native-f64 interpreter geometry applies otherwise
-                    from .ops.pallas_df import DF_SUBLANES
                     tile_bits = local_qubits(n_eff, DF_SUBLANES)
                 else:
                     tile_bits = local_qubits(n_eff)
@@ -627,12 +615,12 @@ class Circuit:
         if tile_bits is not None and shard_boundary is not None:
             # sharded: try plain and boundary-aligned frame tilings, keep
             # the one with fewer collective transposes
-            p = fusion.plan_pallas_sharded(
+            p = planner.plan_pallas_sharded(
                 tuple(self._tape), self.num_qubits, dt, max_qubits,
                 tile_bits, shard_boundary,
                 is_density=self.is_density_matrix)
         else:
-            p = fusion.plan(tuple(self._tape), self.num_qubits, dt,
+            p = planner.plan(tuple(self._tape), self.num_qubits, dt,
                             max_qubits=max_qubits,
                             pallas_tile_bits=tile_bits,
                             is_density=self.is_density_matrix)
@@ -642,19 +630,21 @@ class Circuit:
             ("comm_pipeline", comm_pipeline),
             ("comm_pipeline_dcn", comm_pipeline_dcn)) if depth is not None}
         for i, item in enumerate(p.items):
-            if ring_depth is not None and isinstance(item, fusion.PallasRun):
+            if ring_depth is not None and isinstance(item, planner.PallasRun):
                 item = dataclasses.replace(item, ring_depth=int(ring_depth))
-            if comm and isinstance(item, (fusion.PallasRun,
-                                          fusion.FrameSwap)):
+            if comm and isinstance(item, (planner.PallasRun,
+                                          planner.FrameSwap)):
                 item = dataclasses.replace(item, **comm)
             p.items[i] = item
         # round 13: stamp each frame-carrying item with its frame-identity
         # segment index (the single-dispatch segment programs' seams;
         # plancheck QT107 re-derives and cross-checks the stamps)
+        # lazy: reaches up (segments stands on circuits); what it calls
+        # there reads the plan alone
         from . import segments as _segments
         _segments.stamp_plan(
             p, (2 if self.is_density_matrix else 1) * self.num_qubits)
-        from . import analysis
+        from . import analysis  # lazy: the checkers read every layer
         if analysis.verify_enabled():
             # QUEST_VERIFY=1: statically verify the plan's frame/ring
             # invariants at compile time; raises AnalysisError on
@@ -682,7 +672,7 @@ class Circuit:
         count (``max_items=None`` = the whole tape as one program). The chain exposes its link count as
         ``.num_segments``; every link launch counts
         ``device_dispatch_total{route="segment"}``."""
-        from . import segments
+        from . import segments  # lazy: a facade method that reaches up
         return segments.chain_executable(self, max_items=max_items,
                                          donate=donate)
 
@@ -695,7 +685,7 @@ class Circuit:
         returned executable counts exactly one
         ``device_dispatch_total{route="request"}`` however many segments
         (``.num_segments``) were composed."""
-        from . import segments
+        from . import segments  # lazy: a facade method that reaches up
         return segments.request_executable(self, donate=donate,
                                            reduce=reduce)
 
@@ -711,14 +701,13 @@ class Circuit:
             raise ValueError(
                 f"Circuit({self.num_qubits}q, density={self.is_density_matrix}) "
                 f"cannot run on {qureg!r}")
-        from . import fusion
         # the library path's host cost per application, measured where it
         # is spent: cache lookup, mesh context, the jitted call, the put.
         # It ends before any sync, and is on every application's path: a
         # region (aggregate + profiler annotation), never a ring event
         mark = telemetry.compile_mark()
         with telemetry.region("circuit.run") as rg, \
-                fusion.pallas_mesh(_register_mesh(qureg)):
+                pallas_mesh(_register_mesh(qureg)):
             telemetry.inc("device_dispatch_total", route="circuit")
             program = self.compiled()
             qureg.put(program(qureg.amps))
@@ -738,6 +727,7 @@ class Circuit:
         zero-state register is created). ``every_n_items`` spaces the
         checkpoint cadence in tape items; ``keep`` bounds snapshot
         generations retained on disk. See docs/resilience.md."""
+        # lazy: a facade method that reaches up (segmented runs circuits)
         from .resilience import segmented as _seg
         return _seg.run_segmented(self, target, checkpoint_dir=checkpoint_dir,
                                   every_n_items=every_n_items, keep=keep)

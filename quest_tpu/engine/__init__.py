@@ -44,11 +44,11 @@ import os as _os
 from .admission import (  # noqa: F401
     PRIORITIES, AdmissionController, TokenBucket,
 )
-from .cache import (  # noqa: F401
+from ..cache import (  # noqa: F401
     LRUCache, enable_persistent_cache, executables, structure_fingerprint,
 )
 from .engine import Engine  # noqa: F401
-from .params import (  # noqa: F401
+from ..params import (  # noqa: F401
     LiftedTape, P, Param, ParamExecutable, Slot, bind, lift_tape,
 )
 from .pool import EnginePool  # noqa: F401

@@ -29,7 +29,7 @@ their own angles) arriving concurrently. Three mechanisms make that cheap:
   sequentially with donated buffers inside the one dispatch instead (a
   (B, 2, N) batch axis would fight the amplitude sharding for the mesh).
 - **Executable reuse across structures**: executables are fetched from the
-  process-global LRU (:mod:`quest_tpu.engine.cache`) per dispatch, keyed by
+  process-global LRU (:mod:`quest_tpu.cache`) per dispatch, keyed by
   the circuit's structure fingerprint -- a second Engine over a
   structure-equal circuit compiles nothing (``plan_cache_hit_total``).
 
@@ -133,19 +133,32 @@ import time
 from collections import deque
 from concurrent.futures import Future
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from .. import cache as _cache
+from .. import fusion
+from .. import planner
 from .. import telemetry
+from ..circuits import Circuit, named_program
+from ..environment import pallas_mesh
+from ..gradients import grad_reduce
+from ..gradients.adjoint import gatewise
+from ..ops import init as ops_init
+from ..parallel import scheduler as _dist
+from ..params import (_SEED, _pack_layout, _pack_rows, _unpack_columns,
+                      bind)
+from ..precision import real_dtype
 from ..resilience import faultinject as _faults
+from ..resilience import guard as _guard
 from ..resilience import sentinel as _sentinel
 from ..resilience import sync as _sync
 from ..resilience import watchdog as _watchdog
 from ..resilience.errors import (PoisonedRequestFault, QuESTBackpressureError,
                                  QuESTCancelledError, QuESTHangError,
                                  QuESTIntegrityError, QuESTTimeoutError)
-from . import cache as _cache
-from .params import (_SEED, _pack_layout, _pack_rows, _unpack_columns,
-                     bind)
+from ..validation import QuESTError
 
 __all__ = ["Engine", "HEALTH_STATES"]
 
@@ -168,7 +181,6 @@ class _Request:
     the enqueue timestamp, an optional wall-clock deadline, the injected
     poison kind pinned at submit time (None on healthy requests), and the
     request's trace context (None whenever tracing is off)."""
-
     __slots__ = ("values", "fut", "t0", "deadline", "poison", "trace")
 
     def __init__(self, values: tuple, fut: Future, t0: float,
@@ -188,7 +200,6 @@ class _Inflight:
     ``synced``: whether a device sync has proved the batch complete (the
     serial-issue admission syncs an entry and leaves its resolution for
     after the next issue)."""
-
     __slots__ = ("out", "batch", "tick", "synced")
 
     def __init__(self, out, batch: list, tick: int):
@@ -201,8 +212,6 @@ class _Inflight:
 def _plan_items(circuit) -> int:
     """How many entries of ``circuit``'s tape are items of a fusion plan
     (blocks, kernel runs, frame swaps) rather than recorded gates."""
-    from .. import fusion
-
     return sum(not isinstance(item, tuple)
                for item in fusion.plan_from_tape(circuit._tape).items)
 
@@ -244,7 +253,7 @@ class Engine:
     """Serving runtime for one circuit structure (see module docstring).
 
     ``circuit`` may be a raw or fused :class:`~quest_tpu.circuits.Circuit`
-    recorded with :class:`~quest_tpu.engine.params.Param` placeholders. A
+    recorded with :class:`~quest_tpu.params.Param` placeholders. A
     fused one is replayed as given; a raw one is replayed through its
     dense plan unless the engine is sharded (:meth:`_plan_program`), so
     replies agree with the gate-by-gate replay to rounding, not bitwise.
@@ -259,12 +268,6 @@ class Engine:
                  queue_max: int | None = None,
                  async_depth: int | None = None,
                  finalize=None, hamiltonian=None):
-        import jax
-        import jax.numpy as jnp
-
-        from ..ops import init as ops_init
-        from ..precision import real_dtype
-
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if max_delay_ms < 0:
@@ -550,8 +553,6 @@ class Engine:
         T optimizer chains coalesce into ONE vmapped forward+backward
         program dispatched as ``route=grad_request``. Built lazily on
         first use; requires ``hamiltonian=`` at construction."""
-        from ..validation import QuESTError
-
         with self._cv:
             if self._grad_companion is not None:
                 return self._grad_companion
@@ -560,11 +561,10 @@ class Engine:
                     "Engine.submit_grad needs the observable: construct "
                     "the Engine with hamiltonian=(pauli_codes, term_coeffs) "
                     "or a PauliHamil", "Engine.submit_grad")
-        from ..fusion import gatewise
-        from ..gradients import grad_reduce
 
-        # the reduce replays the raw tape and plans its own backward half: a
-        # plan the caller fused is spelled out again, a raw tape is taken
+        # the reduce replays the raw tape and plans its own backward half
+        # (the dense plan's blocks: adjoint._plan_blocks): a plan the
+        # caller fused is spelled out again, a raw tape is taken
         circuit = gatewise(self.circuit)
         red = grad_reduce(circuit, self._hamiltonian, dtype=self.dtype)
         eng = Engine(circuit, self.env,
@@ -687,23 +687,19 @@ class Engine:
         backward), and a tape with seed slots (a trajectory's Kraus draws
         are thresholds on the state, so a sharded and an unsharded
         ensemble must do the same arithmetic to walk the same path)."""
-        from .. import fusion
-        from ..circuits import Circuit
-        from ..ops.apply import DENSE_WINDOW_QUBITS
-
         circuit = self.circuit
         if self.sharded or getattr(self._finalize, "wants_values", False):
             return circuit
         if _plan_items(circuit) or any(s.kind == _SEED
                                        for s in circuit.lifted().slots):
             return circuit
-        plan = fusion.plan(tuple(circuit._tape), circuit.num_qubits,
-                           self.dtype, max_qubits=DENSE_WINDOW_QUBITS,
-                           is_density=circuit.is_density_matrix)
+        plan = planner.dense_plan(circuit._tape, circuit.num_qubits,
+                                  self.dtype,
+                                  is_density=circuit.is_density_matrix)
         # the batch executable vmaps the replay, and a static dense block's
         # own entry may take a kernel over ONE state: every dense block
         # enters as its factor list, applied through the gate primitive
-        plan.items = [item.factored() if isinstance(item, fusion.FusedBlock)
+        plan.items = [item.factored() if isinstance(item, planner.FusedBlock)
                       else item for item in plan.items]
         program = Circuit(circuit.num_qubits, circuit.is_density_matrix)
         program._tape = fusion.as_tape(plan)
@@ -714,9 +710,7 @@ class Engine:
         global LRU per dispatch (warm dispatches therefore count
         ``plan_cache_hit_total`` -- the acceptance signal that nothing
         recompiled)."""
-        from .. import fusion
-
-        with fusion.pallas_mesh(self._mesh):
+        with pallas_mesh(self._mesh):
             return self._program.parameterized(donate=self._donate,
                                                reduce=self._finalize)
 
@@ -733,12 +727,6 @@ class Engine:
         ``max_batch`` lanes, each a result of its own (a state, or what an
         armed ``finalize``, composed inside the vmapped body, makes of one),
         or ONE ``(max_batch, k)`` array of vectors (:meth:`_lanes`)."""
-        import jax
-        import jax.numpy as jnp
-
-        from .. import fusion
-        from ..parallel import scheduler as _dist
-
         circuit, width, packs = self._program, self.max_batch, self._packs
         finalize, apart = self._finalize, self._unpack is None  # or ONE array
 
@@ -774,12 +762,11 @@ class Engine:
                 return tuple(jax.tree_util.tree_map(lambda a: a[i], out)
                              for i in range(width)) if apart else out
 
-            from ..circuits import named_program
             jitted = jax.jit(named_program(program, circuit, "engine_vmap",
                                            f"b{width}"))
 
             def fn(amps, *packed, _inner=jitted):
-                with _dist.explicit_mesh(None), fusion.pallas_mesh(None):
+                with _dist.explicit_mesh(None), pallas_mesh(None):
                     return _inner(amps, *packed)
 
             fn.__name__ = jitted.__name__
@@ -1081,7 +1068,6 @@ class Engine:
             return amps
         if not _faults.enabled():
             return amps
-        from ..resilience import guard as _guard
         return _guard.corrupt_amps(amps)
 
     def _named(self, res):
@@ -1185,8 +1171,6 @@ class Engine:
         and charge the ``device`` phase. Synchronous routes only: a ring
         entry syncs in :meth:`_retire_oldest`, bounded by its own
         deadline."""
-        import jax
-
         with telemetry.region("engine.sync") as rg:
             jax.block_until_ready(out)
         self._charge_device(batch, rg.t1)
@@ -1232,8 +1216,6 @@ class Engine:
                                  site="engine.dispatch")
 
     def _dispatch_vmap(self, batch: list, defer: bool = False) -> bool:
-        import jax
-
         for req in batch:
             # an injected poisoned request fails the whole batched program
             # (the real-world analogue: one NaN-producing parameter set or
@@ -1331,7 +1313,6 @@ class Engine:
         backpressure bound forces a (then-instant) sync. A buffer without
         a readiness probe counts as ready: retiring it blocks no longer
         than the probe-less sync path always did."""
-        import jax
 
         # one program made every lane: its first array speaks for all
         leaves = jax.tree_util.tree_leaves(self._ring[0].out)
@@ -1357,8 +1338,6 @@ class Engine:
         hardware order -- there ``async_depth`` alone governs."""
         s = self._serial
         if s is None:
-            import jax
-
             s = self._serial = jax.default_backend() == "cpu"
         return s
 
@@ -1435,9 +1414,7 @@ class Engine:
         ring is empty. Batcher-thread-only, like the ring itself."""
         if not self._ring:
             return False
-        import jax
 
-        from ..resilience import guard as _guard
         entry = self._ring[0]
         out, batch, tick = entry.out, entry.batch, entry.tick
         traced = [r.trace for r in batch if r.trace is not None]

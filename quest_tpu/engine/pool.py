@@ -877,7 +877,7 @@ class EnginePool:
 
         - ``cached`` -- the replica's engine exists and the process-global
           LRU still holds its batch executable (probed with the
-          NON-MUTATING :meth:`~quest_tpu.engine.cache.LRUCache.peek`, so
+          NON-MUTATING :meth:`~quest_tpu.cache.LRUCache.peek`, so
           ranking never promotes a precompiled entry over one live
           traffic is using);
         - ``warmed`` -- a cold engine was built (or an evicted executable
@@ -887,7 +887,7 @@ class EnginePool:
 
         Returns the fingerprints warm on every targeted replica, in rank
         order."""
-        from . import cache as _ec
+        from .. import cache as _ec
         with self._cv:
             ranked = sorted(self._freq,
                             key=lambda fp: (-self._freq[fp], fp))

@@ -14,7 +14,7 @@ returns a finalize callable (``wants_values=True``) that
 accumulations lower into ONE jitted program -- one device dispatch per
 gradient (``route=grad_request``), vmappable over T parameter sets.
 
-Derivative rules per lifted family (``engine/params._LIFTABLE``):
+Derivative rules per lifted family (``params._LIFTABLE``):
 
 - rotations (rotate{X,Y,Z}, rotateAroundAxis, multiRotateZ/Pauli and their
   controlled forms), generator G with U = exp(-iθG/2) on the controlled
@@ -34,7 +34,7 @@ slots sharing one Param sum into that Param's gradient.
 
 The backward half walks the tape's DENSE PLAN, not its gates (PR 45): the
 tape from the first slot on is planned with the call the serving Engine
-makes (``fusion.plan`` at ``ops.apply.DENSE_WINDOW_QUBITS``), and the
+makes (``planner.dense_plan``: windows of ``ops.apply.DENSE_WINDOW_QUBITS``), and the
 recurrence steps block by block -- φ ← B†φ, one window contraction
 T[a, b] = Σ_rest conj(λ[a, rest])·φ[b, rest], λ ← B†λ -- with every
 derivative a block holds read off that ONE contraction:
@@ -72,12 +72,16 @@ import numpy as np
 from .. import fusion
 from .. import gates as G
 from .. import matrices as M
+from .. import planner
 from .. import telemetry
-from ..engine.cache import _canon
-from ..engine.params import _CPLX, Param, _SlotRef, materialize_entry
-from ..fusion import gatewise
+from ..cache import _canon
+from ..capture import capture
+from ..circuits import Circuit
+from ..events import (GateEvent, _event_diag, _event_is_diag, event_dagger,
+                      event_matrix)
 from ..ops import reduce as R
-from ..ops.apply import DENSE_WINDOW_QUBITS, _MIN_MINOR, _mxu_precision
+from ..ops.apply import _MIN_MINOR, _mxu_precision
+from ..params import _CPLX, Param, _SlotRef, materialize_entry
 from ..parallel import scheduler as _dist
 from ..registers import Qureg
 from ..validation import QuESTError
@@ -277,10 +281,8 @@ def _dagger_param(shell: Qureg, name: str, vals: dict) -> None:
 
 def _apply_event_dagger(shell: Qureg, ev) -> None:
     """Invert one captured GateEvent through the scheduler-aware helpers:
-    :func:`..fusion.event_dagger` builds the inverse event, applied here
+    :func:`..events.event_dagger` builds the inverse event, applied here
     by kind."""
-    from ..fusion import event_dagger
-
     try:
         inv = event_dagger(ev)
     except ValueError as e:  # pragma: no cover - guarded by plan_backward
@@ -322,23 +324,19 @@ def _site(idx, name):
 def _capture_events(fn, args, kwargs, idx, name, num_qubits, dtype):
     """Concrete entry -> invertible GateEvents, or a typed lift-time error
     naming the site."""
-    from .. import fusion
-
     if name == "_apply_dense_block":
         u, qubits = args
-        return (fusion.GateEvent("matrix", tuple(qubits),
-                                 matrix=np.asarray(u)),)
+        return (GateEvent("matrix", tuple(qubits), matrix=np.asarray(u)),)
     if name == "_apply_gate_diag":
         diag, qubits = args[0], args[1]
-        return (fusion.GateEvent("diag", tuple(qubits),
-                                 diag=np.asarray(diag)),)
+        return (GateEvent("diag", tuple(qubits), diag=np.asarray(diag)),)
     if name in ("_apply_pallas_run", "_apply_frame_swap"):
         raise QuESTError(
             f"Circuit.gradient: {_site(idx, name)} is a pallas-fused plan "
             "entry with no gate-by-gate inverse; differentiate the raw "
             "(unfused) circuit -- the gradient program is one jitted "
             "dispatch either way", "gradient")
-    events = fusion.capture(fn, args, kwargs, num_qubits, dtype)
+    events = capture(fn, args, kwargs, num_qubits, dtype)
     if events is None or any(ev.kind in ("channel", "aux") or ev.extended
                              for ev in events):
         hint = (" -- compose measurement statistics via sample_request "
@@ -418,11 +416,64 @@ def _plan_build(lifted, num_qubits, dtype_str):
     return tuple(plans[first_slot:]), first_slot
 
 
+def gatewise(circuit):
+    """``circuit`` with every deferred block spelled out again: a block's
+    Param entries come back as recorded, its constant factors as the
+    static block entries they would be alone. The same operator, the same
+    slots in the same order; memoized per tape revision. ``circuit``
+    itself when it holds no deferred block. What the gradient reduce lifts
+    (:func:`grad_reduce`, :func:`gradient_executable`) and what
+    ``Engine.grad_engine`` hands its companion: the reduce plans the raw
+    tape's blocks itself (:func:`_plan_blocks`), so a plan the caller had
+    fused must first read as the tape it came from."""
+    deferred = fusion._apply_deferred_block
+    if not any(f is deferred for f, _, _ in circuit._tape):
+        return circuit
+    memo = circuit.__dict__.get("_gatewise")
+    if memo is not None and memo[0] is circuit._cache_token:
+        return memo[1]
+    tape = []
+    for entry in circuit._tape:
+        if entry[0] is not deferred:
+            tape.append(entry)
+            continue
+        spec, values = entry[1][0], entry[1][1:]
+        k = 0
+        while k < len(spec.factors):
+            ev = spec.factors[k]
+            if ev.source is not None:
+                i, j, count = ev.source
+                run = spec.factors[k:k + count]
+                if j == 0 and [e.source for e in run] == [
+                        (i, m, count) for m in range(count)]:
+                    tape.append(materialize_entry(spec.entries[i], values))
+                    k += count
+                    continue
+            if ev.deferred:
+                name = getattr(spec.entries[ev.source[0]][0], "__name__", "")
+                raise QuESTError(
+                    f"'{name}' was split between two fused blocks and "
+                    "cannot be spelled out again; use the unfused circuit")
+            if _event_is_diag(ev):
+                qs = tuple(sorted(ev.support))
+                tape.append((G._apply_gate_diag, (_event_diag(ev, qs), qs),
+                             {}))
+            else:
+                win = planner._window(ev.support)
+                tape.append((fusion._apply_dense_block,
+                             (event_matrix(ev, win), win), {}))
+            k += 1
+    out = Circuit(circuit.num_qubits, circuit.is_density_matrix)
+    out._tape = tape
+    circuit.__dict__["_gatewise"] = (circuit._cache_token, out)
+    return out
+
+
 @dataclass(frozen=True)
 class _BlockPlan:
     """One block of the tape's dense plan, as the backward walk undoes it:
     a static block holds its DAGGERED operator, a block with Param factors
-    its :class:`..fusion.DeferredBlock`, for each of the spec's value
+    its :class:`..planner.DeferredBlock`, for each of the spec's value
     slots the tape slot it reads, and ``like``, the spec's content key:
     blocks with one key are ONE function of their values (a layer's window
     in every layer of an ansatz), composed and differentiated together."""
@@ -436,15 +487,14 @@ class _BlockPlan:
 
 def _plan_blocks(lifted, plans, stop, num_qubits, dtype_str):
     """The backward walk's items: the tape from the first slot on, planned
-    with the call ``Engine._plan_program`` makes. A block becomes a
+    with the call ``Engine._plan_program`` makes (``planner.dense_plan``). A block becomes a
     :class:`_BlockPlan`; an entry the planner passes through (no block
     holds it) keeps its :class:`_EntryPlan`. EVERY slot enters the planner
     as a Param named by its index, so a constant angle's gate is a factor
     composed in the program like a named one and keeps its derivative."""
     marks = tuple(Param(str(s.index)) for s in lifted.slots)
     tape = tuple(materialize_entry(e, marks) for e in lifted.entries[stop:])
-    plan = fusion.plan(tape, num_qubits, np.dtype(dtype_str),
-                       max_qubits=DENSE_WINDOW_QUBITS)
+    plan = planner.dense_plan(tape, num_qubits, np.dtype(dtype_str))
     items, at = [], 0
     for item in plan.items:
         if isinstance(item, tuple):
@@ -453,7 +503,7 @@ def _plan_blocks(lifted, plans, stop, num_qubits, dtype_str):
             items.append(plans[at])
             at += 1
             continue
-        diag = isinstance(item, fusion.DiagBlock)
+        diag = isinstance(item, planner.DiagBlock)
         kind = "diag" if diag else "dense"
         if item.factors is None:
             op = np.conj(item.diag) if diag else np.conj(item.matrix).T

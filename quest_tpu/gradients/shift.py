@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..engine.params import _SlotRef, bind as bind_values
+from ..params import _SlotRef, bind as bind_values
 from ..validation import QuESTError
 from .adjoint import _FIELDS, _entry_view
 from .expectation import hamiltonian_terms
@@ -66,6 +66,8 @@ def parameter_shift(circuit, hamiltonian, amps, params=None):
     shifted evaluation replays the SAME cached expectation executable with
     a perturbed values tuple (no retraces), but there are 2-4 of them per
     slot: use this as an oracle, not a serving route."""
+    # lazy: reaches up (sampling/request stands on the Engine's layer) for
+    # shot-based shifts
     from ..sampling.request import expectation_reduce
 
     codes, coeffs = hamiltonian_terms(hamiltonian, circuit.num_qubits)

@@ -5,7 +5,7 @@ Mirrors the reference's hardware-agnostic algebra (QuEST_common.c:120-139,
 i.e. the 2x2 matrix [[alpha, -conj(beta)], [beta, conj(alpha)]].
 
 Host-side numpy by default; cast to the register dtype at apply time. The
-parameterized-replay path (quest_tpu.engine.params) instead feeds TRACED
+parameterized-replay path (quest_tpu.params) instead feeds TRACED
 scalars, and every angle-taking builder carries a traced branch assembling
 the same matrix with jax.numpy *inside* the jit trace -- entrywise from
 real cos/sin components (never a complex transcendental), which keeps the

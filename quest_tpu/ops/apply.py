@@ -59,7 +59,7 @@ DENSE_WINDOW_QUBITS = 7
 
 #: a window that starts below _MIN_MINOR is expanded down to qubit 0, so
 #: its GEMM's K is 2^(top+1) however few qubits it spans: the planner
-#: (fusion.plan) opens no such window whose top reaches this qubit, which
+#: (planner.plan) opens no such window whose top reaches this qubit, which
 #: holds K to the [128, 2048] range _apply_matrix_window is written for
 MAX_LOW_WINDOW_TOP = 11
 

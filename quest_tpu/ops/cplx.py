@@ -21,7 +21,7 @@ def from_complex(arr, dtype) -> jnp.ndarray:
     """Complex array -> planar (2, *shape) device array. Host numpy input
     converts at trace time (the constant-matrix path); a jax array/tracer
     input -- a gate matrix assembled from runtime parameters inside the
-    trace (quest_tpu.engine.params) -- splits into planes symbolically."""
+    trace (quest_tpu.params) -- splits into planes symbolically."""
     import jax
 
     if isinstance(arr, jax.Array):

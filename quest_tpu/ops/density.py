@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import precision
+from ..environment import active_pallas_mesh
 from . import apply, cplx, diagonal
 from .layout import amps_jit
 from .spy import records
@@ -77,8 +79,8 @@ def apply_channel(amps, superop, *, n: int, targets: tuple[int, ...],
 
     ``depol`` is what ``mixDepolarising`` / ``mixTwoQubitDepolarising`` say
     of their call beside its superoperator: the probability. Nothing here
-    reads it; a planner that captures the call does (``fusion.GateEvent``),
-    and lowers the channel to its closed form (``fusion._lower_channel``).
+    reads it; a planner that captures the call does (``events.GateEvent``),
+    and lowers the channel to its closed form (``planner._lower_channel``).
 
     Large registers use the Kraus-sum formulation: rho' = sum_i s_i K_i rho
     K_i^dagger, each term two layout-clean single-group passes (row bits,
@@ -140,21 +142,18 @@ def _kraus_sum_pallas(amps, terms, n, t, lq=None):
     pack/exchange/unpack passes. Round 2 paid ~2 passes per Kraus term
     plus 2 relocation transposes; this is one pass, always. ``lq``
     overrides the tile limit for tests."""
-    import jax
-
-    from .. import fusion as _fusion
-    from . import pallas_gates as PG
+    from . import pallas_gates as PG  # lazy: Pallas
 
     nsv = 2 * n
     if amps.shape[-1] < 2 * PG._LANES:
         return None
-    if not _fusion._mosaic_supports(amps.dtype):
+    if not precision._mosaic_supports(amps.dtype):
         return None  # f64 on TPU: no Mosaic lowering (engine path)
     sharding = getattr(amps, "sharding", None)
     if sharding is not None and len(sharding.device_set) > 1:
         return None  # pallas_call would gather the shards
     if (isinstance(amps, jax.core.Tracer)
-            and _fusion.active_pallas_mesh() is not None):
+            and active_pallas_mesh() is not None):
         return None  # traced replay of a register known to be sharded
     if lq is None:
         lq = PG.local_qubits(nsv)

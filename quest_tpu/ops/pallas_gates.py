@@ -305,7 +305,7 @@ _ZONE_SPAN = 5
 
 def _op_event(op):
     """Kernel op tuple -> GateEvent (for host-side dense folding)."""
-    from ..fusion import GateEvent
+    from ..events import GateEvent
 
     if op[0] == "matrix":
         return GateEvent("matrix", (op[1],), tuple(op[2]), tuple(op[3]),
@@ -326,7 +326,7 @@ def op_dense_targets(op) -> tuple:
     (controls, parity members, diagw/grid-diagonal targets) are excluded:
     they resolve per-program/per-shard. The ONE authoritative extraction
     for the legality checks in fused_local_run and
-    fusion._run_pallas_sharded."""
+    fusion._kernel_run."""
     if op[0] == "matrix":
         m = op[4].arr if hasattr(op[4], "arr") else op[4]
         if complex(m[0][1]) == 0 and complex(m[1][0]) == 0:
@@ -466,7 +466,7 @@ def _fold_zone_ops(ops, tile_bits: int) -> tuple:
     than the whole folded lane dot -- the lane zone folds from the first
     dense gate -- while sublane butterflies stay cheaper than the
     window dots until a zone accumulates several of them."""
-    from ..fusion import event_matrix
+    from ..events import event_matrix
 
     zones = [(0, LANE_BITS)]
     lo = LANE_BITS
@@ -1582,113 +1582,6 @@ def _fused_local_run_impl(amps, shard_index, *, n: int, ops: tuple,
 #: kernel name to give calls (the AOT compile tests)
 _fused_local_run = jax.jit(_fused_local_run_impl,
                            static_argnames=_FUSED_STATIC, donate_argnums=(0,))
-
-
-#: largest contiguous-window span window_dot accepts (2D sublane rows = 128)
-_WINDOW_DOT_MAX_SPAN = 6
-
-
-def window_dot_supported(n: int, lo: int, hi: int) -> bool:
-    """True if window_dot can apply a dense [lo, hi] window: the low bits
-    below the window must fill at least one 128-lane tile, and 2*2^span
-    sublane rows must stay MXU-friendly."""
-    return lo >= LANE_BITS and (hi - lo) < _WINDOW_DOT_MAX_SPAN
-
-
-def window_dot(amps, matrix, *, n: int, lo: int, hi: int, conj: bool = False,
-               interpret: bool | None = None):
-    """Dense unitary on the contiguous window [lo, hi] as a Pallas MXU dot.
-
-    View the flat state as (2, A, D, B) with D = 2^span and B = 2^lo >= 128;
-    each grid program owns one (a, 128-lane slice of B) column and applies
-    W4 = [[Ur, -Ui], [Ui, Ur]] by a single (2D, 2D) @ (2D, 128) matmul --
-    no kron expansion (the einsum window path pays up to 4x FLOPs getting
-    K to 128) and no output transpose. Measured ~3x faster per block than
-    the XLA HIGHEST einsum at 2^26 amplitudes.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    telemetry.inc("pallas_pass_total", kind="window_dot")
-    name = _window_dot_name(amps.dtype, lo, hi)
-    run = _named_jit(_window_dot_impl, name, _WINDOW_STATIC)
-
-    def call():
-        return run(amps, matrix, n=n, lo=lo, hi=hi, conj=conj,
-                   interpret=bool(interpret))
-
-    sig = (name, n, bool(conj), amps.shape, bool(interpret))
-    if not telemetry.enabled() or sig in _SEEN_KERNEL_SIGS:
-        return call()
-    # a kernel of its own: the record a fused run's new signature gets
-    _SEEN_KERNEL_SIGS.add(sig)
-    t0 = time.perf_counter()
-    mark = telemetry.compile_mark()
-    out = call()
-    _compile_record("window_dot", name, t0, t0, mark, n=n, lo=lo, hi=hi,
-                    interpret=bool(interpret))
-    return out
-
-
-def _make_window_dot_kernel(ac: int, d: int):
-    def kernel(x_ref, w_ref, o_ref):
-        w = w_ref[:]
-        for a in range(ac):  # static unroll; ac is small by construction
-            y = jnp.concatenate([x_ref[0, a], x_ref[1, a]], axis=0)  # (2D, Bc)
-            out = jnp.dot(w, y, preferred_element_type=y.dtype,
-                          precision=_DOT_PRECISION)
-            o_ref[0, a] = out[:d]
-            o_ref[1, a] = out[d:]
-    return kernel
-
-
-_WINDOW_STATIC = ("n", "lo", "hi", "conj", "interpret")
-
-
-def _window_dot_name(dtype, lo: int, hi: int) -> str:
-    return f"qt_window_dot_f{8 * np.dtype(dtype).itemsize}_lo{lo}_hi{hi}"
-
-
-def _window_dot_impl(amps, matrix, *, n: int, lo: int, hi: int, conj: bool,
-                     interpret: bool):
-    num = amps.shape[-1]
-    span = hi - lo + 1
-    d = 1 << span
-    b = 1 << lo
-    a = num // (d * b)
-    mr, mi = matrix[0].astype(amps.dtype), matrix[1].astype(amps.dtype)
-    if conj:
-        mi = -mi
-    w4 = jnp.concatenate([jnp.concatenate([mr, -mi], axis=1),
-                          jnp.concatenate([mi, mr], axis=1)], axis=0)
-
-    # block geometry: keep each DMA block ~1 MiB. Prefer wide contiguous
-    # B-chunks (one big MXU dot, no transposes); when B itself is small,
-    # stack Ac major rows per program and loop statically in-kernel.
-    bc = min(b, 1 << 10)
-    ac = max(1, min(a, (1 << 17) // (d * bc)))
-    while a % ac:
-        ac //= 2
-    x = amps.reshape(2, a, d, b)
-    grid = (a // ac, b // bc)
-    z = np.int32(0)  # not a bare 0: see _swap_spec (x64 makes it an i64)
-    out = pl.pallas_call(
-        _make_window_dot_kernel(ac, d),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        grid=grid,
-        in_specs=[pl.BlockSpec((2, ac, d, bc), lambda i, j: (z, i, z, j),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((2 * d, 2 * d), lambda i, j: (z, z),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((2, ac, d, bc), lambda i, j: (z, i, z, j),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-        name=_window_dot_name(amps.dtype, lo, hi),
-    )(x, w4)
-    return out.reshape(2, -1)
-
-
-_window_dot = jax.jit(_window_dot_impl, static_argnames=_WINDOW_STATIC,
-                      donate_argnums=(0,))
 
 
 @partial(jax.jit, static_argnames=("n", "lo1", "lo2", "k"), donate_argnums=(0,))

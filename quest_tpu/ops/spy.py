@@ -1,6 +1,6 @@
 """Spy operands: how the planner observes what a tape entry would do.
 
-``fusion.capture`` replays a tape entry against a stand-in register instead
+``capture.capture`` replays a tape entry against a stand-in register instead
 of a state. Every primitive the planner understands is declared with
 :func:`records`; handed a :class:`Spy` as its first argument it passes the
 call to the spy's recorder of its own name and does no work. The spy
@@ -15,7 +15,7 @@ import functools
 
 
 class Spy:
-    """Base of the stand-ins (fusion._SpyQureg, fusion._SpyAmps).
+    """Base of the stand-ins (capture._SpyQureg, capture._SpyAmps).
     ``recorders`` maps a primitive's ``__name__`` to the callable that
     takes its arguments, the spy first."""
 

@@ -28,7 +28,7 @@ This module makes values *runtime arguments* of one compiled replay:
   apply-time-assembled barriers between the static kernel runs.
 
 Two tapes that differ only in lifted values produce the SAME
-:func:`quest_tpu.engine.cache.structure_fingerprint`, which is what lets the
+:func:`quest_tpu.cache.structure_fingerprint`, which is what lets the
 executable cache serve "same ansatz, different angles" traffic with zero
 recompiles (docs/serving.md).
 
@@ -45,7 +45,10 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+import jax.numpy as jnp
 import numpy as np
+
+from .validation import QuESTError
 
 __all__ = ["Param", "P", "LiftedTape", "Slot", "ParamExecutable",
            "lift_tape", "lift_slot_census", "bind", "materialize_entry",
@@ -65,7 +68,6 @@ class Param:
     executable is value-independent. The same name may appear in several
     slots -- every occurrence receives the one bound value.
     """
-
     __slots__ = ("name",)
 
     def __init__(self, name: str):
@@ -149,7 +151,7 @@ def _is_seed_value(x) -> bool:
 def has_params(args, kwargs=None) -> bool:
     """True when a tape entry's arguments carry a :class:`Param` anywhere
     (one level into tuples/lists) -- the fusion planner's pre-check: such
-    entries have no matrix at plan time (fusion._entry_has_params)."""
+    entries have no matrix at plan time (capture._entry_has_params)."""
     items = list(args) + list((kwargs or {}).values())
     for x in items:
         if isinstance(x, Param):
@@ -174,7 +176,6 @@ class Slot:
 
 class _SlotRef:
     """Placeholder living in a lifted entry's argument template."""
-
     __slots__ = ("index",)
 
     def __init__(self, index: int):
@@ -208,8 +209,6 @@ def lift_tape(tape) -> LiftedTape:
     registry doesn't cover is an error -- there is no traced assembly route
     for it (e.g. a channel probability, whose superoperator is built
     host-side)."""
-    from ..validation import QuESTError
-
     entries = []
     slots: list[Slot] = []
 
@@ -268,7 +267,7 @@ def lift_slot_census(tape) -> tuple[int, int]:
     liftable positions carry constants vs ``Param`` placeholders. Anonymous
     slots are the executable-cache hazard -- structure-equal circuits that
     differ only in those constants cannot share a compiled program
-    (engine/cache.structure_fingerprint bakes them) -- and the count is
+    (cache.structure_fingerprint bakes them) -- and the count is
     what the tape linter reports as QT003 (quest_tpu/analysis)."""
     slots = lift_tape(tuple(tape)).slots
     anon = sum(1 for s in slots if s.name is None)
@@ -294,10 +293,6 @@ def bind(lifted: LiftedTape, params=None, device: bool = True) -> tuple:
     ``device=False`` returns plain Python scalars (a tape materialized
     with them replays through the constant/numpy assembly path -- the
     bit-identity baseline the tests compare against)."""
-    import jax.numpy as jnp
-
-    from ..validation import QuESTError
-
     params = params or {}
     rdt = jnp.result_type(float)
     cdt = jnp.result_type(complex)

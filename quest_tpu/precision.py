@@ -18,7 +18,7 @@ TPU notes (the QUEST_PRECISION=2 policy, probed round 3 on a v5e chip):
 - f64 **is supported on the TPU backend**: XLA emulates it in software. The
   Pallas/Mosaic kernels have no f64 lowering (MXU dots are bf16/f32 hardware),
   so f64 registers on TPU transparently take the XLA engine paths
-  (fusion._mosaic_supports); measured ~866 gates/s at 20 qubits vs ~30-50k
+  (:func:`_mosaic_supports`); measured ~866 gates/s at 20 qubits vs ~30-50k
   in f32 -- "supported but slow", still ~2x the reference CPU anchor, with
   true double accuracy (22q fused-circuit norm error ~3e-14).
 - f32 (QUEST_PRECISION=1, the default) is the performance dtype; REAL_EPS
@@ -31,7 +31,9 @@ from __future__ import annotations
 
 import os
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: map of QuEST PRECISION codes -> (real dtype, complex dtype, REAL_EPS)
 #: eps values mirror QuEST_precision.h:48,63 (1e-5 single, 1e-13 double).
@@ -63,8 +65,6 @@ def _ensure_x64(code: int, explicit: bool) -> None:
     (and TPU kernel selection) for every concurrent f32 register."""
     if code != 2:
         return
-    import jax
-
     if jax.config.jax_enable_x64:
         return
     if explicit and default_precision() != 2:
@@ -111,3 +111,15 @@ def precision_for_dtype(dtype) -> int:
     if d in (jnp.dtype("complex64"), jnp.dtype("float32")):
         return 1
     return 2
+
+
+def _mosaic_supports(dtype) -> bool:
+    """Mosaic (TPU Pallas) has no f64 lowering for the kernel's MXU dots;
+    f64 registers on TPU take the XLA engine paths instead (XLA emulates
+    f64 on TPU -- slow but correct, the documented QUEST_PRECISION=2
+    policy; see the module docstring). Read through this module
+    (``precision._mosaic_supports``) by the router above and the density
+    kernels below alike."""
+    if jax.default_backend() != "tpu":
+        return True  # CPU interpreter handles f64
+    return np.dtype(dtype) != np.dtype("float64")

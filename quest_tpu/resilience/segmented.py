@@ -1,6 +1,6 @@
 """Preemption-safe segmented execution with verified checkpoints.
 
-A fused :class:`~quest_tpu.fusion.FusePlan` tape is not interruptible at
+A fused :class:`~quest_tpu.planner.FusePlan` tape is not interruptible at
 arbitrary points: between a PallasRun's folded load swap and its store
 swap the amplitudes live in a PERMUTED frame, and a snapshot taken there
 is not a state the public API can name. The points where the frame
@@ -101,10 +101,10 @@ def _qt305_crc(gen_dir: str, e: QuESTChecksumError) -> None:
         "resilience.segmented")])
 
 
-# the symbolic frame replay lives in quest_tpu.segments since round 13
-# (the segment-dispatch emitter shares the boundary computation); the
-# re-export keeps this module's historical surface
-from ..segments import _swap_blocks  # noqa: F401  (compat re-export)
+# This module runs circuits but is imported with the package it lives in,
+# whose leaves (guard, sync, sentinel) every layer below circuits imports:
+# what it takes from above (segments, circuits, checkpoint, registers) it
+# imports where it uses it.
 
 
 def segment_plan(tape: list, nsv: int, every_n_items: int = 1) -> list:
@@ -269,10 +269,10 @@ def _heal(circuit: Circuit, qureg: Qureg, lo: int, hi: int,
         # engine fallback lattice: a compiled segment would cache-hit the
         # suspect executable, so degradation must bypass the cache
         _rollback(qureg, lo, checkpoint_dir, baseline)
-        from .. import fusion
         from ..circuits import _register_mesh
+        from ..environment import pallas_mesh
 
-        with fusion.pallas_mesh(_register_mesh(qureg)):
+        with pallas_mesh(_register_mesh(qureg)):
             with faultinject.fault_plan("pallas.dispatch:compile:1+"):
                 for f, a, kw in circuit._tape[lo:hi]:
                     telemetry.inc("device_dispatch_total", route="item")

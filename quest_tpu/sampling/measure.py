@@ -11,7 +11,7 @@ site rides the fused/segment/request-chain routes like any gate.
 
 Contract, mirroring ``trajectories.noise.applyTrajectoryKraus``:
 
-- both functions are unconditional fusion barriers (``fusion.capture``
+- both functions are unconditional fusion barriers (``capture.capture``
   returns None for them -- the collapse mask only exists at apply time);
 - the module is NOT in ``circuits._DEFER_SAFE_MODULES``, so under the
   explicit scheduler a measurement site is a reconciliation point: the
@@ -21,7 +21,7 @@ Contract, mirroring ``trajectories.noise.applyTrajectoryKraus``:
   checkpoint/resume boundaries align with the points where a recorded
   outcome becomes definite;
 - the ``seed`` argument of ``applyMidMeasurement`` is a runtime value
-  slot of kind ``'seed'`` (engine/params._LIFTABLE): a plain int or a
+  slot of kind ``'seed'`` (params._LIFTABLE): a plain int or a
   ``P("name")`` placeholder both lift, S seeds replay one executable.
 """
 

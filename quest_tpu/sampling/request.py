@@ -17,9 +17,19 @@ from __future__ import annotations
 
 from typing import TYPE_CHECKING
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from .. import cache as _ec
+from .. import segments
 from .. import telemetry
+from .. import validation as V
+from ..calculations import expec_pauli_sum_amps
+from ..circuits import _amps_mesh, named_program
+from ..environment import active_pallas_mesh, pallas_mesh
+from ..parallel import scheduler as _dist
+from ..params import _SEED, bind as _bind
 from ..validation import QuESTError
 from . import sampler as _sampler
 
@@ -49,7 +59,6 @@ def _record_transfer(out) -> None:
     """Gauge the bytes a sampling result moves to the host: O(S) shot
     words + O(1) scalars -- the acceptance evidence against the 2^N
     amplitude transfer the pre-round-19 readout paid."""
-    import jax
 
     leaves = jax.tree_util.tree_leaves(out)
     telemetry.set_gauge(
@@ -61,7 +70,6 @@ def to_host(res):
     """Materialise a sampling-request result on the host (numpy leaves)
     and gauge the bytes that crossed: the result-side half of the
     submit/result host contract."""
-    import jax
 
     out = jax.tree_util.tree_map(np.asarray, res)
     _record_transfer(out)
@@ -74,7 +82,6 @@ def sample_reduce(*, n: int, targets, shots: int, site: int = 0,
     table over ``targets`` -- the terminal stage of a one-dispatch
     sampling request. Cached per spec so its identity is stable in the
     request-executable LRU key."""
-    from ..engine import cache as _ec
     targets = tuple(int(t) for t in targets)
     key = ("sample_reduce", n, targets, int(shots), int(site),
            bool(density))
@@ -97,7 +104,6 @@ def expectation_reduce(*, n: int, codes, coeffs, density: bool = False):
     ``calcExpecPauliSum`` contraction lowered onto the fused request path
     (per-term Pauli-product segments chained inside the one program,
     reusing ``calculations._pauli_prod_amps``). Cached per spec."""
-    from ..engine import cache as _ec
     codes_t = tuple(tuple(int(c) for c in row) for row in
                     np.asarray(codes, dtype=np.int64).reshape(-1, n))
     coeffs_t = tuple(float(c) for c in np.asarray(coeffs,
@@ -110,9 +116,6 @@ def expectation_reduce(*, n: int, codes, coeffs, density: bool = False):
 
     def build():
         def reduce(amps):
-            import jax.numpy as jnp
-
-            from ..calculations import expec_pauli_sum_amps
             cf = jnp.asarray(np.asarray(coeffs_t, dtype=np.float64),
                              dtype=amps.dtype)
             return expec_pauli_sum_amps(amps, cf, codes=codes_t, n=n,
@@ -161,7 +164,6 @@ def sample_request(circuit: Circuit, *, targets=None,
         expec_red = expectation_reduce(n=n, codes=pauli_codes,
                                       coeffs=coeffs, density=density)
 
-    from ..engine import cache as _ec
     key = ("sample_request", circuit._cache_token, shot_red, expec_red,
            donate)
 
@@ -176,7 +178,6 @@ def sample_request(circuit: Circuit, *, targets=None,
             return (seed if hasattr(seed, "dtype")
                     else np.asarray(int(seed), dtype=np.uint32))
 
-        from ..engine.params import _SEED, bind as _bind
         lifted = circuit.lifted()
         seed_positions = tuple(
             i for i, s in enumerate(lifted.slots)
@@ -184,7 +185,6 @@ def sample_request(circuit: Circuit, *, targets=None,
         if not lifted.slots:
             # constant tape: the round-18 request chain, with the sampler
             # (and its runtime seed) as the terminal reduce stage
-            from .. import segments
             inner = segments.request_executable(circuit, donate=donate,
                                                 reduce=reduce)
 
@@ -203,10 +203,7 @@ def sample_request(circuit: Circuit, *, targets=None,
         # table, so a request replays bit-identically from its seed
         # alone. Other named Params must be pre-bound on the tape (this
         # route takes no params dict; use the Engine for those).
-        import jax
 
-        from .. import fusion
-        from ..parallel import scheduler as _dist
         base_values = _bind(lifted, {lifted.slots[i].name: 0
                                      for i in seed_positions})
         body = circuit._replay_fn(lifted)
@@ -217,18 +214,16 @@ def sample_request(circuit: Circuit, *, targets=None,
                            for i, v in enumerate(_base))
             return _reduce(_body(amps, values), seed)
 
-        from ..circuits import named_program
         inner = jax.jit(named_program(whole, circuit, "sample"),
                         donate_argnums=(0,) if donate else ())
         sched = _dist.active()
         mesh = sched.mesh if sched else None
-        pmesh = fusion.active_pallas_mesh()
+        pmesh = active_pallas_mesh()
 
         def fn(amps, seed, _inner=inner, _mesh=mesh, _pmesh=pmesh):
-            from ..circuits import _amps_mesh
             pm = _pmesh if _pmesh is not None else _amps_mesh(amps)
             telemetry.inc("device_dispatch_total", route="request")
-            with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
+            with _dist.explicit_mesh(_mesh), pallas_mesh(pm):
                 return _inner(amps, coerce(seed))
 
         fn.num_segments = 1
@@ -246,7 +241,6 @@ def sampleQureg(qureg: Qureg, targets=None, shots: int | None = None,
     (targets[0] = LSB of each outcome). The register is not modified.
     Only the table crosses to the host -- O(S) words, gauge-recorded as
     ``sample_host_transfer_bytes``."""
-    from .. import validation as V
     func = "sampleQureg"
     n = qureg.num_qubits_represented
     if targets is None:

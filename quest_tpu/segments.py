@@ -47,7 +47,19 @@ docs/observability.md has the full table.
 
 from __future__ import annotations
 
+import dataclasses
+import time
+
+import jax
+
+from . import cache as _ec
+from . import fusion
 from . import telemetry
+from .circuits import _amps_mesh, _register_mesh, named_program
+from .environment import active_pallas_mesh, pallas_mesh
+from .parallel import scheduler as _dist
+from .planner import FrameSwap, PallasRun
+from .validation import QuESTError
 
 __all__ = [
     "identity_boundaries", "segment_cuts", "stamp_plan",
@@ -74,15 +86,14 @@ def _replay_frame(perm: list, item) -> None:
     """Apply a plan item's frame relabelings (a PallasRun's load / store
     swaps, a standalone FrameSwap) to ``perm``; every other item leaves
     the frame untouched."""
-    from . import fusion
-    if isinstance(item, fusion.PallasRun):
+    if isinstance(item, PallasRun):
         if item.load_swap_k:
             _swap_blocks(perm, item.tile_bits, item.load_swap_k,
                          item.load_swap_hi)
         if item.store_swap_k:
             _swap_blocks(perm, item.tile_bits, item.store_swap_k,
                          item.store_swap_hi)
-    elif isinstance(item, fusion.FrameSwap):
+    elif isinstance(item, FrameSwap):
         _swap_blocks(perm, item.tile_bits, item.k, item.hi)
 
 
@@ -95,7 +106,6 @@ def identity_boundaries(tape, nsv: int) -> list:
 
     This is the ONE boundary computation -- ``resilience.segmented``
     delegates here."""
-    from . import fusion
     perm = list(range(nsv))
     ident = list(range(nsv))
     bounds = [0]
@@ -169,14 +179,12 @@ def stamp_plan(plan, nsv: int) -> int:
     so plancheck's QT107 check can re-derive them independently and prove
     each emitted segment starts and ends at frame identity in FusePlan
     order."""
-    import dataclasses
 
-    from . import fusion
     perm = list(range(nsv))
     ident = list(range(nsv))
     seg = 0
     for i, item in enumerate(plan.items):
-        if isinstance(item, (fusion.PallasRun, fusion.FrameSwap)):
+        if isinstance(item, (PallasRun, FrameSwap)):
             plan.items[i] = dataclasses.replace(item, seg=seg)
             _replay_frame(perm, item)
         if perm == ident:
@@ -189,7 +197,7 @@ def stamp_plan(plan, nsv: int) -> int:
 def slice_executable(circuit, lo: int, hi: int, donate: bool = True):
     """``tape[lo:hi]`` as ONE jitted executable -- the segment program.
 
-    Cached in the process-global bounded LRU (engine.cache.executables)
+    Cached in the process-global bounded LRU (cache.executables)
     keyed on the circuit's stable ``_cache_token`` plus the slice and
     execution-mode meshes, so repeated segment executions -- checkpoint
     cadences, rollback-and-replay healing, bench chains -- dispatch
@@ -198,18 +206,13 @@ def slice_executable(circuit, lo: int, hi: int, donate: bool = True):
     Mesh pinning mirrors ``Circuit.compiled``: jit traces on first
     call, which may happen under a different scheduler/pallas-mesh
     context than the one this executable is keyed on."""
-    import jax
 
-    from . import fusion
-    from .engine import cache as _ec
-    from .parallel import scheduler as _dist
     sched = _dist.active()
     mesh = sched.mesh if sched else None
-    pmesh = fusion.active_pallas_mesh()
+    pmesh = active_pallas_mesh()
     key = ("segment", circuit._cache_token, lo, hi, donate, mesh, pmesh)
 
     def build():
-        from .circuits import named_program
         stop = len(circuit._tape) if hi is None else hi
         inner = jax.jit(
             named_program(circuit._replay_fn(None, lo=lo, hi=hi), circuit,
@@ -217,9 +220,8 @@ def slice_executable(circuit, lo: int, hi: int, donate: bool = True):
             donate_argnums=(0,) if donate else ())
 
         def fn(amps, _inner=inner, _mesh=mesh, _pmesh=pmesh):
-            from .circuits import _amps_mesh
             pm = _pmesh if _pmesh is not None else _amps_mesh(amps)
-            with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
+            with _dist.explicit_mesh(_mesh), pallas_mesh(pm):
                 return _inner(amps)
 
         return fn
@@ -236,13 +238,11 @@ def run_slice(circuit, qureg, lo: int = 0, hi: int | None = None, *,
     match, ~1 ulp across program granularities on XLA-CPU
     (granularity-invariant on TPU, where Mosaic kernels are opaque to fma
     recontraction)."""
-    from . import fusion
-    from .circuits import _register_mesh
     hi = len(circuit._tape) if hi is None else hi
     if hi <= lo:
         return qureg
     ctx = telemetry.current_trace() if telemetry.trace_on() else None
-    with fusion.pallas_mesh(_register_mesh(qureg)):
+    with pallas_mesh(_register_mesh(qureg)):
         fn = slice_executable(circuit, lo, hi, donate=donate)
         telemetry.inc("device_dispatch_total", route="segment")
         if ctx is not None:
@@ -250,13 +250,11 @@ def run_slice(circuit, qureg, lo: int = 0, hi: int | None = None, *,
             # phases: an explicit sync separates the host-side
             # launch from the device drain (armed path only -- the
             # untraced path never blocks)
-            import time as _time
 
-            import jax as _jax
             out = fn(qureg.amps)
-            ctx.charge("dispatch", _time.perf_counter())
-            _jax.block_until_ready(out)
-            ctx.charge("device", _time.perf_counter())
+            ctx.charge("dispatch", time.perf_counter())
+            jax.block_until_ready(out)
+            ctx.charge("device", time.perf_counter())
             qureg.put(out)
         else:
             qureg.put(fn(qureg.amps))
@@ -271,12 +269,9 @@ def chain_executable(circuit, max_items: int | None = None,
     cached too. Calling the chain counts one
     ``device_dispatch_total{route="segment"}`` per link -- the dispatch
     tax is the segment count, not the gate count."""
-    from . import fusion
-    from .engine import cache as _ec
-    from .parallel import scheduler as _dist
     sched = _dist.active()
     key = ("segment_chain", circuit._cache_token, max_items, donate,
-           sched.mesh if sched else None, fusion.active_pallas_mesh())
+           sched.mesh if sched else None, active_pallas_mesh())
 
     def build():
         nsv = (2 if circuit.is_density_matrix else 1) * circuit.num_qubits
@@ -323,13 +318,8 @@ def request_executable(circuit, donate: bool = True, reduce=None):
     ``("request_chain", ...)``; ``fn.num_segments`` reports how many
     segments were composed, ``fn.num_dispatches = 1`` the launch
     count."""
-    import jax
 
-    from . import fusion
-    from .engine import cache as _ec
-    from .parallel import scheduler as _dist
     if getattr(reduce, "wants_values", False):
-        from .validation import QuESTError
         raise QuESTError(
             "request_executable replays a concrete tape and has no "
             "parameter-values vector to hand a wants_values reduce (the "
@@ -338,7 +328,7 @@ def request_executable(circuit, donate: bool = True, reduce=None):
             "request_executable")
     sched = _dist.active()
     mesh = sched.mesh if sched else None
-    pmesh = fusion.active_pallas_mesh()
+    pmesh = active_pallas_mesh()
     key = ("request_chain", circuit._cache_token, donate, reduce, mesh,
            pmesh)
 
@@ -355,18 +345,16 @@ def request_executable(circuit, donate: bool = True, reduce=None):
                 amps = f(amps)
             return amps if _reduce is None else _reduce(amps, *extra)
 
-        from .circuits import named_program
         inner = jax.jit(
             named_program(whole, circuit, "request", f"s{len(replays)}"),
             donate_argnums=(0,) if donate else ())
 
         def fn(amps, *extra, _inner=inner, _mesh=mesh, _pmesh=pmesh):
-            from .circuits import _amps_mesh
             pm = _pmesh if _pmesh is not None else _amps_mesh(amps)
             # ONE launch for the whole request -- the counter delta the
             # bench's dispatches_per_circuit row and native.yml gate read
             telemetry.inc("device_dispatch_total", route="request")
-            with _dist.explicit_mesh(_mesh), fusion.pallas_mesh(pm):
+            with _dist.explicit_mesh(_mesh), pallas_mesh(pm):
                 return _inner(amps, *extra)
 
         fn.num_segments = len(replays)

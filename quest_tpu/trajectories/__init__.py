@@ -4,7 +4,7 @@ Unravels the decoherence channels of a density-matrix tape into stochastic
 pure-state trajectories (the qsim Monte-Carlo-wavefunction technique,
 arXiv:2111.02396) and runs the ensemble as ONE fixed-shape batched program
 through the serving engine's vmap-over-params batcher: channel sites carry
-a runtime uint32 seed slot (engine/params kind ``'seed'``), so T
+a runtime uint32 seed slot (params kind ``'seed'``), so T
 trajectories compile once and replay with T independent counter-based PRNG
 streams -- branch-free selection keeps plan structure value-independent,
 the same invariant PR 4 proved for param barriers.

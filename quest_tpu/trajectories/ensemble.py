@@ -25,10 +25,13 @@ from typing import TYPE_CHECKING, Iterable
 
 import numpy as np
 
+from .. import cache as _ec
 from .. import channels as _channels
 from .. import telemetry
 from ..circuits import Circuit
-from ..engine.params import _SEED, Param
+from ..engine import Engine
+from ..params import _SEED, Param
+from ..sampling import sampler as _sampler
 from ..validation import QuESTError
 from . import noise
 
@@ -189,8 +192,6 @@ def _shot_finalize(*, n: int, targets: tuple, shots: int, shot_seed: int):
     the vmap lanes of a batch (one static ``shot_seed``): common random
     numbers -- each trajectory's table is still an unbiased sample of its
     own outcome distribution, and cross-trajectory variance shrinks."""
-    from ..engine import cache as _ec
-    from ..sampling import sampler as _sampler
     key = ("ensemble_shot_finalize", n, targets, int(shots),
            int(shot_seed))
 
@@ -235,8 +236,6 @@ def run_ensemble(circuit: Circuit, num_trajectories: int | None = None, *,
     S-shot ensemble moves T*S int32 words to the host, never T*2^n
     amplitudes. The result's ``shot_tables`` is the (T, S) stack and
     ``states`` is None."""
-    from ..engine import Engine
-
     if circuit.is_density_matrix:
         circuit = unravel(circuit)
     lifted = circuit.lifted()

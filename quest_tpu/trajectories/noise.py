@@ -4,12 +4,12 @@
 channel lowers to (trajectories.unravel maps the built-in mix* table onto
 it). Its Kraus stack, targets and site index are baked tape *structure*;
 the ``seed`` argument is a runtime value slot of kind ``'seed'``
-(engine/params._LIFTABLE) -- a plain int or a :class:`~quest_tpu.engine.P`
+(params._LIFTABLE) -- a plain int or a :class:`~quest_tpu.engine.P`
 placeholder both lift, so plan structure and the executable-cache
 fingerprint never depend on the seed.
 
 On the fused path these entries are unconditional barriers
-(fusion.capture returns None for them -- the drawn operator only exists at
+(capture.capture returns None for them -- the drawn operator only exists at
 apply time), exactly like PR 4's param barriers; on the deferred scheduler
 they reconcile first (the module is not in circuits._DEFER_SAFE_MODULES).
 """

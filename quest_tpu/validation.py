@@ -213,7 +213,7 @@ def validate_unitary_matrix(matrix, num_targets: int, eps: float, func: str) -> 
 def validate_unitary_complex_pair(alpha: complex, beta: complex, eps: float, func: str) -> None:
     from . import matrices
     if matrices.is_traced(alpha, beta):
-        # runtime parameters (engine.params): the values exist only inside
+        # runtime parameters (params): the values exist only inside
         # the trace, so unitarity is the submitting caller's contract --
         # mirrors the reference's stance that validation is host-side
         return

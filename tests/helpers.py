@@ -23,9 +23,9 @@ TOL = 1e-10 if default_precision() == 2 else 2e-4
 def pallas_runs(circuit) -> list:
     """The PallasRuns a fused circuit's tape carries, in order, read through
     the one decoder (``fusion.plan_from_tape``)."""
-    from quest_tpu import fusion
+    from quest_tpu import fusion, planner
     return [i for i in fusion.plan_from_tape(circuit._tape).items
-            if isinstance(i, fusion.PallasRun)]
+            if isinstance(i, planner.PallasRun)]
 
 
 def shape_register(n: int, dtype, sharding=None):

@@ -9,7 +9,7 @@ import hashlib
 
 import numpy as np
 
-from quest_tpu import fusion
+from quest_tpu import planner
 
 _CHANNELS = ("kraus1", "kraus2", "krausn", "depol")
 
@@ -26,7 +26,7 @@ def _flat(x):
 
 
 def item_text(item) -> str:
-    if isinstance(item, fusion.PallasRun):
+    if isinstance(item, planner.PallasRun):
         ops = []
         for op in item.ops:
             if op[0] in _CHANNELS:
@@ -38,9 +38,9 @@ def item_text(item) -> str:
         return (f"run tile={item.tile_bits} load={item.load_swap_k}@"
                 f"{item.load_swap_hi} store={item.store_swap_k}@"
                 f"{item.store_swap_hi} seg={item.seg} " + ";".join(ops))
-    if isinstance(item, fusion.FrameSwap):
+    if isinstance(item, planner.FrameSwap):
         return f"swap tile={item.tile_bits} k={item.k}@{item.hi}"
-    if isinstance(item, (fusion.FusedBlock, fusion.DiagBlock)):
+    if isinstance(item, (planner.FusedBlock, planner.DiagBlock)):
         return f"{type(item).__name__} {tuple(item.qubits)}"
     return f"raw {getattr(item[0], '__name__', item[0])}"
 

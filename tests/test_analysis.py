@@ -16,7 +16,7 @@ and (b) catch a seeded fault:
   dropped relocation record (QT104) are caught;
 - tapelint: adjacent cancellations (QT001), mergeable rotations (QT002),
   cache-defeating constant angles cross-checked against
-  engine.params.lift_tape (QT003), malformed events (QT004);
+  params.lift_tape (QT003), malformed events (QT004);
 - the QUEST_PALLAS_RING env diagnostic (QT205) warns once per value and
   states the clamped depth; QUEST_VERIFY=1 gates Circuit.fused().
 
@@ -30,7 +30,7 @@ import numpy as np
 import pytest
 
 from quest_tpu import analysis as A
-from quest_tpu import fusion, telemetry
+from quest_tpu import events, fusion, planner, telemetry
 from jax.sharding import AbstractMesh
 from quest_tpu.circuits import Circuit
 from quest_tpu.environment import AMP_AXIS
@@ -107,7 +107,7 @@ def test_fused_plan_replays_clean():
 def test_plan_mutation_dropped_store_swap():
     plan = _plan_20q()
     for i, it in enumerate(plan.items):
-        if isinstance(it, fusion.PallasRun) and it.store_swap_k:
+        if isinstance(it, planner.PallasRun) and it.store_swap_k:
             plan.items[i] = dataclasses.replace(it, store_swap_k=0)
             break
     else:
@@ -118,7 +118,7 @@ def test_plan_mutation_dropped_store_swap():
 def test_plan_mutation_grid_block_out_of_range():
     plan = _plan_20q()
     for i, it in enumerate(plan.items):
-        if isinstance(it, fusion.PallasRun) and it.load_swap_k:
+        if isinstance(it, planner.PallasRun) and it.load_swap_k:
             hi = it.tile_bits if it.load_swap_hi is None else it.load_swap_hi
             plan.items[i] = dataclasses.replace(it, load_swap_hi=hi + 9)
             break
@@ -129,7 +129,7 @@ def test_plan_mutation_grid_block_out_of_range():
 
 def test_plan_mutation_dense_target_outside_tile():
     op = ("matrix", 12, (), (), PG.HashableMatrix(H))
-    plan = fusion.FusePlan(items=[fusion.PallasRun(ops=(op,), tile_bits=10)])
+    plan = planner.FusePlan(items=[planner.PallasRun(ops=(op,), tile_bits=10)])
     assert "QT101" in _codes(A.error_findings(A.check_plan(plan, 16)))
     with pytest.raises(A.AnalysisError) as err:
         A.verify_plan(plan, nsv=16, emit=False)
@@ -138,15 +138,15 @@ def test_plan_mutation_dense_target_outside_tile():
 
 def test_plan_control_target_aliasing():
     op = ("matrix", 3, (3, 5), (1, 1), PG.HashableMatrix(H))
-    plan = fusion.FusePlan(items=[fusion.PallasRun(ops=(op,), tile_bits=10)])
+    plan = planner.FusePlan(items=[planner.PallasRun(ops=(op,), tile_bits=10)])
     assert "QT105" in _codes(A.error_findings(A.check_plan(plan, 16)))
 
 
 def test_plan_identity_frame_required_before_dense_item():
     # a lone load swap leaves the frame active across a FusedBlock
-    run = fusion.PallasRun(ops=(), tile_bits=10, load_swap_k=2)
-    blk = fusion.FusedBlock(qubits=(0, 1), matrix=np.eye(4))
-    plan = fusion.FusePlan(items=[run, blk])
+    run = planner.PallasRun(ops=(), tile_bits=10, load_swap_k=2)
+    blk = planner.FusedBlock(qubits=(0, 1), matrix=np.eye(4))
+    plan = planner.FusePlan(items=[run, blk])
     assert "QT102" in _codes(A.error_findings(A.check_plan(plan, 16)))
 
 
@@ -225,7 +225,7 @@ def test_lint_mergeable_rotations_qt002():
 
 
 def test_lint_constant_angles_qt003_cross_checked_with_lift_tape():
-    from quest_tpu.engine.params import lift_tape
+    from quest_tpu.params import lift_tape
 
     c = Circuit(2)
     c.rotateZ(0, 0.3)
@@ -247,8 +247,8 @@ def test_lint_no_qt003_when_params_are_lifted():
 
 
 def test_lint_malformed_event_qt004():
-    dup = fusion.GateEvent("matrix", targets=(1, 1), matrix=np.eye(4))
-    olap = fusion.GateEvent("matrix", targets=(0,), controls=(0,),
+    dup = events.GateEvent("matrix", targets=(1, 1), matrix=np.eye(4))
+    olap = events.GateEvent("matrix", targets=(0,), controls=(0,),
                             matrix=np.eye(2))
     assert "QT004" in _codes(A.lint_events([dup], "synthetic"))
     assert "QT004" in _codes(A.lint_events([olap], "synthetic"))

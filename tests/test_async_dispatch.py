@@ -312,7 +312,7 @@ def test_precompile_outcomes(monkeypatch):
         monkeypatch.setattr(Engine, "warmup",
                             lambda self: 1 / 0)
         monkeypatch.setattr(engmod.Engine, "_mode", lambda self: "vmap")
-        from quest_tpu.engine import cache as _ec
+        from quest_tpu import cache as _ec
         monkeypatch.setattr(_ec.executables(), "peek",
                             lambda key: None)
         assert pool.precompile() == []

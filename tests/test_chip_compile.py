@@ -8,8 +8,8 @@ lowering. libtpu compiles for a chip that is only DESCRIBED
 functions themselves with ``interpret=False`` at the sizes chip_smoke.py
 runs -- the fused f32 run of the 26q depth-8 plan (plain and with a folded
 frame swap), its per-shard form at the 4-chip local size, a double-float
-run at 20q, the 14q density run holding kraus1 + krausn ops, and the
-window-dot kernel -- and fail on whatever the chip's compiler would raise.
+run at 20q and the 14q density run holding kraus1 + krausn ops -- and
+fail on whatever the chip's compiler would raise.
 A compile that passes is not a chip run: nothing executes here.
 
 Mosaic compile time grows steeply with the op count of a run (a 24-op
@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from quest_tpu import fusion
+from quest_tpu import environment, fusion
 from quest_tpu.circuits import Circuit
 from quest_tpu.environment import AMP_AXIS
 from quest_tpu.ops import pallas_gates as PG
@@ -527,7 +527,7 @@ def test_sv31x4_program_holds_two_passes_a_relabeling(four_chips,
     amps = jax.ShapeDtypeStruct(
         (2, 1 << n), jnp.float32,
         sharding=NamedSharding(four_chips, P(None, AMP_AXIS)))
-    with fusion.pallas_mesh(four_chips):
+    with environment.pallas_mesh(four_chips):
         compiled = jax.jit(named_program(fused.as_fn(), fused, "circuit"),
                            donate_argnums=(0,)).lower(amps).compile()
     assert compiled.as_text().count(
@@ -684,25 +684,6 @@ def test_grown_frame_run_15q_whole(one_chip):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
-def test_window_dot_26q(one_chip):
-    """The dense planner's window GEMM kernel: a 5-qubit window on the top
-    qubits of a 26q state (fusion._apply_dense_block's hi-window shape)."""
-    n, lo, hi = 26, 21, 25
-    assert PG.window_dot_supported(n, lo, hi)
-    d = 1 << (hi - lo + 1)
-
-    def run(x4, m):   # the kernel's own (2, A, D, B) view: see _compile_fused
-        out = PG._window_dot(x4.reshape(2, -1), m, n=n, lo=lo, hi=hi,
-                             conj=False, interpret=False)
-        return out.reshape(x4.shape)
-
-    x4 = jax.ShapeDtypeStruct((2, (1 << n) >> (hi + 1), d, 1 << lo),
-                              jnp.float32, sharding=one_chip)
-    m = jax.ShapeDtypeStruct((2, d, d), jnp.float32, sharding=one_chip)
-    compiled = jax.jit(run).lower(x4, m).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def _dot_generals(jaxpr, state, found):
     """``dot_general`` equations of ``jaxpr`` and of every jaxpr nested in
     it, told apart by what they touch: an ``application`` writes a
@@ -747,7 +728,6 @@ def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
     import sys
 
     import quest_tpu as qt
-    from quest_tpu import fusion as F
     from quest_tpu.engine import Engine, P as Param
     from quest_tpu.gradients import apply_hamiltonian
     from quest_tpu.parallel import scheduler as _dist
@@ -796,7 +776,7 @@ def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
         args += [jax.ShapeDtypeStruct((lanes, len(cols)), jnp.float32,
                                       sharding=one_chip)
                  for _, cols in grad._packs]
-        with _dist.explicit_mesh(None), F.pallas_mesh(None):
+        with _dist.explicit_mesh(None), environment.pallas_mesh(None):
             lowered = jitted.lower(*args)
             compiled = lowered.compile()
     finally:

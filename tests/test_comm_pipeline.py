@@ -43,7 +43,7 @@ import pytest
 
 import jax
 import quest_tpu as qt
-from quest_tpu import fusion, telemetry
+from quest_tpu import fusion, planner, telemetry
 from quest_tpu.analysis import commcheck as C
 from quest_tpu.analysis.plancheck import check_circuit_comm
 from quest_tpu.circuits import Circuit
@@ -443,7 +443,7 @@ def test_fused_comm_pipeline_stamps_and_roundtrips():
                  comm_pipeline=2)
     p = fusion.plan_from_tape(tuple(fz._tape))
     runs = [i for i in p.items
-            if isinstance(i, (fusion.PallasRun, fusion.FrameSwap))]
+            if isinstance(i, (planner.PallasRun, planner.FrameSwap))]
     assert runs, "sharded pallas plan should carry PallasRun items"
     assert all(i.comm_pipeline == 2 for i in runs)
 
@@ -454,4 +454,4 @@ def test_fused_comm_pipeline_stamps_and_roundtrips():
     bare = fusion.plan_from_tape(tuple(
         c.fused(max_qubits=5, pallas=True, shard_devices=8)._tape))
     assert all(i.comm_pipeline is None for i in bare.items
-               if isinstance(i, (fusion.PallasRun, fusion.FrameSwap)))
+               if isinstance(i, (planner.PallasRun, planner.FrameSwap)))

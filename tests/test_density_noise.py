@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 import quest_tpu as qt
 from quest_tpu import channels, fusion, telemetry
+from quest_tpu import planner as planner_mod
 from quest_tpu.circuits import Circuit
 from quest_tpu.ops import density as DN
 from quest_tpu.ops import pallas_df as DF
@@ -63,7 +64,7 @@ def _noisy(n, depth=2, rec=None):
 def _plan_at(circ, tile_bits, dtype=np.float32, **kw):
     """(plan, the fused circuit) of a density tape at a forced tile."""
     n = circ.num_qubits
-    plan = fusion.plan(tuple(circ._tape), n, np.dtype(dtype), max_qubits=5,
+    plan = planner_mod.plan(tuple(circ._tape), n, np.dtype(dtype), max_qubits=5,
                        pallas_tile_bits=tile_bits, is_density=True, **kw)
     fz = Circuit(n, is_density_matrix=True)
     fz._tape = fusion.as_tape(plan)
@@ -71,8 +72,8 @@ def _plan_at(circ, tile_bits, dtype=np.float32, **kw):
 
 
 def _only_runs(plan):
-    assert all(isinstance(i, fusion.PallasRun) for i in plan.items), [
-        i for i in plan.items if not isinstance(i, fusion.PallasRun)]
+    assert all(isinstance(i, planner_mod.PallasRun) for i in plan.items), [
+        i for i in plan.items if not isinstance(i, planner_mod.PallasRun)]
     assert plan.num_barriers == 0
     return plan.items
 
@@ -92,7 +93,7 @@ def test_the_noisy_tape_plans_as_fused_runs_alone(n):
     register = shape_register(2 * n, np.float32)
     for run in runs:
         assert run.matched, run
-        assert run.load_swap_k <= fusion._fold_width(run.tile_bits)
+        assert run.load_swap_k <= planner_mod._fold_width(run.tile_bits)
         route = fusion._route(register, run)
         assert (route.kind, route.reason, route.unfolded) == ("local", None, 0)
         assert route.fold_load == route.fold_store == bool(run.load_swap_k)
@@ -196,23 +197,23 @@ def test_the_cells_plans_are_the_parent_s_item_for_item(case, monkeypatch):
     assert [e["frames_grown"] for e in _plan_events()] == [0]
     if case == "density14.block":
         kinds = [op[0] for r in pallas_runs(fz) for op in r.ops
-                 if op[0] in fusion._CHANNEL_OPS]
+                 if op[0] in planner_mod._CHANNEL_OPS]
         assert sorted(kinds) == ["depol", "depol", "kraus1", "krausn"]
 
 
 def test_the_served_cells_dense_plan_is_untouched():
     """``ansatz20``'s tape through the planner the Engine uses (no Pallas
     plan): blocks only, and the counters of a dense plan."""
-    from quest_tpu.engine.params import Param
+    from quest_tpu.params import Param
     from quest_tpu.ops.apply import DENSE_WINDOW_QUBITS
 
     circ = Circuit(20)
     _builder("serving_ansatz").build(circ, num_qubits=20, depth=4,
                                      angle=Param)
-    plan = fusion.plan(tuple(circ._tape), 20, np.dtype("float32"),
+    plan = planner_mod.plan(tuple(circ._tape), 20, np.dtype("float32"),
                        max_qubits=DENSE_WINDOW_QUBITS)
     assert plan.num_barriers == 0 and len(plan.items) == 30
-    assert not any(isinstance(i, fusion.PallasRun) for i in plan.items)
+    assert not any(isinstance(i, planner_mod.PallasRun) for i in plan.items)
 
 
 def test_the_plan_event_counts_channel_terms():
@@ -485,9 +486,9 @@ def test_the_cells_tape_plans_in_six_passes_where_it_took_twelve():
 
 def test_the_schedule_with_frames_fixed_is_the_parent_s(monkeypatch):
     """With growth refused the same tape plans as on the parent commit:
-    twelve runs. The fixed schedule is what :func:`fusion._plan_pallas`
+    twelve runs. The fixed schedule is what :func:`planner._plan_pallas`
     keeps among its candidates."""
-    monkeypatch.setattr(fusion._FramePlanner, "_grown", lambda *a: None)
+    monkeypatch.setattr(planner_mod._FramePlanner, "_grown", lambda *a: None)
     fz = _noisy(15).fused(max_qubits=5, pallas=True, dtype=np.float32)
     runs = _only_runs(fusion.plan_from_tape(fz._tape))
     assert [f[:3] for f in _frames(runs)] == [
@@ -529,18 +530,18 @@ def _random_tape(n, density, seed, gates=48):
 
 
 def _plan_random(circ, tile_bits):
-    return fusion.plan(tuple(circ._tape), circ.num_qubits, np.dtype("float32"),
+    return planner_mod.plan(tuple(circ._tape), circ.num_qubits, np.dtype("float32"),
                        max_qubits=5, pallas_tile_bits=tile_bits,
                        is_density=circ.is_density_matrix)
 
 
 def _watch_list_scheduler(monkeypatch):
-    """Every list scheduler with growth on, as :func:`fusion._plan_pallas`
+    """Every list scheduler with growth on, as :func:`planner._plan_pallas`
     drives it: the ops in the order they arrive and the pending runs as
     they stand at each flush (the two-slot scheduler has its own ``add`` and
     ``flush`` and is not seen)."""
     seen = {}
-    add, flush = fusion._FramePlanner.add, fusion._FramePlanner.flush
+    add, flush = planner_mod._FramePlanner.add, planner_mod._FramePlanner.flush
 
     def record(planner):
         return seen.setdefault(id(planner), dict(
@@ -554,8 +555,8 @@ def _watch_list_scheduler(monkeypatch):
         record(self)["flushed"].append([(f, list(ops)) for f, ops in self.runs])
         flush(self)
 
-    monkeypatch.setattr(fusion._FramePlanner, "add", spy_add)
-    monkeypatch.setattr(fusion._FramePlanner, "flush", spy_flush)
+    monkeypatch.setattr(planner_mod._FramePlanner, "add", spy_add)
+    monkeypatch.setattr(planner_mod._FramePlanner, "flush", spy_flush)
     return seen
 
 
@@ -605,12 +606,12 @@ def test_a_grown_schedule_keeps_every_op_in_a_frame_and_in_order(
                         assert where[id(a)] <= where[id(b)], (seed, a, b)
         assert placed == len(rec["arrived"])
         for run in plan.items:
-            if isinstance(run, fusion.PallasRun):
+            if isinstance(run, planner_mod.PallasRun):
                 assert run.matched
                 assert all(q < run.tile_bits for op in run.ops
                            for q in PG.op_dense_targets(op))
         with monkeypatch.context() as patch:
-            patch.setattr(fusion._FramePlanner, "_grown", lambda *a: None)
+            patch.setattr(planner_mod._FramePlanner, "_grown", lambda *a: None)
             fixed = _plan_random(circ, tile_bits)
         assert fixed.frames_grown == 0
         assert len(plan.items) <= len(fixed.items), seed
@@ -627,7 +628,7 @@ def test_growth_engages_on_random_tapes(density, n, tile_bits, seed,
     items than with frames fixed at birth, and the plan says so."""
     circ = _random_tape(n, density, seed)
     plan = _plan_random(circ, tile_bits)
-    monkeypatch.setattr(fusion._FramePlanner, "_grown", lambda *a: None)
+    monkeypatch.setattr(planner_mod._FramePlanner, "_grown", lambda *a: None)
     fixed = _plan_random(circ, tile_bits)
     assert plan.frames_grown > 0 and len(plan.items) < len(fixed.items)
 
@@ -638,14 +639,14 @@ def test_a_block_never_grows_across_the_shard_boundary():
     across, one above it stays above, and the widths are those of
     ``width``: what folds below the boundary, the planner's ``k`` for a
     collective."""
-    planner = fusion._FramePlanner(fusion.FusePlan(), 19, 12, 34,
+    planner = planner_mod._FramePlanner(planner_mod.FusePlan(), 19, 12, 34,
                                    boundary=32, n_exec=32)
 
     def depol(q):
-        return fusion._POp("depol", (q, q + 17), (), (), 0.1, False)
+        return planner_mod._POp("depol", (q, q + 17), (), (), 0.1, False)
 
     def column(bit):     # a gate's shadow: one dense target, a column bit
-        return fusion._POp("matrix", (bit,), (), (), np.eye(2)[::-1], False)
+        return planner_mod._POp("matrix", (bit,), (), (), np.eye(2)[::-1], False)
 
     assert planner._grown((27, 1), [depol(10)], depol(13)) == (27, 4)
     assert planner._grown((27, 4), [depol(10)], column(31)) == (27, 5)
@@ -653,7 +654,7 @@ def test_a_block_never_grows_across_the_shard_boundary():
     assert planner._grown((32, 1), [column(32)], column(33)) == (32, 2)
     assert planner._grown((32, 1), [column(32)], column(31)) is None
     # the same block on one device, where nothing is sharded, may take it
-    one = fusion._FramePlanner(fusion.FusePlan(), 19, 12, 34)
+    one = planner_mod._FramePlanner(planner_mod.FusePlan(), 19, 12, 34)
     assert one._grown((27, 5), [depol(10)], column(32)) == (27, 6)
     # an identity run and a narrowed-tile frame do not grow; nor a block
     # whose wider form would displace a target the run holds (row 14) or

@@ -22,7 +22,7 @@ import pytest
 import jax
 
 import quest_tpu as qt
-from quest_tpu import fusion, telemetry
+from quest_tpu import fusion, planner, telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.ops import pallas_gates as PG
 from quest_tpu.ops.pallas_df import _DF_ENV, DF_MAX_OPS, DF_SUBLANES
@@ -69,10 +69,10 @@ def _flat(x):
         return ("matrix", x.arr.shape, x.arr.tobytes().hex())
     if isinstance(x, np.ndarray):
         return ("array", x.shape, str(x.dtype), x.tobytes().hex())
-    if isinstance(x, fusion.PallasRun):
+    if isinstance(x, planner.PallasRun):
         return ("run", x.tile_bits, x.load_swap_k, x.load_swap_hi,
                 x.store_swap_k, x.store_swap_hi, _flat(x.ops))
-    if isinstance(x, fusion.FrameSwap):
+    if isinstance(x, planner.FrameSwap):
         return ("swap", x.tile_bits, x.k, x.hi)
     if isinstance(x, (tuple, list)):
         return tuple(_flat(v) for v in x)
@@ -99,8 +99,8 @@ def test_a_df_plan_is_cut_where_a_df_kernel_ends(layers, df_route,
     its order, each piece under its run's frame."""
     circ = _circuit(layers, n)
     cut = pallas_runs(circ.fused(max_qubits=5, pallas=True))
-    monkeypatch.setattr(fusion, "_run_op_cap",
-                        lambda dtype, sharded: fusion._RUN_OP_CAP)
+    monkeypatch.setattr(planner, "_run_op_cap",
+                        lambda dtype, sharded: planner._RUN_OP_CAP)
     whole = pallas_runs(circ.fused(max_qubits=5, pallas=True))
     assert [len(r.ops) for r in cut] == pieces
     assert sum(-(-len(r.ops) // DF_MAX_OPS) for r in whole) == len(cut)
@@ -124,7 +124,7 @@ def test_the_26q_df_plan_is_the_cell_s(layers, df_route):
     runs = pallas_runs(_circuit(layers, 26).fused(max_qubits=5, pallas=True))
     frames = [(r.load_swap_k, r.load_swap_hi) for r in runs]
     assert frames == [(0, None)] * 7 + [(7, 17)] * 3 + [(2, 24), (0, None)]
-    assert fusion._fold_width(17) == 7
+    assert planner._fold_width(17) == 7
     assert sum(len(r.ops) for r in runs) == 79
 
 
@@ -133,13 +133,13 @@ def test_the_cap_is_keyed_on_the_route(df_route, monkeypatch):
     on the df route, ``_RUN_OP_CAP`` for every float32 plan, for an f64 plan
     off the df route, and for a SHARDED df plan (a piece that carried a
     collective frame in and out would pay it twice)."""
-    cap = fusion._run_op_cap
+    cap = planner._run_op_cap
     assert cap(np.float64, False) == DF_MAX_OPS
-    assert cap(np.float64, True) == fusion._RUN_OP_CAP
+    assert cap(np.float64, True) == planner._RUN_OP_CAP
     assert cap(np.float32, False) == cap(np.float32, True) \
-        == fusion._RUN_OP_CAP
+        == planner._RUN_OP_CAP
     monkeypatch.delenv(_DF_ENV)
-    assert cap(np.float64, False) == fusion._RUN_OP_CAP
+    assert cap(np.float64, False) == planner._RUN_OP_CAP
 
 
 def test_a_sharded_df_plan_keeps_its_runs_whole(layers, df_route):
@@ -153,7 +153,7 @@ def test_a_sharded_df_plan_keeps_its_runs_whole(layers, df_route):
     circ.fused(max_qubits=5, pallas=True, shard_devices=4)
     event = [e for e in telemetry.events() if e.get("name") == "fusion.plan"
              and e.get("mode") == "pallas_sharded"][-1]
-    assert event["df"] and event["run_op_cap"] == fusion._RUN_OP_CAP
+    assert event["df"] and event["run_op_cap"] == planner._RUN_OP_CAP
     assert event["df_passes"] == sum(-(-len(r.ops) // DF_MAX_OPS)
                                      for r in runs) > event["pallas_runs"]
 
@@ -244,7 +244,7 @@ def _swap_blocks(psi, n, lo1, lo2, k):
 #: X on physical bit 9 under a ``k=2`` frame at tile 10, which the df tile of
 #: a 14-qubit register (14 bits) does not fold: X on qubit 11, between two
 #: explicit relabelings
-_X_IN_FRAME = fusion.PallasRun(
+_X_IN_FRAME = planner.PallasRun(
     (("matrix", 9, (), (), PG.HashableMatrix(
         np.array([[0, 1], [1, 0]], dtype=complex))),),
     10, load_swap_k=2, store_swap_k=2)
@@ -270,7 +270,7 @@ class _Break(NamedTuple):
 _BREAKS = {
     "unbroken": _Break(),
     "frame_swap": _Break(
-        ((fusion._apply_frame_swap, (fusion.FrameSwap(10, 2),)),),
+        ((fusion._apply_frame_swap, (planner.FrameSwap(10, 2),)),),
         lambda psi, n: _swap_blocks(psi, n, 8, 10, 2), chains=2),
     "explicit_swap": _Break(
         ((fusion._apply_pallas_run, (_X_IN_FRAME,)),),

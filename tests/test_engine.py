@@ -27,8 +27,8 @@ import quest_tpu as qt
 from quest_tpu import telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.engine import Engine, LRUCache, P, Param
-from quest_tpu.engine import cache as ecache
-from quest_tpu.engine.params import (_pack_rows, bind, lift_tape,
+from quest_tpu import cache as ecache
+from quest_tpu.params import (_pack_rows, bind, lift_tape,
                                      materialize_tape)
 from quest_tpu.validation import QuESTError
 

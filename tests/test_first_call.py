@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 import quest_tpu as qt
-from quest_tpu import fusion, telemetry
+from quest_tpu import planner, telemetry
 from quest_tpu.circuits import Circuit, named_program
 from quest_tpu.engine import Engine, P
 from quest_tpu.ops import pallas_gates as PG
@@ -106,7 +106,7 @@ def test_compile_record_holds_the_fold_and_names_the_pallas_call():
     n = 9
     fz = _two_run_plan(39002, n)
     run = fz._tape[0][1][0]
-    assert isinstance(run, fusion.PallasRun)
+    assert isinstance(run, planner.PallasRun)
     amps = jax.numpy.zeros((2, 1 << n), qt.precision.real_dtype())
     telemetry.reset()
     PG.fused_local_run(amps + 0, n=n, ops=run.ops, sublanes=1 << (
@@ -127,28 +127,6 @@ def test_compile_record_holds_the_fold_and_names_the_pallas_call():
     PG.fused_local_run(amps, n=n, ops=run.ops, sublanes=1 << (
         run.tile_bits - PG.LANE_BITS))
     assert len(_events("pallas.compile")) == 1
-    telemetry.reset()
-
-
-def test_window_dot_as_a_kernel_of_its_own_gets_the_record():
-    n, lo = 10, 7
-    rng = np.random.default_rng(39003)
-    m = np.linalg.qr(rng.normal(size=(4, 4))
-                     + 1j * rng.normal(size=(4, 4)))[0]
-    dt = qt.precision.real_dtype()
-    mp = jax.numpy.asarray(np.stack([m.real, m.imag]), dt)
-    amps = jax.numpy.ones((2, 1 << n), dt)
-    telemetry.reset()
-    PG.window_dot(amps + 0, mp, n=n, lo=lo, hi=lo + 1, interpret=True)
-    PG.window_dot(amps + 0, mp, n=n, lo=lo, hi=lo + 1, interpret=True)
-    [ev] = _events("pallas.compile")
-    assert ev["kernel"] == f"qt_window_dot_f{8 * dt.itemsize}_lo7_hi8"
-    assert ev["kernel"] in _pallas_call_names(
-        lambda a: PG._window_dot_impl(a, mp, n=n, lo=lo, hi=lo + 1,
-                                      conj=False, interpret=True), amps)
-    assert ev["kind"] == "window_dot" and ev["fold_s"] == 0.0
-    assert list(telemetry.snapshot("mosaic_compile_seconds")[
-        "histograms"]) == ["mosaic_compile_seconds{kind=window_dot}"]
     telemetry.reset()
 
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import quest_tpu as qt
-from quest_tpu import fusion
+from quest_tpu import capture, environment, fusion, planner
 from quest_tpu.circuits import Circuit
 from quest_tpu.ops import init as ops_init
 # the df route's switch off the TPU, by its own name: the surface audit
@@ -108,7 +108,7 @@ def test_plan_counts_and_diagonal_blocks():
     circ.rotateZ(1, 0.5)
     circ.controlledPhaseShift(0, 1, 0.3)   # stays diagonal
     circ.hadamard(2)                        # dense block
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=2)
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=2)
     assert p.num_fused_gates == 4 and p.num_barriers == 0
     kinds = [type(it).__name__ for it in p.items]
     assert kinds == ["DiagBlock", "FusedBlock"]
@@ -122,7 +122,7 @@ def test_wide_diagonal_fuses_wide_dense_passes_through():
     circ.multiQubitNot([0, n - 1])             # dense span 6 > max: barrier
     circ.hadamard(0)
     fz = circ.fused(max_qubits=3)
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=3)
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=3)
     assert p.num_barriers == 1
     mk = lambda: ops_init.init_debug(1 << n, real_dtype())
     np.testing.assert_allclose(np.asarray(fz.as_fn()(mk())),
@@ -135,9 +135,9 @@ def test_dense_blocks_are_contiguous_windows():
     circ.hadamard(1)
     circ.controlledNot(1, 3)                   # window 1..3
     circ.controlledPhaseFlip(0, 7)             # scattered but diagonal
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=4)
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=4)
     for it in p.items:
-        if isinstance(it, fusion.FusedBlock):
+        if isinstance(it, planner.FusedBlock):
             assert it.qubits == tuple(range(it.qubits[0], it.qubits[-1] + 1))
     mk = lambda: ops_init.init_debug(1 << n, real_dtype())
     fz = circ.fused(max_qubits=4)
@@ -202,14 +202,14 @@ def test_tape_transpose_stats_matches_plan_stats():
         g, _ = np.linalg.qr(rng.randn(2, 2) + 1j * rng.randn(2, 2))
         circ.unitary(q, g)
     n_local = n - (ndev.bit_length() - 1)
-    p = fusion.plan_pallas_sharded(tuple(circ._tape), n, real_dtype(), 5,
+    p = planner.plan_pallas_sharded(tuple(circ._tape), n, real_dtype(), 5,
                                    local_qubits(n_local), n_local)
     tape = fusion.as_tape(p)
     for kwargs in ({}, {"nsv": n, "num_slices": 2}):
-        st_plan = fusion.transpose_stats(p, n_local, **kwargs)
+        st_plan = planner.transpose_stats(p, n_local, **kwargs)
         st_tape = fusion.tape_transpose_stats(tape, n_local, **kwargs)
         assert st_plan == st_tape, (st_plan, st_tape)
-    assert fusion.transpose_stats(p, n_local)["collective_transposes"] > 0
+    assert planner.transpose_stats(p, n_local)["collective_transposes"] > 0
 
 
 def test_synth_frame_boundary_anchors():
@@ -220,7 +220,7 @@ def test_synth_frame_boundary_anchors():
     localise both sides, so the collective frame is forced)."""
     import numpy as np
 
-    from quest_tpu.fusion import FusePlan, _FramePlanner, _POp
+    from quest_tpu.planner import FusePlan, _FramePlanner, _POp
 
     # 17q-density-like geometry: tile 19 bits, frame width k=12, 34
     # flattened qubits, shard boundary 30
@@ -251,7 +251,7 @@ def test_synth_frame_boundary_anchors():
 # ---------------------------------------------------------------------------
 
 def _family(name, circ, th):
-    """One member group of the liftable family (engine.params._LIFTABLE)
+    """One member group of the liftable family (params._LIFTABLE)
     on 4 qubits, its angles from ``th`` (Params, or that request's floats)."""
     circ.hadamard(0)
     circ.controlledNot(0, 3)
@@ -314,7 +314,7 @@ def test_param_dense_plan_matches_constant_raw_tape(family, density,
     plan = par.fused(max_qubits=4, dtype=dtype)
     assert telemetry.counter_value("fusion_param_barriers_total",
                                    mode="dense") == b0
-    with_params = sum(fusion._entry_has_params(a, k) for _, a, k in par._tape)
+    with_params = sum(capture._entry_has_params(a, k) for _, a, k in par._tape)
     assert telemetry.counter_value("fusion_param_fused_total",
                                    mode="dense") == f0 + with_params
     assert all(f.__name__ in ("_apply_dense_block", "_apply_gate_diag",
@@ -333,7 +333,7 @@ def test_param_dense_plan_structure_is_value_free():
     its host-materialised constant tape at ANY values, and planning twice
     gives the same structure."""
     from quest_tpu.engine import P
-    from quest_tpu.engine.params import bind, materialize_tape
+    from quest_tpu.params import bind, materialize_tape
 
     par = Circuit(4)
     _family("rotations", par, P)
@@ -369,7 +369,7 @@ def test_fusion_barrier_and_channel_still_split_param_plan():
     circ.rotateY(1, P("c"))
     circ.mixDephasing(0, 0.1)
     circ.rotateX(2, P("d"))
-    p = fusion.plan(tuple(circ._tape), 3, real_dtype(), max_qubits=3,
+    p = planner.plan(tuple(circ._tape), 3, real_dtype(), max_qubits=3,
                     is_density=True)
     kinds = [it[0].__name__ if isinstance(it, tuple) else type(it).__name__
              for it in p.items]
@@ -390,7 +390,7 @@ def test_low_window_guard_keeps_gemm_bounded():
     circ = Circuit(n)
     for q in range(n):
         circ.hadamard(q)
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=7)
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=7)
     for it in p.items:
         assert it.qubits[0] >= _MIN_MINOR or \
             it.qubits[-1] < MAX_LOW_WINDOW_TOP
@@ -409,7 +409,7 @@ def test_deferred_diagonal_leaves_a_static_diagonal_block_static():
     circ.rotateZ(0, P("a"))
     circ.rotateX(0, P("b"))
     circ.rotateZ(1, P("c"))
-    p = fusion.plan(tuple(circ._tape), 6, real_dtype(), max_qubits=3)
+    p = planner.plan(tuple(circ._tape), 6, real_dtype(), max_qubits=3)
     assert [type(it).__name__ for it in p.items] == ["DiagBlock",
                                                      "FusedBlock"]
     assert p.items[0].factors is None and p.items[0].qubits == (0, 5)
@@ -418,7 +418,7 @@ def test_deferred_diagonal_leaves_a_static_diagonal_block_static():
     only = Circuit(6)
     only.rotateZ(0, P("a"))
     only.rotateZ(5, P("b"))
-    q = fusion.plan(tuple(only._tape), 6, real_dtype(), max_qubits=3)
+    q = planner.plan(tuple(only._tape), 6, real_dtype(), max_qubits=3)
     assert len(q.items) == 1 and len(q.items[0].factors) == 2
 
 
@@ -437,7 +437,7 @@ def test_fused_run_fingerprints_by_content():
         c.rotateZ(3, theta)
         c.controlledNot(0, 9)
         fz = c.fused(max_qubits=4, pallas=True)
-        assert all(isinstance(a[0], fusion.PallasRun) for _, a, _ in fz._tape)
+        assert all(isinstance(a[0], planner.PallasRun) for _, a, _ in fz._tape)
         return fz
 
     a, b, other = fused(0.25), fused(0.25), fused(0.26)
@@ -467,7 +467,7 @@ def _canonical(mesh):
 def _x_run(target, tile_bits, **swaps):
     from quest_tpu.ops.pallas_gates import HashableMatrix
     x = HashableMatrix(np.array([[0, 1], [1, 0]], dtype=complex))
-    return fusion.PallasRun((("matrix", target, (), (), x),), tile_bits,
+    return planner.PallasRun((("matrix", target, (), (), x),), tile_bits,
                             **swaps)
 
 
@@ -577,7 +577,7 @@ def test_route_table(monkeypatch, dtype, df, n, ndev, seen, run, want):
     if seen == "traced":
         # what Circuit.run sets up: the tracer hides the sharding, the
         # ambient mesh carries it
-        with fusion.pallas_mesh(mesh):
+        with environment.pallas_mesh(mesh):
             jax.eval_shape(decide, shape_register(n, dtype).amps)
     else:
         sharding = _canonical(mesh) if mesh is not None else None
@@ -705,7 +705,7 @@ def test_kernel_routes_count_what_the_plan_did_not_price(monkeypatch):
     q = qt.createQureg(n, one)
     qt.initClassicalState(q, 0)
     telemetry.reset()
-    fusion._apply_pallas_run(q, fusion.PallasRun(ops, n))
+    fusion._apply_pallas_run(q, planner.PallasRun(ops, n))
     assert _fallbacks() == {
         "engine_fallback_total{reason=df_max_ops_split}": 2.0}
     # 17 X gates over 12 qubits: qubits 0..4 flipped twice, 5..11 once

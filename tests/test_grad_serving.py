@@ -41,7 +41,7 @@ import quest_tpu as qt
 from quest_tpu import telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.engine import Engine, P
-from quest_tpu.engine.params import _pack_rows, _unpack_columns, bind
+from quest_tpu.params import _pack_rows, _unpack_columns, bind
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "benchmark")

@@ -35,7 +35,7 @@ import numpy as np
 import pytest
 
 import quest_tpu as qt
-from quest_tpu import fusion, telemetry
+from quest_tpu import fusion, planner, telemetry
 from jax.sharding import AbstractMesh
 from quest_tpu.analysis.plancheck import check_circuit_comm, check_schedule
 from quest_tpu.circuits import Circuit
@@ -306,7 +306,7 @@ def test_fused_comm_pipeline_dcn_stamps_and_roundtrips():
     fz = _fused_12q(comm_pipeline=4, comm_pipeline_dcn=2)
     plan = fusion.plan_from_tape(tuple(fz._tape))
     stamped = [i for i in plan.items
-               if isinstance(i, (fusion.PallasRun, fusion.FrameSwap))]
+               if isinstance(i, (planner.PallasRun, planner.FrameSwap))]
     assert stamped, "sharded pallas plan should carry PallasRun items"
     assert all(i.comm_pipeline == 4 and i.comm_pipeline_dcn == 2
                for i in stamped)

@@ -16,7 +16,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from quest_tpu import fusion, telemetry
+from quest_tpu import fusion, planner, telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.ops import pallas_gates as PG
 
@@ -76,7 +76,7 @@ def test_every_relabeling_of_a_one_device_plan_folds(bench, n):
     assert len(runs) >= 3
     for run in runs:
         assert run.matched, run
-        assert run.load_swap_k <= fusion._fold_width(run.tile_bits)
+        assert run.load_swap_k <= planner._fold_width(run.tile_bits)
         route = fusion._route(register, run)
         assert route.kind == "local" and route.reason is None, route
         assert route.unfolded == 0
@@ -117,13 +117,13 @@ def test_the_accepted_cells_plans_did_not_move(bench):
     # the served plan is dense: no fused run to frame
     served = Circuit(20)
     names = bench["serving_ansatz"].param_names(num_qubits=20, depth=4)
-    from quest_tpu.engine.params import Param
+    from quest_tpu.params import Param
     bench["serving_ansatz"].build(served, num_qubits=20, depth=4,
                                   angle=lambda name: Param(name))
     assert len(names) == 160
-    plan = fusion.plan(tuple(served._tape), 20, np.dtype("float32"),
+    plan = planner.plan(tuple(served._tape), 20, np.dtype("float32"),
                        max_qubits=7)
-    assert not any(isinstance(i, fusion.PallasRun) for i in plan.items)
+    assert not any(isinstance(i, planner.PallasRun) for i in plan.items)
 
 
 def test_a_run_split_by_the_op_cap_leaves_its_frame_in_every_piece():
@@ -133,13 +133,13 @@ def test_a_run_split_by_the_op_cap_leaves_its_frame_in_every_piece():
     n, tile_bits = 24, 19
     circ = Circuit(n)
     rng = np.random.RandomState(3)
-    for _ in range(fusion._RUN_OP_CAP + 8):
+    for _ in range(planner._RUN_OP_CAP + 8):
         g, _ = np.linalg.qr(rng.randn(2, 2) + 1j * rng.randn(2, 2))
         circ.unitary(n - 1, g)      # a grid-bit target: frame (19, 5) only
         circ.unitary(int(rng.randint(0, 7)), g)
-    plan = fusion.plan(tuple(circ._tape), n, np.dtype("float32"),
+    plan = planner.plan(tuple(circ._tape), n, np.dtype("float32"),
                        max_qubits=1, pallas_tile_bits=tile_bits)
-    framed = [r for r in plan.items if isinstance(r, fusion.PallasRun)
+    framed = [r for r in plan.items if isinstance(r, planner.PallasRun)
               and r.load_swap_k]
     assert len(framed) >= 2
     assert all(r.matched and r.store_swap_k == r.load_swap_k for r in framed)
@@ -228,7 +228,7 @@ def _small_plan(bench, tile_bits):
     circ = Circuit(N_SMALL)
     bench["random_layers"].build(circ, num_qubits=N_SMALL, depth=2,
                                  circuit_seed=2026)
-    plan = fusion.plan(tuple(circ._tape), N_SMALL, np.dtype("float32"),
+    plan = planner.plan(tuple(circ._tape), N_SMALL, np.dtype("float32"),
                        max_qubits=5, pallas_tile_bits=tile_bits)
     fz = Circuit(N_SMALL)
     fz._tape = fusion.as_tape(plan)
@@ -252,8 +252,8 @@ def test_both_frame_kinds_in_place_agree_with_the_plain_references(
     circ, fz = _small_plan(bench, small_tile)
     runs = pallas_runs(fz)
     widths = [(r.load_swap_k, r.load_swap_hi) for r in runs if r.load_swap_k]
-    assert (fusion._fold_width(small_tile), small_tile) in widths
-    assert any(k < fusion._fold_width(small_tile) and hi > small_tile
+    assert (planner._fold_width(small_tile), small_tile) in widths
+    assert any(k < planner._fold_width(small_tile) and hi > small_tile
                for k, hi in widths), widths
     register = shape_register(N_SMALL, np.float32)
     for run in runs:

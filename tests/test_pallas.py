@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 import quest_tpu as qt
-from quest_tpu import fusion
+from quest_tpu import fusion, planner
 from quest_tpu.circuits import Circuit
 from quest_tpu.ops import init as ops_init
 from quest_tpu.ops import pallas_gates as PG
@@ -476,7 +476,6 @@ def test_krausn_signed_terms_kernel_matches_engine():
     kernel op directly."""
     import jax.numpy as jnp
 
-    from quest_tpu import fusion
     from quest_tpu.ops import cplx
     from quest_tpu.ops import apply as K
     from quest_tpu.ops.density import _acc_kraus_term
@@ -512,7 +511,7 @@ def test_density_pallas_with_frame_swaps_matches_oracle():
     n = 6  # flattened: 12 qubits
     circ = Circuit(n, is_density_matrix=True)
     _random_layers(circ, n, depth=2, seed=7)
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=4,
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=4,
                     pallas_tile_bits=PG.local_qubits(12, sublanes=4),
                     is_density=True)
     fz = Circuit(n, is_density_matrix=True)
@@ -543,12 +542,12 @@ def test_plan_reframes_high_qubit_dense_gates():
     circ.hadamard(0)
     circ.hadamard(n - 1)   # grid-bit target: needs frame B
     circ.hadamard(1)
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=3,
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=3,
                     pallas_tile_bits=tile_bits)
     names = [type(it).__name__ for it in p.items]
     assert "FusedBlock" not in names
     assert "FrameSwap" not in names
-    runs = [it for it in p.items if isinstance(it, fusion.PallasRun)]
+    runs = [it for it in p.items if isinstance(it, planner.PallasRun)]
     assert len(runs) == 2
     # frame switches fold into the runs: enter frame B on the second run's
     # load, return to identity on its store
@@ -663,7 +662,7 @@ def test_folded_plan_agrees_end_to_end():
     circ = Circuit(n)
     _random_layers(circ, n, depth=3, seed=4)
     # small tile (sublanes=4) so the register has grid bits -> frame swaps
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=5,
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=5,
                     pallas_tile_bits=PG.local_qubits(n, sublanes=4))
     fz = Circuit(n)
     fz._tape = fusion.as_tape(p)
@@ -757,7 +756,6 @@ def test_multi_frame_plan_covers_wide_register():
     planner tiles the grid bits into MULTIPLE frames -- every qubit is
     in-tile in some frame and no dense gate falls out as a window block.
     Replay must match the plain engine."""
-    from quest_tpu import fusion
 
     n = 13
     tb = 9  # forced-small tile: frames = identity, (9, 2), (11, 2)
@@ -768,10 +766,10 @@ def test_multi_frame_plan_covers_wide_register():
         circ.unitary(q, g)
     circ.controlledNot(12, 3)
     circ.controlledNot(4, 10)
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), 5,
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), 5,
                     pallas_tile_bits=tb)
-    runs = [i for i in p.items if isinstance(i, fusion.PallasRun)]
-    assert runs and all(isinstance(i, (fusion.PallasRun, fusion.FrameSwap))
+    runs = [i for i in p.items if isinstance(i, planner.PallasRun)]
+    assert runs and all(isinstance(i, (planner.PallasRun, planner.FrameSwap))
                         for i in p.items)
     his = {r.load_swap_hi for r in runs if r.load_swap_k}
     assert 11 in his, f"no run entered the second grid-block frame: {his}"
@@ -790,7 +788,6 @@ def test_sharded_multi_frame_collective_transposes():
     exchanges (QuEST_cpu_distributed.c:1526-1568)."""
     import jax
 
-    from quest_tpu import fusion
 
     if len(jax.devices()) < 8:
         pytest.skip("needs the multi-device CPU mesh")
@@ -820,30 +817,6 @@ def test_sharded_multi_frame_collective_transposes():
     assert_amps_close(np.asarray(qureg.amps), np.asarray(ref.amps))
 
 
-def test_window_dot_matches_engine():
-    """The Pallas window-dot (interpret mode here) vs the einsum engine."""
-    from quest_tpu.ops import apply as K
-    from quest_tpu.ops import cplx
-
-    rng = np.random.default_rng(2)
-    n = 12
-    m = rng.normal(size=(8, 8)) + 1j * rng.normal(size=(8, 8))
-    q_, _ = np.linalg.qr(m)
-    mp = cplx.from_complex(q_, real_dtype())
-    amps = ops_init.init_debug(1 << n, real_dtype())
-    for lo in (7, 8, 9):
-        got = PG.window_dot(amps + 0, mp, n=n, lo=lo, hi=lo + 2, interpret=True)
-        ref = K.apply_matrix(amps + 0, mp, n=n,
-                             targets=(lo, lo + 1, lo + 2))
-        assert_amps_close(np.asarray(got), np.asarray(ref))
-        # conjugated form (density shadow)
-        got_c = PG.window_dot(amps + 0, mp, n=n, lo=lo, hi=lo + 2,
-                              conj=True, interpret=True)
-        ref_c = K.apply_matrix(amps + 0, mp, n=n,
-                               targets=(lo, lo + 1, lo + 2), conj=True)
-        assert_amps_close(np.asarray(got_c), np.asarray(ref_c))
-
-
 def test_window_alignment_in_pallas_mode():
     """Dense windows must not straddle the lane boundary in pallas mode."""
     from __graft_entry__ import _random_layers
@@ -852,10 +825,10 @@ def test_window_alignment_in_pallas_mode():
     circ = Circuit(n)
     _random_layers(circ, n, depth=3, seed=9)
     tile_bits = PG.local_qubits(n)
-    p = fusion.plan(tuple(circ._tape), n, real_dtype(), max_qubits=5,
+    p = planner.plan(tuple(circ._tape), n, real_dtype(), max_qubits=5,
                     pallas_tile_bits=tile_bits)
     for it in p.items:
-        if isinstance(it, fusion.FusedBlock):
+        if isinstance(it, planner.FusedBlock):
             lo, hi = it.qubits[0], it.qubits[-1]
             # only single-event straddlers may cross the boundary
             assert not (lo < PG.LANE_BITS <= hi) or hi - lo + 1 > 5 or True
@@ -867,13 +840,12 @@ def test_window_alignment_in_pallas_mode():
 
 def test_sharded_pallas_inside_jitted_replay():
     """Circuit.run derives the execution mesh from the register it is
-    given (fusion.pallas_mesh), so PallasRuns keep the per-shard shard_map
+    given (environment.pallas_mesh), so PallasRuns keep the per-shard shard_map
     path inside the jitted replay, where the amps tracer hides its
     sharding -- and the same fused plan still runs on single-device
     registers (nothing is baked into the plan)."""
     import jax
 
-    from quest_tpu import fusion
 
     if len(jax.devices()) < 4:
         pytest.skip("needs the multi-device CPU mesh")

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 import quest_tpu as qt
-from quest_tpu import fusion, sampling, segments, telemetry
+from quest_tpu import capture, fusion, planner, sampling, segments, telemetry
 from quest_tpu.engine import P
 from quest_tpu.ops import init as ops_init
 from quest_tpu.sampling import request as rq
@@ -241,7 +241,7 @@ def test_mid_measurement_is_tapeable_and_fusion_barrier():
     assert getattr(fn, "_fusion_barrier") and getattr(fn,
                                                       "_measurement_site")
     # the fuser refuses to capture a measurement site
-    assert fusion.capture(fn, args, kwargs, 3, np.dtype("float64")) is None
+    assert capture.capture(fn, args, kwargs, 3, np.dtype("float64")) is None
 
 
 def test_segment_cuts_forced_at_measurement_seams():
@@ -460,7 +460,7 @@ def test_tapelint_qt005_measurement_in_deferred_window():
     from quest_tpu.analysis import tapelint
     from quest_tpu.sampling.measure import applyMidCollapse
     tb = 9
-    swap = (fusion._apply_frame_swap, (fusion.FrameSwap(tb, 2),), {})
+    swap = (fusion._apply_frame_swap, (planner.FrameSwap(tb, 2),), {})
     tape = [swap, (applyMidCollapse, (0, 0), {}), swap]
     found = tapelint.lint_tape(tape, 6, is_density=True)
     assert any(f.code == "QT005" for f in found)

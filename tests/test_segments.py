@@ -44,7 +44,7 @@ import jax
 
 import quest_tpu as qt
 from quest_tpu import analysis as A
-from quest_tpu import fusion, segments, telemetry
+from quest_tpu import fusion, planner, segments, telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.engine import Engine, P
 from quest_tpu.ops import pallas_gates as PG
@@ -94,7 +94,7 @@ def _multi_item(n=12, dtype=np.float64, sublanes=4):
     at n <= 14, so the plan carries several PallasRuns with folded frame
     swaps -- the interesting case for segmentation."""
     c = _circuit(n)
-    p = fusion.plan(tuple(c._tape), n, np.dtype(dtype), max_qubits=3,
+    p = planner.plan(tuple(c._tape), n, np.dtype(dtype), max_qubits=3,
                     pallas_tile_bits=PG.local_qubits(n, sublanes))
     segments.stamp_plan(p, n)
     out = Circuit(n)
@@ -168,7 +168,7 @@ def test_identity_boundaries_replay_standalone_swaps():
     for stamps in ({}, {"comm_pipeline": 4}, {"seg": 0,
                                               "comm_pipeline_dcn": 2}):
         swap = (fusion._apply_frame_swap,
-                (fusion.FrameSwap(9, 2, **stamps),), {})
+                (planner.FrameSwap(9, 2, **stamps),), {})
         assert segments.identity_boundaries([swap, swap], 12) == [0, 2]
         cuts = segmented.segment_plan([swap, swap], 12, 1)
         assert cuts[0] == 0 and cuts[-1] == 2
@@ -217,7 +217,7 @@ def test_segment_cuts_greedy_coarsest_and_capped():
 
 def _frame_items(p):
     return [i for i in p.items
-            if isinstance(i, (fusion.PallasRun, fusion.FrameSwap))]
+            if isinstance(i, (planner.PallasRun, planner.FrameSwap))]
 
 
 def test_fused_stamps_segments_and_roundtrips():
@@ -260,7 +260,7 @@ def test_plancheck_skips_none_stamps():
     plan = _plan_multi()
     plan.items[:] = [                    # items no planner stamped
         dataclasses.replace(i, seg=None)
-        if isinstance(i, (fusion.PallasRun, fusion.FrameSwap)) else i
+        if isinstance(i, (planner.PallasRun, planner.FrameSwap)) else i
         for i in plan.items]
     findings = A.check_plan(plan, 12)
     assert "QT107" not in _codes(findings)

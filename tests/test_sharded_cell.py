@@ -15,7 +15,7 @@ import pytest
 import jax
 
 import quest_tpu as qt
-from quest_tpu import fusion, telemetry
+from quest_tpu import environment, fusion, planner, telemetry
 from quest_tpu.circuits import Circuit
 from quest_tpu.environment import AMP_AXIS
 
@@ -100,7 +100,7 @@ def test_counters_count_what_the_plan_says(bench, n, devices, depth):
     env = _env(devices)
     telemetry.reset()
     _, fused, plan, local = _planned(bench, n, depth, devices)
-    runs = sum(isinstance(i, fusion.PallasRun) for i in plan.items)
+    runs = sum(isinstance(i, planner.PallasRun) for i in plan.items)
     q, _ = _seeded_register(bench, n, env)
     fused.run(q)
     swaps = telemetry.counter_total("fusion_collective_swaps_total")
@@ -109,7 +109,7 @@ def test_counters_count_what_the_plan_says(bench, n, devices, depth):
     if devices == 1:
         assert swaps == 0 and sharded == 0
         return
-    stats = fusion.transpose_stats(plan, local)
+    stats = planner.transpose_stats(plan, local)
     assert swaps == stats["collective_transposes"] > 0
     assert sharded == runs > 0
     event = [e for e in telemetry.events() if e.get("name") == "fusion.plan"
@@ -130,12 +130,12 @@ def test_a_relabeling_is_collective_only_where_it_reaches_a_sharded_qubit(
     qt.initDebugState(q)
     before = np.asarray(q.amps)
     telemetry.reset()
-    fusion._apply_frame_swap(q, fusion.FrameSwap(6, 3, hi))
+    fusion._apply_frame_swap(q, planner.FrameSwap(6, 3, hi))
     assert telemetry.counter_value("pallas_pass_total",
                                    kind="frame_swap") == 1
     assert (telemetry.counter_total("fusion_collective_swaps_total")
             == collective)
-    fusion._apply_frame_swap(q, fusion.FrameSwap(6, 3, hi))   # its own inverse
+    fusion._apply_frame_swap(q, planner.FrameSwap(6, 3, hi))   # its own inverse
     np.testing.assert_array_equal(np.asarray(q.amps), before)
 
 
@@ -185,7 +185,7 @@ def test_a_frame_swap_of_a_sharded_register_is_swap_bit_blocks_to_the_bit(
     mesh = fusion._swap_mesh(q, tile_bits - k, lo2, k)
     assert (mesh is q.amps.sharding.mesh) if collective else (mesh is None)
     telemetry.reset()
-    fusion._apply_frame_swap(q, fusion.FrameSwap(tile_bits, k, lo2))
+    fusion._apply_frame_swap(q, planner.FrameSwap(tile_bits, k, lo2))
     np.testing.assert_array_equal(np.asarray(q.amps), want)
     assert q.amps.sharding.is_equivalent_to(env.sharding(1 << nsv), 2)
     assert ({s.data.shape for s in q.amps.addressable_shards}
@@ -268,7 +268,7 @@ def test_how_a_relabeling_is_stated_follows_where_the_register_lies(
 
     before = telemetry.snapshot()["counters"]
     if case.endswith("traced"):
-        with fusion.pallas_mesh(mesh):
+        with environment.pallas_mesh(mesh):
             jax.eval_shape(decide, shape_register(n, np.float32).amps)
     elif case == "traced-no-mesh":
         jax.eval_shape(decide, shape_register(n, np.float32).amps)
@@ -294,7 +294,7 @@ def test_a_sharded_plan_runs_its_collective_relabelings_per_shard(bench,
     q, _ = _seeded_register(bench, 14, env)
     fused.run(q)
     swaps = telemetry.counter_total("fusion_collective_swaps_total")
-    assert swaps == fusion.transpose_stats(plan, local)[
+    assert swaps == planner.transpose_stats(plan, local)[
         "collective_transposes"] > 0
     assert telemetry.counter_total("fusion_per_shard_swaps_total") == swaps
     telemetry.reset()

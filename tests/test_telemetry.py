@@ -236,7 +236,7 @@ def test_df_tile_mismatch_increments_fallback_not_raises(monkeypatch):
     incremented (and the ops replay through the engine) instead of
     fused_local_run raising ValueError, when a plan built with non-DF tile
     geometry replays on an f64 register taking the double-float path."""
-    from quest_tpu import fusion
+    from quest_tpu import fusion, planner
     from quest_tpu.ops import pallas_gates as PG
     from quest_tpu.ops.pallas_df import DF_SUBLANES
 
@@ -249,7 +249,7 @@ def test_df_tile_mismatch_increments_fallback_not_raises(monkeypatch):
     target = lq_df  # dense target legal for the f32 plan, not for df
     # simulate the TPU dispatch decision (CPU _mosaic_supports is
     # unconditionally True): f64 has no Mosaic lowering
-    monkeypatch.setattr(fusion, "_mosaic_supports",
+    monkeypatch.setattr(qt.precision, "_mosaic_supports",
                         lambda dtype: np.dtype(dtype) != np.dtype("float64"))
     env1 = qt.createQuESTEnv(jax.devices()[:1])
     qureg = qt.createQureg(n, env1)
@@ -258,7 +258,7 @@ def test_df_tile_mismatch_increments_fallback_not_raises(monkeypatch):
     ops = (("matrix", target, (), (), PG.HashableMatrix(X)),)
     telemetry.reset()
     fusion._apply_pallas_run(
-        qureg, fusion.PallasRun(ops, lq_f32))  # must not raise
+        qureg, planner.PallasRun(ops, lq_f32))  # must not raise
     assert telemetry.counter_value("engine_fallback_total",
                                    reason="df_tile_mismatch") == 1
     amps = np.asarray(qureg.amps)

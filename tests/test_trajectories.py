@@ -146,16 +146,23 @@ def test_fixed_seed_replay_bit_identical_f32():
 
 
 def test_fixed_seed_replay_bit_identical_sharded():
-    """The 8-device mesh replays the SAME bits as the single device, and
-    twice over the mesh is bit-stable -- the seeding contract is
-    placement-independent (counter-based threefry, no device state)."""
+    """Twice over the 8-device mesh is bit-stable (ONE lowering replayed),
+    and the mesh walks the single device's Kraus path: the draws are
+    placement-independent (counter-based threefry, no device state), so
+    every trajectory takes the same branch at every site and the states
+    agree to rounding. Not to the bit: the mesh and the single device are
+    two XLA:CPU lowerings of the program, and a branch taken otherwise
+    would differ by amplitudes of order 0.1, not by a few eps."""
     u = _eight_qubit_noisy()
     seeds = [101, 202, 303, 404]
     one = tr.run_ensemble(u, env=ENV1, seeds=seeds)
     mesh_a = tr.run_ensemble(u, env=ENV8, seeds=seeds)
     mesh_b = tr.run_ensemble(u, env=ENV8, seeds=seeds)
     assert np.array_equal(mesh_a.states, mesh_b.states)
-    assert np.array_equal(np.asarray(one.states), np.asarray(mesh_a.states))
+    states = np.asarray(one.states)
+    eps = np.finfo(states.dtype).eps
+    np.testing.assert_allclose(np.asarray(mesh_a.states), states, rtol=0,
+                               atol=16 * eps)
 
 
 def test_fixed_seed_replay_bit_identical_df(monkeypatch):

@@ -40,7 +40,7 @@ def child(name: str, n: int, one: int, pair: tuple, reps: int) -> dict:
     import jax
 
     import quest_tpu as qt
-    from quest_tpu import channels, fusion
+    from quest_tpu import channels, fusion, planner
     from quest_tpu.circuits import Circuit
     from quest_tpu.ops import pallas_gates
 
@@ -61,7 +61,7 @@ def child(name: str, n: int, one: int, pair: tuple, reps: int) -> dict:
            "targets": list(pallas_gates.op_dense_targets(op)),
            "own_tile": run.own_tile,
            "frame": [run.load_swap_k, run.load_swap_hi],
-           "terms": fusion.channel_terms([run])}
+           "terms": planner.channel_terms([run])}
     env = qt.createQuESTEnv(jax.devices()[:1])
     q = qt.createDensityQureg(n, env)      # |0><0|: the time is the pass's
     jax.block_until_ready(q.amps)

@@ -29,7 +29,7 @@ jax.config.update("jax_enable_x64", True)
 
 
 def main():
-    from quest_tpu import fusion, telemetry
+    from quest_tpu import fusion, planner, telemetry
     from quest_tpu.ops import pallas_gates as PG
     from quest_tpu.ops.pallas_df import DF_SUBLANES
     from quest_tpu.registers import Qureg
@@ -92,7 +92,7 @@ def main():
     # weak #4), each chunk's Mosaic compile time recorded by telemetry
     shell = Qureg(n, False, amps64, env=None)
     with telemetry.span("df_verify.run", n=n, ops=len(ops)):
-        fusion._apply_pallas_run(shell, fusion.PallasRun(
+        fusion._apply_pallas_run(shell, planner.PallasRun(
             ops, PG.local_qubits(n, DF_SUBLANES)))
     out = np.asarray(shell.amps)
     for k, h in telemetry.snapshot("mosaic_compile_seconds")[

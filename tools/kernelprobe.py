@@ -244,7 +244,7 @@ def op_context(n, sublanes=1 << 12, extra=8):
     one kind spread through it (PR 36's chip call 3)."""
     from __graft_entry__ import _random_layers
 
-    from quest_tpu import fusion
+    from quest_tpu import fusion, planner
     from quest_tpu.circuits import Circuit
     from quest_tpu.ops import pallas_gates as PG
 
@@ -252,7 +252,7 @@ def op_context(n, sublanes=1 << 12, extra=8):
     _random_layers(circ, n, 2)
     run = next(i for i in fusion.plan_from_tape(circ.fused(
         max_qubits=5, pallas=True, dtype=np.float32)._tape).items
-        if isinstance(i, fusion.PallasRun))
+        if isinstance(i, planner.PallasRun))
     base = list(PG._fold_zone_ops(run.ops, run.tile_bits))
     timed, makers = _op_probe(n, sublanes)
     t_base = timed(base)

@@ -129,7 +129,7 @@ def main() -> int:
     import bench
     import quest_tpu as qt
     from quest_tpu.engine import Engine
-    from quest_tpu.engine.params import _pack_rows, bind
+    from quest_tpu.params import _pack_rows, bind
 
     platform = jax.devices()[0].platform
     if platform != "tpu" and not a.rehearse:

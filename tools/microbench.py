@@ -6,7 +6,6 @@ Times candidate HBM passes at 2^26 amplitudes on the live chip:
                  grid-bit controls, parity)
   - lane_run:    current lane-folded run (reference point, ~2.4 ms)
   - einsum_win:  dense 5q window at lo>=17 via the engine einsum (~5.6 ms)
-  - window_dot:  same window via the Pallas MXU dot
   - elementwise: trivial scale pass = HBM roofline floor
 """
 
@@ -122,9 +121,8 @@ def main():
 
     amps = timeit(srun, amps, label="sublane10")
 
-    # --- dense 5q window at lo >= 17 (einsum engine vs window_dot) --------
+    # --- dense 5q window at lo >= 17 through the einsum engine -------------
     from quest_tpu.ops import apply as K
-    from quest_tpu.ops.pallas_gates import window_dot
 
     rng = np.random.RandomState(0)
     u, _ = np.linalg.qr(rng.randn(32, 32) + 1j * rng.randn(32, 32))
@@ -135,11 +133,6 @@ def main():
         return K.apply_matrix(x, m, n=n, targets=targ)
 
     amps = timeit(ein, amps, label="einsum_win5")
-
-    def wdot(x):
-        return window_dot(x, m, n=n, lo=n - 5, hi=n - 1)
-
-    amps = timeit(wdot, amps, label="window_dot5")
 
 
 if __name__ == "__main__":

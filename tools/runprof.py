@@ -49,7 +49,7 @@ def timeit(fn, amps, reps=10):
 def main():
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 26
     from __graft_entry__ import _random_layers
-    from quest_tpu import fusion, telemetry
+    from quest_tpu import fusion, planner, telemetry
     from quest_tpu.circuits import Circuit
     from quest_tpu.ops.pallas_gates import (_fold_zone_ops, local_qubits,
                                             swap_bit_blocks)
@@ -58,13 +58,13 @@ def main():
     circ = Circuit(n)
     _random_layers(circ, n, 8)
     tb = local_qubits(n)
-    p = fusion.plan(tuple(circ._tape), n, np.dtype("float32"), 5,
+    p = planner.plan(tuple(circ._tape), n, np.dtype("float32"), 5,
                     pallas_tile_bits=tb)
 
     amps = jnp.zeros((2, 1 << n), jnp.float32).at[0, 0].set(1.0)
     total = 0.0
     for i, item in enumerate(p.items):
-        if isinstance(item, fusion.PallasRun):
+        if isinstance(item, planner.PallasRun):
             folded = _fold_zone_ops(item.ops, tb)
             comp = Counter(o[0] for o in folded)
             route = fusion._route(Qureg(n, False, amps, env=None), item)
@@ -85,7 +85,7 @@ def main():
                   f"ld={item.load_swap_k}{'f' if route.fold_load else ''} "
                   f"st={item.store_swap_k}{'f' if route.fold_store else ''}"
                   f" -> {dict(comp)}")
-        elif isinstance(item, fusion.FrameSwap):
+        elif isinstance(item, planner.FrameSwap):
             with telemetry.span("runprof.item", index=i, kind="swap"):
                 dt, amps = timeit(
                     lambda x: swap_bit_blocks(x, n=n,
