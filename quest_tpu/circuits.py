@@ -326,7 +326,7 @@ class Circuit:
 
     def _replay_fn(self, lifted, lo: int = 0, hi: int | None = None):
         """The replay body behind :meth:`as_fn` (``lifted=None``) and the
-        parameterized executables (``lifted`` an params.LiftedTape):
+        parameterized executables (``lifted`` a params.LiftedTape):
         with a lifted tape the returned ``fn(amps, values)`` substitutes the
         bound -- typically traced -- scalars into the slotted entries before
         each application, so gate matrices assemble from runtime values

@@ -706,30 +706,17 @@ def _dot_generals(jaxpr, state, found):
     return found
 
 
-def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
+def _grad_batch_lowered(one_chip, monkeypatch):
     """``ansatz20.grad-closed8``'s batch program
     (``jit_qt_engine_vmap_sv_n20_g202_b8``: the 20-qubit depth-4 ansatz,
-    six Pauli strings, eight lanes under ``vmap``) for the described chip.
-
-    What ONE lane's reduce holds, by its jaxpr: the backward half walks the
-    30 blocks of the tape's dense plan (PR 45), 26 of them dense windows
-    undone by one GEMM on ``phi`` and one on ``lambda`` (the four
-    diagonals are elementwise passes), and harvests all 160 derivatives
-    from 12 window contractions; the costate's 68 one-qubit Pauli
-    applications stand as they did (the gate walk held 384 applications
-    there and no contraction). Then the chip compiler's
-    ``memory_analysis`` of the whole batch program: the gate walk read
-    10.6 GB of temporaries and 3.24 GB of generated code (PERF.md section
-    7, PR 44), and the ceilings here stand under 60% of both. The lanes
-    are steered to ``vmap`` as the chip steers them (``jax.default_backend``
-    reads ``tpu`` there; the CPU runs them as a ``lax.map`` scan). And
-    what it hands back: one ``(8, 321)`` array (PR 46). About four
-    minutes."""
+    six Pauli strings, eight lanes under ``vmap``) lowered for the
+    described chip: ``(lowered, reduce, n, lanes)``. The lanes are steered
+    to ``vmap`` as the chip steers them (``jax.default_backend`` reads
+    ``tpu`` there; the CPU runs them as a ``lax.map`` scan)."""
     import sys
 
     import quest_tpu as qt
     from quest_tpu.engine import Engine, P as Param
-    from quest_tpu.gradients import apply_hamiltonian
     from quest_tpu.parallel import scheduler as _dist
 
     bench = os.path.join(os.path.dirname(os.path.dirname(
@@ -750,22 +737,6 @@ def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
     try:
         grad = engine.grad_engine()
         reduce = grad._finalize
-        state = 2 << n
-        amps = jax.ShapeDtypeStruct((2, 1 << n), jnp.float32)
-        values = tuple(jax.ShapeDtypeStruct((), jnp.float32)
-                       for _ in range(reduce.num_slots))
-
-        def count(fn, *args):
-            return _dot_generals(jax.make_jaxpr(fn)(*args).jaxpr, state,
-                                 {"application": 0, "contraction": 0})
-
-        h_codes, h_coeffs = reduce.hamiltonian
-        costate = count(lambda a: apply_hamiltonian(
-            a, codes=h_codes, coeffs=h_coeffs, num_qubits=n), amps)
-        assert costate == {"application": 68, "contraction": 0}
-        assert count(reduce, amps, values) == {
-            "application": 68 + 2 * 26, "contraction": 12}
-
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         batch = grad._execB()
         monkeypatch.undo()
@@ -778,15 +749,58 @@ def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
                  for _, cols in grad._packs]
         with _dist.explicit_mesh(None), environment.pallas_mesh(None):
             lowered = jitted.lower(*args)
-            compiled = lowered.compile()
     finally:
         engine.close()
+    return lowered, reduce, n, lanes
+
+
+def test_grad_batch_program_walks_blocks_and_fits(one_chip, monkeypatch):
+    """What ONE lane's reduce of ``ansatz20.grad-closed8``'s batch program
+    holds, by its jaxpr: the backward half walks the 30 blocks of the
+    tape's dense plan (PR 45), 26 of them dense windows undone by one GEMM
+    on ``phi`` and one on ``lambda`` (the four diagonals are elementwise
+    passes), and harvests all 160 derivatives from 12 window contractions;
+    the costate's 68 one-qubit Pauli applications stand as they did (the
+    gate walk held 384 applications there and no contraction). And what
+    the program lowered for the described chip carries: its name, and ONE
+    ``(8, 321)`` array handed back a launch (PR 46). No backend compile:
+    whether it FITS is :func:`test_grad_batch_program_fits_the_chip`."""
+    from quest_tpu.gradients import apply_hamiltonian
+
+    lowered, reduce, n, lanes = _grad_batch_lowered(one_chip, monkeypatch)
+    state = 2 << n
+    amps = jax.ShapeDtypeStruct((2, 1 << n), jnp.float32)
+    values = tuple(jax.ShapeDtypeStruct((), jnp.float32)
+                   for _ in range(reduce.num_slots))
+
+    def count(fn, *args):
+        return _dot_generals(jax.make_jaxpr(fn)(*args).jaxpr, state,
+                             {"application": 0, "contraction": 0})
+
+    h_codes, h_coeffs = reduce.hamiltonian
+    costate = count(lambda a: apply_hamiltonian(
+        a, codes=h_codes, coeffs=h_coeffs, num_qubits=n), amps)
+    assert costate == {"application": 68, "contraction": 0}
+    assert count(reduce, amps, values) == {
+        "application": 68 + 2 * 26, "contraction": 12}
     # what the program hands back a launch (PR 46): ONE array, a row a lane
     # of the value, 160 slot derivatives and 160 named ones; the lanes' 321
     # numbers as outputs of their own were 2,568 arrays
     assert [out.shape for out in jax.tree_util.tree_leaves(
         lowered.out_info)] == [(lanes, 1 + 2 * reduce.num_slots)]
-    mem = compiled.memory_analysis()
+
+
+@pytest.mark.slow
+def test_grad_batch_program_fits_the_chip(one_chip, monkeypatch):
+    """The chip compiler's ``memory_analysis`` of the whole batch program:
+    the gate walk read 10.6 GB of temporaries and 3.24 GB of generated
+    code (PERF.md section 7, PR 44), and the ceilings here stand under 60%
+    of both. Marked ``slow``: the backend compile of this ONE program is
+    about four minutes on a sandbox core, a quarter of tier-1's limit
+    (ISSUE 47), and the cell itself, which the driver runs on the chip on
+    every PR, is what notices a batch program that stops fitting."""
+    lowered = _grad_batch_lowered(one_chip, monkeypatch)[0]
+    mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes < 1.6e9, mem.temp_size_in_bytes
     assert mem.generated_code_size_in_bytes < 1.6e9, \
         mem.generated_code_size_in_bytes

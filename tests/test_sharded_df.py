@@ -363,7 +363,9 @@ def test_sharded_df_density_kraus_parity(df_route):
         f(rho_ref, *a, **kw)
     np.testing.assert_allclose(np.asarray(rho.amps),
                                np.asarray(rho_ref.amps), atol=ATOL_DF_CPU)
-    assert abs(qt.calcTotalProb(rho) - 1.0) < 1e-12
+    # the trace is a sum of 2^n diagonal elements, each held to ATOL_DF_CPU
+    # (measured 1.4e-7 here; 1e-12 is the chip's: chip_smoke.py's df phase)
+    assert abs(qt.calcTotalProb(rho) - 1.0) < (1 << n) * ATOL_DF_CPU
 
 
 def test_df_tile_mismatch_counts_on_sharded_plans(df_route):

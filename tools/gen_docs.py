@@ -42,8 +42,6 @@ MODULES = [
     "quest_tpu.analysis.surface",
     "quest_tpu.analysis.conformance",
     "quest_tpu.engine",
-    "quest_tpu.engine.params",
-    "quest_tpu.engine.cache",
     "quest_tpu.engine.engine",
     "quest_tpu.engine.pool",
     "quest_tpu.engine.admission",
@@ -69,7 +67,12 @@ MODULES = [
     "quest_tpu.gradients.adjoint",
     "quest_tpu.gradients.expectation",
     "quest_tpu.gradients.shift",
+    "quest_tpu.events",
+    "quest_tpu.capture",
+    "quest_tpu.planner",
     "quest_tpu.fusion",
+    "quest_tpu.params",
+    "quest_tpu.cache",
     "quest_tpu.qasm",
     "quest_tpu.reporting",
     "quest_tpu.checkpoint",
