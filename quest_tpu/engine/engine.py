@@ -141,6 +141,8 @@ from .. import cache as _cache
 from .. import fusion
 from .. import planner
 from .. import telemetry
+from ..analysis.diagnostics import (emit_findings, make_finding,
+                                    parse_env_int)
 from ..circuits import Circuit, named_program
 from ..environment import pallas_mesh
 from ..gradients import grad_reduce
@@ -157,7 +159,8 @@ from ..resilience import sync as _sync
 from ..resilience import watchdog as _watchdog
 from ..resilience.errors import (PoisonedRequestFault, QuESTBackpressureError,
                                  QuESTCancelledError, QuESTHangError,
-                                 QuESTIntegrityError, QuESTTimeoutError)
+                                 QuESTIntegrityError, QuESTTimeoutError,
+                                 TransientFault)
 from ..validation import QuESTError
 
 __all__ = ["Engine", "HEALTH_STATES"]
@@ -227,7 +230,6 @@ def async_depth_default() -> int:
     dispatch (the batcher drains each batch before issuing another -- the
     A/B baseline the bench compares against). Malformed or negative values
     fall back through :func:`parse_env_int` with a QT310 warn-once."""
-    from ..analysis.diagnostics import parse_env_int
     return parse_env_int(_ASYNC_ENV, 2, minimum=0, code="QT310",
                          warned=_ASYNC_ENV_WARNED,
                          noun="async completion-ring depth")
@@ -242,7 +244,6 @@ def _env_queue_max() -> int:
     try:
         return max(0, int(raw))
     except ValueError:
-        from ..analysis.diagnostics import emit_findings, make_finding
         emit_findings([make_finding(
             "QT303", f"QUEST_ENGINE_QUEUE_MAX={raw!r} is not numeric; "
             "using the default", "engine.Engine")])
@@ -917,7 +918,6 @@ class Engine:
                     # ring): the bisection ladder below re-dispatches it,
                     # so healthy requests still complete and attribution
                     # never leaks onto a different in-flight batch
-                    from ..resilience.errors import TransientFault
                     raise TransientFault("engine.dispatch", kind)
                 if ringable:
                     # ring admission runs OUTSIDE the dispatch watchdog:

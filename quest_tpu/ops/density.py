@@ -92,7 +92,7 @@ def apply_channel(amps, superop, *, n: int, targets: tuple[int, ...],
     relocation-planner path as gates (the analogue of the reference's
     half-chunk depolarising/damping exchanges,
     QuEST_cpu_distributed.c:535-868) and show up in the plan stats."""
-    from ..parallel import scheduler as _dist
+    from ..parallel import scheduler as _dist  # lazy: parallel stands on ops
 
     sched = _dist.active()
     if sched is not None:
@@ -188,7 +188,7 @@ def _kraus_sum_pallas_run(amps, *, n, t, c, hi, terms, sublanes):
     _kraus_sum_pallas); ``hi`` is the grid-bit column position relocated
     into the top tile slot by the folded load/store swaps. ``sublanes``
     pins the tile geometry to the ``lq`` the caller planned against."""
-    from . import pallas_gates as PG
+    from . import pallas_gates as PG  # lazy: Pallas
 
     k = 0 if hi is None else 1
     return PG.fused_local_run(
@@ -237,7 +237,7 @@ def dephase_factors_2q(prob: float) -> np.ndarray:
 def _diag_dispatch(amps, d, *, n, targets):
     """Dephasing diagonals via the explicit scheduler when one is active
     (comm-free by construction, counted in its plan stats)."""
-    from ..parallel import scheduler as _dist
+    from ..parallel import scheduler as _dist  # lazy: parallel stands on ops
 
     sched = _dist.active()
     if sched is not None:
